@@ -1,0 +1,828 @@
+"""The quickest proof that the system still starts on the chip.
+
+``python chip_smoke.py`` drives the training path once, through the entry
+points a user calls, at BERT-base width on one TPU chip, and checks what
+comes out by the repo's own means:
+
+* **eager** — ``models.bert.BertForPretraining`` through
+  ``dygraph.functional.functional_loss`` and bench.py's fused-Adam
+  two-program step: loss falls, the grad program's HLO holds Mosaic calls,
+  outputs live on the TPU.
+* **executor** — ``models.static_graphs.build_bert_train_program`` run by
+  ``fluid.Executor(fluid.TPUPlace(0))`` as a plain program, then as a
+  ``CompiledProgram`` with the AMP plane and the kernel tier.
+* **kernels** — every kernel in ``ops/pallas_kernels.__all__`` compiled by
+  Mosaic and run once through its op lowering against an XLA reference.
+* **cache** — compile seconds, and the files in the compile cache before and
+  after (``--expect-warm``: a second run must add none).
+
+``--chips 4`` runs, instead, the executor program data-parallel over four
+real devices and one ``parallel/hybrid`` step on a pp2 x tp2 mesh.
+
+One process, no children, no handler around a leg: any failure is a non-zero
+exit.  It exits non-zero, printing no result, where JAX finds no TPU — unless
+``--tiny`` is given, which runs toy sizes on any backend as a control-flow
+check for the sandbox and the tests and is NOT a chip result.  The last line
+of a passing run is one JSON object naming the device as JAX reports it.
+"""
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import math
+import os
+import time
+
+import numpy as np
+
+# (vocab, hidden, layers, heads, ffn, seq, batch): published BERT-base width
+# and depth; --tiny keeps the structure and shrinks every number.
+FULL = dict(vocab=30522, hidden=768, layers=12, heads=12, ffn=3072, seq=128,
+            batch=64)
+TINY = dict(vocab=512, hidden=128, layers=2, heads=2, ffn=256, seq=128,
+            batch=4)
+DROPOUT = 0.1
+STEPS = 5
+
+
+def say(msg):
+    print(f"[smoke] {msg}", flush=True)
+
+
+# ---------------------------------------------------------------------------
+# compile accounting: jax reports every backend compile (cache hit or not)
+# ---------------------------------------------------------------------------
+
+class CompileLog:
+    """Seconds jax spent in backend compiles and how many were served from
+    the persistent cache, from jax's own monitoring events."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.seconds = 0.0
+        self.compiles = 0
+        self.cache_hits = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+            self.compiles += 1
+            if secs >= 1.0:
+                say(f"  compiled one executable in {secs:.1f}s")
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    @contextlib.contextmanager
+    def leg(self, name, results):
+        s0, c0, h0 = self.seconds, self.compiles, self.cache_hits
+        t0 = time.perf_counter()
+        say(f"== {name}")
+        out = results[name] = {}
+        yield out
+        out["wall_s"] = round(time.perf_counter() - t0, 1)
+        out["compile_s"] = round(self.seconds - s0, 1)
+        out["compiles"] = self.compiles - c0
+        out["cache_hits"] = self.cache_hits - h0
+        say(f"== {name} ok: {json.dumps(out)}")
+
+
+def cache_files(cache_dir):
+    return sum(len(files) for _, _, files in os.walk(cache_dir))
+
+
+def on_device(x, device):
+    """Every shard of ``x`` lives on ``device``."""
+    return {s.device for s in x.addressable_shards} == {device}
+
+
+def check_losses(losses, what):
+    assert all(math.isfinite(v) for v in losses), (what, losses)
+    assert losses[-1] < losses[0], \
+        f"{what}: loss did not fall over {len(losses)} steps: {losses}"
+
+
+# ---------------------------------------------------------------------------
+# leg: eager front door
+# ---------------------------------------------------------------------------
+
+def leg_eager(size, device, out):
+    """BertForPretraining -> functional_loss -> bench.py's two-program
+    fused-Adam step, bf16 autocast, dropout on."""
+    import jax
+    import jax.numpy as jnp
+    import bench
+
+    jstep, state, n_params = bench.build_train_step(
+        size["vocab"], size["hidden"], size["layers"], size["heads"],
+        size["ffn"], size["seq"], size["batch"])
+    rng = np.random.RandomState(0)
+    shape = (size["batch"], size["seq"])
+    ids = jnp.asarray(rng.randint(0, size["vocab"], shape).astype("int32"))
+    mlm = jnp.asarray(rng.randint(0, size["vocab"], shape).astype("int32"))
+    nsp = jnp.asarray(rng.randint(0, 2, shape[:1]).astype("int32"))
+
+    t0 = time.perf_counter()
+    state, loss = jstep(state, ids, mlm, nsp)           # warm-up: compiles
+    jax.block_until_ready(loss)
+    out["first_step_s"] = round(time.perf_counter() - t0, 1)
+
+    losses = []
+    t0 = time.perf_counter()
+    for _ in range(STEPS):
+        state, loss = jstep(state, ids, mlm, nsp)
+        losses.append(loss)
+    jax.block_until_ready((state, loss))
+    out["step_ms"] = round((time.perf_counter() - t0) / STEPS * 1e3, 2)
+    assert on_device(loss, device), loss.sharding
+    assert all(on_device(p, device) for p in jstep.params_of(state))
+    losses = [float(v) for v in losses]
+    check_losses(losses, "eager")
+    out["losses"] = [round(v, 4) for v in losses]
+    out["params"] = n_params
+
+    # the dropout epilogues took the Pallas kernels, not the bernoulli
+    # lowering: Mosaic custom calls in the grad program's compiled HLO
+    # (this lowering is a cache hit — the executable was just built)
+    from paddle_tpu.fluid import device_stats
+    from paddle_tpu.ops.pallas_preflight import mosaic_call_count
+    args = device_stats.sds_tree((jstep.params_of(state), ids, mlm, nsp))
+    n = mosaic_call_count(jstep.grad_program.lower(*args).compile())
+    out["mosaic_calls"] = n
+    if device.platform == "tpu":
+        assert n > 0, "no Mosaic custom call in the BERT grad program"
+
+
+# ---------------------------------------------------------------------------
+# leg: Program -> passes -> Executor
+# ---------------------------------------------------------------------------
+
+def build_static_bert(size):
+    from paddle_tpu.fluid.framework import reset_unique_name
+    from paddle_tpu.models.static_graphs import build_bert_train_program
+    reset_unique_name()
+    return build_bert_train_program(
+        vocab=size["vocab"], hidden=size["hidden"], heads=size["heads"],
+        seq=size["seq"], layers=size["layers"], dropout=DROPOUT)
+
+
+def static_feed(size):
+    from paddle_tpu.models.static_graphs import bert_demo_feed
+    return bert_demo_feed(np.random.RandomState(1), batch=size["batch"],
+                          seq=size["seq"], vocab=size["vocab"])
+
+
+def run_executor(program, startup, loss, feed, device, check_param=None):
+    """startup + first step (compiles) + STEPS timed steps in a fresh scope,
+    then ``check_param(a trained parameter)`` while the state is still live
+    (default: it sits on ``device``).  Returns (losses, first_step_s,
+    step_ms)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.core import Scope, scope_guard
+
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    with scope_guard(Scope()):
+        exe.run(startup)
+        t0 = time.perf_counter()
+        first, = exe.run(program, feed=feed, fetch_list=[loss])
+        first_s = time.perf_counter() - t0
+        losses = [float(np.asarray(first).ravel()[0])]
+        t0 = time.perf_counter()
+        for _ in range(STEPS):
+            lv, = exe.run(program, feed=feed, fetch_list=[loss],
+                          return_numpy=False)     # a lazy FetchHandle
+            losses.append(lv)
+        lv.block_until_ready()
+        step_ms = (time.perf_counter() - t0) / STEPS * 1e3
+        assert device in {s.device for s in lv.raw.addressable_shards}
+        losses = [float(np.asarray(v).ravel()[0]) for v in losses]
+        # TPUPlace(0).jax_device() may resolve to a CPU device where no
+        # accelerator exists (fluid/core.py): check where state really is
+        scope = fluid.global_scope()
+        main = getattr(program, "_program", program)
+        w = scope.find_var(main.all_parameters()[0].name)
+        if check_param is None:
+            assert on_device(w, device), w.sharding
+        else:
+            check_param(w)
+    exe.close()
+    return losses, round(first_s, 1), round(step_ms, 2)
+
+
+def executor_plain(size, device, out):
+    """The plain (unrewritten, f32) program on one chip; returns its
+    losses, the reference the rewritten and the sharded runs track."""
+    main, startup, loss = build_static_bert(size)
+    losses, first_s, step_ms = run_executor(main, startup, loss,
+                                            static_feed(size), device)
+    check_losses(losses, "executor plain")
+    out.update({"first_step_s": first_s, "step_ms": step_ms,
+                "losses": [round(v, 4) for v in losses]})
+    return losses
+
+
+def leg_executor(size, device, out):
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.dygraph import base as dybase
+    from paddle_tpu.fluid import trace
+    from paddle_tpu.fluid.framework import in_dygraph_mode
+
+    if in_dygraph_mode():           # the eager leg leaves eager mode on
+        dybase.disable_dygraph()
+    out["plain"] = {}
+    losses = executor_plain(size, device, out["plain"])
+    say(f"  plain program: {json.dumps(out['plain'])}")
+
+    feed = static_feed(size)
+    main, startup, loss = build_static_bert(size)
+    bs = fluid.BuildStrategy()
+    bs.amp = True
+    bs.kernel_tier = True
+    m = trace.metrics()
+    r0 = {p: m.counter(f"kernel_tier.{p}.rewrites").value
+          for p in ("fuse_attention", "fuse_optimizer")}
+    tier_losses, first_s, step_ms = run_executor(
+        fluid.CompiledProgram(main, build_strategy=bs), startup, loss, feed,
+        device)
+    check_losses(tier_losses, "executor amp+kernel_tier")
+    rewrites = {p: int(m.counter(f"kernel_tier.{p}.rewrites").value - r0[p])
+                for p in r0}
+    assert rewrites["fuse_attention"] == size["layers"], rewrites
+    assert rewrites["fuse_optimizer"] >= 1, rewrites
+    types = [op.type for op in main.global_block().ops]
+    assert "fused_adam" in types and "softmax" not in types
+    # same weights, same batch: the bf16 rewritten program starts where
+    # the plain one does
+    assert abs(tier_losses[0] - losses[0]) <= 0.05 * abs(losses[0]), \
+        (tier_losses[0], losses[0])
+    out["amp_kernel_tier"] = {
+        "first_step_s": first_s, "step_ms": step_ms,
+        "losses": [round(v, 4) for v in tier_losses], "rewrites": rewrites}
+    say(f"  amp + kernel tier: {json.dumps(out['amp_kernel_tier'])}")
+
+
+# ---------------------------------------------------------------------------
+# leg: kernel roll-call, every kernel through its op lowering
+# ---------------------------------------------------------------------------
+
+def lower_op(op_type, attrs=None):
+    """``fn(ins, key) -> outs`` for one op lowering, ins/outs as
+    {slot: [arrays]} like the executor hands them over."""
+    import jax
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    def fn(ins, key):
+        return get_op(op_type).fn(ins, dict(attrs or {}),
+                                  LoweringContext(base_key=key))
+    return jax.jit(fn)
+
+
+def run_lowered(jfn, *args, expect_mosaic):
+    """Compile, count Mosaic calls, run.  ``expect_mosaic`` is None on a
+    backend without the kernels, else whether the Pallas path must
+    (True) or must not (False) have been taken."""
+    from paddle_tpu.ops.pallas_preflight import mosaic_call_count
+    compiled = jfn.lower(*args).compile()
+    n = mosaic_call_count(compiled)
+    if expect_mosaic is True:
+        assert n > 0, "the op lowering did not take its Pallas kernel"
+    if expect_mosaic is False:
+        assert n == 0, "the op lowering took a kernel past its gate"
+    return compiled(*args), n
+
+
+def rel_err(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
+
+
+def roll_flash(tiny, tpu, key):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.attention import _reference_attention
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    b, h, t, d = (1, 2, 1024, 64) if tiny else (8, 12, 1024, 64)
+    ks = jax.random.split(key, 4)
+    q, k, v = (jax.random.normal(kk, (b, h, t, d), jnp.bfloat16)
+               for kk in ks[:3])
+    # additive padding mask, [B, 1, 1, T]: the last quarter of the keys of
+    # every odd batch row is masked out
+    pad = (jnp.arange(t)[None, :] >= (3 * t) // 4) \
+        & (jnp.arange(b)[:, None] % 2 == 1)
+    mask = jnp.where(pad, -10000.0, 0.0).astype(jnp.float32)[:, None, None]
+    w = jax.random.normal(ks[3], (b, h, t, d), jnp.float32)
+    scale = d ** -0.5
+
+    def op(q, k, v):
+        ins = {"Q": [q], "K": [k], "V": [v], "Mask": [mask]}
+        return get_op("fused_multihead_attention").fn(
+            ins, {"scale": scale}, LoweringContext(base_key=key))["Out"][0]
+
+    def ref(q, k, v):
+        f32 = jnp.float32
+        return _reference_attention(q.astype(f32), k.astype(f32),
+                                    v.astype(f32), mask, scale, False)
+
+    def fwd_bwd(f):
+        def g(q, k, v):
+            out, vjp = jax.vjp(f, q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(g)
+
+    got, n = run_lowered(fwd_bwd(op), q, k, v, expect_mosaic=tpu)
+    want = fwd_bwd(ref)(q, k, v)
+    errs = [rel_err(a, b_) for a, b_ in zip(got, want)]
+    assert max(errs) < 3e-2, errs            # bf16 operands, f32 reference
+    return {"shape": [b, h, t, d], "mosaic": n,
+            "rel_err_out_dq_dk_dv": [round(e, 4) for e in errs]}
+
+
+def roll_dropout(tiny, tpu, key):
+    """dropout / fused_dropout_add / fused_act_dropout at both shapes and
+    both dtypes: keep rate within 1% of 1-p, kept values exact, and the
+    backward's regenerated mask identical to the forward's."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    p = DROPOUT
+    shapes = [(256, 768), (2, 2, 128, 128)] if tiny \
+        else [(8192, 768), (64, 12, 128, 128)]
+    attrs = {"dropout_prob": p,
+             "dropout_implementation": "upscale_in_train"}
+
+    def make_case(key, shape, dtype):
+        """Inputs and the f32 gelu references.  0.5 <= |x| <= 3 keeps
+        gelu(x) and its derivative away from zero (the in-kernel erf
+        polynomial rounds gelu to exactly 0 below about -4), so "the
+        output is non-zero" reads a keep-mask back exactly."""
+        kx, kr = jax.random.split(key)
+        x = jax.random.normal(kx, shape, jnp.float32)
+        x = (jnp.sign(x) * (0.5 + jnp.minimum(jnp.abs(x), 2.5))
+             ).astype(dtype)
+        r = jax.random.normal(kr, shape, jnp.float32).astype(dtype)
+        xf = x.astype(jnp.float32)
+        cdf = 0.5 * (1.0 + jax.lax.erf(xf / math.sqrt(2.0)))
+        pdf = jnp.exp(-0.5 * xf * xf) / math.sqrt(2.0 * math.pi)
+        return x, r, xf * cdf, cdf + xf * pdf
+
+    def fwd_bwd(op_type, extra, slots, op_key):
+        """jitted (Out, Mask or None, input cotangents of ones)."""
+        def f(*xs):
+            outs = get_op(op_type).fn(
+                {s: [a] for s, a in zip(slots, xs)},
+                dict(attrs, **extra), LoweringContext(base_key=op_key))
+            return outs["Out"][0], outs.get("Mask", [None])[0]
+
+        def g(*xs):
+            out, vjp, mask = jax.vjp(f, *xs, has_aux=True)
+            return out, mask, vjp(jnp.ones_like(out))
+        return jax.jit(g)
+
+    def f32(a):
+        return np.asarray(a, np.float32)
+
+    def check_keep(keep, what):
+        rate = float(keep.mean())
+        assert abs(rate - (1 - p)) < 0.01, \
+            f"{what} {shape} {dtype}: keep rate {rate:.4f}, want {1 - p}"
+
+    rows = []
+    for shape in shapes:
+        for dtype in ("float32", "bfloat16"):
+            tol = 1e-5 if dtype == "float32" else 2e-2
+            kc, kk = jax.random.split(jax.random.fold_in(key, len(rows)))
+            x, r, gelu, dgelu = jax.jit(
+                make_case, static_argnums=(1, 2))(kc, shape, dtype)
+            xf, rf, gelu, dgelu = f32(x), f32(r), f32(gelu), f32(dgelu)
+
+            # -- dropout: Out, Mask, dX
+            (out, mask, (dx,)), n1 = run_lowered(
+                fwd_bwd("dropout", {}, ("X",), kk), x, expect_mosaic=tpu)
+            keep = f32(mask) != 0
+            check_keep(keep, "dropout")
+            assert np.allclose(f32(out), np.where(keep, xf / (1 - p), 0),
+                               rtol=tol, atol=tol)
+            assert ((f32(dx) != 0) == keep).all(), "dropout bwd mask"
+
+            # -- fused_dropout_add: Out = dropout(X) + Residual; the mask
+            # is read off the backward and must explain the forward
+            (out, _, (dx, dr)), n2 = run_lowered(
+                fwd_bwd("fused_dropout_add", {}, ("X", "Residual"), kk),
+                x, r, expect_mosaic=tpu)
+            keep = f32(dx) != 0
+            check_keep(keep, "fused_dropout_add")
+            assert np.allclose(f32(out),
+                               np.where(keep, xf / (1 - p), 0) + rf,
+                               rtol=tol, atol=2 * tol)
+            assert (f32(dr) == 1).all()
+
+            # -- fused_act_dropout (gelu): Out = dropout(gelu(X))
+            (out, _, (dx,)), n3 = run_lowered(
+                fwd_bwd("fused_act_dropout", {"act": "gelu"}, ("X",), kk),
+                x, expect_mosaic=tpu)
+            keep = f32(out) != 0
+            check_keep(keep, "fused_act_dropout")
+            assert np.allclose(f32(out), np.where(keep, gelu / (1 - p), 0),
+                               rtol=tol, atol=tol)
+            assert ((f32(dx) != 0) == keep).all(), "act_dropout bwd mask"
+            assert np.allclose(f32(dx), np.where(keep, dgelu / (1 - p), 0),
+                               rtol=10 * tol, atol=10 * tol)
+            rows.append({"shape": list(shape), "dtype": dtype,
+                         "keep_rate": round(float(keep.mean()), 4),
+                         "mosaic": n1 + n2 + n3})
+    return {"cases": rows, "mosaic": sum(r["mosaic"] for r in rows)}
+
+
+def bert_base_bucket(tiny):
+    """Parameter shapes of one optimizer bucket the size of BERT-base."""
+    if tiny:
+        return [(512, 128), (128, 256), (256, 128), (128,), (3,)]
+    return ([(30522, 768), (512, 768)]
+            + [(768, 768)] * 48 + [(768, 3072)] * 12 + [(3072, 768)] * 12
+            + [(768,)] * 100 + [(3072,)] * 12)
+
+
+def roll_optimizers(tiny, tpu, key):
+    import jax
+    import jax.numpy as jnp
+
+    shapes = bert_base_bucket(tiny)
+    n = len(shapes)
+
+    @jax.jit
+    def make(key):
+        ks = jax.random.split(key, 4 * n)
+        rnd = lambda i, s: jax.random.normal(ks[i], s, jnp.float32)  # noqa
+        return ([rnd(i, s) * 0.02 for i, s in enumerate(shapes)],
+                [rnd(n + i, s) for i, s in enumerate(shapes)],
+                [rnd(2 * n + i, s) * 0.1 for i, s in enumerate(shapes)],
+                [jnp.abs(rnd(3 * n + i, s)) * 0.01
+                 for i, s in enumerate(shapes)])
+
+    @jax.jit
+    def worst_rel_err(got, want):
+        """Largest per-array max-abs error over max-abs value, on device
+        (the bucket is 1.3 GB a side — not worth a trip to the host)."""
+        assert jax.tree_util.tree_structure(got) \
+            == jax.tree_util.tree_structure(want)
+        errs = [jnp.max(jnp.abs(a - b)) / jnp.maximum(
+            jnp.max(jnp.abs(b)), 1e-30) for a, b in zip(
+                jax.tree_util.tree_leaves(got),
+                jax.tree_util.tree_leaves(want)) if a.shape == b.shape]
+        assert len(errs) == len(jax.tree_util.tree_leaves(want))
+        return jnp.max(jnp.stack(errs))
+
+    ps, gs, ms, vs = make(key)
+    lr = jnp.asarray([1e-3], jnp.float32)
+    b1, b2, eps = 0.9, 0.999, 1e-8
+    # per-param beta-pow accumulators, as if each param were at step i%7+1
+    b1p = [jnp.asarray([b1 ** (i % 7 + 1)], jnp.float32) for i in range(n)]
+    b2p = [jnp.asarray([b2 ** (i % 7 + 1)], jnp.float32) for i in range(n)]
+
+    ins = {"Param": ps, "Grad": gs, "Moment1": ms, "Moment2": vs,
+           "Beta1Pow": b1p, "Beta2Pow": b2p, "LearningRate": [lr]}
+    outs, n_adam = run_lowered(
+        lower_op("fused_adam", {"beta1": b1, "beta2": b2, "epsilon": eps}),
+        ins, key, expect_mosaic=tpu)
+
+    @jax.jit
+    def adam_ref(ps, gs, ms, vs):
+        res = []
+        for p, g, m, v, p1, p2 in zip(ps, gs, ms, vs, b1p, b2p):
+            lrt = lr[0] * jnp.sqrt(1 - p2[0]) / (1 - p1[0])
+            m2 = b1 * m + (1 - b1) * g
+            v2 = b2 * v + (1 - b2) * jnp.square(g)
+            res.append((p - lrt * m2 / (jnp.sqrt(v2) + eps), m2, v2))
+        return res
+
+    want = adam_ref(ps, gs, ms, vs)
+    got = [(outs["ParamOut"][i], outs["Moment1Out"][i],
+            outs["Moment2Out"][i]) for i in range(n)]
+    err = float(worst_rel_err(got, want))
+    assert err < 1e-5, err
+    del outs, got, want
+
+    mu, l2 = 0.9, 1e-4
+    ins = {"Param": ps, "Grad": gs, "Velocity": ms, "LearningRate": [lr]}
+    outs, n_mom = run_lowered(
+        lower_op("fused_momentum",
+                 {"mu": mu, "use_nesterov": True,
+                  "regularization_method": "l2_decay",
+                  "regularization_coeff": l2}),
+        ins, key, expect_mosaic=tpu)
+
+    @jax.jit
+    def momentum_ref(ps, gs, vs):
+        res = []
+        for p, g, v in zip(ps, gs, vs):
+            g = g + l2 * p
+            v2 = mu * v + g
+            res.append((p - lr[0] * (g + mu * v2), v2))
+        return res
+
+    got = [(outs["ParamOut"][i], outs["VelocityOut"][i]) for i in range(n)]
+    err_m = float(worst_rel_err(got, momentum_ref(ps, gs, ms)))
+    assert err_m < 1e-5, err_m
+    return {"params": n, "elements": int(sum(np.prod(s) for s in shapes)),
+            "mosaic": n_adam + n_mom, "rel_err_adam": err,
+            "rel_err_momentum": err_m}
+
+
+def roll_embedding(tiny, tpu, key):
+    """fused_embedding_pool and its fused gradient: a table that fits the
+    kernels' VMEM gate takes Pallas, one far past it takes XLA — both
+    against a host reference."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    d = 128
+    small_v = pk._EMB_VMEM_BYTES // (d * 4)               # exactly 4 MB
+    cases = [("fits_vmem", 256 if tiny else small_v, 64 if tiny else 4096, 5),
+             ("past_vmem", 2 * small_v if tiny else 13 * small_v,
+              64 if tiny else 4096, 5)]
+    opdef = get_op("fused_embedding_pool")
+    attrs = {"pooltype": "SUM", "padding_idx": 0}
+    out = {}
+    for name, vocab, b, s in cases:
+        kw, ki, kg = jax.random.split(jax.random.fold_in(key, vocab), 3)
+        w = jax.random.normal(kw, (vocab, d), jnp.float32)
+        ids = jax.random.randint(ki, (b, s), 0, vocab, jnp.int32)
+        length = (jnp.arange(b, dtype=jnp.int32) % s) + 1
+        g = jax.random.normal(kg, (b, d), jnp.float32)
+        ins = {"W": [w], "Ids": [ids], "Length": [length]}
+        in_gate = tpu and pk.fused_embedding_pool_supported(w, ids)
+        expect = None if tpu is None else bool(in_gate)
+        assert expect is None or expect == (name == "fits_vmem")
+
+        fwd = jax.jit(lambda ins, key: opdef.fn(
+            ins, attrs, LoweringContext(base_key=key)))
+        bwd = jax.jit(lambda ins, g, key: opdef.custom_grad(
+            ins, None, {"Out": g}, attrs, LoweringContext(base_key=key)))
+        pooled, n_f = run_lowered(fwd, ins, key, expect_mosaic=expect)
+        dw, n_b = run_lowered(bwd, ins, g, key, expect_mosaic=expect)
+
+        wn, idn, gn = np.asarray(w), np.asarray(ids), np.asarray(g)
+        wgt = (np.arange(s)[None, :] < np.asarray(length)[:, None]) \
+            * (idn != 0)
+        want = np.einsum("bsd,bs->bd", wn[idn], wgt.astype(np.float32))
+        want_dw = np.zeros_like(wn)
+        np.add.at(want_dw, idn.reshape(-1),
+                  (gn[:, None, :] * wgt[:, :, None]).reshape(-1, d))
+        e_f = rel_err(pooled["Out"][0], want)
+        e_b = rel_err(dw["W"][0], want_dw)
+        assert e_f < 1e-5 and e_b < 1e-5, (name, e_f, e_b)
+        out[name] = {"table_mb": round(vocab * d * 4 / 2 ** 20, 1),
+                     "ids": [b, s], "mosaic": n_f + n_b,
+                     "path": "pallas" if n_f + n_b else "xla"}
+    return out
+
+
+def roll_paged(tiny, tpu, key):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.ops.attention import _paged_reference
+
+    d, ps = 128, 16
+    # both pools together just under the kernel's own VMEM gate
+    rows = 256 if tiny else pk._PAGED_VMEM_BYTES // (2 * d * 4) - ps
+    b, s = (4, 64) if tiny else (8, 1024)
+    kq, kk, kv = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (b, d), jnp.float32)
+    kp = jax.random.normal(kk, (rows, d), jnp.float32)
+    vp = jax.random.normal(kv, (rows, d), jnp.float32)
+    rng = np.random.RandomState(3)
+    pages = np.stack([rng.permutation(rows // ps)[:s // ps]
+                      for _ in range(b)])
+    idx = (pages[:, :, None] * ps + np.arange(ps)).reshape(b, s)
+    lens = rng.randint(1, s + 1, b)
+    lens[0], lens[-1] = s, 1
+    valid = (np.arange(s)[None, :] < lens[:, None]).astype("float32")
+    ins = {"Q": [q], "KPool": [kp], "VPool": [vp],
+           "Index": [jnp.asarray(idx.reshape(-1).astype("int32"))],
+           "Valid": [jnp.asarray(valid)]}
+    scale = d ** -0.5
+    assert tpu is None or pk.paged_attention_supported(q, kp, idx)
+    got, n = run_lowered(
+        lower_op("paged_attention",
+                 {"scale": scale, "page_size": ps, "neg": 1e30}),
+        ins, key, expect_mosaic=tpu)
+    want = _paged_reference(q, kp, vp, ins["Index"][0], ins["Valid"][0],
+                            scale, 1e30)
+    err = rel_err(got["Out"][0], want)
+    assert err < 1e-5, err
+    return {"pool_rows": int(rows), "pool_mb_both": round(
+        2 * rows * d * 4 / 2 ** 20, 2), "window": [b, s], "page_size": ps,
+        "mosaic": n, "rel_err": err}
+
+
+# which of pallas_kernels.__all__ each roll-call entry drives
+ROLL_CALL = [
+    ("flash", roll_flash, ["flash_attention_tpu"]),
+    ("dropout", roll_dropout, ["fused_dropout_tpu", "fused_dropout_add_tpu",
+                               "fused_act_dropout_tpu"]),
+    ("optimizers", roll_optimizers, ["fused_adam_tpu", "fused_momentum_tpu"]),
+    ("embedding", roll_embedding, ["fused_embedding_pool_tpu",
+                                   "embedding_pool_grad_tpu"]),
+    ("paged", roll_paged, ["paged_flash_attention_tpu"]),
+]
+
+
+def leg_kernels(tiny, device, out):
+    import jax
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    covered = sorted(k for _, _, ks in ROLL_CALL for k in ks)
+    assert covered == sorted(pk.__all__), \
+        f"roll-call {covered} != pallas_kernels.__all__ {sorted(pk.__all__)}"
+    # True/False: the Pallas path must / must not be taken; None: this
+    # backend has no kernels, the XLA lowering is checked instead
+    tpu = True if device.platform == "tpu" else None
+    key = jax.random.PRNGKey(7)
+    for i, (name, fn, _) in enumerate(ROLL_CALL):
+        out[name] = fn(tiny, tpu, jax.random.fold_in(key, i))
+        say(f"  {name}: {json.dumps(out[name])}")
+
+
+# ---------------------------------------------------------------------------
+# legs: four chips
+# ---------------------------------------------------------------------------
+
+def leg_dp4(size, devices, one_chip_losses, out):
+    """The executor leg's program under BuildStrategy.sharding = "dp": the
+    one-chip batch tiled over four devices, so the mean loss and gradient
+    are the one-chip leg's up to dropout noise."""
+    import jax
+    import paddle_tpu.fluid as fluid
+
+    n = len(devices)
+    main, startup, loss = build_static_bert(size)
+    bs = fluid.BuildStrategy()
+    bs.sharding = "dp"
+    prog = fluid.CompiledProgram(main, build_strategy=bs)
+    plan = prog._ensure_sharding_plan()
+    assert plan.mesh.devices.size == n, plan.mesh
+    feed = {k: jax.device_put(
+        np.concatenate([v] * n), plan.data_sharding((v.shape[0] * n,)
+                                                    + v.shape[1:]))
+        for k, v in static_feed(size).items()}
+    for k, v in feed.items():
+        shard_devs = {s.device for s in v.addressable_shards}
+        assert shard_devs == set(devices), (k, shard_devs)
+        assert v.addressable_shards[0].data.shape[0] == size["batch"], k
+
+    in_use = []
+
+    def check_param(w):
+        """Called while the trained state is still in scope."""
+        assert {s.device for s in w.addressable_shards} == set(devices), \
+            w.sharding
+        # the CPU backend (--tiny) reports no memory statistics
+        if devices[0].platform == "tpu":
+            in_use.extend(d.memory_stats()["bytes_in_use"] for d in devices)
+            assert all(b > 0 for b in in_use), in_use
+
+    losses, first_s, step_ms = run_executor(prog, startup, loss, feed,
+                                            devices[0], check_param)
+    check_losses(losses, "executor dp4")
+    # same weights, the same 64 rows on every chip: the first steps agree
+    # to dropout noise (the masks differ — other block shapes) and the
+    # last one lands in the same place; in between Adam at lr 1e-3 is
+    # chaotic on this program, so the drift there is only recorded
+    drifts = [abs(a - b) / abs(b) for a, b in zip(losses, one_chip_losses)]
+    assert max(drifts[:2]) < 0.02 and drifts[-1] < 0.1, \
+        (losses, one_chip_losses)
+    drift = max(drifts)
+    out.update({"first_step_s": first_s, "step_ms": step_ms,
+                "global_batch": size["batch"] * n,
+                "losses": [round(v, 4) for v in losses],
+                "max_rel_drift_vs_one_chip": round(drift, 4),
+                "bytes_in_use": in_use})
+
+
+def leg_hybrid(tiny, devices, out):
+    """One step of parallel/hybrid.make_train_step on a pp2 x tp2 mesh."""
+    import jax
+    from paddle_tpu.parallel.hybrid import (TransformerConfig,
+                                            build_hybrid_mesh, demo_batch,
+                                            make_train_step)
+
+    mesh = build_hybrid_mesh(4, devices=devices,
+                             axes={"dp": 1, "pp": 2, "tp": 2, "sp": 1})
+    cfg = TransformerConfig(vocab=512, d_model=128, n_heads=4, d_ff=256,
+                            n_layers=2, seq_len=32, batch=4) if tiny \
+        else TransformerConfig(vocab=30522, d_model=768, n_heads=12,
+                               d_ff=3072, n_layers=4, seq_len=128, batch=16)
+    params, opt_state, step_fn = make_train_step(mesh, cfg)
+    tok, lbl = demo_batch(cfg, mesh, seed=0)
+    t0 = time.perf_counter()
+    params, opt_state, loss = step_fn(params, opt_state, tok, lbl)
+    jax.block_until_ready((params, loss))
+    loss = float(loss)
+    assert math.isfinite(loss), loss
+    placed = {s.device for p in params.values()
+              for s in p.addressable_shards}
+    assert placed == set(devices), placed
+    out.update({"mesh": {a: int(mesh.shape[a]) for a in mesh.axis_names},
+                "first_step_s": round(time.perf_counter() - t0, 1),
+                "loss": round(loss, 4)})
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="toy sizes on any backend: a control-flow check, "
+                         "NOT a chip result")
+    ap.add_argument("--chips", type=int, default=1, choices=[1, 4],
+                    help="4: the data-parallel and pp2 x tp2 legs over four "
+                         "real devices")
+    ap.add_argument("--expect-warm", action="store_true",
+                    help="a second run against the same compile cache: "
+                         "fail if it adds a file")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jaxlib
+    from importlib import metadata
+
+    devices = jax.devices()
+    device = devices[0]
+    info = {"platform": device.platform, "kind": device.device_kind,
+            "count": len(devices)}
+    say(f"platform={info['platform']} device_kind={info['kind']!r} "
+        f"devices={info['count']} jax={jax.__version__} "
+        f"jaxlib={jaxlib.__version__} libtpu={metadata.version('libtpu')}")
+    if args.tiny:
+        say("--tiny: toy sizes, a control-flow check — NOT a chip result")
+    elif device.platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke: JAX found no TPU (platform {device.platform!r}). "
+            f"This check runs on the chip; --tiny runs its control flow "
+            f"on any backend.")
+    if len(devices) < args.chips:
+        raise SystemExit(f"chip_smoke: --chips {args.chips} needs "
+                         f"{args.chips} devices, JAX reports {len(devices)}")
+
+    import paddle_tpu
+    from paddle_tpu.fluid import compile_cache
+    # weights, dropout seeds and the tracer's base key all come from this
+    # seed: unseeded, every process bakes different constants into its
+    # programs and the compile cache can never hit
+    paddle_tpu.seed(0)
+    cache_dir = compile_cache.enable_jax_cache()
+    files0 = cache_files(cache_dir)
+    say(f"compile cache {cache_dir}: {files0} files at start")
+
+    size = TINY if args.tiny else FULL
+    log = CompileLog()
+    results = {}
+    if args.chips == 1:
+        with log.leg("eager", results) as out:
+            leg_eager(size, device, out)
+        with log.leg("executor", results) as out:
+            leg_executor(size, device, out)
+        with log.leg("kernels", results) as out:
+            leg_kernels(args.tiny, device, out)
+    else:
+        four = devices[:4]
+        with log.leg("executor_one_chip", results) as out:
+            losses = executor_plain(size, device, out)
+        with log.leg("executor_dp4", results) as out:
+            leg_dp4(size, four, losses, out)
+        with log.leg("hybrid_pp2_tp2", results) as out:
+            leg_hybrid(args.tiny, four, out)
+
+    files1 = cache_files(cache_dir)
+    results["cache"] = {
+        "dir": cache_dir, "files_start": files0, "files_end": files1,
+        "files_added": files1 - files0,
+        "compile_s": round(log.seconds, 1), "compiles": log.compiles,
+        "cache_hits": log.cache_hits}
+    say(f"== cache: {json.dumps(results['cache'])}")
+    if args.expect_warm:
+        assert files1 == files0, \
+            f"a warm run added {files1 - files0} files to {cache_dir}"
+
+    # a record for chiprun to bring back; the verdict is the last line
+    os.makedirs("chiprun_out", exist_ok=True)
+    name = "chip_smoke" + ("_tiny" if args.tiny else "") \
+        + (f"_chips{args.chips}" if args.chips != 1 else "") \
+        + ("_warm" if args.expect_warm else "") + ".json"
+    with open(os.path.join("chiprun_out", name), "w") as f:
+        json.dump({"device": info, "legs": results}, f, indent=1)
+    print(json.dumps({"ok": True, "device": info}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -49,11 +49,6 @@ def main(argv=None):
                     help="request count for --engine")
     args = ap.parse_args(argv)
 
-    # honor JAX_PLATFORMS in-process: some PJRT plugins ignore the env var
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
     from jax import export as jexport
 
     with open(os.path.join(args.model_dir, "model.stablehlo"), "rb") as f:

@@ -12,9 +12,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-if not os.environ.get("EXAMPLES_ON_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 from paddle_tpu.dygraph import base as dybase
 from paddle_tpu.dygraph.functional import functional_loss
 from paddle_tpu.models.bert import BertForPretraining

@@ -10,11 +10,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax
-
-if not os.environ.get("EXAMPLES_ON_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 import paddle_tpu.fluid as fluid
 from paddle_tpu.vision.datasets import MNIST
 

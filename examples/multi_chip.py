@@ -9,18 +9,16 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if not os.environ.get("EXAMPLES_ON_TPU"):
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        # APPEND to any preexisting flags: setdefault would silently drop
-        # the virtual devices and degrade the demo to a 1-device mesh
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8").strip()
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    # 8 virtual devices where the platform is the host CPU (the flag is
+    # inert on an accelerator).  APPEND to any preexisting flags:
+    # setdefault would silently drop the virtual devices and degrade the
+    # demo to a 1-device mesh
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax
-
-if not os.environ.get("EXAMPLES_ON_TPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 
 def main():

@@ -31,11 +31,6 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args(argv)
 
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
-
     from paddle_tpu.inference import AnalysisConfig, create_predictor
 
     cfg = AnalysisConfig(args.model_dir)

@@ -7,7 +7,7 @@ whole-block XLA compilation (executor.py here) every distinct feed shape is
 a full recompile: multi-second cold compiles versus microsecond dispatch,
 paid again for every ragged tail batch (`drop_last=False` loaders, eval
 epoch ends, variable-length NLP batches) and again after every process
-restart (tpu_watch canary restarts, preemption recovery).  This module owns
+restart (preemption recovery).  This module owns
 the three defenses, all gated by flags in fluid.core:
 
 * **Shape bucketing** (`FLAGS_shape_bucketing`, `FLAGS_shape_bucket_edges`)
@@ -17,12 +17,14 @@ the three defenses, all gated by flags in fluid.core:
   traced ``__batch_valid__`` scalar; mask-aware batch reductions
   (ops/reduction.py, ops/nn_ops.py batch-norm stats) keep padded-step
   numerics equal to the unpadded step within fp tolerance.
-* **Persistent compile cache** (`FLAGS_persistent_cache_dir`) — jax's own
-  compilation cache persists the compiled XLA executables; the
-  :class:`PersistentCache` index here records which (program fingerprint,
-  bucketed feed sig, jax/backend version) keys have compiled before, so a
-  restarted trainer reports a persistent-warm start (zero *cold* misses)
-  and tooling can inspect what lives in the cache.
+* **Persistent compile cache** — jax's own compilation cache persists the
+  compiled XLA executables at :func:`jax_cache_dir`
+  (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``); the
+  :class:`PersistentCache` index under `FLAGS_persistent_cache_dir` records
+  which (program fingerprint, bucketed feed sig, jax/backend version) keys
+  have compiled before, so a restarted trainer reports a persistent-warm
+  start (zero *cold* misses) and tooling can inspect what lives in the
+  cache.
 * **Recompile-storm detection** (`FLAGS_recompile_warn_threshold` /
   `FLAGS_recompile_warn_window`) — a sliding-window miss counter that
   fires a trace-plane event with shape/bucket attribution when the miss
@@ -130,29 +132,39 @@ def persistent_key(fingerprint: str, feed_sig, fetch_names,
     return hashlib.sha256(repr(payload).encode()).hexdigest()
 
 
-_jax_cache_dir_applied: Optional[str] = None
+# ---------------------------------------------------------------------------
+# where jax's own compilation cache lives
+# ---------------------------------------------------------------------------
+
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def _configure_jax_cache(root: str) -> None:
-    """Point jax's own compilation cache at ``root``/xla so the XLA
-    executables (not just this index) survive the process.  Thresholds are
-    zeroed: on this stack even a tiny program's compile dwarfs a dispatch,
-    so every entry is worth persisting.  Knob names vary across jax
-    versions — each update degrades independently."""
-    global _jax_cache_dir_applied
-    if _jax_cache_dir_applied == root:
-        return
+def jax_cache_dir() -> str:
+    """The one place compiled XLA executables persist: wherever
+    ``JAX_COMPILATION_CACHE_DIR`` says when it is set, otherwise the fixed
+    ``<checkout>/.jax_cache`` (git-ignored).  Never a temp name, pid or
+    time — the path is part of the cache key, so a directory that moves
+    never hits."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
+
+
+def enable_jax_cache() -> str:
+    """Switch jax's persistent compilation cache on at
+    :func:`jax_cache_dir` and return that directory.  With
+    ``JAX_COMPILATION_CACHE_DIR`` set, jax has already read it and the
+    directory is never set in code.  Size/time thresholds are zeroed: a
+    whole-block compile dwarfs a dispatch, so every entry is worth
+    persisting.  Call before the process's first compile (entry points do:
+    ``chip_smoke.py``, ``bench.py``, ``tools/serve_bench.py``, the fleet's
+    replica child); idempotent."""
     import jax
-    xla_dir = os.path.join(root, "xla")
-    os.makedirs(xla_dir, exist_ok=True)
-    for knob, val in (("jax_compilation_cache_dir", xla_dir),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:       # noqa: BLE001 — the index works without
-            pass
-    _jax_cache_dir_applied = root
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax_cache_dir()
 
 
 class PersistentCache:
@@ -161,18 +173,16 @@ class PersistentCache:
 
     One JSON file per key (``index/<sha256>.json``) written via
     tempfile + atomic rename: no locks, safe for concurrent trainers
-    sharing the directory (canary restarts, multi-host launches on a
+    sharing the directory (restarts, multi-host launches on a
     shared filesystem).  Existence of the file IS the hit predicate."""
 
-    def __init__(self, root: str, configure_jax: bool = True):
+    def __init__(self, root: str):
         self.root = os.path.abspath(root)
         self.index_dir = os.path.join(self.root, "index")
         os.makedirs(self.index_dir, exist_ok=True)
-        if configure_jax:
-            # a secondary index (the autotune config store under
-            # FLAGS_auto_tune_dir) must NOT re-root jax's compilation
-            # cache away from the primary persistent dir
-            _configure_jax_cache(self.root)
+        # the executables themselves persist where jax_cache_dir() says,
+        # not under this index's root
+        enable_jax_cache()
 
     def path_for(self, key: str) -> str:
         return os.path.join(self.index_dir, key + ".json")
@@ -276,7 +286,7 @@ def config_store() -> Optional[PersistentCache]:
         return persistent_cache()
     root = os.path.abspath(str(root))
     if _config_instance is None or _config_instance.root != root:
-        _config_instance = PersistentCache(root, configure_jax=False)
+        _config_instance = PersistentCache(root)
     return _config_instance
 
 

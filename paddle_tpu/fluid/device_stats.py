@@ -177,7 +177,7 @@ def capture(jitted, example_args: Sequence,
         + info["generated_code_bytes"] - info["alias_bytes"])
     # per-shard HBM truth: the analysis above is already per-device (one
     # SPMD program per chip); record it under the explicit name the
-    # sharding plane's consumers (bench --sharding, tpu_watch, OOM
+    # sharding plane's consumers (bench --sharding, OOM
     # forensics) read, beside the mesh width
     info["mesh_devices"] = max(1, int(n_devices or 1))
     info["per_device_peak_bytes"] = info["peak_bytes"]

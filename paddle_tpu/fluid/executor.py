@@ -1350,6 +1350,9 @@ class Executor:
             # collectives) pin values through this mesh; everything else
             # is GSPMD's problem, not per-op dispatch
             ctx.mesh = plan_mesh
+            ctx.partitioned = any(
+                m is not None and m.devices.size > 1
+                for m in (plan_mesh, mesh))
             if bucket is not None:
                 # true batch size rides in as a traced scalar: varying
                 # tails within one bucket share ONE executable

@@ -2,14 +2,16 @@
 
 Reference: paddle/fluid/pybind/pybind.cc:353 exposes the C++ runtime to
 Python; here the C++ data-feed pipeline (native/src/data_feed.cc, the
-data_feed.cc + channel.h analog) is compiled on first use with the baked-in
-g++ toolchain and bound through ctypes (no pybind11 in the image; the C ABI
+data_feed.cc + channel.h analog) is compiled on first use — keyed on a hash
+of its sources — with the baked-in g++ toolchain and bound through ctypes (no pybind11 in the image; the C ABI
 is the `framework/c/c_api.cc` pattern).  A pure-Python fallback keeps the
 package importable where no compiler exists.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,33 +23,47 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "data_feed.cc")
 _SRCS = [_SRC, os.path.join(_HERE, "src", "memory.cc"),
          os.path.join(_HERE, "src", "pad_pack.cc")]
-_LIB_PATH = os.path.join(_HERE, "libptnative.so")
+_DEPS = _SRCS + [os.path.join(_HERE, "src", "channel.h")]
 _lib = None
 _lib_lock = threading.Lock()
 
 
+def _lib_path() -> str:
+    """The library's name carries a hash of its sources: a binary is reused
+    only for the exact sources it was built from.  (File times say nothing
+    — a copy or a checkout resets them — so a stale binary could outlive
+    its sources under an mtime comparison.)"""
+    h = hashlib.sha256()
+    for dep in _DEPS:
+        with open(dep, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"libptnative-{h.hexdigest()[:16]}.so")
+
+
 def _build() -> Optional[str]:
-    """Compile the native library if stale (mtime-based cache).
+    """Compile the native library unless the binary for these exact
+    sources is already there.
 
     Compiles to a process-unique temp path and os.replace()s into place so a
     concurrent process never dlopens a half-written .so (rename is atomic on
-    POSIX)."""
+    POSIX); binaries of other source versions are removed."""
     try:
-        deps = _SRCS + [os.path.join(_HERE, "src", "channel.h")]
-        if (os.path.exists(_LIB_PATH)
-                and os.path.getmtime(_LIB_PATH) >= max(
-                    os.path.getmtime(d) for d in deps)):
-            return _LIB_PATH
-        tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+        path = _lib_path()
+        if os.path.exists(path):
+            return path
+        tmp = f"{path}.{os.getpid()}.tmp"
         cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
                "-o", tmp] + _SRCS
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-            os.replace(tmp, _LIB_PATH)
+            os.replace(tmp, path)
         finally:
             if os.path.exists(tmp):
                 os.remove(tmp)
-        return _LIB_PATH
+        for old in glob.glob(os.path.join(_HERE, "libptnative*.so")):
+            if old != path:
+                os.remove(old)
+        return path
     except (OSError, subprocess.SubprocessError):
         return None
 

@@ -20,15 +20,12 @@ import jax.numpy as jnp
 from .registry import register_op
 
 _PALLAS_MIN_SEQ_DEFAULT = 1024
-# Crossover rationale (measured, BERT sweep round 3): below ~1024 the XLA
-# softmax(QK^T)V fusion is already near-roofline — at seq 512 the flash
-# kernel LOSES end-to-end (23.4% vs 34.8% MFU) despite winning a fwd+bwd
-# microbench, because the [B,H,T,T] score tensor still fits fusion scale
-# and the kernel's block bookkeeping is pure overhead.  Only above the
-# crossover does streaming K/V blocks through VMEM pay.  The knob
-# (FLAGS_pallas_min_seq) exists so bench.py/tpu_watch can sweep the real
-# crossover per chip generation and the future auto-tuner (ROADMAP item 5)
-# can own the value instead of this constant.
+# Crossover rationale: below some sequence length the XLA softmax(QK^T)V
+# fusion still holds the [B,H,T,T] score tensor at fusion scale and the
+# kernel's block bookkeeping is pure overhead; only above it does
+# streaming K/V blocks through VMEM pay.  Where the crossover sits is not
+# measured on this code; FLAGS_pallas_min_seq exists so a chip run can
+# sweep it.
 
 
 def _pallas_min_seq() -> int:
@@ -83,8 +80,10 @@ def _bias_broadcastable(mask, q, k) -> bool:
 
 def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                     dropout_rate=0.0, dropout_key=None,
-                    dropout_upscale=True, prob_scale=None):
+                    dropout_upscale=True, prob_scale=None, use_pallas=None):
     """Dispatch to the Pallas TPU kernel when profitable, else XLA.
+    ``use_pallas``: an op lowering passes ``ctx.pallas_ok()``; None (the
+    shard_map bodies in parallel/) means "on the tpu backend".
 
     The Pallas path handles additive-bias masks via the kernel's ``ab``
     argument (anything broadcastable to [B, H, Tq, Tk]); genuinely
@@ -93,26 +92,23 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     seq = q.shape[-2]
-    on_tpu = jax.default_backend() not in ("cpu",)
     drop_active = bool(dropout_rate) and dropout_key is not None
-    if on_tpu and seq >= _pallas_min_seq() and not drop_active \
-            and prob_scale is None and scale != 0.0 \
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if use_pallas and seq >= _pallas_min_seq() \
+            and not drop_active and prob_scale is None and scale != 0.0 \
             and (mask is None or _bias_broadcastable(mask, q, k)):
-        try:
-            from .pallas_kernels import flash_attention_tpu
-        except ImportError:
-            flash_attention_tpu = None
-        if flash_attention_tpu is not None:
-            ab = None
-            if mask is not None:
-                # the Pallas kernel computes softmax((QKᵀ + ab)·scale);
-                # our contract is softmax(QKᵀ·scale + mask), so the bias
-                # rides in pre-divided by the scale
-                ab = (jnp.broadcast_to(
-                    mask, (q.shape[0], q.shape[1], q.shape[2], k.shape[2])
-                ).astype(jnp.float32) / scale).astype(q.dtype)
-            return flash_attention_tpu(q, k, v, scale=scale, causal=causal,
-                                       ab=ab)
+        from .pallas_kernels import flash_attention_tpu
+        ab = None
+        if mask is not None:
+            # the Pallas kernel computes softmax((QKᵀ + ab)·scale); our
+            # contract is softmax(QKᵀ·scale + mask), so the bias rides in
+            # pre-divided by the scale
+            ab = (jnp.broadcast_to(
+                mask, (q.shape[0], q.shape[1], q.shape[2], k.shape[2])
+            ).astype(jnp.float32) / scale).astype(q.dtype)
+        return flash_attention_tpu(q, k, v, scale=scale, causal=causal,
+                                   ab=ab)
     return _reference_attention(q, k, v, mask, scale, causal,
                                 dropout_rate if drop_active else 0.0,
                                 dropout_key, dropout_upscale, prob_scale)
@@ -141,7 +137,8 @@ def _fused_mha(ins, attrs, ctx):
                           scale=attrs.get("scale", None),
                           causal=attrs.get("causal", False),
                           dropout_rate=rate, dropout_key=dropout_key,
-                          dropout_upscale=upscale, prob_scale=prob_scale)
+                          dropout_upscale=upscale, prob_scale=prob_scale,
+                          use_pallas=ctx.pallas_ok())
     return {"Out": [out]}
 
 
@@ -173,9 +170,10 @@ def _paged_attention(ins, attrs, ctx):
 
     Q [B, d]; KPool/VPool [R, d] flat page pools; Index [B*S] (or [B, S])
     int32 pool-row per logical position; Valid [B, S] float 0/1 mask.
-    On TPU with lane-aligned shapes the lowering is the Pallas paged
-    flash kernel (pallas_kernels.paged_flash_attention_tpu); elsewhere
-    the XLA gather fallback mirrors the unfused chain bit-for-bit."""
+    On TPU, with a lane-aligned head dim and pools that fit VMEM
+    (pallas_kernels.paged_attention_supported), the lowering is the
+    Pallas paged flash kernel; otherwise the XLA gather lowering, which
+    mirrors the unfused chain bit-for-bit."""
     q = ins["Q"][0]
     kp, vp = ins["KPool"][0], ins["VPool"][0]
     idx, valid = ins["Index"][0], ins["Valid"][0]
@@ -183,19 +181,14 @@ def _paged_attention(ins, attrs, ctx):
     neg = float(attrs.get("neg", 1e30))
     b, s_len = valid.shape
     idx2 = idx.reshape(b, s_len)
-    on_tpu = jax.default_backend() not in ("cpu",)
-    if on_tpu:
-        try:
-            from .pallas_kernels import (paged_attention_supported,
-                                         paged_flash_attention_tpu)
-        except ImportError:
-            paged_attention_supported = None
-        if paged_attention_supported is not None \
-                and paged_attention_supported(q, kp, idx2):
+    if ctx.pallas_ok():
+        from .pallas_kernels import (paged_attention_supported,
+                                     paged_flash_attention_tpu)
+        if paged_attention_supported(q, kp, idx2):
             ps = int(attrs.get("page_size", 1) or 1)
             if s_len % ps != 0:
                 ps = 1
-            lengths = jnp.sum(valid, axis=1, keepdims=True).astype(jnp.int32)
+            lengths = jnp.sum(valid, axis=1).astype(jnp.int32)
             return {"Out": [paged_flash_attention_tpu(
                 q, kp, vp, idx2, lengths, scale, page_size=ps)]}
     return {"Out": [_paged_reference(q, kp, vp, idx.reshape(-1), valid,
@@ -212,5 +205,6 @@ def _multihead_matmul(ins, attrs, ctx):
     d = c3 // 3 // h
     qkv = x.reshape(b, t, 3, h, d).transpose(2, 0, 3, 1, 4)
     out = flash_attention(qkv[0], qkv[1], qkv[2], bias_qk,
-                          scale=attrs.get("alpha", None))
+                          scale=attrs.get("alpha", None),
+                          use_pallas=ctx.pallas_ok())
     return {"Out": [out.transpose(0, 2, 1, 3).reshape(b, t, h * d)]}

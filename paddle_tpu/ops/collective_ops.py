@@ -23,16 +23,6 @@ from ..fluid import trace
 from .registry import register_op
 
 
-def axis_size(axis_name):
-    """lax.axis_size across jax versions: 0.4.x lacks it; psum of the
-    literal 1 is the portable spelling (statically folded to the axis
-    size, no collective launched)."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _axis(ctx, attrs):
     return ctx.axis_for_ring(attrs.get("ring_id", 0))
 
@@ -177,7 +167,7 @@ def _c_scatter(ins, attrs, ctx):
     axis = _axis(ctx, attrs)
     if axis is None:
         return {"Out": [x]}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     chunks = x.reshape((n, -1) + x.shape[1:])
     return {"Out": [lax.dynamic_index_in_dim(chunks, idx, keepdims=False)]}
@@ -200,7 +190,7 @@ def _c_split(ins, attrs, ctx):
     axis = _axis(ctx, attrs)
     if axis is None:
         return {"Out": [x]}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     step = x.shape[-1] // n
     return {"Out": [lax.dynamic_slice_in_dim(x, idx * step, step, x.ndim - 1)]}
@@ -256,7 +246,7 @@ def _recv_v2(ins, attrs, ctx):
     axis = _axis(ctx, attrs)
     if axis is None:
         return {"Out": [x]}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     return {"Out": [lax.ppermute(x, axis, perm)]}
 
@@ -274,7 +264,7 @@ def _c_ppermute(ins, attrs, ctx):
     axis = _axis(ctx, attrs)
     if axis is None:
         return {"Out": [x]}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     shift = attrs.get("shift", 1)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return {"Out": [lax.ppermute(x, axis, perm)]}

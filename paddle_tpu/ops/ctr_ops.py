@@ -40,9 +40,11 @@ def _cvm_fwd(x, use_cvm):
 # Reference: operators/fused/fused_embedding_seq_pool_op.cc (the PaddleBox
 # CTR hot path).  Produced by the kernel-tier fuse_sparse_embedding pass
 # (fluid/passes/kernel_tier.py) from lookup_table(+sequence_pool/reduce_sum)
-# chains; on TPU the lowering is the Pallas fused gather+pool kernel with a
-# fused scatter-add (segment-sum) gradient (ops/pallas_kernels.py), on CPU
-# an XLA take + masked sum that mirrors the unfused chain bit-for-bit.
+# chains; on TPU, for an f32 table that fits VMEM
+# (pallas_kernels.fused_embedding_pool_supported), the lowering is the Pallas
+# fused gather+pool kernel with a fused scatter-add (segment-sum) gradient;
+# otherwise an XLA take + masked sum that mirrors the unfused chain
+# bit-for-bit.
 
 def _emb_pool_prep(ins, attrs):
     """(w, ids, wgt, denom-applied weights): the per-(row, position)
@@ -79,7 +81,7 @@ def _fused_embedding_pool_grad(ins, outs, out_grads, attrs, ctx):
         return {"W": [jnp.zeros_like(w)]}
     g = g.astype(w.dtype)
     vocab = w.shape[0]
-    if jax.default_backend() == "tpu":
+    if ctx.pallas_ok():
         from .pallas_kernels import (embedding_pool_grad_tpu,
                                      fused_embedding_pool_supported)
         if fused_embedding_pool_supported(w, ids):
@@ -94,16 +96,16 @@ def _fused_embedding_pool_grad(ins, outs, out_grads, attrs, ctx):
              custom_grad=_fused_embedding_pool_grad)
 def _fused_embedding_pool(ins, attrs, ctx):
     w, ids, wgt = _emb_pool_prep(ins, attrs)
-    if jax.default_backend() == "tpu":
+    if ctx.pallas_ok():
         from .pallas_kernels import (fused_embedding_pool_supported,
                                      fused_embedding_pool_tpu)
         if fused_embedding_pool_supported(w, ids):
             return {"Out": [fused_embedding_pool_tpu(w, ids, wgt)]}
-    # XLA fallback mirrors the unfused lookup_table + sequence_pool chain
-    # (take -> zero padding rows -> masked sum); for sum pooling the
+    # the XLA lowering mirrors the unfused lookup_table + sequence_pool
+    # chain (take -> zero padding rows -> masked sum); for sum pooling the
     # elementwise structure is identical, so a kernel-tier rewrite matches
-    # the unrewritten program bit-for-bit on CPU (mean folds the divide
-    # into the weights — allclose, one rounding step apart)
+    # the unrewritten program bit-for-bit (mean folds the divide into the
+    # weights — allclose, one rounding step apart)
     gathered = jnp.take(w, ids, axis=0)
     padding_idx = attrs.get("padding_idx", -1)
     if padding_idx is not None and padding_idx >= 0:

@@ -1,37 +1,60 @@
-"""Pallas TPU kernels for the ops where XLA fusion leaves perf on the table.
+"""Pallas TPU kernels for the ops where XLA fusion leaves work on the table.
 
-Two hot spots (measured with tools/mfu_sweep.py on BERT-base, v5e):
-
-* flash attention — at seq>=256 XLA materialises the [B, H, T, T] score
-  tensor; the pallas kernel streams K/V blocks through VMEM (SURVEY §7
-  step 3: "Pallas kernels only where XLA fusion falls short, e.g. fused
+* flash attention — at long sequences XLA materialises the [B, H, T, T]
+  score tensor; the pallas kernel streams K/V blocks through VMEM (SURVEY
+  §7 step 3: "Pallas kernels only where XLA fusion falls short, e.g. fused
   attention").  Wraps jax's production TPU kernel.
-* fused dropout — the jax.random path costs ~15ms/step on BERT-base
-  (sweep case `nodrop`): per-element uniforms + a bool mask residual both
-  round-trip HBM.  Here the mask is derived from the on-core hardware PRNG
-  (pltpu.prng_random_bits) and the backward pass RE-SEEDS the same PRNG to
-  regenerate it — zero mask bytes written, zero residuals saved.
+* fused dropout — the jax.random path writes per-element uniforms and a
+  bool mask residual to HBM.  Here the mask is derived from the on-core
+  hardware PRNG (pltpu.prng_random_bits) and the backward pass RE-SEEDS the
+  same PRNG to regenerate it — zero mask bytes written, zero residuals
+  saved.  What this buys per step is not measured on this code.
+* paged decode attention, fused embedding gather+pool, bucketed optimizer
+  updates — see each section.
 
-Everything degrades gracefully: CPU/interpret backends take the jnp path in
-the callers (ops/attention.py, ops/nn_ops.py gate on backend).
+The callers (ops/attention.py, ops/nn_ops.py, ops/ctr_ops.py,
+ops/optimizer_ops.py) take these on the ``tpu`` backend only; every kernel
+here is compiled by Mosaic and checked against its XLA reference by
+``chip_smoke.py``'s kernel roll-call.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.flash_attention import (
+    flash_attention as _jax_flash_attention)
 
 __all__ = ["flash_attention_tpu", "fused_dropout_tpu",
            "fused_dropout_add_tpu", "fused_act_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
-           "fused_embedding_pool_stream_tpu",
-           "embedding_pool_grad_stream_tpu",
-           "fused_embedding_pool_supported",
            "fused_adam_tpu", "fused_momentum_tpu",
-           "paged_flash_attention_tpu", "paged_attention_supported"]
+           "paged_flash_attention_tpu"]
+
+# A pallas_call double-buffers every block it pipelines, and v5e's scoped
+# VMEM default is 16 MiB: one block of every operand together stays under
+# half of this budget.
+_PIPELINE_VMEM_BYTES = 8 << 20
+# Sublane tile: 8 rows of f32, 16 of bf16, 32 of the uint8 dropout mask.
+# One alignment for all, so a forward, its backward and the mask kernel
+# always cut an [m, n] operand into the same blocks.
+_ROW_ALIGN = 32
+
+
+def _block_rows(m: int, row_bytes: int) -> int:
+    """Rows per block for a kernel over [m, n] operands whose one row,
+    summed over every pipelined operand, is ``row_bytes``.  The whole array
+    when it fits (a full dim needs no alignment), else a multiple of
+    ``_ROW_ALIGN``; the grid is ``pl.cdiv(m, rows)`` and Pallas masks the
+    ragged last block."""
+    rows = _PIPELINE_VMEM_BYTES // (2 * row_bytes)
+    if rows >= m:
+        return m
+    return max(_ROW_ALIGN, rows // _ROW_ALIGN * _ROW_ALIGN)
 
 
 # ---------------------------------------------------------------------------
@@ -41,14 +64,11 @@ __all__ = ["flash_attention_tpu", "fused_dropout_tpu",
 def flash_attention_tpu(q, k, v, scale=None, causal=False, ab=None):
     """q/k/v: [B, H, T, D]; ``ab`` an optional additive bias already
     broadcast to [B, H, Tq, Tk] (the kernel's attention-bias argument —
-    how a BERT padding mask rides the Pallas path).  Falls back by
-    raising ImportError-like None handling in the caller if shapes are
-    unsupported."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _fa)
+    how a BERT padding mask rides the Pallas path)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _fa(q, k, v, ab=ab, causal=causal, sm_scale=float(scale))
+    return _jax_flash_attention(q, k, v, ab=ab, causal=causal,
+                                sm_scale=float(scale))
 
 
 # ---------------------------------------------------------------------------
@@ -58,21 +78,22 @@ def flash_attention_tpu(q, k, v, scale=None, causal=False, ab=None):
 # device-resident pool; a slot's logical KV window is the pool rows named by
 # its page table.  The dense decode kernel would need the [B, max_len, d]
 # caches materialised per slot — here each grid step walks ITS page-table row
-# (SMEM), streams one page of pool rows at a time through VMEM, and folds
-# them into an online-softmax accumulator, so the gathered [B, max_len, d]
-# tensor never exists.  Positions >= the slot's length mask to -1e30 before
-# the running max, matching the XLA fallback's masked-softmax exactly-0.0
-# contract (ops/attention.py paged_attention).
+# (scalar-prefetched to SMEM), reads one page of pool rows at a time, and
+# folds them into an online-softmax accumulator, so the gathered
+# [B, max_len, d] tensor never exists.  Positions >= the slot's length mask
+# to -1e30 before the running max, matching the XLA lowering's
+# masked-softmax exactly-0.0 contract (ops/attention.py paged_attention).
 # ---------------------------------------------------------------------------
 
-_PAGED_VMEM_BYTES = 8 << 20   # both pools ride as whole VMEM blocks; bigger
-                              # pools take the XLA take/reshape fallback
+# Both pools sit in VMEM whole (copied once, not pipelined); bigger pools
+# take the XLA gather lowering in ops/attention.py.
+_PAGED_VMEM_BYTES = 8 << 20
 
 
 def paged_attention_supported(q, k_pool, idx) -> bool:
-    """Static gate for the Pallas paged path: lane-aligned head dim, flat
-    2-d pools small enough to hold as one VMEM block each, and a
-    per-position index row per batch entry."""
+    """Shapes the Pallas paged path covers: lane-aligned head dim, flat
+    2-d pools small enough to hold in VMEM, and a per-position index row
+    per batch entry."""
     if q.ndim != 2 or k_pool.ndim != 2 or idx.ndim != 2:
         return False
     d = q.shape[-1]
@@ -81,37 +102,39 @@ def paged_attention_supported(q, k_pool, idx) -> bool:
     return 2 * k_pool.size * k_pool.dtype.itemsize <= _PAGED_VMEM_BYTES
 
 
-def _paged_attn_kernel(idx_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref, *,
-                       n_blocks, page_size, scale):
+def _paged_attn_kernel(base_ref, len_ref, q_ref, kp_ref, vp_ref, o_ref, *,
+                       n_pages, page_size, scale):
+    """One query row against its pages, on the vector unit in f32: the
+    same mul + reduce_sum arithmetic as the XLA lowering (a one-row matmul
+    would leave the MXU idle and round its f32 operands to bf16)."""
+    i = pl.program_id(0)
     d = o_ref.shape[-1]
-    q = q_ref[:]                                    # [1, d]
-    length = len_ref[0, 0]
+    q = q_ref[...].astype(jnp.float32)              # [1, d]
+    length = len_ref[i]
 
     def body(j, carry):
         m, l, acc = carry
-        base = idx_ref[0, j * page_size]            # page rows contiguous
-        k = pl.load(kp_ref, (pl.dslice(base, page_size), pl.dslice(0, d)))
-        v = pl.load(vp_ref, (pl.dslice(base, page_size), pl.dslice(0, d)))
-        s = jax.lax.dot_general(                    # [1, page_size]
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        base = base_ref[i, j]                       # page rows contiguous
+        if page_size % 8 == 0:
+            base = pl.multiple_of(base, 8)
+        k = kp_ref[pl.ds(base, page_size), :].astype(jnp.float32)
+        v = vp_ref[pl.ds(base, page_size), :].astype(jnp.float32)
+        s = jnp.sum(k * q, axis=1, keepdims=True) * scale   # [page_size, 1]
         pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+            jnp.int32, (page_size, 1), 0)
         s = jnp.where(pos < length, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)                      # masked -> exactly 0.0
-        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_new = l * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc_new = acc * corr + jnp.sum(p * v, axis=0, keepdims=True)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((1, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((1, 1), jnp.float32)
     acc0 = jnp.zeros((1, d), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    o_ref[:] = (acc / l).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
 def paged_flash_attention_tpu(q, k_pool, v_pool, idx, lengths, scale,
@@ -119,70 +142,96 @@ def paged_flash_attention_tpu(q, k_pool, v_pool, idx, lengths, scale,
     """q: [B, d] one query row per decode slot; k_pool/v_pool: [R, d] flat
     page pools (R = n_pages * page_size); idx: [B, S] int32 pool-row index
     per logical position (page-contiguous in runs of ``page_size``);
-    lengths: [B, 1] int32 valid-position counts.  Returns [B, d]."""
+    lengths: [B] or [B, 1] int32 valid-position counts.  Returns [B, d]."""
     b, s = idx.shape
-    r, d = k_pool.shape
+    d = k_pool.shape[-1]
     if s % page_size != 0:
         raise ValueError(f"seq window {s} not a multiple of page_size "
                          f"{page_size}")
-    return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, n_blocks=s // page_size,
+    # [B, 1, d] so a one-row block spans the array's whole last two dims
+    # (a (1, d) block of a [B, d] array breaks the (8, 128) tiling rule)
+    row = pl.BlockSpec((pl.Squeezed(), 1, d), lambda i, *_: (i, 0, 0))
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_paged_attn_kernel, n_pages=s // page_size,
                           page_size=page_size, scale=float(scale)),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, s), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, d), lambda i: (i, 0)),
-                  pl.BlockSpec((r, d), lambda i: (0, 0)),
-                  pl.BlockSpec((r, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, d), q.dtype),
-    )(idx.astype(jnp.int32), lengths.astype(jnp.int32).reshape(b, 1),
-      q, k_pool, v_pool)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[row, whole, whole], out_specs=row),
+        out_shape=jax.ShapeDtypeStruct((b, 1, d), q.dtype),
+    )(idx[:, ::page_size].astype(jnp.int32),
+      lengths.astype(jnp.int32).reshape(b), q.reshape(b, 1, d),
+      k_pool, v_pool)
+    return out.reshape(b, d)
 
 
 # ---------------------------------------------------------------------------
 # fused dropout with mask regeneration in backward
 # ---------------------------------------------------------------------------
 
-def _pick_block_rows(m: int, n: int) -> int:
-    """Largest power-of-two row count that divides m and keeps a block
-    under ~2MB of VMEM at 4B/elem."""
-    cap = max(1, (2 << 20) // (n * 4))
-    bm = 1
-    while bm * 2 <= cap and m % (bm * 2) == 0:
-        bm *= 2
-    return bm
+def _dropout_row_bytes(n: int) -> int:
+    """One row of the widest dropout kernel: three f32 operands."""
+    return 3 * 4 * n
+
+
+def _dropout_block_rows(m: int, n: int) -> int:
+    """A function of the shape alone — never of dtype or operand count —
+    because the keep-mask is a function of (seed, block index, block
+    shape): forward, backward and the mask kernel must block alike."""
+    return _block_rows(m, _dropout_row_bytes(n))
+
+
+def fused_dropout_supported(x) -> bool:
+    """Shapes the dropout kernels cover: lane-aligned last dim, and one
+    aligned block of rows within the VMEM budget."""
+    if x.ndim == 0 or x.size == 0:
+        return False
+    n = x.shape[-1]
+    rows = min(x.size // n, _ROW_ALIGN)
+    return (n % 128 == 0 and
+            2 * rows * _dropout_row_bytes(n) <= _PIPELINE_VMEM_BYTES)
+
+
+def _keep_mask(seed_ref, shape, threshold):
+    # distinct stream per grid block: hardware PRNG seeded from (seed, block)
+    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
+    bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+    return bits >= jnp.uint32(threshold)
+
+
+def _dropped(keep, x, scale):
+    return jnp.where(keep, x * x.dtype.type(scale), x.dtype.type(0.0))
 
 
 def _dropout_kernel(seed_ref, x_ref, o_ref, *, threshold, scale):
-    # distinct stream per grid block: hardware PRNG seeded from (seed, block)
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
-    keep = bits >= jnp.uint32(threshold)
-    x = x_ref[:]
-    o_ref[:] = jnp.where(keep, x * x.dtype.type(scale),
-                         x.dtype.type(0.0))
+    keep = _keep_mask(seed_ref, x_ref.shape, threshold)
+    o_ref[...] = _dropped(keep, x_ref[...], scale)
 
 
 def _dropout_mask_kernel(seed_ref, o_ref, *, threshold):
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(o_ref.shape), jnp.uint32)
-    o_ref[:] = (bits >= jnp.uint32(threshold)).astype(jnp.uint8)
+    keep = _keep_mask(seed_ref, o_ref.shape, threshold)
+    o_ref[...] = keep.astype(jnp.uint8)
+
+
+def _rowwise_call(kernel, seed, operands, shape, dtype):
+    """One elementwise pallas_call producing an [m, n] ``dtype`` array from
+    same-shape operands, the PRNG seed riding in SMEM."""
+    m, n = shape
+    bm = _dropout_block_rows(m, n)
+    spec = pl.BlockSpec((bm, n), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(m, bm),),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [spec] * len(operands),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), dtype),
+    )(seed, *operands)
 
 
 def _run_dropout(x2d, seed, threshold, scale):
-    m, n = x2d.shape
-    bm = _pick_block_rows(m, n)
-    return pl.pallas_call(
+    return _rowwise_call(
         functools.partial(_dropout_kernel, threshold=threshold, scale=scale),
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((bm, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x2d.dtype),
-    )(seed, x2d)
+        seed, [x2d], x2d.shape, x2d.dtype)
 
 
 def _threshold_for(rate: float) -> int:
@@ -213,55 +262,52 @@ def _seed_from_key(key):
     return jax.random.bits(key, (1,), "uint32").astype(jnp.int32)
 
 
-def fused_dropout_supported(x) -> bool:
-    """Static shape check: last dim lane-aligned, total a multiple of it."""
-    if x.ndim == 0 or x.size == 0:
-        return False
-    n = x.shape[-1]
-    return n % 128 == 0 and (x.size // n) >= 1
+def fused_dropout_tpu(x, key, rate, upscale_in_train):
+    """Dropout with on-core PRNG mask, regenerated in backward.
+
+    Returns (out, mask_fn) where mask_fn() materialises the uint8 keep-mask
+    with a second kernel from the same seed — called only if the consumer
+    actually fetches the Mask output, so XLA DCEs it otherwise.
+    """
+    seed = _seed_from_key(key)
+    shape = x.shape
+    x2d = x.reshape(-1, shape[-1])
+    out = _fused_dropout(x2d, seed, float(rate), bool(upscale_in_train))
+
+    def mask_fn():
+        mask = _rowwise_call(
+            functools.partial(_dropout_mask_kernel,
+                              threshold=_threshold_for(float(rate))),
+            seed, [], x2d.shape, jnp.uint8)
+        return mask.reshape(shape)
+
+    return out.reshape(shape), mask_fn
 
 
 # ---------------------------------------------------------------------------
 # dropout fused with its elementwise neighbours: residual add / activation.
 #
-# The round-3 sweep showed ~13 MFU points between `nodrop` (55.3%) and
-# baseline (42.7%) BERT: each pallas dropout call is an opaque boundary, so
-# the residual add AFTER it and the gelu BEFORE it each cost a full extra
+# Each pallas dropout call is an opaque boundary to XLA fusion, so the
+# residual add AFTER it and the gelu BEFORE it would each cost a full extra
 # HBM pass of the activation tensor.  Pulling those neighbours INTO the
 # dropout kernel removes the boundary; backward regenerates the mask from
 # the same on-core PRNG seed (no residual bytes), and the activation
 # derivative is recomputed from the pre-activation x the matmul backward
-# already keeps live.
+# already keeps live.  The gain is not measured on this code.
 # ---------------------------------------------------------------------------
 
 def _dropout_add_kernel(seed_ref, x_ref, r_ref, o_ref, *, threshold, scale):
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
-    keep = bits >= jnp.uint32(threshold)
-    x = x_ref[:]
-    o_ref[:] = jnp.where(keep, x * x.dtype.type(scale),
-                         x.dtype.type(0.0)) + r_ref[:]
-
-
-def _run_dropout_add(x2d, r2d, seed, threshold, scale):
-    m, n = x2d.shape
-    bm = _pick_block_rows(m, n)
-    return pl.pallas_call(
-        functools.partial(_dropout_add_kernel, threshold=threshold,
-                          scale=scale),
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((bm, n), lambda i: (i, 0)),
-                  pl.BlockSpec((bm, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x2d.dtype),
-    )(seed, x2d, r2d)
+    keep = _keep_mask(seed_ref, x_ref.shape, threshold)
+    o_ref[...] = _dropped(keep, x_ref[...], scale) + r_ref[...]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _fused_dropout_add(x2d, r2d, seed, rate, upscale):
     scale = 1.0 / (1.0 - rate) if upscale else 1.0
-    return _run_dropout_add(x2d, r2d, seed, _threshold_for(rate), scale)
+    return _rowwise_call(
+        functools.partial(_dropout_add_kernel,
+                          threshold=_threshold_for(rate), scale=scale),
+        seed, [x2d, r2d], x2d.shape, x2d.dtype)
 
 
 def _fused_dropout_add_fwd(x2d, r2d, seed, rate, upscale):
@@ -303,10 +349,10 @@ def _erf(x):
 
 
 def _act_fns(act):
-    import math
     if act == "relu":
         return (lambda x: jnp.maximum(x, x.dtype.type(0.0)),
-                lambda x: (x > 0).astype(x.dtype))
+                # f32 compare: v5e's VPU has no bf16 comparison
+                lambda x: (x.astype(jnp.float32) > 0).astype(x.dtype))
     if act == "gelu":                   # erf form (paddle default)
         c = 1.0 / math.sqrt(2.0)
         cpdf = 1.0 / math.sqrt(2.0 * math.pi)
@@ -325,40 +371,26 @@ def _act_fns(act):
 
 
 def _act_dropout_kernel(seed_ref, x_ref, o_ref, *, threshold, scale, act):
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
-    keep = bits >= jnp.uint32(threshold)
+    keep = _keep_mask(seed_ref, x_ref.shape, threshold)
     f, _ = _act_fns(act)
-    a = f(x_ref[:])
-    o_ref[:] = jnp.where(keep, a * a.dtype.type(scale), a.dtype.type(0.0))
+    o_ref[...] = _dropped(keep, f(x_ref[...]), scale)
 
 
 def _act_dropout_bwd_kernel(seed_ref, x_ref, g_ref, o_ref, *, threshold,
                             scale, act):
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
-    keep = bits >= jnp.uint32(threshold)
+    keep = _keep_mask(seed_ref, x_ref.shape, threshold)
     _, df = _act_fns(act)
-    g = g_ref[:]
-    o_ref[:] = jnp.where(keep, g * g.dtype.type(scale),
-                         g.dtype.type(0.0)) * df(x_ref[:])
+    o_ref[...] = _dropped(keep, g_ref[...], scale) * df(x_ref[...])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _fused_act_dropout(x2d, seed, rate, upscale, act):
     scale = 1.0 / (1.0 - rate) if upscale else 1.0
-    m, n = x2d.shape
-    bm = _pick_block_rows(m, n)
-    return pl.pallas_call(
+    return _rowwise_call(
         functools.partial(_act_dropout_kernel,
                           threshold=_threshold_for(rate), scale=scale,
                           act=act),
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((bm, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x2d.dtype),
-    )(seed, x2d)
+        seed, [x2d], x2d.shape, x2d.dtype)
 
 
 def _fused_act_dropout_fwd(x2d, seed, rate, upscale, act):
@@ -370,19 +402,11 @@ def _fused_act_dropout_fwd(x2d, seed, rate, upscale, act):
 def _fused_act_dropout_bwd(rate, upscale, act, res, g):
     x2d, seed = res
     scale = 1.0 / (1.0 - rate) if upscale else 1.0
-    m, n = x2d.shape
-    bm = _pick_block_rows(m, n)
-    dx = pl.pallas_call(
+    dx = _rowwise_call(
         functools.partial(_act_dropout_bwd_kernel,
                           threshold=_threshold_for(rate), scale=scale,
                           act=act),
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((bm, n), lambda i: (i, 0)),
-                  pl.BlockSpec((bm, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x2d.dtype),
-    )(seed, x2d, g)
+        seed, [x2d, g], x2d.shape, x2d.dtype)
     return dx, None
 
 
@@ -405,222 +429,111 @@ def fused_act_dropout_tpu(x, key, rate, upscale_in_train, act):
 #
 # The kernel-tier pass (fluid/passes/kernel_tier.py fuse_sparse_embedding)
 # rewrites lookup_table(+sequence_pool) chains onto the fused_embedding_pool
-# op; on TPU its lowering lands here.  The naive chain materialises the
-# [B, S, D] gathered tensor in HBM just to collapse it one op later — here
-# each batch row streams its S table rows through VMEM and accumulates the
-# pooled [1, D] result in registers, so the intermediate never exists.  The
-# backward is the PaddleBox fused gradient: a weighted scatter-add
+# op; on TPU, for a table that fits VMEM, its lowering lands here.  The naive
+# chain materialises the [B, S, D] gathered tensor in HBM just to collapse it
+# one op later — here the ids and weights are scalar-prefetched to SMEM, the
+# table sits in VMEM whole, and each batch row's S table rows accumulate into
+# its pooled [1, D] result in registers, so the intermediate never exists.
+# The backward is the PaddleBox fused gradient: a weighted scatter-add
 # (segment-sum) straight into the dW buffer, one pass, no [B, S, D]
 # cotangent.  TPU grid steps run sequentially, so the read-modify-write
 # scatter is race-free by construction.
 # ---------------------------------------------------------------------------
 
-_EMB_VMEM_BYTES = 4 << 20     # the table block must fit VMEM; bigger tables
-                              # take the XLA take/segment_sum fallback
+# The table (forward) or the dW block (backward) must fit VMEM; bigger
+# tables take the XLA take/segment_sum lowering in ops/ctr_ops.py.
+_EMB_VMEM_BYTES = 4 << 20
+_EMB_ROWS = 8                 # batch rows per grid step: one f32 sublane tile
 
 
 def fused_embedding_pool_supported(w, ids) -> bool:
-    """Static gate for the pallas path: lane-aligned row dim and 2-d ids.
-    Tables that fit one VMEM block take the whole-table kernels below;
-    bigger tables take the streaming variants (grid over row blocks) —
-    the old ≤4MB whole-table ceiling is no longer a gate."""
+    """Shapes the Pallas embedding path covers: lane-aligned f32 row dim,
+    2-d ids, and a table that fits one VMEM block."""
     if w.ndim != 2 or ids.ndim != 2 or ids.shape[1] == 0:
         return False
-    return w.shape[1] % 128 == 0
+    return (w.dtype == jnp.float32 and w.shape[1] % 128 == 0
+            and w.size * w.dtype.itemsize <= _EMB_VMEM_BYTES)
 
 
-def _emb_whole_table_ok(w) -> bool:
-    v, d = w.shape
-    return v * d * w.dtype.itemsize <= _EMB_VMEM_BYTES
-
-
-def _emb_stream_block_rows(d, itemsize) -> int:
-    """Largest fp32-sublane-aligned row count whose [block_rows, d] block
-    fits the VMEM budget."""
-    return max(8, (_EMB_VMEM_BYTES // (d * itemsize)) // 8 * 8)
+def _pad_batch(ids, wgt, *rows):
+    """Flatten ids/weights for SMEM and pad the batch to whole
+    ``_EMB_ROWS`` blocks; padding positions carry weight 0."""
+    pad = (-ids.shape[0]) % _EMB_ROWS
+    if pad:
+        ids = jnp.pad(ids, ((0, pad), (0, 0)))
+        wgt = jnp.pad(wgt, ((0, pad), (0, 0)))
+        rows = tuple(jnp.pad(r, ((0, pad), (0, 0))) for r in rows)
+    return (ids.astype(jnp.int32).reshape(-1),
+            wgt.astype(jnp.float32).reshape(-1)) + rows
 
 
 def _gather_pool_kernel(ids_ref, wgt_ref, w_ref, o_ref, *, n_ids):
     d = o_ref.shape[-1]
+    first = pl.program_id(0) * _EMB_ROWS
+    for r in range(_EMB_ROWS):
+        at = (first + r) * n_ids
 
-    def body(j, acc):
-        idx = ids_ref[0, j]
-        row = pl.load(w_ref, (pl.dslice(idx, 1), pl.dslice(0, d)))
-        return acc + row * wgt_ref[0, j]
+        def body(j, acc, at=at):
+            row = w_ref[pl.ds(ids_ref[at + j], 1), :]
+            return acc + row * wgt_ref[at + j]
 
-    o_ref[:] = jax.lax.fori_loop(
-        0, n_ids, body, jnp.zeros((1, d), w_ref.dtype))
+        o_ref[r:r + 1, :] = jax.lax.fori_loop(
+            0, n_ids, body, jnp.zeros((1, d), o_ref.dtype))
 
 
 def fused_embedding_pool_tpu(w, ids, wgt):
     """out[i] = sum_j w[ids[i, j]] * wgt[i, j] — gather and pool in one
     kernel.  ``wgt`` carries the pooling semantics (0 for padding_idx /
-    beyond-length positions, 1/len for mean pooling).  Tables beyond the
-    VMEM block budget take the streaming variant."""
-    if not _emb_whole_table_ok(w):
-        return fused_embedding_pool_stream_tpu(w, ids, wgt)
+    beyond-length positions, 1/len for mean pooling)."""
     b, s = ids.shape
-    v, d = w.shape
-    return pl.pallas_call(
+    d = w.shape[1]
+    ids_f, wgt_f = _pad_batch(ids, wgt)
+    bp = ids_f.size // s
+    out = pl.pallas_call(
         functools.partial(_gather_pool_kernel, n_ids=s),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, s), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, s), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((v, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, d), w.dtype),
-    )(ids.astype(jnp.int32), wgt.astype(w.dtype), w)
-
-
-def _gather_pool_stream_kernel(ids_ref, wgt_ref, w_ref, o_ref, *, n_ids,
-                               block_rows):
-    """Streaming forward: grid (batch, row_blocks), one [block_rows, d]
-    table slab resident per step.  Each step folds the ids that land in
-    its slab into the pooled row; out-of-slab positions contribute an
-    exact 0 (weight masked), so out[i] = sum over slabs of partials —
-    the pooled sum regrouped by slab (sum pooling reassociated; each
-    term is still w[id] * wgt computed once)."""
-    k = pl.program_id(1)
-    d = o_ref.shape[-1]
-    base = k * block_rows
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-    def body(j, acc):
-        local = ids_ref[0, j] - base
-        in_blk = jnp.logical_and(local >= 0, local < block_rows)
-        row = pl.load(w_ref, (pl.dslice(jnp.where(in_blk, local, 0), 1),
-                              pl.dslice(0, d)))
-        wj = jnp.where(in_blk, wgt_ref[0, j],
-                       jnp.zeros((), w_ref.dtype))
-        return acc + row * wj
-
-    o_ref[:] += jax.lax.fori_loop(
-        0, n_ids, body, jnp.zeros((1, d), w_ref.dtype))
-
-
-def fused_embedding_pool_stream_tpu(w, ids, wgt, block_rows=None):
-    """Streaming gather+pool for tables bigger than one VMEM block: the
-    table streams through VMEM as [block_rows, d] slabs (row-block grid
-    axis, innermost so each output row accumulates over consecutive
-    steps), ids/weights ride in SMEM.  HBM-size tables never hit the old
-    ≤4MB whole-table ceiling."""
-    b, s = ids.shape
-    v, d = w.shape
-    br = int(block_rows or _emb_stream_block_rows(d, w.dtype.itemsize))
-    vp = -(-v // br) * br
-    if vp != v:                  # pad to a whole number of slabs; padding
-        w = jnp.pad(w, ((0, vp - v), (0, 0)))      # rows are never indexed
-    return pl.pallas_call(
-        functools.partial(_gather_pool_stream_kernel, n_ids=s,
-                          block_rows=br),
-        grid=(b, vp // br),
-        in_specs=[pl.BlockSpec((1, s), lambda i, k: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, s), lambda i, k: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((br, d), lambda i, k: (k, 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, d), w.dtype),
-    )(ids.astype(jnp.int32), wgt.astype(w.dtype), w)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(bp // _EMB_ROWS,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((_EMB_ROWS, d), lambda i, *_: (i, 0))),
+        out_shape=jax.ShapeDtypeStruct((bp, d), w.dtype),
+    )(ids_f, wgt_f, w)
+    return out[:b]
 
 
 def _scatter_grad_kernel(ids_ref, wgt_ref, g_ref, o_ref, *, n_ids):
-    d = o_ref.shape[-1]
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        o_ref[:] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    def body(j, _):
-        idx = ids_ref[0, j]
-        cur = pl.load(o_ref, (pl.dslice(idx, 1), pl.dslice(0, d)))
-        pl.store(o_ref, (pl.dslice(idx, 1), pl.dslice(0, d)),
-                 cur + g_ref[:] * wgt_ref[0, j])
-        return 0
+    for r in range(_EMB_ROWS):
+        at = (i * _EMB_ROWS + r) * n_ids
+        g = g_ref[r:r + 1, :]
 
-    jax.lax.fori_loop(0, n_ids, body, 0)
+        def body(j, carry, at=at, g=g):
+            row = pl.ds(ids_ref[at + j], 1)
+            o_ref[row, :] = o_ref[row, :] + g * wgt_ref[at + j]
+            return carry
+
+        jax.lax.fori_loop(0, n_ids, body, 0)
 
 
 def embedding_pool_grad_tpu(g, ids, wgt, vocab):
     """dW[ids[i, j]] += g[i] * wgt[i, j]: the fused gradient scatter-add.
     The whole dW buffer is the (sequentially-gridded) output block, so the
-    accumulation never materialises per-position cotangent rows.  dW
-    buffers beyond the VMEM block budget take the streaming variant."""
-    b, s = ids.shape
+    accumulation never materialises per-position cotangent rows."""
+    s = ids.shape[1]
     d = g.shape[-1]
-    if vocab * d * g.dtype.itemsize > _EMB_VMEM_BYTES:
-        return embedding_pool_grad_stream_tpu(g, ids, wgt, vocab)
+    ids_f, wgt_f, g = _pad_batch(ids, wgt, g)
     return pl.pallas_call(
         functools.partial(_scatter_grad_kernel, n_ids=s),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, s), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, s), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, d), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((vocab, d), lambda i: (0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(g.shape[0] // _EMB_ROWS,),
+            in_specs=[pl.BlockSpec((_EMB_ROWS, d), lambda i, *_: (i, 0))],
+            out_specs=pl.BlockSpec((vocab, d), lambda i, *_: (0, 0))),
         out_shape=jax.ShapeDtypeStruct((vocab, d), g.dtype),
-    )(ids.astype(jnp.int32), wgt.astype(g.dtype), g)
-
-
-def _scatter_grad_stream_kernel(ids_ref, wgt_ref, g_ref, o_ref, *, n_ids,
-                                block_rows):
-    """Streaming backward: grid (row_blocks, batch) — row-block axis
-    OUTERMOST so each [block_rows, d] dW slab stays resident while every
-    batch row scatters into it (consecutive revisits, the canonical
-    accumulation shape).  For any given table row the contributions
-    land in the same (i, j) order as the whole-table kernel, so the two
-    paths are bit-identical, not just close."""
-    k = pl.program_id(0)
-    i = pl.program_id(1)
-    d = o_ref.shape[-1]
-    base = k * block_rows
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-    def body(j, _):
-        local = ids_ref[0, j] - base
-        in_blk = jnp.logical_and(local >= 0, local < block_rows)
-        safe = jnp.where(in_blk, local, 0)
-        cur = pl.load(o_ref, (pl.dslice(safe, 1), pl.dslice(0, d)))
-        wj = jnp.where(in_blk, wgt_ref[0, j], jnp.zeros((), g_ref.dtype))
-        # out-of-slab ids write row 0 back unchanged (wj == 0)
-        pl.store(o_ref, (pl.dslice(safe, 1), pl.dslice(0, d)),
-                 cur + g_ref[:] * wj)
-        return 0
-
-    jax.lax.fori_loop(0, n_ids, body, 0)
-
-
-def embedding_pool_grad_stream_tpu(g, ids, wgt, vocab, block_rows=None):
-    """Streaming scatter-add gradient for vocabularies whose dW exceeds
-    one VMEM block: dW is built slab by slab ([block_rows, d] output
-    grid axis), each slab swept once over the batch."""
-    b, s = ids.shape
-    d = g.shape[-1]
-    br = int(block_rows or _emb_stream_block_rows(d, g.dtype.itemsize))
-    vp = -(-vocab // br) * br
-    dw = pl.pallas_call(
-        functools.partial(_scatter_grad_stream_kernel, n_ids=s,
-                          block_rows=br),
-        grid=(vp // br, b),
-        in_specs=[pl.BlockSpec((1, s), lambda k, i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, s), lambda k, i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, d), lambda k, i: (i, 0))],
-        out_specs=pl.BlockSpec((br, d), lambda k, i: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((vp, d), g.dtype),
-    )(ids.astype(jnp.int32), wgt.astype(g.dtype), g)
-    return dw[:vocab] if vp != vocab else dw
+    )(ids_f, wgt_f, g)
 
 
 # ---------------------------------------------------------------------------
@@ -636,84 +549,54 @@ def embedding_pool_grad_stream_tpu(g, ids, wgt, vocab, block_rows=None):
 
 def _fused_adam_kernel(p_ref, g_ref, m_ref, v_ref, lrt_ref,
                        po_ref, mo_ref, vo_ref, *, beta1, beta2, eps):
-    g = g_ref[:]
-    m_new = beta1 * m_ref[:] + (1.0 - beta1) * g
-    v_new = beta2 * v_ref[:] + (1.0 - beta2) * jnp.square(g)
-    po_ref[:] = p_ref[:] - lrt_ref[:] * m_new / (jnp.sqrt(v_new) + eps)
-    mo_ref[:] = m_new
-    vo_ref[:] = v_new
+    g = g_ref[...]
+    m_new = beta1 * m_ref[...] + (1.0 - beta1) * g
+    v_new = beta2 * v_ref[...] + (1.0 - beta2) * jnp.square(g)
+    po_ref[...] = p_ref[...] - lrt_ref[...] * m_new / (jnp.sqrt(v_new) + eps)
+    mo_ref[...] = m_new
+    vo_ref[...] = v_new
 
 
 def fused_adam_tpu(p2d, g2d, m2d, v2d, lrt2d, beta1, beta2, eps):
     """(p, m, v) updated over a padded [rows, lanes] bucket in one launch."""
     m, n = p2d.shape
-    bm = _pick_block_rows(m, n)
+    bm = _block_rows(m, 8 * n * p2d.dtype.itemsize)
     spec = pl.BlockSpec((bm, n), lambda i: (i, 0))
-    outs = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fused_adam_kernel, beta1=float(beta1),
                           beta2=float(beta2), eps=float(eps)),
-        grid=(m // bm,),
+        grid=(pl.cdiv(m, bm),),
         in_specs=[spec] * 5,
         out_specs=[spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((m, n), p2d.dtype)] * 3,
     )(p2d, g2d, m2d, v2d, lrt2d)
-    return outs
 
 
 def _fused_momentum_kernel(lr_ref, p_ref, g_ref, v_ref, po_ref, vo_ref, *,
                            mu, use_nesterov, l2_decay):
-    g = g_ref[:]
-    p = p_ref[:]
+    g = g_ref[...]
+    p = p_ref[...]
     if l2_decay:
         g = g + p.dtype.type(l2_decay) * p
-    v_new = p.dtype.type(mu) * v_ref[:] + g
+    v_new = p.dtype.type(mu) * v_ref[...] + g
     lr = lr_ref[0]
     if use_nesterov:
-        po_ref[:] = p - lr * (g + p.dtype.type(mu) * v_new)
+        po_ref[...] = p - lr * (g + p.dtype.type(mu) * v_new)
     else:
-        po_ref[:] = p - lr * v_new
-    vo_ref[:] = v_new
+        po_ref[...] = p - lr * v_new
+    vo_ref[...] = v_new
 
 
 def fused_momentum_tpu(p2d, g2d, v2d, lr, mu, use_nesterov, l2_decay):
     m, n = p2d.shape
-    bm = _pick_block_rows(m, n)
+    bm = _block_rows(m, 5 * n * p2d.dtype.itemsize)
     spec = pl.BlockSpec((bm, n), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_fused_momentum_kernel, mu=float(mu),
                           use_nesterov=bool(use_nesterov),
                           l2_decay=float(l2_decay)),
-        grid=(m // bm,),
+        grid=(pl.cdiv(m, bm),),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [spec] * 3,
         out_specs=[spec] * 2,
         out_shape=[jax.ShapeDtypeStruct((m, n), p2d.dtype)] * 2,
     )(lr.reshape(1).astype(p2d.dtype), p2d, g2d, v2d)
-
-
-def fused_dropout_tpu(x, key, rate, upscale_in_train):
-    """Dropout with on-core PRNG mask, regenerated in backward.
-
-    Returns (out, mask_fn) where mask_fn() materialises the uint8 keep-mask
-    with a second kernel from the same seed — called only if the consumer
-    actually fetches the Mask output, so XLA DCEs it otherwise.
-    """
-    seed = _seed_from_key(key)
-    shape = x.shape
-    n = shape[-1]
-    x2d = x.reshape(-1, n)
-    out = _fused_dropout(x2d, seed, float(rate), bool(upscale_in_train))
-
-    def mask_fn():
-        m = x2d.shape[0]
-        bm = _pick_block_rows(m, n)
-        mask = pl.pallas_call(
-            functools.partial(_dropout_mask_kernel,
-                              threshold=_threshold_for(float(rate))),
-            grid=(m // bm,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
-            out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8),
-        )(seed)
-        return mask.reshape(shape)
-
-    return out.reshape(shape), mask_fn

@@ -1,118 +1,74 @@
-"""Offline Mosaic-lowering pre-flight for Pallas TPU kernels.
+"""Offline Mosaic compile pre-flight for Pallas TPU kernels.
 
-Round-3's one hardware up-window was burned discovering that ``lax.erf``
-has no Mosaic lowering rule — the kernel traced fine, interpret mode ran
-fine, and the failure only surfaced on the real chip.  This module makes
-that class of failure a CPU-testable property: trace a function that
-contains ``pl.pallas_call``s, walk every kernel jaxpr (recursing through
-scan/cond/jit/custom-vjp sub-jaxprs), and reject any primitive the Mosaic
-TensorCore lowering registry has no rule for.
+A Pallas kernel can trace and run in interpret mode and still be refused by
+Mosaic: a primitive with no lowering rule (``lax.erf``), a block that breaks
+the (8, 128) tiling rule, a comparison the target's vector unit lacks.  The
+installed libtpu compiles for a TPU topology with no chip attached
+(``jax.experimental.topologies``), so that whole class of failure is a
+CPU-testable property: ``compile_for_tpu`` lowers a function for one
+TPU v5e device and runs the real XLA:TPU + Mosaic compile on the host.
+Nothing executes — numerics still need interpret mode or the chip.
 
-The registry is read from jax's own
-``jax._src.pallas.mosaic.lowering.lowering_rules`` (the dict Mosaic
-consults at lowering time, keyed by kernel type — TC is the TensorCore
-set), so the check can't drift from what the compiler actually supports.
 Reference analog: the per-op kernel-availability check in
 ``paddle/fluid/framework/operator.cc:1161`` (ChooseKernel raises before
 launch when no kernel is registered for the place) — here the "place" is
-the Mosaic TC target and the check runs at test time instead of on chip.
+the v5e TensorCore and the check runs at test time instead of on chip.
 """
 from __future__ import annotations
 
-import jax
+import functools
 
-__all__ = ["mosaic_tc_primitives", "find_unlowerable",
+import jax
+from jax.sharding import SingleDeviceSharding
+
+__all__ = ["compile_for_tpu", "mosaic_call_count",
            "assert_mosaic_lowerable", "MosaicLoweringError"]
+
+# libtpu's default host bounds are 2x2x1, so this is the smallest v5e
+# layout it describes without a chip; kernels compile for one device of it.
+_TOPOLOGY = "v5e:2x2"
 
 
 class MosaicLoweringError(RuntimeError):
-    """A pallas kernel uses a primitive Mosaic cannot lower."""
+    """A pallas kernel does not compile for the TPU."""
 
 
-def mosaic_tc_primitives() -> frozenset:
-    """Names of primitives the Mosaic TensorCore backend can lower."""
-    from jax._src.pallas.mosaic import lowering as _ml
-    rules = _ml.lowering_rules
-    # keyed by KernelType since jax 0.8; TC (TensorCore) is what
-    # pl.pallas_call targets on TPU.  On 0.4.x the registry is flat —
-    # primitive -> rule directly — so the keys ARE the TC set.
-    tc_key = next((k for k in rules if getattr(k, "name", "") == "TC"
-                   or str(k).endswith("TC")), None)
-    if tc_key is not None:
-        return frozenset(p.name for p in rules[tc_key])
-    if rules and all(hasattr(k, "name") for k in rules):
-        return frozenset(p.name for p in rules)
-    raise MosaicLoweringError(
-        f"could not locate the TensorCore rule set in jax's Mosaic "
-        f"lowering registry (keys: {list(rules)}) — jax internals "
-        f"moved; update mosaic_tc_primitives()")
+@functools.lru_cache(maxsize=1)
+def _tpu_device():
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name=_TOPOLOGY).devices[0]
 
 
-def _sub_jaxprs(eqn):
-    """Yield every Jaxpr/ClosedJaxpr reachable from an eqn's params."""
-    from jax._src import core as jcore
-    for v in eqn.params.values():
-        vs = v if isinstance(v, (list, tuple)) else (v,)
-        for item in vs:
-            if isinstance(item, jcore.ClosedJaxpr):
-                yield item.jaxpr
-            elif isinstance(item, jcore.Jaxpr):
-                yield item
+def compile_for_tpu(fn, *args):
+    """AOT-compile ``fn(*args)`` for one TPU v5e device, Mosaic included,
+    on a host with no chip.  ``args`` are arrays or ShapeDtypeStructs (only
+    shape and dtype are read).  Returns the ``jax.stages.Compiled``."""
+    sharding = SingleDeviceSharding(_tpu_device())
+    specs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    return jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).compile()
 
 
-def _walk_kernel(jaxpr, allowed, bad, kernel_name):
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name not in allowed:
-            bad.append((kernel_name, name))
-        for sub in _sub_jaxprs(eqn):
-            _walk_kernel(sub, allowed, bad, kernel_name)
+def mosaic_call_count(compiled) -> int:
+    """Mosaic custom calls in a compiled executable's HLO."""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
-def _find_pallas_calls(jaxpr, out):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            kernel = eqn.params.get("jaxpr")
-            kname = eqn.params.get("name_and_src_info", None)
-            out.append((str(kname) if kname is not None else "<kernel>",
-                        kernel))
-        else:
-            for sub in _sub_jaxprs(eqn):
-                _find_pallas_calls(sub, out)
-
-
-def find_unlowerable(fn, *args, **kwargs):
-    """Trace ``fn(*args, **kwargs)`` (no execution, works on any backend)
-    and return ``(bad, n_kernels)``: ``bad`` is a list of (kernel_name,
-    primitive_name) pairs for every primitive inside a pallas kernel that
-    Mosaic TC cannot lower (empty = all lowerable), ``n_kernels`` the
-    number of pallas_call sites found."""
-    closed = jax.make_jaxpr(fn)(*args, **kwargs)
-    calls = []
-    _find_pallas_calls(closed.jaxpr, calls)
-    allowed = mosaic_tc_primitives()
-    bad = []
-    for kname, kernel in calls:
-        if kernel is None:
-            continue
-        from jax._src import core as jcore
-        if isinstance(kernel, jcore.ClosedJaxpr):
-            kernel = kernel.jaxpr
-        _walk_kernel(kernel, allowed, bad, kname)
-    return bad, len(calls)
-
-
-def assert_mosaic_lowerable(fn, *args, require_kernels=True, **kwargs):
-    """Raise MosaicLoweringError naming the offending (kernel, primitive)
-    pairs; with require_kernels, also fail if NO pallas_call was found
-    (the sweep would silently pass on a refactor that drops the kernel)."""
-    bad, n_calls = find_unlowerable(fn, *args, **kwargs)
-    if require_kernels and n_calls == 0:
+def assert_mosaic_lowerable(fn, *args, require_kernels=True):
+    """Raise MosaicLoweringError if ``fn(*args)`` does not compile for the
+    TPU; with require_kernels, also if the compiled HLO holds NO Mosaic
+    call (the check would silently pass on a refactor that drops the
+    kernel)."""
+    try:
+        compiled = compile_for_tpu(fn, *args)
+    except Exception as e:      # noqa: BLE001 — jax raises several types
         raise MosaicLoweringError(
-            "no pallas_call found in traced function — preflight entry is "
-            "not exercising a kernel")
-    if bad:
-        lines = ", ".join(f"{k}: '{p}'" for k, p in bad)
+            f"does not compile for the TPU (would fail at compile time on "
+            f"the chip): {type(e).__name__}: {e}") from e
+    if require_kernels and mosaic_call_count(compiled) == 0:
         raise MosaicLoweringError(
-            f"pallas kernel uses primitives with no Mosaic TC lowering "
-            f"rule (would fail at compile time on real TPU): {lines}")
+            "no pallas_call found in the compiled function — preflight "
+            "entry is not exercising a kernel")

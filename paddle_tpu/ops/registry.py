@@ -113,6 +113,20 @@ class LoweringContext:
         # the batch, even if dim 0 aliases the bucket size), True when the
         # IR marks it batch-major (-1 leading dim), None when unknown
         self.cur_op_batch_major = None
+        # set by the executor when the whole block compiles as ONE
+        # GSPMD-partitioned program over a multi-device mesh
+        # (parallel/sharding.py wrap_with_plan, parallel/api.py
+        # wrap_with_mesh)
+        self.partitioned = False
+
+    def pallas_ok(self) -> bool:
+        """May a lowering take its Pallas TPU kernel?  On the tpu backend,
+        and not inside a GSPMD-partitioned program: Mosaic calls cannot be
+        partitioned automatically (jax refuses at lowering), so there
+        every op keeps its XLA lowering.  Under shard_map each device runs
+        its own kernel and this stays True."""
+        import jax
+        return jax.default_backend() == "tpu" and not self.partitioned
 
     def batch_mask(self, dim0):
         """Row-validity mask (bool[dim0]) when ``dim0`` is the bucketed

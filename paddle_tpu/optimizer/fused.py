@@ -4,10 +4,10 @@ Reference: the fluid stack fuses per-parameter optimizer ops into a single
 kernel over one contiguous buffer — `coalesce_tensor_op` packs grads and
 `fuse_adam_op_pass` / `fuse_sgd_op_pass` / `fuse_momentum_op_pass`
 (framework/ir/fuse_optimizer_ops_pass/) rewrite N small optimizer ops into one.
-Without this a BERT-base step runs ~200 small update kernels; worse, XLA will
-happily fuse an elementwise Adam update INTO the weight-gradient matmul it
-consumes, de-optimising the matmul tiling (observed 10x slowdown on the dW
-matmuls).  The TPU-native equivalent is therefore:
+Without this a BERT-base step runs ~200 small update kernels, and XLA is
+free to fuse an elementwise Adam update INTO the weight-gradient matmul it
+consumes (what that costs the matmul is not measured on this code).  The
+TPU-native equivalent is therefore:
 
   1. `jax.lax.optimization_barrier` between the backward pass and the update,
      so the optimizer never fuses into gradient matmuls, and
@@ -17,7 +17,7 @@ matmuls).  The TPU-native equivalent is therefore:
 
 The buffer is shaped (rows, LANE*8) with every parameter's segment row-aligned
 — a flat 1D buffer tempts XLA's remat compression into a bf16[N,2] layout that
-pads 64x on TPU tiles (observed: a 254M tensor padded to 15.6G of HBM).
+pads 64x on TPU tiles.
 """
 from __future__ import annotations
 
@@ -81,8 +81,8 @@ def make_fused_adam(param_values: Sequence[jax.Array], lr=1e-4, beta1=0.9,
     Small parameters (the ~200 biases/norm scales whose individual update
     kernels are pure launch overhead) are packed into one (rows, 1024) f32
     buffer and updated by a single kernel; large parameters update in place —
-    their kernels are already bandwidth-bound, and coalescing them costs
-    extra HBM copies plus minutes of XLA compile for the giant slice graph.
+    coalescing them costs extra HBM copies and a giant slice graph for XLA
+    to compile.
 
     state = (params_list, m_list, v_list, small_state, t).
     update_fn(state, grads) -> (new_state, params_list).

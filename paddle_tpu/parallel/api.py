@@ -31,25 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import mesh as mesh_registry
 
-# ---------------------------------------------------------------------------
-# jax-version compat, resolved ONCE at import (not per call): new
-# (use_mesh-era) jax exports shard_map at top level with the `check_vma`
-# switch; 0.4.x only has jax.experimental.shard_map with the same switch
-# named `check_rep`.  A per-call getattr probed this on EVERY wrapped-step
-# build; the resolution is a property of the installed jax, not the call.
-# ---------------------------------------------------------------------------
-_SHARD_MAP_FN = getattr(jax, "shard_map", None)
-if _SHARD_MAP_FN is not None:
-    _SHARD_MAP_CHECK_KW = "check_vma"
-else:                                   # 0.4.x fallback, import-time only
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP_FN
-    _SHARD_MAP_CHECK_KW = "check_rep"
-# use_mesh-era marker (jax >= 0.6 context-manager mesh API): informational
-# for callers that want to gate on the new ambient-mesh style
-USE_MESH_API = hasattr(jax.sharding, "use_mesh") \
-    or hasattr(jax, "set_mesh")
-
-
 def resolved_mesh(mesh: Optional[Mesh] = None) -> Optional[Mesh]:
     """THE mesh both planes share.  With an explicit mesh, install it as
     the process mesh (parallel/mesh.py) and return it; otherwise return
@@ -100,13 +81,12 @@ def wrap_with_mesh(fn, mesh: Mesh, program, batch_axis: str = "dp",
 
 
 def compat_shard_map(fn, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions, resolved at module import (the
-    top-level export + `check_vma` on use_mesh-era jax, the experimental
-    one + `check_rep` on 0.4.x).  The mesh is used as passed — an ad-hoc
-    shard_map never mutates the shared process mesh (resolved_mesh)."""
-    return _SHARD_MAP_FN(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs,
-                         **{_SHARD_MAP_CHECK_KW: check_vma})
+    """``jax.shard_map`` with replication checking off by default (the
+    collective lowerings in ops/collective_ops.py do not carry varying-
+    manual-axes types).  The mesh is used as passed — an ad-hoc shard_map
+    never mutates the shared process mesh (resolved_mesh)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def shard_map_step(fn, mesh: Mesh, in_specs, out_specs):

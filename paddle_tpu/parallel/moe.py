@@ -64,8 +64,7 @@ def moe_ffn(x, gate_w, w_in, w_out, axis_name: Optional[str] = None,
     E_local = E when axis_name is None).  Returns (out [T, D], aux_loss).
     """
     t, d = x.shape
-    from ..ops.collective_ops import axis_size
-    n = axis_size(axis_name) if axis_name is not None else 1
+    n = lax.axis_size(axis_name) if axis_name is not None else 1
     e_local = w_in.shape[0]
     e = e_local * n
     capacity = max(1, int(math.ceil(t / e * capacity_factor)))

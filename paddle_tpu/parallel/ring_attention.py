@@ -51,8 +51,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     Returns [B, H, T_local, D].
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    from ..ops.collective_ops import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     t_local = q.shape[-2]
     acc = jnp.float32

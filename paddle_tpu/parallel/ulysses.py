@@ -55,8 +55,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     (e.g. the pallas flash path) — it sees head-sharded, full-sequence
     tensors, so any single-device kernel drops in.
     """
-    from ..ops.collective_ops import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[1]
     if h % n != 0:
         raise ValueError(f"ulysses needs heads ({h}) divisible by the "

@@ -1574,16 +1574,18 @@ class FleetMetricsAggregator:
 class ServingFleet:
     """N replicas + router + health monitor + replacement.
 
-    Subprocess fleet (the deployment shape)::
+    Subprocess fleet (CPU hosts; refused on a TPU host, where a chip
+    belongs to one process and the parent already holds it)::
 
         fleet = ServingFleet(spec=demo_mlp_spec(), n_replicas=3,
-                             persistent_cache_dir="/var/cache/xla",
+                             persistent_cache_dir="/var/cache/paddle_tpu",
                              auto_replace=True)
         fut = fleet.submit({"x": rows})
         out = fut.result(timeout=5)
         fleet.close()
 
-    In-process fleet (tests / single-host canaries)::
+    In-process fleet (the only shape on a TPU host — one process drives
+    every chip, each engine on its own device; also tests)::
 
         fleet = ServingFleet(replicas=[ReplicaHandle("r0", engine=e0),
                                        ReplicaHandle("r1", engine=e1)])
@@ -1733,6 +1735,17 @@ class ServingFleet:
         name = name or f"r{self._n_spawned - 1}"
         if self.host_agents:
             return self._spawn_on_agent(name)
+        import jax
+        if jax.default_backend() == "tpu":
+            # a chip belongs to one process: this parent holds it (it has
+            # just asked JAX for the backend), so a child that needs the
+            # chip would fail or hang at start-up
+            raise RuntimeError(
+                "ServingFleet: refusing to start subprocess replicas on a "
+                "TPU host — a chip belongs to one process at a time.  Use "
+                "in-process replicas, each engine on its own device: "
+                "ServingFleet(replicas=[ReplicaHandle(name, engine=e), "
+                "...])")
         env = dict(os.environ)
         env.update(self.env)
         env.update(self._spec_env())
@@ -2176,9 +2189,8 @@ def _main(argv=None) -> int:
     args = ap.parse_args(argv)
     if not args.serve_replica:
         ap.error("only --serve-replica mode is supported")
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    from ..fluid import compile_cache
+    compile_cache.enable_jax_cache()
     serve_replica(json.loads(args.spec))
     return 0
 

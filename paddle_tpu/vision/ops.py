@@ -79,7 +79,7 @@ def batched_nms(boxes, scores, iou_threshold=0.5, top_k=100,
 
     ``max_outputs`` is the pre-round-4 keyword for ``top_k``, kept as an
     alias; the old (boxes, scores, mask) tuple return became the single
-    -1-padded index array (see PARITY.md)."""
+    -1-padded index array."""
     if max_outputs is not None:
         top_k = max_outputs
     import jax.numpy as jnp

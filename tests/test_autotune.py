@@ -20,7 +20,11 @@ def tune_env(tmp_path):
     turns it on.  Restores every touched flag afterwards."""
     saved = {k: core.get_flag(k) for k in
              ("auto_tune", "auto_tune_dir", "auto_tune_probe_steps",
-              "auto_tune_hbm_budget_mb", "persistent_cache_dir")}
+              "auto_tune_hbm_budget_mb", "persistent_cache_dir",
+              # the flag-kind knobs a committed winner writes: which
+              # candidate wins is a timing question, and a leaked
+              # max_inflight_steps=4 fails test_checkpoint_elastic
+              "max_inflight_steps", "pallas_min_seq")}
     core._FLAGS.update({"auto_tune": False,
                         "auto_tune_dir": str(tmp_path),
                         "auto_tune_probe_steps": 2,

@@ -17,7 +17,6 @@ EXAMPLES = ["mnist_static.py", "bert_dygraph.py", "ctr_boxps.py",
 @pytest.mark.parametrize("script", EXAMPLES)
 def test_example_runs(script):
     env = dict(os.environ)
-    env.pop("EXAMPLES_ON_TPU", None)
     env.pop("XLA_FLAGS", None)      # each script owns its device config
     r = subprocess.run(
         [sys.executable, os.path.join(ROOT, "examples", script)],
@@ -93,7 +92,6 @@ def test_cpp_trainer(tmp_path):
     out_dir = str(tmp_path / "m")
     env = dict(os.environ, CPP_TRAINER_PLATFORM="cpu")
     env.pop("XLA_FLAGS", None)          # the trainer owns device config
-    env.pop("EXAMPLES_ON_TPU", None)
     env["PYTHONPATH"] = ROOT + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     r = subprocess.run([exe, out_dir], capture_output=True, text=True,

@@ -7,7 +7,7 @@ semantics — these tests pin the op contract (eval-mode exactness,
 train-mode statistics, gradient structure) on any backend, and the
 TPU-only class adds the pallas/jnp cross-check when a chip is present.
 Fusion motivation: round-3 sweep showed ~13 MFU points lost at the
-dropout kernel boundaries (STATUS.md nodrop ablation)."""
+dropout kernel boundaries."""
 import numpy as np
 import pytest
 

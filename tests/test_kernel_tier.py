@@ -480,44 +480,26 @@ class TestSatellites:
         assert not _bias_broadcastable(None, q, k)
 
     def test_embedding_kernels_interpret_numerics(self):
-        """The Pallas gather+pool / scatter-add kernels in interpret mode
-        against the XLA reference (no TPU required)."""
-        import functools
-        from jax.experimental import pallas as pl
+        """The Pallas gather+pool / scatter-add kernels, through their
+        public wrappers in TPU interpret mode, against the XLA reference
+        (no TPU required).  Batch 5 is not a whole 8-row block and row 0
+        repeats an id, so the padding and the read-modify-write paths
+        both run."""
         from jax.experimental.pallas import tpu as pltpu
         from paddle_tpu.ops import pallas_kernels as pk
         rng = np.random.RandomState(0)
         w = jnp.asarray(rng.randn(64, 128).astype("float32"))
-        ids = jnp.asarray(rng.randint(0, 64, (4, 5)).astype("int32"))
-        wgt = jnp.asarray(rng.rand(4, 5).astype("float32"))
-        g = jnp.asarray(rng.randn(4, 128).astype("float32"))
-
-        fwd = pl.pallas_call(
-            functools.partial(pk._gather_pool_kernel, n_ids=5),
-            grid=(4,),
-            in_specs=[pl.BlockSpec((1, 5), lambda i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, 5), lambda i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((64, 128), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((4, 128), jnp.float32),
-            interpret=True)(ids, wgt, w)
+        ids_np = rng.randint(0, 64, (5, 6)).astype("int32")
+        ids_np[0, :3] = 7
+        ids = jnp.asarray(ids_np)
+        wgt = jnp.asarray(rng.rand(5, 6).astype("float32"))
+        g = jnp.asarray(rng.randn(5, 128).astype("float32"))
+        with pltpu.force_tpu_interpret_mode():
+            fwd = pk.fused_embedding_pool_tpu(w, ids, wgt)
+            bwd = pk.embedding_pool_grad_tpu(g, ids, wgt, 64)
         want = jnp.einsum("bsd,bs->bd", jnp.take(w, ids, axis=0), wgt)
         np.testing.assert_allclose(np.asarray(fwd), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)
-
-        bwd = pl.pallas_call(
-            functools.partial(pk._scatter_grad_kernel, n_ids=5),
-            grid=(4,),
-            in_specs=[pl.BlockSpec((1, 5), lambda i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, 5), lambda i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, 128), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((64, 128), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((64, 128), jnp.float32),
-            interpret=True)(ids, wgt, g)
         rows = g[:, None, :] * wgt[:, :, None]
         want_b = jax.ops.segment_sum(rows.reshape(-1, 128),
                                      ids.reshape(-1), num_segments=64)
@@ -526,7 +508,7 @@ class TestSatellites:
 
     def test_new_kernels_pass_mosaic_preflight(self):
         """Every pallas_call in the fused embedding/optimizer kernels
-        passes the Mosaic lowering pre-flight offline."""
+        compiles through Mosaic offline."""
         import functools
         from paddle_tpu.ops import pallas_kernels as pk
         from paddle_tpu.ops.pallas_preflight import assert_mosaic_lowerable
@@ -534,7 +516,7 @@ class TestSatellites:
         ids = jnp.zeros((2, 4), jnp.int32)
         wgt = jnp.ones((2, 4), jnp.float32)
         g = jnp.zeros((2, 128), jnp.float32)
-        p = jnp.zeros((8, 1024), jnp.float32)
+        p = jnp.zeros((1000, 1024), jnp.float32)   # ragged last row block
         assert_mosaic_lowerable(pk.fused_embedding_pool_tpu, w, ids, wgt)
         assert_mosaic_lowerable(
             lambda g_, i_, w_: pk.embedding_pool_grad_tpu(g_, i_, w_, 64),
@@ -547,138 +529,38 @@ class TestSatellites:
                               use_nesterov=True, l2_decay=1e-4),
             p, p, p, jnp.asarray(0.1))
 
-    # -- PR-18: streaming (row-block) embedding kernels ---------------------
+    def test_kernels_are_off_inside_a_partitioned_program(self,
+                                                           monkeypatch):
+        """Mosaic calls cannot be partitioned by GSPMD: a lowering takes
+        its kernel on the tpu backend only outside a partitioned
+        program."""
+        from paddle_tpu.ops.registry import LoweringContext
+        ctx = LoweringContext()
+        assert not ctx.pallas_ok()                  # cpu backend
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert ctx.pallas_ok()
+        ctx.partitioned = True
+        assert not ctx.pallas_ok()
 
-    @staticmethod
-    def _stream_fwd(w, ids, wgt, br, interpret=True):
-        """fused_embedding_pool_stream_tpu's exact pallas_call, interpret
-        mode (the wrapper itself has no interpret knob — CPU CI runs the
-        same grid/specs this way)."""
-        import functools
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
+    def test_table_beyond_vmem_takes_the_xla_lowering(self):
+        """The embedding kernels hold the table in VMEM whole; an 8MB
+        table is past that gate and stays on XLA's take/segment_sum."""
         from paddle_tpu.ops import pallas_kernels as pk
-        b, s = ids.shape
-        v, d = w.shape
-        vp = -(-v // br) * br
-        if vp != v:
-            w = jnp.pad(w, ((0, vp - v), (0, 0)))
-        return pl.pallas_call(
-            functools.partial(pk._gather_pool_stream_kernel, n_ids=s,
-                              block_rows=br),
-            grid=(b, vp // br),
-            in_specs=[pl.BlockSpec((1, s), lambda i, k: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, s), lambda i, k: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((br, d), lambda i, k: (k, 0))],
-            out_specs=pl.BlockSpec((1, d), lambda i, k: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, d), w.dtype),
-            interpret=interpret)(ids.astype(jnp.int32),
-                                 wgt.astype(w.dtype), w)
-
-    @staticmethod
-    def _stream_bwd(g, ids, wgt, vocab, br, interpret=True):
-        import functools
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-        from paddle_tpu.ops import pallas_kernels as pk
-        b, s = ids.shape
-        d = g.shape[-1]
-        vp = -(-vocab // br) * br
-        dw = pl.pallas_call(
-            functools.partial(pk._scatter_grad_stream_kernel, n_ids=s,
-                              block_rows=br),
-            grid=(vp // br, b),
-            in_specs=[pl.BlockSpec((1, s), lambda k, i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, s), lambda k, i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, d), lambda k, i: (i, 0))],
-            out_specs=pl.BlockSpec((br, d), lambda k, i: (k, 0)),
-            out_shape=jax.ShapeDtypeStruct((vp, d), g.dtype),
-            interpret=interpret)(ids.astype(jnp.int32),
-                                 wgt.astype(g.dtype), g)
-        return dw[:vocab] if vp != vocab else dw
-
-    def test_streaming_fwd_interpret_numerics(self):
-        """Streaming gather+pool == XLA reference; vocab 100 is NOT a
-        slab multiple, so the padded-tail path is exercised too."""
-        rng = np.random.RandomState(3)
-        w = jnp.asarray(rng.randn(100, 128).astype("float32"))
-        ids = jnp.asarray(rng.randint(0, 100, (4, 5)).astype("int32"))
-        wgt = jnp.asarray(rng.rand(4, 5).astype("float32"))
-        got = self._stream_fwd(w, ids, wgt, br=16)
-        want = jnp.einsum("bsd,bs->bd", jnp.take(w, ids, axis=0), wgt)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-6, atol=1e-6)
-
-    def test_streaming_fwd_bit_exact_on_dyadic(self):
-        """On dyadic values the slab reassociation is exact — the
-        streaming sum is the whole-table sum regrouped, each term
-        computed once."""
-        rng = np.random.RandomState(4)
-        w = jnp.asarray((rng.randint(-8, 8, (96, 128)) * 0.25)
-                        .astype("float32"))
-        ids = jnp.asarray(rng.randint(0, 96, (3, 7)).astype("int32"))
-        wgt = jnp.asarray((rng.randint(0, 4, (3, 7)) * 0.5)
-                          .astype("float32"))
-        got = self._stream_fwd(w, ids, wgt, br=32)
-        want = jnp.einsum("bsd,bs->bd", jnp.take(w, ids, axis=0), wgt)
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-
-    def test_streaming_bwd_bit_identical_to_whole_table(self):
-        """The k-outermost grid keeps per-row contributions in the same
-        (i, j) order as the whole-table scatter kernel — bit-identical,
-        not allclose (duplicate ids included)."""
-        import functools
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-        from paddle_tpu.ops import pallas_kernels as pk
-        rng = np.random.RandomState(5)
-        vocab = 80                       # not a multiple of br=32
-        ids_np = rng.randint(0, vocab, (4, 6)).astype("int32")
-        ids_np[0, :3] = 7                # duplicate ids in one batch row
-        ids = jnp.asarray(ids_np)
-        wgt = jnp.asarray(rng.rand(4, 6).astype("float32"))
-        g = jnp.asarray(rng.randn(4, 128).astype("float32"))
-        whole = pl.pallas_call(
-            functools.partial(pk._scatter_grad_kernel, n_ids=6),
-            grid=(4,),
-            in_specs=[pl.BlockSpec((1, 6), lambda i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, 6), lambda i: (i, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((1, 128), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((vocab, 128), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((vocab, 128), jnp.float32),
-            interpret=True)(ids, wgt, g)
-        stream = self._stream_bwd(g, ids, wgt, vocab, br=32)
-        assert np.array_equal(np.asarray(stream), np.asarray(whole))
-
-    def test_streaming_kernels_pass_mosaic_preflight(self):
-        """An 8MB table (past the 4MB whole-table VMEM gate) lowers
-        through Mosaic via the public dispatchers — big vocabs no longer
-        fall back to XLA."""
-        from paddle_tpu.ops import pallas_kernels as pk
-        from paddle_tpu.ops.pallas_preflight import assert_mosaic_lowerable
-        w = jnp.zeros((16384, 128), jnp.float32)       # 8MB
         ids = jnp.zeros((2, 4), jnp.int32)
-        wgt = jnp.ones((2, 4), jnp.float32)
-        g = jnp.zeros((2, 128), jnp.float32)
-        assert not pk._emb_whole_table_ok(w)
-        assert pk.fused_embedding_pool_supported(w, ids)
-        assert_mosaic_lowerable(pk.fused_embedding_pool_tpu, w, ids, wgt)
-        assert_mosaic_lowerable(
-            lambda g_, i_, w_: pk.embedding_pool_grad_tpu(g_, i_, w_,
-                                                          16384),
-            g, ids, wgt)
+        assert pk.fused_embedding_pool_supported(
+            jnp.zeros((8192, 128), jnp.float32), ids)          # 4MB
+        assert not pk.fused_embedding_pool_supported(
+            jnp.zeros((16384, 128), jnp.float32), ids)         # 8MB
 
-    def test_stream_block_rows_sizing(self):
+    def test_block_rows_alignment(self):
+        """Row blocks are the whole array or a multiple of the widest
+        sublane tile, whatever the row count's factors."""
         from paddle_tpu.ops import pallas_kernels as pk
-        br = pk._emb_stream_block_rows(128, 4)
-        assert br % 8 == 0 and br >= 8
-        assert br * 128 * 4 <= pk._EMB_VMEM_BYTES
+        assert pk._block_rows(5, 1024) == 5
+        for m in (107_417, 8192, 98_304, 33):
+            bm = pk._block_rows(m, 8 * 1024 * 4)
+            assert bm == m or bm % 32 == 0
+            assert 2 * bm * 8 * 1024 * 4 <= 8 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -805,9 +687,35 @@ class TestFusePagedAttention:
         assert pa.attrs["page_size"] == 4
         assert pa.attrs["neg"] == pytest.approx(1e30)
 
+    @pytest.mark.parametrize("page_size", [8, 1])
+    def test_paged_kernel_interpret_numerics(self, page_size):
+        """The paged kernel through its wrapper in TPU interpret mode
+        against the XLA gather lowering, ragged lengths included."""
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops import pallas_kernels as pk
+        from paddle_tpu.ops.attention import _paged_reference
+        rng = np.random.RandomState(7)
+        b, s, r, d, ps = 3, 32, 96, 128, 8
+        q = jnp.asarray(rng.randn(b, d).astype("float32"))
+        kp = jnp.asarray(rng.randn(r, d).astype("float32"))
+        vp = jnp.asarray(rng.randn(r, d).astype("float32"))
+        pages = rng.permutation(r // ps)[:b * (s // ps)].reshape(b, -1)
+        idx = (pages[:, :, None] * ps + np.arange(ps)).reshape(b, s)
+        lens = np.array([5, 32, 17], "int32")
+        with pltpu.force_tpu_interpret_mode():
+            got = pk.paged_flash_attention_tpu(
+                q, kp, vp, jnp.asarray(idx.astype("int32")),
+                jnp.asarray(lens), 0.25, page_size=page_size)
+        valid = (np.arange(s)[None] < lens[:, None]).astype("float32")
+        want = _paged_reference(q, kp, vp,
+                                jnp.asarray(idx.reshape(-1).astype("int32")),
+                                jnp.asarray(valid), 0.25, 1e30)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
     def test_paged_kernel_mosaic_preflight(self):
-        """The paged flash kernel passes the Mosaic lowering pre-flight
-        offline (lane-aligned head dim, SMEM page table)."""
+        """The paged flash kernel compiles through Mosaic offline
+        (lane-aligned head dim, scalar-prefetched page table)."""
         import functools
         from paddle_tpu.ops import pallas_kernels as pk
         from paddle_tpu.ops.pallas_preflight import assert_mosaic_lowerable

@@ -131,3 +131,45 @@ class TestPersistentCache:
         # a different program under the same dir cold-compiles
         cold3, _ = child("h * 2.0")
         assert cold3 > 0
+
+
+class TestJaxCacheResolver:
+    """Where jax's own compilation cache lives is decided in one place
+    (compile_cache.jax_cache_dir): outside the code when
+    JAX_COMPILATION_CACHE_DIR is set, else one fixed path."""
+
+    @staticmethod
+    def _spy(monkeypatch):
+        import jax
+        calls = []
+        # record, do not apply: the session's jax config stays untouched
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: calls.append((k, v)))
+        return calls
+
+    def test_variable_set_means_code_never_sets_the_dir(self, monkeypatch):
+        calls = self._spy(monkeypatch)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+        assert cc.enable_jax_cache() == "/x"
+        assert cc.PersistentCache(os.path.join(_ROOT, ".jax_cache")).root
+        keys = [k for k, _ in calls]
+        assert "jax_compilation_cache_dir" not in keys
+        # the thresholds are still zeroed: every entry is worth persisting
+        assert "jax_persistent_cache_min_compile_time_secs" in keys
+
+    def test_unset_is_one_fixed_path_in_every_process(self, monkeypatch):
+        calls = self._spy(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        fixed = os.path.join(_ROOT, ".jax_cache")
+        assert cc.enable_jax_cache() == fixed
+        assert ("jax_compilation_cache_dir", fixed) in calls
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        other = subprocess.run(
+            [sys.executable, "-c",
+             "from paddle_tpu.fluid import compile_cache as cc; "
+             "print(cc.jax_cache_dir())"],
+            cwd="/", env=dict(env, PYTHONPATH=_ROOT), capture_output=True,
+            text=True, timeout=120)
+        assert other.returncode == 0, other.stderr[-1000:]
+        assert other.stdout.strip().splitlines()[-1] == fixed

@@ -565,3 +565,12 @@ class TestSubprocessReplica:
             assert r.proc.poll() is not None
         finally:
             fl.close()
+
+    def test_refused_on_a_tpu_host(self, monkeypatch):
+        """A chip belongs to one process, and the parent that asks JAX
+        for the platform holds it: subprocess replicas are refused there,
+        with a message that names in-process replicas."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="in-process replicas"):
+            F.ServingFleet(spec=F.demo_mlp_spec(hidden=16), n_replicas=1,
+                           quiet_children=True)

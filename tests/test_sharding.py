@@ -437,22 +437,8 @@ def test_run_scan_rejects_sharded_programs():
 
 
 # ---------------------------------------------------------------------------
-# satellite: compat_shard_map resolved once at import; one shared mesh
+# satellite: one shared mesh
 # ---------------------------------------------------------------------------
-
-def test_compat_shard_map_resolved_at_import():
-    # the generation probe ran at import: module constants, no per-call
-    # getattr.  Whichever generation, the resolved callable must exist
-    # and the kw name must match it.
-    assert callable(papi._SHARD_MAP_FN)
-    assert papi._SHARD_MAP_CHECK_KW in ("check_vma", "check_rep")
-    if getattr(jax, "shard_map", None) is not None:
-        assert papi._SHARD_MAP_FN is jax.shard_map
-        assert papi._SHARD_MAP_CHECK_KW == "check_vma"
-    else:
-        assert papi._SHARD_MAP_CHECK_KW == "check_rep"
-    assert isinstance(papi.USE_MESH_API, bool)
-
 
 def test_both_planes_share_one_mesh_object():
     mesh = one_dev_mesh("dp")

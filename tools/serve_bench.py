@@ -39,10 +39,10 @@ breaker transitions.  Same seed ⇒ same injected-fault sequence:
 
     python tools/serve_bench.py --chaos 42 --fleet 2 --qps 60 --seconds 6
 
-Emits one JSON line (machine-readable, bench.py-style) and appends it
-to BENCH_evidence.json via bench.record_evidence on real accelerators.
-``bench.py --model serve`` (child mode) rides this module for the
-driver-window serving row.
+Emits one JSON line (machine-readable, bench.py-style).
+``bench.py --model serve`` rides this module's single-engine leg.  The
+``--fleet`` legs start subprocess replicas, which ``ServingFleet`` refuses
+on a TPU host (a chip belongs to one process; docs/serving.md).
 """
 from __future__ import annotations
 
@@ -500,13 +500,10 @@ def fleet_decode_leg(n_replicas=2, n_requests=24, max_new=6, qps=50.0,
     for the whole fleet.  The identity contract (routed == engine-
     direct, preserved across migration) is proved by the test suite;
     this leg prices the plane."""
-    import shutil
-    import tempfile
-
+    from paddle_tpu.fluid import compile_cache
     from paddle_tpu.serving import fleet as fleet_mod
 
-    own_cache = cache_dir is None
-    cache_dir = cache_dir or tempfile.mkdtemp(prefix="serve-dec-cache-")
+    cache_dir = cache_dir or compile_cache.jax_cache_dir()
     spec = fleet_mod.demo_decode_spec(vocab=vocab, page_size=page_size,
                                       seed=seed)
     prompts = decode_workload(n_requests, shared_prefix_ratio, vocab,
@@ -540,8 +537,6 @@ def fleet_decode_leg(n_replicas=2, n_requests=24, max_new=6, qps=50.0,
         fstats = fl.stats()
     finally:
         fl.close()
-        if own_cache:
-            shutil.rmtree(cache_dir, ignore_errors=True)
     return {
         "replicas": int(n_replicas),
         "requests": len(prompts),
@@ -572,15 +567,11 @@ def fleet_bench(n_replicas=2, qps=200.0, n_requests=400, sizes=(1, 2, 4, 8),
     sustained QPS, latency percentiles, ejection latency, requests
     rerouted, warm spin-up seconds, and (the invariant) how many
     accepted requests were lost — which must be 0."""
-    import shutil
-    import tempfile
-
     from paddle_tpu.distributed import faultline
-    from paddle_tpu.fluid import trace
+    from paddle_tpu.fluid import compile_cache, trace
     from paddle_tpu.serving import fleet as fleet_mod
 
-    own_cache = cache_dir is None
-    cache_dir = cache_dir or tempfile.mkdtemp(prefix="serve-fleet-cache-")
+    cache_dir = cache_dir or compile_cache.jax_cache_dir()
     m = trace.metrics()
     chips_per_replica = _mesh_chips(replica_mesh)
     spec = fleet_mod.demo_mlp_spec(
@@ -728,16 +719,12 @@ def fleet_bench(n_replicas=2, qps=200.0, n_requests=400, sizes=(1, 2, 4, 8),
             faultline.uninstall()
         fl.close()
     dec_leg = None
-    try:
-        if decode:
-            # routed-decode leg rides the same report line: one JSON
-            # object carries examples/s/chip AND tokens/s/chip
-            dec_leg = fleet_decode_leg(
-                n_replicas=n_replicas, policy=policy, seed=seed,
-                quiet=quiet)
-    finally:
-        if own_cache:
-            shutil.rmtree(cache_dir, ignore_errors=True)
+    if decode:
+        # routed-decode leg rides the same report line: one JSON
+        # object carries examples/s/chip AND tokens/s/chip
+        dec_leg = fleet_decode_leg(
+            n_replicas=n_replicas, policy=policy, seed=seed,
+            cache_dir=cache_dir, quiet=quiet)
 
     report = {
         "metric": "fleet_sustained_qps",
@@ -856,14 +843,14 @@ def main(argv=None):
     ap.add_argument("--policy", default="least_queue",
                     choices=("least_queue", "round_robin"))
     ap.add_argument("--cache-dir", default=None,
-                    help="fleet mode: shared persistent compile cache "
-                         "(default: a temp dir per run)")
+                    help="fleet mode: where the replicas' program-level "
+                         "compile index lives (default: beside jax's "
+                         "compilation cache, compile_cache.jax_cache_dir())")
     ap.add_argument("--watchdog-stall-s", type=float, default=2.0)
     args = ap.parse_args(argv)
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    from paddle_tpu.fluid import compile_cache
+    compile_cache.enable_jax_cache()
 
     n = args.requests
     if args.seconds:
