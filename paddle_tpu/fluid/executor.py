@@ -206,18 +206,24 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
     # write/read_to_array resolve their slot at trace time
     const_env: Dict[str, float] = {}
     n_dispatched = 0
+    after_backward = False
     for i, op in enumerate(op_list):
         if stop_at is not None and i >= stop_at:
             break
         if op.type in ("feed", "fetch"):
             continue
         n_dispatched += 1
+        # the scope that charges every device instruction this op emits to
+        # it (device_stats: label, role, instance).  Trace time only.
+        op_scope = device_stats.op_scope(op, after_backward)
+        after_backward = after_backward or op.attrs.get("op_role") == 1
         if op.type in ("while", "conditional_block", "select_input",
                        "select_output"):
             for n in op.output_arg_names:    # runtime writes: un-fold
                 const_env.pop(n, None)
             _t0 = trace.now() if tr_on else 0
-            control_flow_impl.run_control_flow_op(op, block, env, ctx)
+            with jax.named_scope(op_scope):
+                control_flow_impl.run_control_flow_op(op, block, env, ctx)
             if tr_on:
                 trace.complete(op.type, _t0, cat="op")
             continue
@@ -228,11 +234,13 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
             if amp_cast and slot in amp_cast:
                 # folded AMP cast (passes/amp.py prune_redundant_casts):
                 # the astype happens here, inline, instead of as its own
-                # dispatched cast op — zero extra ops in the traced block
+                # dispatched cast op — zero extra ops in the traced block.
+                # Inside the op's scope: the cast is the consumer's cost.
                 dts = amp_cast[slot]
-                vals = [env[n] if j >= len(dts) or dts[j] is None
-                        else env[n].astype(dts[j])
-                        for j, n in enumerate(names) if n in env]
+                with jax.named_scope(op_scope):
+                    vals = [env[n] if j >= len(dts) or dts[j] is None
+                            else env[n].astype(dts[j])
+                            for j, n in enumerate(names) if n in env]
             else:
                 vals = [env[n] for n in names if n in env]
             if vals or names:
@@ -264,10 +272,11 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
             # batch-major, so a parameter whose dim 0 aliases the bucket
             # size is never masked
             ctx.cur_op_batch_major = _batch_major_hint(block, op)
-        # named_scope: per-op spans in profiler traces / HLO metadata
-        # (platform/profiler.h:127 RecordEvent placement, operator.cc:1077)
+        # named_scope: the op in the executable's HLO metadata
+        # (platform/profiler.h:127 RecordEvent placement, operator.cc:1077);
+        # the host span below keeps the plain op type
         _t0 = trace.now() if tr_on else 0
-        with jax.named_scope(op.type):
+        with jax.named_scope(op_scope):
             if call_op is not None:
                 outs = call_op(opdef, ins, op_attrs, ctx)
             else:
@@ -996,6 +1005,7 @@ class Executor:
         while cap > 0 and len(self._cache) > cap:
             old_key, _ = self._cache.popitem(last=False)
             trace.metrics().counter("executor.compile_cache_evict").inc()
+            device_stats.forget((id(self), old_key))
             fp = self._footprints.pop(old_key, None)
             if fp is not None:
                 device_stats.unpublish(fp.get("label", ""))
@@ -1011,8 +1021,24 @@ class Executor:
         """AOT cost/memory analysis of a freshly compiled executable,
         published as per-executable gauges and kept beside the LRU for
         OOM forensics.  Runs only on a compile miss and only when
-        FLAGS_device_cost_analysis allows — never on the step path."""
-        if compiled.jitted is None or not device_stats.capture_enabled():
+        FLAGS_device_cost_analysis allows — never on the step path.
+        Before that gate, every miss leaves device_stats what it needs
+        to map the executable's instructions to Program ops later."""
+        if compiled.jitted is None:
+            return None
+        # always, switch or no switch: what device_stats.op_maps() needs
+        # to read this executable's Program-op map on demand, after a
+        # traced window.  The callable and the arguments' structs (placed
+        # where the step really receives them): no buffer.  Outlives
+        # close(); retired by the LRU (_cache_store).
+        example_args = device_stats.sds_tree(
+            tuple(example_args),
+            shardings=getattr(compiled.fn, "in_shardings", True))
+        device_stats.remember(
+            (id(self), key), compiled.jitted, example_args,
+            label=f"{key[0][:12]}:{compiled.n_ops}ops"
+            + (f":scan{scan}" if scan else ""))
+        if not device_stats.capture_enabled():
             return None
         # label salt includes THIS executor: two Executors compiling the
         # same (program, scope) produce identical cache keys, and a
@@ -1023,7 +1049,8 @@ class Executor:
                  + hashlib.sha1(repr((id(self), key)).encode())
                  .hexdigest()[:6])
         info = device_stats.capture(compiled.jitted, example_args,
-                                    label=label, n_devices=n_devices)
+                                    label=label, n_devices=n_devices,
+                                    op_map_key=(id(self), key))
         if info is None:
             return None
         info["bucket"] = bucket
@@ -1109,7 +1136,9 @@ class Executor:
         seed = program.random_seed if program.random_seed is not None else 0
         info = device_stats.capture(
             compiled.jitted,
-            (mut, ro, feeds, jax.random.PRNGKey(seed)),
+            device_stats.sds_tree(
+                (mut, ro, feeds, jax.random.PRNGKey(seed)),
+                shardings=getattr(compiled.fn, "in_shardings", True)),
             n_devices=plan.n_devices if plan is not None else 1)
         if info is not None:
             info["bucket"] = bucket
@@ -1459,3 +1488,5 @@ class Executor:
         self._async_runners.clear()
         self._cache.clear()
         _unpublish_footprints(self._footprints)
+        # device_stats' remembered executables stay: their readers (the
+        # profiler's table, the benchmark's metrics) run after close()

@@ -10,23 +10,41 @@ TPU-native, two tiers:
 * ``jax.profiler`` XPlane traces (TensorBoard / Perfetto) for device-side
   op time — best effort: on backends/headless setups where
   ``start_trace`` raises, the profiler DEGRADES to host-only tracing
-  instead of crashing the training run.
+  instead of crashing the training run.  Only the profiler's DEVICE half
+  is started: with its host half on, the TPU runtime logs every tile it
+  transposes while staging a batch (4.3 M events for one 154 MB image
+  batch, PERF.md section 3) and the traced steps slow tenfold.
 
-``RecordEvent`` spans land in both tiers, so host annotations line up with
-device ops in either viewer.
+``stop_profiler`` prints two tables.  Where the device trace holds ``XLA
+Ops``: **device time by Program op** (``device_stats.device_time_by_op``:
+every device instruction charged to the Program op whose scope its HLO
+metadata carries), per step.  Below it, always: **host time by span**, the host plane's
+summary; its ``cat="op"`` rows are each op's lowering time, taken once per
+compile and not per step: what explains a long trace + compile, never
+device time.
+
+``RecordEvent`` spans land in the host plane; the exported host timeline's
+metadata carries the wall clock of its epoch (``epoch_unix_ns``), and the
+device trace that of its session start (``profile_start_time``), so the two
+lie on one clock.
 """
 from __future__ import annotations
 
 import contextlib
+import glob
 import os
 import sys
 import time
 
 import jax
 
-from . import trace
+from . import device_stats, trace
 
 _DEFAULT_PATH = "/tmp/paddle_tpu_profile"
+
+# what the host plane's table is, said in its heading: not device time
+HOST_TABLE_TITLE = ("Host time by span; an op's row is its lowering time, "
+                    "once per compile, not per step")
 
 # whether a jax.profiler trace session is live (start/stop must pair)
 _jax_trace_active = False
@@ -39,7 +57,10 @@ def _start_jax_trace(profile_path: str) -> bool:
     if _jax_trace_active:
         return True
     try:
-        jax.profiler.start_trace(profile_path)
+        options = jax.profiler.ProfileOptions()
+        options.host_tracer_level = 0       # the device's half only: see
+        options.python_tracer_level = 0     # the module docstring
+        jax.profiler.start_trace(profile_path, profiler_options=options)
         _jax_trace_active = True
         return True
     except Exception as e:          # noqa: BLE001 — degrade by contract
@@ -49,15 +70,37 @@ def _start_jax_trace(profile_path: str) -> bool:
         return False
 
 
-def _stop_jax_trace() -> None:
+def _stop_jax_trace() -> bool:
+    """True where a live device trace was stopped (and so written)."""
     global _jax_trace_active
     if not _jax_trace_active:
-        return
+        return False
     _jax_trace_active = False
     try:
         jax.profiler.stop_trace()
+        return True
     except Exception:               # noqa: BLE001 — stop must not raise
-        pass
+        return False
+
+
+def device_op_table(profile_path: str, sorted_key=None):
+    """The device-time-by-Program-op table of the newest device trace under
+    ``profile_path``, or None where there is none or it holds no device
+    plane with ``XLA Ops`` (the CPU's)."""
+    files = glob.glob(os.path.join(profile_path, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    if not files:
+        return None
+    try:
+        table = device_stats.device_time_by_op(
+            max(files, key=os.path.getmtime))
+    except Exception as e:          # noqa: BLE001 — a report, not the run
+        print(f"paddle_tpu.profiler: device trace unreadable "
+              f"({type(e).__name__}: {e})", file=sys.stderr)
+        return None
+    if table is None:
+        return None
+    return device_stats.format_device_ops(table, sorted_key)
 
 
 def start_profiler(state="All", tracer_option="Default",
@@ -69,13 +112,18 @@ def start_profiler(state="All", tracer_option="Default",
 
 
 def stop_profiler(sorted_key=None, profile_path=_DEFAULT_PATH):
-    """Stop profiling; print the reference-style sorted op-time summary and
-    export the host timeline next to the device trace."""
-    _stop_jax_trace()
+    """Stop profiling; print device time by Program op (where the device
+    trace holds any), then the host plane's sorted summary, and export the
+    host timeline next to the device trace."""
+    if _stop_jax_trace():
+        table = device_op_table(profile_path, sorted_key)
+        if table:
+            print(table)
     if trace.get_events():
         out = os.path.join(profile_path, "paddle_tpu_timeline.json")
         trace.export_chrome_trace(out)
-        print(trace.summary_table(sorted_key or "total"))
+        print(trace.summary_table(sorted_key or "total",
+                                  title=HOST_TABLE_TITLE))
         print(f"[profiler] host timeline: {out} "
               f"(chrome://tracing / ui.perfetto.dev)")
 

@@ -79,6 +79,9 @@ class _State:
         self.lock = threading.Lock()
         self.events: List[Dict[str, Any]] = []
         self.epoch_ns = time.perf_counter_ns()
+        # the wall clock at the epoch: exported, it lays this timeline on
+        # the device trace's clock (wall-clock ns since its session start)
+        self.epoch_wall_ns = time.time_ns()
         self.path = os.environ.get("FLAGS_trace_path") or _DEFAULT_PATH
         self.atexit_registered = False
         # buffer bound: a days-long traced run must degrade (drop + count),
@@ -784,7 +787,8 @@ def export_chrome_trace(path: Optional[str] = None) -> str:
                         # stitcher place several per-process traces
                         # (each in its own perf_counter coordinate
                         # system) on one common axis
-                        "epoch_unix_ts": time.time() - elapsed_us() / 1e6,
+                        "epoch_unix_ts": _state.epoch_wall_ns / 1e9,
+                        "epoch_unix_ns": _state.epoch_wall_ns,
                         "dropped_events": _state.dropped,
                         "metrics": _registry.snapshot()}}
     d = os.path.dirname(os.path.abspath(path))
@@ -842,13 +846,17 @@ def op_summary(sorted_key: str = "total", cats=_SUMMARY_CATS):
     return out
 
 
-def summary_table(sorted_key: str = "total", cats=_SUMMARY_CATS) -> str:
+def summary_table(sorted_key: str = "total", cats=_SUMMARY_CATS,
+                  title: str = "Profiling Report") -> str:
     """The reference profiler's text report (profiler.cc PrintProfiler
-    shape): Event / Calls / Total / Min. / Max. / Ave. in microseconds."""
+    shape): Event / Calls / Total / Min. / Max. / Ave. in microseconds.
+    These are HOST spans: under whole-block jit a ``cat="op"`` row is the
+    op's lowering time, once per compile (module docstring); device time
+    by op is ``device_stats.device_time_by_op``."""
     rows = op_summary(sorted_key, cats)
     head = (f"{'Event':<40s} {'Calls':>8s} {'Total(us)':>12s} "
             f"{'Min(us)':>10s} {'Max(us)':>10s} {'Ave(us)':>10s}")
-    bar = "-" * 25 + f"  Profiling Report (sorted by {sorted_key})  " \
+    bar = "-" * 25 + f"  {title} (sorted by {sorted_key})  " \
         + "-" * 25
     lines = [bar, head]
     for name, calls, total, lo, hi, ave in rows:

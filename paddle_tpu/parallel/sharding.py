@@ -522,4 +522,7 @@ def wrap_with_plan(fn, plan: ShardingPlan, shapes: Dict[str, Any],
         key = jax.device_put(step_key, key_sh)
         return jitted(mut, ro, fd, key)
 
+    # where the jitted step's arguments really are: what an AOT lowering of
+    # the very program that ran describes (device_stats.sds_tree)
+    wrapped.in_shardings = (mut_sh, ro_sh, feed_sh, key_sh)
     return wrapped, jitted
