@@ -343,6 +343,90 @@ def roll_flash(tiny, tpu, key):
             "rel_err_out_dq_dk_dv": [round(e, 4) for e in errs]}
 
 
+def roll_fused_attention(tiny, tpu, key):
+    """The fused attention kernel through the op's lowering at BERT's two
+    lengths, padding bias as its [B, 1, 1, S] row, dropout on the
+    probabilities at DROPOUT: the keep rate, the backward's regenerated mask
+    against the forward's (row and column counts of the exported mask, read
+    back exactly from a uniform-attention call in float32), and outputs and
+    dQ/dK/dV against a float32 reference fed the exported mask."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    p = DROPOUT
+    seed = 11
+    drop_key = LoweringContext(base_key=key).key_for(seed)
+    attrs = {"dropout_rate": p, "dropout_seed": seed,
+             "dropout_implementation": "upscale_in_train"}
+    f32 = jnp.float32
+
+    def op(scale, mask):
+        def f(q, k, v):
+            ins = {"Q": [q], "K": [k], "V": [v]}
+            if mask is not None:
+                ins["Mask"] = [mask]
+            return get_op("fused_multihead_attention").fn(
+                ins, dict(attrs, scale=scale),
+                LoweringContext(base_key=key))["Out"][0]
+        return f
+
+    def fwd_bwd(f, w):
+        def g(q, k, v):
+            out, vjp = jax.vjp(f, q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(g)
+
+    rows = []
+    shapes = [(2, 2, 128, 64), (1, 2, 512, 64)] if tiny \
+        else [(16, 12, 128, 64), (8, 12, 512, 64)]
+    for i, (b, h, t, d) in enumerate(shapes):
+        ks = jax.random.split(jax.random.fold_in(key, i), 4)
+        keep = rate = None
+        if tpu:
+            keep = pk.fused_attention_keep_mask((b, h, t, d), t, p, drop_key)
+            rate = float(jnp.mean(keep.astype(f32)))
+            assert abs(rate - (1 - p)) < 1e-3, f"keep rate {rate:.5f}"
+            # q = 0: every probability is 1/t, so out * t * (1 - p) counts
+            # the forward mask's rows (v = 1) and dv the backward mask's
+            # columns (dout = 1)
+            ones = jnp.ones((b, h, t, d), f32)
+            (out, _, _, dv), _ = run_lowered(
+                fwd_bwd(op(1.0, None), ones), jnp.zeros_like(ones), ones,
+                ones, expect_mosaic=tpu)
+            count = t * (1 - p)
+            kf = np.asarray(keep, np.float32)
+            assert np.abs(np.asarray(out)[..., 0] * count
+                          - kf.sum(-1)).max() < 0.05, "forward mask"
+            assert np.abs(np.asarray(dv)[..., 0] * count
+                          - kf.sum(-2)).max() < 0.05, "backward mask"
+
+        q, k, v = (jax.random.normal(kk, (b, h, t, d), jnp.bfloat16)
+                   for kk in ks[:3])
+        w = jax.random.normal(ks[3], (b, h, t, d), f32)
+        pad = (jnp.arange(t)[None, :] >= (3 * t) // 4) \
+            & (jnp.arange(b)[:, None] % 2 == 1)
+        mask = jnp.where(pad, -10000.0, 0.0).astype(f32)[:, None, None]
+        scale = d ** -0.5
+        got, n = run_lowered(fwd_bwd(op(scale, mask), w), q, k, v,
+                             expect_mosaic=tpu)
+        row = {"shape": [b, h, t, d], "mosaic": n, "keep_rate": rate}
+        if tpu:
+            def ref(q, k, v):
+                s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(f32),
+                               k.astype(f32)) * scale + mask
+                pr = jnp.where(keep != 0, jax.nn.softmax(s, -1) / (1 - p),
+                               0.0)
+                return jnp.einsum("bhqk,bhkd->bhqd", pr, v.astype(f32))
+            want = fwd_bwd(ref, w)(q, k, v)
+            errs = [rel_err(a, b_) for a, b_ in zip(got, want)]
+            assert max(errs) < 3e-2, errs    # bf16 operands, f32 reference
+            row["rel_err_out_dq_dk_dv"] = [round(e, 4) for e in errs]
+        rows.append(row)
+    return {"cases": rows}
+
+
 def roll_dropout(tiny, tpu, key):
     """dropout / fused_dropout_add / fused_act_dropout at both shapes and
     both dtypes: keep rate within 1% of 1-p, kept values exact, and the
@@ -628,6 +712,7 @@ def roll_paged(tiny, tpu, key):
 # which of pallas_kernels.__all__ each roll-call entry drives
 ROLL_CALL = [
     ("flash", roll_flash, ["flash_attention_tpu"]),
+    ("fused_attention", roll_fused_attention, ["fused_attention_tpu"]),
     ("dropout", roll_dropout, ["fused_dropout_tpu", "fused_dropout_add_tpu",
                                "fused_act_dropout_tpu"]),
     ("optimizers", roll_optimizers, ["fused_adam_tpu", "fused_momentum_tpu"]),

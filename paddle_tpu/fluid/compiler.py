@@ -50,12 +50,14 @@ class BuildStrategy:
         self.fuse_bn_act_ops = False           # -> fuse_bn_act
         # Pallas kernel tier (fluid/passes/kernel_tier.py,
         # docs/performance.md "Custom kernel tier"): pattern-rewrite the
-        # naive attention chain onto fused_multihead_attention (flash
+        # naive attention chain onto fused_multihead_attention (a Pallas
         # kernel on TPU), lookup_table+pool chains onto
         # fused_embedding_pool (fused gather/scatter-add), and runs of
         # per-param adam/lamb/momentum updates onto one fused bucket
         # update.  kernel_tier=True is the umbrella for all three.
         self.kernel_tier = False
+        # every chain; without the field an unpartitioned program still
+        # gets the pass for the chains a kernel covers
         self.fuse_attention = False            # -> fuse_attention
         self.fuse_paged_attention = False      # -> fuse_paged_attention
         self.fuse_sparse_embedding = False     # -> fuse_sparse_embedding

@@ -654,11 +654,13 @@ class ShardCollectivesPass(Pass):
 def passes_for_build_strategy(build_strategy) -> List[Pass]:
     """Instantiate the pass list a BuildStrategy's knobs select, in the
     canonical order: fold -> fuse -> kernel_tier -> clean -> amp -> dce
-    -> coalesce.  The kernel tier runs after the pairwise fusions (they
-    never overlap its chains) and before AMP (the fused attention op is
-    white-listed MXU compute, so the bf16 rewrite sees ONE op instead of
-    the six-op chain); AMP runs before DCE (which sweeps the cast
-    orphans the redundancy pruner leaves)."""
+    -> coalesce.  ``fuse_attention`` needs no knob: an unpartitioned
+    program gets it for the attention chains a kernel covers.  The kernel
+    tier runs after the pairwise fusions (they never overlap its chains)
+    and before AMP (the fused attention op is white-listed MXU compute,
+    so the bf16 rewrite sees ONE op instead of the six-op chain); AMP
+    runs before DCE (which sweeps the cast orphans the redundancy pruner
+    leaves)."""
     from . import amp as _amp  # noqa: F401 — registers the AMP passes
     from . import kernel_tier as _kt  # noqa: F401 — registers the tier
     bs = build_strategy
@@ -673,6 +675,12 @@ def passes_for_build_strategy(build_strategy) -> List[Pass]:
         specs.append(("fuse_bn_act", {}))
     if tier or getattr(bs, "fuse_attention", False):
         specs.append(("fuse_attention", {}))
+    elif not getattr(bs, "sharding", None):
+        # the default: chains whose fused op would lower to a kernel.  Not
+        # in a partitioned program, where a Mosaic call cannot run
+        # (LoweringContext.pallas_ok) and the rewrite would only swap one
+        # XLA spelling for another.
+        specs.append(("fuse_attention", {"where_kernel_runs": True}))
     if tier or getattr(bs, "fuse_paged_attention", False):
         specs.append(("fuse_paged_attention", {}))
     if tier or getattr(bs, "fuse_sparse_embedding", False):

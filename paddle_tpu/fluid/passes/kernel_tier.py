@@ -16,11 +16,11 @@ touching model code.
   (+mask) → softmax → (dropout) → matmul(·,V), including the paired
   ``generic_grad`` ops of training programs, rewrites to ONE
   ``fused_multihead_attention`` op (+ one fused generic_grad).  The
-  lowering dispatches to the Pallas flash kernel on TPU
-  (``FLAGS_pallas_min_seq`` crossover, additive-bias masks ride the
-  kernel's ``ab`` argument) and the XLA-fused reference elsewhere; an
-  absorbed dropout op's seed is stamped into the fused op so the XLA
-  path regenerates the identical mask.
+  lowering picks a kernel on TPU (``ops.attention.attention_path``: the
+  fused kernel with in-kernel dropout up to S = 512, jax's flash kernel
+  from ``FLAGS_pallas_min_seq`` up) and the XLA-fused reference
+  elsewhere; an absorbed dropout op's seed is stamped into the fused op
+  so the XLA path regenerates the identical mask.
 * ``fuse_paged_attention`` — the block-paged decode attend chain
   (serving/decode.py paged programs): page-table gather ×2 → reshape ×2
   → mul+reduce_sum scores → scale → exact-zero mask → softmax →
@@ -47,7 +47,9 @@ Every pass counts ``kernel_tier.<pass>.rewrites``; wiring is the
 ``BuildStrategy.fuse_attention`` / ``fuse_sparse_embedding`` /
 ``fuse_optimizer`` knobs plus the ``kernel_tier`` umbrella, appended by
 ``passes_for_build_strategy`` after the pairwise fusions and before AMP
-(docs/passes.md).
+(docs/passes.md).  ``fuse_attention`` is also in the default pipeline of
+every unpartitioned program, there only for the chains whose fused op
+lowers to a kernel (``where_kernel_runs``).
 """
 from __future__ import annotations
 
@@ -118,8 +120,14 @@ class FuseAttentionPass(PatternRewritePass):
 
     name = "fuse_attention"
 
-    def __init__(self, **options):
+    def __init__(self, where_kernel_runs: bool = False, **options):
+        """``where_kernel_runs``: rewrite only chains whose fused op would
+        lower to a Pallas kernel on a chip, judged from the variables'
+        static shapes (``ops.attention.attention_path``); every other
+        chain, and so every program without such a chain, is left op for
+        op as it was.  The default pipeline runs the pass this way."""
         super().__init__(**options)
+        self.where_kernel_runs = bool(where_kernel_runs)
         for train in (True, False):
             for with_drop in (True, False):
                 for with_mask in (True, False):
@@ -202,6 +210,33 @@ class FuseAttentionPass(PatternRewritePass):
 
         return (p, rewrite)
 
+    @staticmethod
+    def _kernel_runs(m, drop_op) -> bool:
+        """Would the fused op over this chain's operands lower to a kernel
+        where kernels run?  Shapes and dtypes as the block declares them
+        (-1 for the batch)."""
+        from types import SimpleNamespace
+        import jax.numpy as jnp
+        from ...ops.attention import attention_path
+
+        def operand(name):
+            v = m.block._find_var_recursive(m.var(name))
+            if v is None or v.shape is None or v.dtype is None:
+                return None
+            return SimpleNamespace(shape=tuple(v.shape), ndim=len(v.shape),
+                                   dtype=jnp.dtype(v.dtype))
+
+        operands = [operand(n) for n in ("q", "k", "v", "mask")
+                    if n in m.binding]
+        if any(o is None for o in operands):
+            return False                 # undeclared shape: nothing to judge
+        if len(operands) == 3:
+            operands.append(None)        # no mask
+        drop_active = drop_op is not None \
+            and not drop_op.attrs.get("is_test", False) \
+            and bool(drop_op.attrs.get("dropout_prob", 0.5))
+        return attention_path(*operands, False, drop_active, True) != "xla"
+
     # -- rewrite ------------------------------------------------------------
     def _rewrite(self, m, ctx, train, with_scale, with_mask,
                  with_drop) -> bool:
@@ -234,6 +269,8 @@ class FuseAttentionPass(PatternRewritePass):
             mask_out = (drop_op.outputs.get("Mask") or [None])[0]
             if mask_out and _consumers(block, mask_out):
                 return False
+        if self.where_kernel_runs and not self._kernel_runs(m, drop_op):
+            return False
         if train:
             # grad chain intermediates are internal too, and the mask must
             # not itself require a gradient (the fused op cannot emit one)

@@ -2,13 +2,24 @@
 
 Reference: paddle/fluid/operators/fused/multihead_matmul_op.cu (fused
 transformer attention) and math/bert_encoder_functor.cu (SURVEY §2.5 fused/).
-TPU-native: one `fused_multihead_attention` op whose lowering is (a) a Pallas
-flash-attention kernel on TPU for long sequences (pallas_kernels.py), or
-(b) an XLA-fused softmax(QK^T)V otherwise.  The op boundary is what enables
-kernel substitution without touching model code — and since the kernel tier
-landed (fluid/passes/kernel_tier.py), the `fuse_attention` pass PRODUCES
-this op from the naive matmul→softmax→matmul chain, so plain static
-programs get the kernel too.
+TPU-native: one `fused_multihead_attention` op; the `fuse_attention` pass
+(fluid/passes/kernel_tier.py, in the default pipeline of an unpartitioned
+program) PRODUCES it from the naive matmul→softmax→matmul chain, so plain
+static programs get the kernels without touching model code.  The lowering
+picks one of three paths from what it can see (`attention_path`):
+
+* `fused_kernel` — `pallas_kernels.fused_attention_tpu`: key lengths up to
+  512 (BERT's), whole score rows on the core, dropout on the probabilities
+  from the on-core PRNG, the padding bias passed as its [B, 1, 1, S] row;
+* `flash_kernel` — jax's flash kernel, K/V streamed through VMEM: from
+  `FLAGS_pallas_min_seq` (1024) up, dropout-free;
+* `xla` — `_reference_attention`, the XLA softmax(QK^T)V: the CPU, inside a
+  GSPMD-partitioned program (a Mosaic call cannot be partitioned), and every
+  shape the kernels do not cover.
+
+`attention.lowering.<path>` in `trace.metrics()` counts the picks, once per
+lowering of an op (a training program lowers each attention twice: the
+forward op and the grad op that re-traces it).
 """
 from __future__ import annotations
 
@@ -20,12 +31,16 @@ import jax.numpy as jnp
 from .registry import register_op
 
 _PALLAS_MIN_SEQ_DEFAULT = 1024
-# Crossover rationale: below some sequence length the XLA softmax(QK^T)V
-# fusion still holds the [B,H,T,T] score tensor at fusion scale and the
-# kernel's block bookkeeping is pure overhead; only above it does
-# streaming K/V blocks through VMEM pay.  Where the crossover sits is not
-# measured on this code; FLAGS_pallas_min_seq exists so a chip run can
-# sweep it.
+# From this length up the flash kernel streams K/V blocks through VMEM; the
+# crossover against XLA is not measured on this code, FLAGS_pallas_min_seq
+# exists so a chip run can sweep it.
+
+# Shortest sequence that takes the fused kernel: it beats XLA's chain at
+# every length it covers (v5e, forward + backward of one BERT-base layer
+# with dropout 0.1, same 16,384 tokens: [128, 12, 128, 64] 0.76 ms against
+# 1.83, [32, 12, 512, 64] 1.32 against 7.70; my chip runs, PR 25), so the
+# floor is the shortest lane-aligned length.
+_FUSED_MIN_SEQ = 128
 
 
 def _pallas_min_seq() -> int:
@@ -78,26 +93,55 @@ def _bias_broadcastable(mask, q, k) -> bool:
     return all(m == 1 or m == t for m, t in zip(mask.shape, target))
 
 
+def attention_path(q, k, v, mask, causal, drop_active, use_pallas,
+                   min_seq=_PALLAS_MIN_SEQ_DEFAULT) -> str:
+    """Which lowering attention over these operands takes: ``fused_kernel``,
+    ``flash_kernel`` or ``xla``.  A function of shapes, dtypes and flags
+    alone (the operands may be ShapeDtypeStructs)."""
+    if not use_pallas:
+        return "xla"
+    seq = q.shape[-2]
+    if not causal and seq >= _FUSED_MIN_SEQ:
+        from .pallas_kernels import fused_attention_supported
+        if fused_attention_supported(q, k, v, mask):
+            return "fused_kernel"
+    if seq >= min_seq and not drop_active \
+            and (mask is None or _bias_broadcastable(mask, q, k)):
+        return "flash_kernel"
+    return "xla"
+
+
 def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                     dropout_rate=0.0, dropout_key=None,
                     dropout_upscale=True, prob_scale=None, use_pallas=None):
-    """Dispatch to the Pallas TPU kernel when profitable, else XLA.
-    ``use_pallas``: an op lowering passes ``ctx.pallas_ok()``; None (the
-    shard_map bodies in parallel/) means "on the tpu backend".
+    """Dispatch to a Pallas TPU kernel where one covers the call, else XLA
+    (``attention_path``).  ``use_pallas``: an op lowering passes
+    ``ctx.pallas_ok()``; None (the shard_map bodies in parallel/) means
+    "on the tpu backend".
 
-    The Pallas path handles additive-bias masks via the kernel's ``ab``
-    argument (anything broadcastable to [B, H, Tq, Tk]); genuinely
-    unsupported mask shapes and active attention dropout fall back to the
-    XLA reference (the jax flash kernel has no in-kernel prob dropout).
+    The flash kernel takes additive-bias masks through its ``ab`` argument
+    (anything broadcastable to [B, H, Tq, Tk], materialised at that size)
+    and has no dropout; the fused kernel takes the [B, 1, 1, Tk] bias row
+    as it is and drops probabilities in-kernel.
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    seq = q.shape[-2]
     drop_active = bool(dropout_rate) and dropout_key is not None
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if use_pallas and seq >= _pallas_min_seq() \
-            and not drop_active and prob_scale is None and scale != 0.0 \
-            and (mask is None or _bias_broadcastable(mask, q, k)):
+    path = attention_path(q, k, v, mask, causal, drop_active, use_pallas,
+                          _pallas_min_seq())
+    if path == "flash_kernel" and (prob_scale is not None or scale == 0.0):
+        path = "xla"
+    from ..fluid import trace
+    trace.metrics().counter(f"attention.lowering.{path}").inc()
+    if path == "fused_kernel":
+        from .pallas_kernels import fused_attention_tpu
+        return fused_attention_tpu(
+            q, k, v, mask, scale=scale,
+            dropout_rate=dropout_rate if drop_active else 0.0,
+            dropout_key=dropout_key, dropout_upscale=dropout_upscale,
+            prob_scale=prob_scale)
+    if path == "flash_kernel":
         from .pallas_kernels import flash_attention_tpu
         ab = None
         if mask is not None:

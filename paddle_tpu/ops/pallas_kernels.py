@@ -1,9 +1,13 @@
 """Pallas TPU kernels for the ops where XLA fusion leaves work on the table.
 
-* flash attention — at long sequences XLA materialises the [B, H, T, T]
-  score tensor; the pallas kernel streams K/V blocks through VMEM (SURVEY
-  §7 step 3: "Pallas kernels only where XLA fusion falls short, e.g. fused
-  attention").  Wraps jax's production TPU kernel.
+* attention — XLA writes the [B, H, T, T] scores, probabilities and dropout
+  mask to HBM and reads them back, forward and backward.  Two kernels, picked
+  by ``ops.attention.attention_path``: the fused attention kernel for the
+  lengths BERT runs (T <= 512: whole score rows on the core, in-kernel
+  dropout on the probabilities, the padding bias as its [B, 1, 1, T] row),
+  and jax's production flash kernel, which streams K/V blocks through VMEM,
+  for long dropout-free sequences (SURVEY §7 step 3: "Pallas kernels only
+  where XLA fusion falls short, e.g. fused attention").
 * fused dropout — the jax.random path writes per-element uniforms and a
   bool mask residual to HBM.  Here the mask is derived from the on-core
   hardware PRNG (pltpu.prng_random_bits) and the backward pass RE-SEEDS the
@@ -29,7 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.flash_attention import (
     flash_attention as _jax_flash_attention)
 
-__all__ = ["flash_attention_tpu", "fused_dropout_tpu",
+__all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_dropout_add_tpu", "fused_act_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
            "fused_adam_tpu", "fused_momentum_tpu",
@@ -69,6 +73,392 @@ def flash_attention_tpu(q, k, v, scale=None, causal=False, ab=None):
         scale = q.shape[-1] ** -0.5
     return _jax_flash_attention(q, k, v, ab=ab, causal=causal,
                                 sm_scale=float(scale))
+
+
+# ---------------------------------------------------------------------------
+# fused attention for the lengths BERT runs (S <= 512): whole score rows on
+# the core.
+#
+# One head's [S, S] float32 scores are at most 1 MiB, so nothing is streamed
+# and no online softmax is needed: a grid step holds K and V of its heads
+# whole and, per head, does scores -> softmax -> keep-mask -> PV in one
+# pass.  The backward recomputes the scores, regenerates the mask and
+# produces dQ, dK and dV in the same grid step (7 matmul units against
+# flash's 9).  HBM sees Q, K, V, the [B, 1, 1, S] bias row, the output and
+# the [B, H, S] log-sum-exp; scores, probabilities and mask never leave
+# VMEM.  The keep-mask of a head is a function of (op seed, batch, head,
+# [Sq, Sk]): forward, backward and the mask-export kernel draw the same bits.
+#
+# Layout.  The kernels read and write [B, S, H*D], the layout the Q/K/V
+# projections produce and the output projection consumes, not [B, H, S, D]:
+# the wrapper undoes the caller's head transpose and XLA cancels the pair,
+# so no transposed copy of Q, K, V, O or their gradients is ever written
+# (with [B, H, S, D] kernels those copies were 13 of 111 ms a step at
+# seq 512 and cost seq 128 more memory than the kernel saved, and a layer's
+# forward + backward took 2.0 ms against 1.3 here; my chip runs, PR 25).
+# A head is then D of the 128 lanes of a lane group, and 128 // D heads
+# share a group.  Nothing is sliced below a vreg: a head's scores are
+# (q2 * m) @ k2.T over the whole group with the other heads' lanes of q2
+# zeroed by m (the same multiply that applies the softmax scale), P @ v2 and
+# the gradient matmuls produce the whole group's width, and a lane select
+# keeps each head's own columns.  The MXU is 128 deep and wide: a 128-lane
+# operand costs the passes a 64-lane one does.
+#
+# Precision, as AMP runs the unfused chain: the matmul operands in the
+# caller's dtype (bf16 under AMP) with float32 accumulation; scores, max,
+# sum and exp in float32; probabilities rounded to the operand dtype only as
+# the PV / dV / dS matmul operand.
+# ---------------------------------------------------------------------------
+
+# Longest sequence whose [S, S] float32 score tile (1 MiB) and its few
+# same-size temporaries are held whole; beyond it callers stream K/V through
+# the flash kernel or take XLA.
+_ATTN_MAX_SEQ = 512
+# Query rows (heads x S) one grid step works through, so that S = 128 is
+# not one head per ~0.35 us grid step: all 12 heads there, 6 at S = 512
+# (v5e, forward + backward of a BERT-base layer: [128, 12, 128, 64] 0.854 ms
+# with 6 heads a step, 0.759 with 12; [32, 12, 512, 64] 1.335 / 1.323 /
+# 1.278 ms with 2 / 4 / 6; my chip runs, PR 25).
+_ATTN_ROWS_PER_STEP = 4096
+# The tile temporaries plus the double-buffered head blocks pass v5e's 16 MiB
+# scoped default at S = 512; the core has 128 MiB.
+_ATTN_VMEM_LIMIT_BYTES = 64 << 20
+_LANES = 128
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _heads_per_group(d):
+    """Heads that share one 128-lane group."""
+    return max(1, _LANES // d)
+
+
+def fused_attention_supported(q, k, v, bias=None) -> bool:
+    """Shapes the fused attention kernel covers: [B, H, S, D] operands of
+    one float dtype, lane-aligned lengths up to ``_ATTN_MAX_SEQ``, a head
+    width that tiles the 128 lanes (32, 64, 128) with whole lane groups of
+    heads, and an additive bias that is one row per batch entry,
+    [B or 1, 1, 1, Sk] (a bias that varies over heads or query rows would
+    have to sit in HBM at the scores' size, which is the traffic this kernel
+    removes)."""
+    if q.ndim != 4 or k.shape != v.shape or q.shape[:2] != k.shape[:2] \
+            or q.shape[3] != k.shape[3]:
+        return False
+    if not (q.dtype == k.dtype == v.dtype) \
+            or q.dtype not in (jnp.bfloat16, jnp.float32):
+        return False
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    if sk % 128 or sk > _ATTN_MAX_SEQ or sq % 128 or sq > _ATTN_MAX_SEQ \
+            or d not in (32, 64, 128) or h % _heads_per_group(d):
+        return False
+    return bias is None or (bias.ndim == 4 and bias.shape[0] in (1, b)
+                            and bias.shape[1:] == (1, 1, sk))
+
+
+def _heads_per_step(h, sq, d):
+    """Whole lane groups, about ``_ATTN_ROWS_PER_STEP`` query rows."""
+    g = _heads_per_group(d)
+    groups = h // g
+    n = max(1, min(groups, _ATTN_ROWS_PER_STEP // (sq * g)))
+    while groups % n:
+        n -= 1
+    return n * g
+
+
+def _head_bits(seed_ref, head, shape):
+    """One head's random bits: the on-core PRNG seeded from the op seed and
+    the head's index over (batch, head); Mosaic takes two seed words.  (The
+    CPU interpreter's PRNG is a stub; the tests put a counter-based
+    generator here.)"""
+    pltpu.prng_seed(seed_ref[0], head)
+    return pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+
+
+def _first_head(hb):
+    """Index over (batch, head) of the grid step's first head, for a grid
+    of (batch, blocks of ``hb`` heads)."""
+    return (pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)) * hb
+
+
+def _col_to_row(col):
+    """[n, 1] -> [1, n] float32 through an aligned 2-d transpose: the
+    statistics leave the core lane-dense ([B, H, 1, S]; a trailing dim of 1
+    would be padded to 128 lanes in HBM)."""
+    n = col.shape[0]
+    return jnp.transpose(jnp.broadcast_to(col, (n, 128)))[:1, :]
+
+
+def _row_to_col(row):
+    """[1, n] -> [n, 1]."""
+    n = row.shape[1]
+    return jnp.transpose(jnp.broadcast_to(row, (128, n)))[:, :1]
+
+
+def _lane_group_plan(d, scale, dtype):
+    """How one lane group's heads are told apart: per head a bool [1, w]
+    lane mask (None for a group of one head) and the [1, w] factor that
+    zeroes the other heads' lanes of q; and the factor left for the scores.
+    A power-of-two scale (D = 64: 1/8) rides in q's factor: exact in any
+    binary float, and a pass over [S, w] instead of [S, S]."""
+    g = _heads_per_group(d)
+    in_q = math.frexp(scale)[0] == 0.5
+    q_scale, s_scale = (scale, 1.0) if in_q else (1.0, scale)
+    if g == 1:
+        factor = None if q_scale == 1.0 else jnp.full((1, d), q_scale, dtype)
+        return [(None, factor)], s_scale
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, g * d), 1)
+    masks = [lane // d == x for x in range(g)]
+    return [(m, jnp.where(m, q_scale, 0.0).astype(dtype))
+            for m in masks], s_scale
+
+
+def _own_lanes(plan, parts):
+    """Each head's own columns of its [rows, w] float32 result."""
+    out = parts[0]
+    for (mask, _), part in zip(plan[1:], parts[1:]):
+        out = jnp.where(mask, part, out)
+    return out
+
+
+def _attn_scores(q2, factor, k2, bias, s_scale):
+    """One head's q @ k.T * scale + bias in float32, from its lane group."""
+    if factor is not None:
+        q2 = q2 * factor
+    s = jax.lax.dot_general(q2, k2, _NT, preferred_element_type=jnp.float32)
+    if s_scale != 1.0:
+        s = s * s_scale
+    return s if bias is None else s + bias
+
+
+def _for_lane_groups(d, width, group):
+    """Run ``group(columns, first head of the group within the step)`` for
+    the grid step's lane groups, as straight-line code: heads are
+    independent, and the scheduler fills one head's matmul latency with
+    another's vector work (v5e, [128, 12, 128, 64] forward + backward of
+    this kernel's [B, H, S, D] predecessor: 2.33 ms as a ``fori_loop`` over
+    heads, 1.60 unrolled; at S = 512, 2.09 and 2.01; my chip runs, PR 25)."""
+    g = _heads_per_group(d)
+    w = g * d
+    for i in range(width // w):
+        group(slice(i * w, (i + 1) * w), i * g)
+
+
+def _attn_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, d, scale,
+                     threshold, out_scale, has_bias):
+    b_ref = rest[0] if has_bias else None
+    o_ref, lse_ref = rest[-2:]
+    bias = b_ref[...] if has_bias else None
+    plan, s_scale = _lane_group_plan(d, scale, q_ref.dtype)
+    first = _first_head(lse_ref.shape[0])
+
+    def group(cols, head0):
+        q2, k2, v2 = q_ref[:, cols], k_ref[:, cols], v_ref[:, cols]
+        outs = []
+        for x, (_, factor) in enumerate(plan):
+            s = _attn_scores(q2, factor, k2, bias, s_scale)
+            m = jnp.max(s, axis=1, keepdims=True)
+            e = jnp.exp(s - m)
+            # the normaliser is the sum of the UNdropped exponentials
+            l = jnp.sum(e, axis=1, keepdims=True)
+            if threshold:
+                keep = _head_bits(seed_ref, first + head0 + x, e.shape) \
+                    >= jnp.uint32(threshold)
+                e = jnp.where(keep, e, 0.0)
+            o = jnp.dot(e.astype(v2.dtype), v2,
+                        preferred_element_type=jnp.float32)
+            outs.append(o * (out_scale / l))
+            lse_ref[head0 + x] = _col_to_row(m + jnp.log(l))
+        o_ref[:, cols] = _own_lanes(plan, outs).astype(o_ref.dtype)
+
+    _for_lane_groups(d, q_ref.shape[1], group)
+
+
+def _attn_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, d, scale,
+                     threshold, out_scale, has_bias):
+    b_ref = rest[0] if has_bias else None
+    o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_ref = rest[-6:]
+    bias = b_ref[...] if has_bias else None
+    f32 = jnp.float32
+    plan, s_scale = _lane_group_plan(d, scale, q_ref.dtype)
+    first = _first_head(lse_ref.shape[0])
+
+    def group(cols, head0):
+        q2, k2, v2, do2 = (q_ref[:, cols], k_ref[:, cols], v_ref[:, cols],
+                           do_ref[:, cols])
+        do_o = do2.astype(f32) * o_ref[:, cols].astype(f32)
+        dqs, dks, dvs = [], [], []
+        for x, (mask, factor) in enumerate(plan):
+            p = jnp.exp(_attn_scores(q2, factor, k2, bias, s_scale)
+                        - _row_to_col(lse_ref[head0 + x]))
+            # out = out_scale * (keep . p) @ v, so with pk = keep . p and
+            # dpk = keep . (do @ v.T):  ds = out_scale * p . (dpk - delta'),
+            # delta' = rowsum(do . out) / out_scale; out_scale multiplies
+            # the [S, w] results, never an [S, S] tile
+            dox = do2 if mask is None else do2 * mask.astype(do2.dtype)
+            dp = jax.lax.dot_general(dox, v2, _NT, preferred_element_type=f32)
+            pk = p
+            if threshold:
+                keep = _head_bits(seed_ref, first + head0 + x, p.shape) \
+                    >= jnp.uint32(threshold)
+                pk = jnp.where(keep, p, 0.0)
+                dp = jnp.where(keep, dp, 0.0)
+            dvs.append(jax.lax.dot_general(pk.astype(do2.dtype), do2, _TN,
+                                           preferred_element_type=f32))
+            own = do_o if mask is None else jnp.where(mask, do_o, 0.0)
+            delta = jnp.sum(own, axis=1, keepdims=True) * (1.0 / out_scale)
+            ds = (p * (dp - delta)).astype(q2.dtype)
+            dqs.append(jnp.dot(ds, k2, preferred_element_type=f32))
+            dks.append(jax.lax.dot_general(ds, q2, _TN,
+                                           preferred_element_type=f32))
+        dq_ref[:, cols] = (_own_lanes(plan, dqs)
+                           * (scale * out_scale)).astype(dq_ref.dtype)
+        dk_ref[:, cols] = (_own_lanes(plan, dks)
+                           * (scale * out_scale)).astype(dk_ref.dtype)
+        dv_ref[:, cols] = (_own_lanes(plan, dvs)
+                           * out_scale).astype(dv_ref.dtype)
+
+    _for_lane_groups(d, q_ref.shape[1], group)
+
+
+def _attn_mask_kernel(seed_ref, o_ref, *, threshold):
+    first = _first_head(o_ref.shape[0])
+    for h in range(o_ref.shape[0]):
+        keep = _head_bits(seed_ref, first + h, o_ref.shape[1:]) \
+            >= jnp.uint32(threshold)
+        o_ref[h] = keep.astype(jnp.uint8)
+
+
+_ATTN_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=_ATTN_VMEM_LIMIT_BYTES)
+
+
+def _attn_call(kernel, operands, bias, heads, statics, more_specs,
+               out_specs, out_shape):
+    """One grid step per (batch entry, block of heads) over [B, S, H*D]
+    arrays.  ``operands``: seed, q, k, v, then more; the bias row goes in
+    after v.  ``more_specs`` and ``out_specs`` name their blocks ``q``,
+    ``k`` or ``lse``."""
+    bsz, sq, width = operands[1].shape
+    sk = operands[2].shape[1]
+    d = width // heads
+    hb = _heads_per_step(heads, sq, d)
+    specs = {
+        "q": pl.BlockSpec((None, sq, hb * d), lambda b, j: (b, 0, j)),
+        "k": pl.BlockSpec((None, sk, hb * d), lambda b, j: (b, 0, j)),
+        "lse": pl.BlockSpec((None, hb, 1, sq), lambda b, j: (b, j, 0, 0)),
+    }
+    operands = list(operands)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), specs["q"],
+                specs["k"], specs["k"]]
+    if bias is not None:
+        per_batch = bias.shape[0] != 1
+        operands.insert(4, bias.reshape(bias.shape[0], 1, sk)
+                        .astype(jnp.float32))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, sk), lambda b, j: (b if per_batch else 0, 0, 0)))
+    scale, rate, out_scale = statics
+    return pl.pallas_call(
+        functools.partial(kernel, d=d, scale=scale,
+                          threshold=_threshold_for(rate),
+                          out_scale=out_scale, has_bias=bias is not None),
+        grid=(bsz, heads // hb),
+        in_specs=in_specs + [specs[s] for s in more_specs],
+        out_specs=[specs[s] for s in out_specs], out_shape=out_shape,
+        compiler_params=_ATTN_PARAMS,
+    )(*operands)
+
+
+def _attn_forward(q, k, v, bias, seed, heads, statics):
+    bsz, sq, _ = q.shape
+    return _attn_call(
+        _attn_fwd_kernel, [seed, q, k, v], bias, heads, statics, [],
+        ["q", "lse"],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((bsz, heads, 1, sq), jnp.float32)])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _fused_attention(q, k, v, bias, seed, heads, statics):
+    """q, k, v [B, S, H*D]; statics = (scale, rate, out_scale)."""
+    return _attn_forward(q, k, v, bias, seed, heads, statics)[0]
+
+
+def _fused_attention_fwd(q, k, v, bias, seed, heads, statics):
+    out, lse = _attn_forward(q, k, v, bias, seed, heads, statics)
+    return out, (q, k, v, bias, seed, out, lse)
+
+
+def _fused_attention_bwd(heads, statics, res, do):
+    q, k, v, bias, seed, out, lse = res
+    dq, dk, dv = _attn_call(
+        _attn_bwd_kernel, [seed, q, k, v, out, lse, do.astype(q.dtype)],
+        bias, heads, statics, ["q", "lse", "q"], ["q", "k", "k"],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)])
+    return dq, dk, dv, None, None
+
+
+_fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
+
+
+def _heads_to_lanes(x):
+    """[B, H, S, D] -> [B, S, H*D]: the inverse of the caller's head split,
+    which XLA cancels against it."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _fused_attention_jit(q, k, v, bias, seed, statics):
+    """One trace per (shapes, statics): a 12-layer program holds 24 calls
+    (12 forward ops, 12 grad ops re-tracing them)."""
+    b, h, sq, d = q.shape
+    out = _fused_attention(_heads_to_lanes(q), _heads_to_lanes(k),
+                           _heads_to_lanes(v), bias, seed, h, statics)
+    return out.reshape(b, sq, h, d).transpose(0, 2, 1, 3)
+
+
+def fused_attention_tpu(q, k, v, bias=None, scale=None, dropout_rate=0.0,
+                        dropout_key=None, dropout_upscale=True,
+                        prob_scale=None):
+    """softmax(q @ k.T * scale + bias) -> dropout -> @ v, q/k/v [B, H, S, D],
+    ``bias`` [B or 1, 1, 1, S] passed as that row.  Dropout is active with
+    a rate and a key; ``dropout_upscale`` scales the kept probabilities by
+    1 / (1 - rate) (upscale_in_train), ``prob_scale`` scales all of them
+    (downgrade_in_infer at test time).  Rate 0 is the same kernel without
+    the PRNG."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    rate = float(dropout_rate) if dropout_key is not None else 0.0
+    out_scale = 1.0 if prob_scale is None else float(prob_scale)
+    if rate and dropout_upscale:
+        out_scale /= 1.0 - rate
+    seed = _seed_from_key(dropout_key) if rate \
+        else jnp.zeros((1,), jnp.int32)
+    return _fused_attention_jit(q, k, v, bias, seed,
+                                (float(scale), rate, out_scale))
+
+
+def fused_attention_keep_mask(q_shape, sk, dropout_rate, dropout_key):
+    """The uint8 [B, H, Sq, Sk] keep-mask ``fused_attention_tpu`` applies
+    for this key, from a kernel that draws the same bits per (batch, head):
+    for the tests and the chip roll-call, never on a training path."""
+    bsz, h, sq, d = q_shape
+    hb = _heads_per_step(h, sq, d)
+    return pl.pallas_call(
+        functools.partial(_attn_mask_kernel,
+                          threshold=_threshold_for(float(dropout_rate))),
+        grid=(bsz, h // hb),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((None, hb, sq, sk),
+                               lambda b, j: (b, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, h, sq, sk), jnp.uint8),
+        compiler_params=_ATTN_PARAMS,
+    )(_seed_from_key(dropout_key))
 
 
 # ---------------------------------------------------------------------------

@@ -187,6 +187,355 @@ class TestFuseAttentionNegative:
 
 
 # ---------------------------------------------------------------------------
+# fuse_attention in the default pipeline, the lowering's dispatch, and the
+# fused attention kernel (ops/pallas_kernels.fused_attention_tpu)
+# ---------------------------------------------------------------------------
+
+def _default_pipeline(main, loss, **fields):
+    """What CompiledProgram would run with only ``fields`` set; returns
+    the rewrites counted."""
+    from paddle_tpu.fluid.passes import passes_for_build_strategy
+    bs = _tier_bs(**fields)
+    r0 = _counter("kernel_tier.fuse_attention.rewrites")
+    PassPipeline(passes_for_build_strategy(bs)).apply(
+        main, targets=[loss.name], build_strategy=bs)
+    return _counter("kernel_tier.fuse_attention.rewrites") - r0
+
+
+# a BERT layer at a length and head width the fused kernel covers
+_KERNEL_BERT = dict(hidden=128, heads=2, seq=512, layers=1, dropout=0.1)
+
+
+class TestFuseAttentionByDefault:
+    @pytest.mark.parametrize("fields", [{}, {"amp": True}])
+    def test_rewrites_without_any_speed_field(self, fields):
+        m, _, loss = build_bert_train_program(**_KERNEL_BERT)
+        assert _default_pipeline(m, loss, **fields) == 1
+        types = _op_types(m)
+        assert types.count("fused_multihead_attention") == 1
+        assert "softmax" not in types
+
+    @pytest.mark.parametrize("why, model, fields", [
+        ("partitioned", _KERNEL_BERT, {"sharding": "dp"}),
+        ("no kernel at this length", dict(_KERNEL_BERT, seq=16), {}),
+        ("no kernel for this head width",
+         dict(_KERNEL_BERT, hidden=48, heads=2), {}),
+    ])
+    def test_leaves_the_program_op_for_op(self, why, model, fields):
+        m, _, loss = build_bert_train_program(**model)
+        before = _op_types(m)
+        assert _default_pipeline(m, loss, **fields) == 0
+        assert _op_types(m) == before
+
+    def test_the_field_still_means_on(self):
+        """BuildStrategy.fuse_attention rewrites every chain, whatever
+        its lowering will be."""
+        m, _, loss = build_bert_train_program(**dict(_KERNEL_BERT, seq=16))
+        assert _default_pipeline(m, loss, fuse_attention=True) == 1
+
+
+def _sds(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _lowering_counts():
+    return {p: _counter(f"attention.lowering.{p}")
+            for p in ("fused_kernel", "flash_kernel", "xla")}
+
+
+class TestAttentionDispatch:
+    """``flash_attention`` picks its path from shapes, dtypes and flags
+    alone, and counts the pick; traced abstractly, so no kernel runs."""
+
+    @pytest.mark.parametrize("seq, d, bias, drop, on_chip, want", [
+        (512, 64, "row", True, True, "fused_kernel"),
+        (512, 64, None, False, True, "fused_kernel"),
+        (256, 128, "row", True, True, "fused_kernel"),
+        (128, 64, "row", True, True, "fused_kernel"),
+        (512, 64, "full", False, True, "xla"),     # a [B,H,S,S] bias
+        (512, 64, "full", True, True, "xla"),
+        (1024, 64, "row", False, True, "flash_kernel"),
+        (1024, 64, "full", False, True, "flash_kernel"),
+        (1024, 128, "row", True, True, "xla"),     # flash has no dropout
+        (512, 64, "row", True, False, "xla"),      # partitioned, or the CPU
+        (1024, 64, None, False, False, "xla"),
+    ])
+    def test_path_and_counter(self, seq, d, bias, drop, on_chip, want):
+        import functools
+        from paddle_tpu.ops import attention
+        b, h = 2, 4
+        q = _sds(b, h, seq, d)
+        mask = {None: None, "row": _sds(b, 1, 1, seq, dtype=jnp.float32),
+                "full": _sds(b, h, seq, seq, dtype=jnp.float32)}[bias]
+        assert attention.attention_path(q, q, q, mask, False, drop,
+                                        on_chip) == want
+        before = _lowering_counts()
+        f = functools.partial(
+            attention.flash_attention, dropout_rate=0.1 if drop else 0.0,
+            dropout_key=jax.random.PRNGKey(0) if drop else None,
+            use_pallas=on_chip)
+        out = jax.eval_shape(f, q, q, q, mask)
+        assert (out.shape, out.dtype) == (q.shape, q.dtype)
+        after = _lowering_counts()
+        assert {p: after[p] - before[p] for p in after} \
+            == {p: int(p == want) for p in after}
+
+    def test_causal_and_unaligned_lengths_stay_off_the_fused_kernel(self):
+        from paddle_tpu.ops.attention import attention_path
+        q = _sds(2, 4, 512, 64)
+        assert attention_path(q, q, q, None, True, False, True) == "xla"
+        q = _sds(2, 4, 200, 64)
+        assert attention_path(q, q, q, None, False, False, True) == "xla"
+
+    def test_the_op_lowering_asks_the_context(self, monkeypatch):
+        """Inside a partitioned program the op takes XLA on a TPU too."""
+        from paddle_tpu.ops.registry import LoweringContext, get_op
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q = _sds(2, 4, 512, 64)
+        for partitioned, want in ((False, "fused_kernel"), (True, "xla")):
+            ctx = LoweringContext(base_key=jax.random.PRNGKey(0))
+            ctx.partitioned = partitioned
+            before = _lowering_counts()
+            jax.eval_shape(
+                lambda q: get_op("fused_multihead_attention").fn(
+                    {"Q": [q], "K": [q], "V": [q]},
+                    {"dropout_rate": 0.1, "dropout_seed": 3}, ctx), q)
+            assert _lowering_counts()[want] - before[want] == 1
+
+
+def _counter_bits(seed_ref, head, shape):
+    """Stands in for the on-core PRNG, which the CPU interpreter stubs
+    with zeros: a counter-based hash of (seed, head, position), so the
+    tests see what the kernel's plumbing does with real bits."""
+    u32 = jnp.uint32
+    r = jax.lax.broadcasted_iota(u32, shape, 0)
+    c = jax.lax.broadcasted_iota(u32, shape, 1)
+    x = r * u32(shape[1]) + c
+    x = x ^ (jnp.asarray(head).astype(u32) * u32(0x9E3779B9))
+    x = x ^ (seed_ref[0].astype(u32) * u32(0x85EBCA6B))
+    x = (x ^ (x >> 16)) * u32(0x7FEB352D)
+    x = (x ^ (x >> 15)) * u32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def _attn_operands(b, h, sq, sk, d, dtype=jnp.float32, bias_batch=None):
+    ks = jax.random.split(jax.random.PRNGKey(sq + sk + d), 4)
+    q = jax.random.normal(ks[0], (b, h, sq, d), dtype)
+    k = jax.random.normal(ks[1], (b, h, sk, d), dtype)
+    v = jax.random.normal(ks[2], (b, h, sk, d), dtype)
+    w = jax.random.normal(ks[3], (b, h, sq, d), dtype)
+    bias = None
+    if bias_batch:
+        # odd batch entries pad their last quarter, as BERT's mask does
+        pad = (jnp.arange(sk)[None, :] >= (3 * sk) // 4) \
+            & (jnp.arange(bias_batch)[:, None] % 2 == (bias_batch > 1))
+        bias = jnp.where(pad, -10000.0, 0.0).astype(jnp.float32)[
+            :, None, None]
+    return q, k, v, w, bias
+
+
+def _fwd_and_grads(f, q, k, v, w):
+    out, vjp = jax.vjp(f, q, k, v)
+    return (out,) + vjp(w)
+
+
+class TestFusedAttentionKernel:
+    @pytest.mark.parametrize("b, h, sq, sk, d, bias_batch", [
+        (2, 4, 128, 128, 64, 2),      # two lane groups of two heads
+        (2, 4, 256, 256, 32, None),   # four heads a lane group, no bias
+        (3, 2, 128, 256, 64, 1),      # one bias row for the whole batch,
+                                      # queries shorter than keys
+        (1, 2, 128, 128, 128, 1),     # a head is a whole lane group
+    ])
+    def test_interpret_numerics_against_the_reference(self, b, h, sq, sk,
+                                                      d, bias_batch):
+        """Forward and the three gradients in float32, dropout off."""
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops import pallas_kernels as pk
+        from paddle_tpu.ops.attention import _reference_attention
+        q, k, v, w, bias = _attn_operands(b, h, sq, sk, d,
+                                          bias_batch=bias_batch)
+        assert pk.fused_attention_supported(q, k, v, bias)
+        scale = d ** -0.5
+        with pltpu.force_tpu_interpret_mode():
+            got = _fwd_and_grads(
+                lambda q, k, v: pk.fused_attention_tpu(q, k, v, bias,
+                                                       scale=scale),
+                q, k, v, w)
+        want = _fwd_and_grads(
+            lambda q, k, v: _reference_attention(
+                q, k, v, bias, scale, False, 0.0, None, True), q, k, v, w)
+        for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=2e-5, atol=2e-5, err_msg=name)
+
+    @pytest.mark.parametrize("upscale", [True, False])
+    def test_dropout_is_consistent_with_the_exported_mask(self, upscale,
+                                                          monkeypatch):
+        """With dropout on: the forward and the backward both apply the
+        mask the export kernel shows (same seed, same head index, same
+        tiles), the normaliser is the sum of the UNdropped exponentials,
+        kept probabilities scale by 1/(1-p) under upscale_in_train."""
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops import pallas_kernels as pk
+        monkeypatch.setattr(pk, "_head_bits", _counter_bits)
+        b, h, s, d, p = 2, 4, 128, 64, 0.25
+        q, k, v, w, bias = _attn_operands(b, h, s, s, d, bias_batch=b)
+        key = jax.random.PRNGKey(5)
+        scale = d ** -0.5
+        with pltpu.force_tpu_interpret_mode():
+            keep = pk.fused_attention_keep_mask(q.shape, s, p, key)
+            other = pk.fused_attention_keep_mask(q.shape, s, p,
+                                                 jax.random.PRNGKey(6))
+            got = _fwd_and_grads(
+                lambda q, k, v: pk.fused_attention_tpu(
+                    q, k, v, bias, scale=scale, dropout_rate=p,
+                    dropout_key=key, dropout_upscale=upscale), q, k, v, w)
+        keep = np.asarray(keep)
+        assert keep.shape == (b, h, s, s) and keep.dtype == np.uint8
+        assert abs(keep.mean() - (1 - p)) < 0.01
+        # every head draws its own bits, and another key other bits
+        flat = keep.reshape(b * h, -1)
+        assert len({row.tobytes() for row in flat}) == b * h
+        assert (np.asarray(other) != keep).mean() > 0.2
+
+        def ref(q, k, v):
+            sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale + bias
+            pr = jax.nn.softmax(sc, -1) * keep
+            if upscale:
+                pr = pr / (1 - p)
+            return jnp.einsum("bhqk,bhkd->bhqd", pr, v)
+        want = _fwd_and_grads(ref, q, k, v, w)
+        for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=2e-5, atol=2e-5, err_msg=name)
+
+    def test_infer_time_downgrade_scales_the_probabilities(self):
+        """downgrade_in_infer at test time: no mask, probabilities times
+        (1 - p), through the op's lowering arguments."""
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops import pallas_kernels as pk
+        q, k, v, _, bias = _attn_operands(1, 2, 128, 128, 64, bias_batch=1)
+        with pltpu.force_tpu_interpret_mode():
+            plain = pk.fused_attention_tpu(q, k, v, bias)
+            scaled = pk.fused_attention_tpu(q, k, v, bias, dropout_rate=0.1,
+                                            prob_scale=0.9)
+        np.testing.assert_allclose(np.asarray(scaled),
+                                   0.9 * np.asarray(plain), rtol=1e-6)
+
+    def test_supported_shapes(self):
+        from paddle_tpu.ops import pallas_kernels as pk
+        q = _sds(4, 12, 512, 64)
+        row = _sds(4, 1, 1, 512, dtype=jnp.float32)
+        assert pk.fused_attention_supported(q, q, q, row)
+        assert pk.fused_attention_supported(q, q, q, None)
+        assert pk.fused_attention_supported(
+            q, q, q, _sds(1, 1, 1, 512, dtype=jnp.float32))
+        # a bias over heads or query rows would sit in HBM at score size
+        assert not pk.fused_attention_supported(
+            q, q, q, _sds(4, 12, 512, 512, dtype=jnp.float32))
+        assert not pk.fused_attention_supported(
+            q, q, q, _sds(4, 1, 512, 512, dtype=jnp.float32))
+        long = _sds(4, 12, 1024, 64)
+        assert not pk.fused_attention_supported(long, long, long, None)
+        odd = _sds(4, 12, 200, 64)
+        assert not pk.fused_attention_supported(odd, odd, odd, None)
+        assert not pk.fused_attention_supported(
+            q, q, _sds(4, 12, 512, 64, dtype=jnp.float32), None)
+        # a head width that tiles the 128 lanes, in whole lane groups
+        assert pk.fused_attention_supported(*[_sds(4, 8, 512, 32)] * 3)
+        assert pk.fused_attention_supported(*[_sds(4, 3, 512, 128)] * 3)
+        assert not pk.fused_attention_supported(*[_sds(4, 12, 512, 40)] * 3)
+        assert not pk.fused_attention_supported(*[_sds(4, 5, 512, 64)] * 3)
+        # heads per grid step: whole lane groups that divide the head count
+        assert pk._heads_per_step(12, 128, 64) == 12
+        assert pk._heads_per_step(12, 512, 64) == 6
+        assert pk._heads_per_step(16, 512, 64) == 8
+        assert pk._heads_per_step(10, 512, 64) == 2
+        assert pk._heads_per_step(16, 512, 32) == 8
+        assert pk._heads_per_step(3, 512, 128) == 3
+
+    @pytest.mark.parametrize("b, h, s, d", [(32, 12, 512, 64),
+                                            (128, 12, 128, 64)])
+    def test_mosaic_preflight_at_berts_widths(self, b, h, s, d):
+        """bf16, the padding bias as its [B, 1, 1, S] row, dropout 0.1,
+        forward and backward through the op's own dispatch, compiled for a
+        described v5e: both kernels are there and no [B, H, S, S] array
+        exists outside them."""
+        import functools
+        from paddle_tpu.ops.attention import flash_attention
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        q = _sds(b, h, s, d)
+        bias = _sds(b, 1, 1, s, dtype=jnp.float32)
+        key = jax.random.PRNGKey(0)
+
+        def step(q, k, v, bias, key, w):
+            attend = functools.partial(
+                flash_attention, mask=bias, dropout_rate=0.1,
+                dropout_key=key, use_pallas=True)
+            return _fwd_and_grads(attend, q, k, v, w)
+        compiled = compile_for_tpu(step, q, q, q, bias, key, q)
+        assert mosaic_call_count(compiled) == 2
+        assert f"[{b},{h},{s},{s}]" not in compiled.as_text()
+
+
+class TestBertStepKeepsTheScoresOnTheCore:
+    """The whole seq-512 training step as the benchmark's cell compiles it
+    (Program -> default passes + AMP -> Executor's step function, batch 32,
+    BERT-base widths; two layers, a layer is a layer), for a described v5e:
+    every attention is the fused kernel, forward and backward, and no
+    [32, 12, 512, 512] array exists in the executable outside the custom
+    calls."""
+
+    def test_no_score_sized_buffer_in_the_step(self, monkeypatch):
+        import paddle_tpu.fluid as fluid
+        from paddle_tpu.fluid.core import Scope, scope_guard
+        from paddle_tpu.fluid.framework import reset_unique_name
+        from paddle_tpu.models.static_graphs import (
+            bert_demo_feed, build_bert_train_program)
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        from paddle_tpu.ops.registry import LoweringContext
+        # the lowerings ask jax.default_backend(); the target is the TPU
+        monkeypatch.setattr(LoweringContext, "pallas_ok",
+                            lambda self: not self.partitioned)
+        batch, seq, layers = 32, 512, 2
+        reset_unique_name()
+        main, startup, loss = build_bert_train_program(
+            vocab=30522, hidden=768, heads=12, seq=seq, layers=layers,
+            dropout=0.1)
+        bs = fluid.BuildStrategy()
+        bs.amp = True
+        program = fluid.CompiledProgram(main, build_strategy=bs)
+        feed = bert_demo_feed(np.random.RandomState(0), batch=batch,
+                              seq=seq, vocab=30522)
+        before = _lowering_counts()
+        exe = fluid.Executor()
+        with scope_guard(Scope()):
+            exe.run(startup)
+            program._apply_ir_passes([loss.name])
+            scope = fluid.global_scope()
+            step = exe._prepare(main, feed, [loss.name], scope, plan=None)
+            mut = {n: scope.find_var(n) for n in step.param_names
+                   if n in step.written_names}
+            ro = {n: scope.find_var(n) for n in step.param_names
+                  if n not in step.written_names}
+            compiled = compile_for_tpu(step.raw_fn, mut, ro, feed,
+                                       jax.random.PRNGKey(0))
+        ops = [op.type for op in main.global_block().ops]
+        assert ops.count("fused_multihead_attention") == layers
+        assert "softmax" not in ops
+        # the forward op and the grad op that re-traces it, per layer
+        after = _lowering_counts()
+        assert after["fused_kernel"] - before["fused_kernel"] == 2 * layers
+        assert after["xla"] == before["xla"]
+        text = compiled.as_text()
+        assert mosaic_call_count(compiled) >= 2 * layers
+        assert f"[{batch},12,{seq},{seq}]" not in text
+
+
+# ---------------------------------------------------------------------------
 # fuse_sparse_embedding
 # ---------------------------------------------------------------------------
 
@@ -445,7 +794,7 @@ class TestKernelTierUmbrella:
         bs = _tier_bs(fuse_all_optimizer_ops=True)
         from paddle_tpu.fluid.passes import passes_for_build_strategy
         assert [p.name for p in passes_for_build_strategy(bs)] \
-            == ["fuse_optimizer"]
+            == ["fuse_attention", "fuse_optimizer"]
 
     def test_ops_per_step_drops_under_tier(self):
         rng = np.random.RandomState(0)

@@ -118,3 +118,26 @@ class TestRepoKernels:
         g = jax.grad(lambda q, k, v: pk.flash_attention_tpu(q, k, v).sum(),
                      argnums=(0, 1, 2))
         assert_mosaic_lowerable(g, q, k, v)
+
+    @pytest.mark.parametrize("shape", [(32, 12, 512, 64),
+                                       (128, 12, 128, 64)])
+    def test_fused_attention_fwd_bwd(self, shape):
+        """BERT-base's widths in bf16, the padding bias as its
+        [B, 1, 1, S] row, dropout 0.1 on the probabilities."""
+        b, _, s, _ = shape
+        q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        bias = jax.ShapeDtypeStruct((b, 1, 1, s), jnp.float32)
+
+        def f(q, k, v, bias, key):
+            return pk.fused_attention_tpu(q, k, v, bias, dropout_rate=0.1,
+                                          dropout_key=key)
+        assert_mosaic_lowerable(f, q, q, q, bias, KEY)
+        assert_mosaic_lowerable(
+            jax.grad(lambda *a: f(*a).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2)), q, q, q, bias, KEY)
+
+    def test_fused_attention_mask_kernel(self):
+        assert_mosaic_lowerable(
+            lambda k: pk.fused_attention_keep_mask((8, 12, 512, 64), 512,
+                                                   0.1, k), KEY)
+

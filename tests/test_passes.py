@@ -558,17 +558,23 @@ def test_program_to_dot_shapes_and_persistables():
 
 def test_passes_for_build_strategy_mapping():
     bs = fluid.BuildStrategy()
-    assert passes_for_build_strategy(bs) == []
+    # the one pass no field selects: attention chains a kernel covers
+    assert [p.name for p in passes_for_build_strategy(bs)] \
+        == ["fuse_attention"]
     bs.memory_optimize = True
     names = [p.name for p in passes_for_build_strategy(bs)]
-    assert names == ["constant_fold", "prune_identity", "dce"]
+    assert names == ["constant_fold", "fuse_attention", "prune_identity",
+                     "dce"]
     bs.fuse_elewise_add_act_ops = True
     bs.fuse_bn_act_ops = True
     bs.fuse_all_reduce_ops = True
     names = [p.name for p in passes_for_build_strategy(bs)]
     assert names == ["constant_fold", "fuse_elewise_add_act",
-                     "fuse_bn_act", "prune_identity", "dce",
-                     "coalesce_allreduce"]
+                     "fuse_bn_act", "fuse_attention", "prune_identity",
+                     "dce", "coalesce_allreduce"]
+    bs.sharding = "dp"               # no Mosaic call in a partitioned program
+    assert "fuse_attention" not in [
+        p.name for p in passes_for_build_strategy(bs)]
 
 
 def test_compiled_program_applies_passes_once():
