@@ -19,12 +19,25 @@ WHITE_OPS = {
     "conv_fusion", "fc", "batch_fc", "scaled_fc", "multihead_matmul",
     "fused_multihead_attention", "var_conv_2d", "sequence_conv",
     "row_conv",
+    # sparse experts (ops/decoder_ops.py): the grouped matmuls take bf16
+    # operands with float32 accumulation, and the rows they read are
+    # gathered in bf16 (half the bytes of the largest buffer of the layer)
+    # the combine gathers the experts' bf16 rows and sums them in float32
+    # under the routing weights, which stay float32 (KEEP_FP32_SLOTS)
+    "moe_grouped_matmul", "moe_dispatch", "moe_combine",
 }
+# input slots of white ops that keep float32 all the same: small operands
+# whose precision decides the result
+KEEP_FP32_SLOTS = {"moe_combine": ("TopKWeight",)}
 BLACK_OPS = {
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
     "reduce_mean", "reduce_sum", "mean", "sum", "exp",
     "log", "rsqrt", "sqrt", "square", "sigmoid_cross_entropy_with_logits",
     "cumsum", "p_norm", "l2_normalize", "softplus",
+    # a decoder's pre-norm feeds the router, whose eighth-best logit a bf16
+    # input would move; the router's own matmul, top-k and softmax are
+    # float32
+    "rms_norm", "moe_route",
 }
 # matmul/conv-family ops deliberately kept fp32: recurrent cells whose
 # hidden-state chains drift in bf16, int8-quantized kernels, gather-heavy

@@ -358,12 +358,39 @@ class AsyncStepRunner:
         self.flush()
         while self._inflight:
             self._wait_oldest()
+        self._publish_device_counters()
         for fut in self._error_futures:
             if not fut._consumed:
                 fut._consumed = True
                 raise fut._error
         self._error_futures = [f for f in self._error_futures
                                if not f._consumed]
+
+    def _publish_device_counters(self):
+        """Counts the program keeps on the device (``program._hints
+        ["device_counters"]``: persistable variable -> metric name, e.g. the
+        expert layer's tokens per held expert) become gauges of
+        ``trace.metrics()``: ``<metric>`` for a scalar, ``<metric>.<i>`` for
+        element ``i`` of a vector.  Read here, after the window has emptied
+        and the device is idle, so no step carries a fetch or a host
+        callback for them."""
+        prog = getattr(self._program, "_program", self._program)
+        counters = (getattr(prog, "_hints", None) or {}).get(
+            "device_counters")
+        if not counters:
+            return
+        scope = self._scope or core.global_scope()
+        metrics = trace.metrics()
+        for var, metric in counters.items():
+            value = scope.find_var(var)
+            if value is None:
+                continue
+            flat = np.asarray(value).ravel()
+            if flat.size == 1:
+                metrics.gauge(metric).set(float(flat[0]))
+            else:
+                for i, v in enumerate(flat):
+                    metrics.gauge(f"{metric}.{i}").set(float(v))
 
     def abort(self):
         """Error-path cleanup: DROP buffered feeds (their futures resolve

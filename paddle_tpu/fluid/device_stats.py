@@ -467,7 +467,9 @@ def _hlo_computations(hlo_text):
                 cur = comps[m.group(2)] = []
                 if m.group(1):
                     entry = m.group(2)
-        elif line.startswith("}"):
+        elif line.rstrip() == "}":
+            # the computation's own closing brace: a constant printed over
+            # several lines ends in "}}, metadata=..." at column 0 too
             cur = None
         else:
             m = _INSTRUCTION.match(line)
@@ -475,6 +477,12 @@ def _hlo_computations(hlo_text):
                 rest = m.group(2)
                 op = _OPCODE.search(" " + rest)
                 cur.append((m.group(1), op.group(1) if op else "", rest))
+            elif cur and "metadata=" in line:
+                # an instruction printed over several lines (a custom call
+                # with a literal among its attributes): its metadata is on
+                # the last of them
+                name, opcode, rest = cur[-1]
+                cur[-1] = (name, opcode, rest + " " + line.strip())
     return comps, entry
 
 

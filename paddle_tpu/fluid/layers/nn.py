@@ -459,6 +459,116 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out, act)
 
 
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """``input / sqrt(mean(input^2, last axis) + epsilon) * scale``."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        param_attr, [int(input.shape[-1])], "float32",
+        default_initializer=ConstantInitializer(1.0))
+    return _append_single(helper, "rms_norm",
+                          {"X": [input], "Scale": [scale]}, input.dtype,
+                          {"epsilon": epsilon}, out_slot="Y")
+
+
+def rotary_embedding(input, inv_freq, scale=1.0, name=None):
+    """Rotary position embedding of ``input`` [..., S, D] (rotate-half
+    convention) under the D/2 frequencies ``inv_freq``, cos and sin times
+    ``scale``."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    return _append_single(
+        helper, "rotary_embedding", {"X": [input]}, input.dtype,
+        {"inv_freq": [float(f) for f in inv_freq], "scale": float(scale)})
+
+
+def fused_multihead_attention(q, k, v, scale=None, causal=False, window=0,
+                              name=None):
+    """softmax(q k^T * scale) v over [B, heads, S, D] operands.
+    ``k`` and ``v`` may have fewer heads than ``q`` (each shared by a group
+    of query heads); ``causal`` attends j <= i, ``window`` > 0 only
+    0 <= i - j < window."""
+    helper = LayerHelper("fused_multihead_attention", name=name)
+    inputs = {"Q": [q], "K": [k], "V": [v]}
+    attrs = {"causal": bool(causal), "window": int(window),
+             "num_kv_heads": int(k.shape[1])}
+    if scale is not None:
+        attrs["scale"] = float(scale)
+    return _append_single(helper, "fused_multihead_attention", inputs,
+                          q.dtype, attrs)
+
+
+def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
+                 num_held=None, router_attr=None, gate_attr=None,
+                 up_attr=None, down_attr=None, name=None):
+    """Sparse experts over tokens ``input`` [T, D] without dropping: the
+    router scores all ``num_experts``, each token goes to its ``top_k`` best,
+    and this program holds the ``num_held`` experts from ``first_expert`` on
+    (default: all) and adds their weighted gated FFNs (width ``expert_size``)
+    of the tokens routed to them (parallel/moe.py, docs/moe.md).  Four kinds
+    of op and the ``swiglu`` gate between the grouped matmuls.  The routing's counts accumulate
+    on the device in ``<name>.tokens_per_expert`` [num_held] and
+    ``<name>.steps`` [1], published as gauges of ``trace.metrics()`` under
+    ``moe.<name>.…`` when an ``AsyncStepRunner`` drains."""
+    from .tensor import create_global_var
+    from ..framework import default_main_program
+    name = name or unique_name("expert_layer")
+    helper = LayerHelper("expert_layer", name=name)
+    num_held = num_experts if num_held is None else num_held
+    d = int(input.shape[-1])
+    router = helper.create_parameter(router_attr, [d, num_experts], "float32")
+    w_gate = helper.create_parameter(gate_attr, [num_held, d, expert_size],
+                                     "float32")
+    w_up = helper.create_parameter(up_attr, [num_held, d, expert_size],
+                                   "float32")
+    w_down = helper.create_parameter(down_attr, [num_held, expert_size, d],
+                                     "float32")
+    counts = create_global_var([num_held], 0, "int32", persistable=True,
+                               name=name + ".tokens_per_expert")
+    steps = create_global_var([1], 0, "int32", persistable=True,
+                              name=name + ".steps")
+    default_main_program()._hints.setdefault("device_counters", {}).update(
+        {counts.name: "moe." + counts.name, steps.name: "moe." + steps.name})
+
+    def var(dtype, stop_gradient=False):
+        return helper.create_variable_for_type_inference(
+            dtype=dtype, stop_gradient=stop_gradient)
+
+    weight = var("float32")
+    plan = {"Order": [var("int32", True)], "Pos": [var("int32", True)],
+            "GroupSizes": [var("int32", True)]}
+    helper.append_op(
+        "moe_route",
+        inputs={"X": [input], "RouterWeight": [router], "Counts": [counts],
+                "Steps": [steps]},
+        outputs={"TopKWeight": [weight], "CountsOut": [counts],
+                 "StepsOut": [steps], **plan},
+        attrs={"top_k": int(top_k), "first_expert": int(first_expert),
+               "num_held": int(num_held)})
+    rows = _append_single(helper, "moe_dispatch", {"X": [input], **plan},
+                          input.dtype)
+
+    def grouped(x, w):
+        return _append_single(
+            helper, "moe_grouped_matmul",
+            {"X": [x], "W": [w], "GroupSizes": plan["GroupSizes"]}, x.dtype)
+
+    hidden = _append_single(
+        helper, "swiglu",
+        {"X": [grouped(rows, w_gate)], "Y": [grouped(rows, w_up)]},
+        input.dtype)
+    return _append_single(
+        helper, "moe_combine",
+        {"X": [grouped(hidden, w_down)], "TopKWeight": [weight], **plan},
+        input.dtype)
+
+
+def _append_single(helper, op_type, inputs, dtype, attrs=None,
+                   out_slot="Out"):
+    out = helper.create_variable_for_type_inference(dtype=dtype)
+    op = helper.append_op(op_type, inputs=inputs, outputs={out_slot: [out]},
+                          attrs=attrs or {})
+    return op[out_slot][0] if in_dygraph_mode() else out
+
+
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,
                act=None, data_layout="NCHW", name=None):
     helper = LayerHelper("group_norm", name=name)

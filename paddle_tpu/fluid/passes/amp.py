@@ -230,9 +230,12 @@ class AmpBf16Pass(Pass):
         """Cast ``op``'s float inputs with dtypes in ``from_dts`` to
         ``target``; mirror onto paired generic_grads (fresh I_ casts, GI_
         cast-backs)."""
+        from ...amp.lists import KEEP_FP32_SLOTS
         n_cast = 0
         grads = pairs.get(id(op), [])
         for slot in list(op.inputs):
+            if slot in KEEP_FP32_SLOTS.get(op.type, ()):
+                continue
             names = op.inputs[slot]
             for j, name in enumerate(names):
                 d = dt_of(name)

@@ -25,6 +25,7 @@ from . import sequence_extra  # noqa: F401  sequence_conv/pad/slice/...
 from . import plumbing_ops    # noqa: F401  tensor arrays/LoD/queues/save-load
 from . import fused_extra_ops # noqa: F401  nn tail + fused compositions
 from . import catalog_tail_ops # noqa: F401  fc/py_func/rnn/detection tail
+from . import decoder_ops     # noqa: F401  rms_norm/rotary/sparse experts
 
 # stamp per-op exclusion reasons onto non-differentiable registrations
 # (test_op_grads_auto.py enforces full coverage of the audit)

@@ -6,8 +6,12 @@ TPU-native: one `fused_multihead_attention` op; the `fuse_attention` pass
 (fluid/passes/kernel_tier.py, in the default pipeline of an unpartitioned
 program) PRODUCES it from the naive matmul→softmax→matmul chain, so plain
 static programs get the kernels without touching model code.  The lowering
-picks one of three paths from what it can see (`attention_path`):
+picks one of four paths from what it can see (`attention_path`):
 
+* `splash_kernel` — `pallas_kernels.splash_attention_tpu`: causal attention
+  from `FLAGS_pallas_min_seq` up, and every length with a sliding `window`
+  or with fewer key/value heads than query heads; only the blocks inside the
+  causal band are visited, nothing of size [S, S] exists;
 * `fused_kernel` — `pallas_kernels.fused_attention_tpu`: key lengths up to
   512 (BERT's), whole score rows on the core, dropout on the probabilities
   from the on-core PRNG, the padding bias passed as its [B, 1, 1, S] row;
@@ -15,11 +19,15 @@ picks one of three paths from what it can see (`attention_path`):
   `FLAGS_pallas_min_seq` (1024) up, dropout-free;
 * `xla` — `_reference_attention`, the XLA softmax(QK^T)V: the CPU, inside a
   GSPMD-partitioned program (a Mosaic call cannot be partitioned), and every
-  shape the kernels do not cover.
+  shape the kernels do not cover.  With a `window` or grouped heads it is
+  `_banded_attention`: blocks of queries against the keys of their band,
+  each block recomputed in backward, so that no [S, S] scores exist there
+  either.
 
 `attention.lowering.<path>` in `trace.metrics()` counts the picks, once per
 lowering of an op (a training program lowers each attention twice: the
-forward op and the grad op that re-traces it).
+forward op and the grad op that re-traces it); a causal op also counts
+`attention.lowering.<path>.window` or `.full_causal`.
 """
 from __future__ import annotations
 
@@ -84,6 +92,44 @@ def _reference_attention(q, k, v, mask, scale, causal,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
+# queries a block of `_banded_attention`: [B, H, 512, band] float32 scores
+_BAND_BLOCK = 512
+
+
+def _banded_attention(q, k, v, scale, window):
+    """Causal attention in XLA without [S, S] scores: q [B, Hq, S, D], k/v
+    [B, Hkv, S, D] (each key/value head shared by Hq / Hkv query heads),
+    position i attending j <= i and, with ``window`` > 0, i - j < window.
+    Each block of queries sees only the keys of its band and is recomputed
+    in backward (``jax.checkpoint``)."""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, s, d)
+    acc = jnp.float32
+
+    @jax.checkpoint
+    def block(qb, kb, vb, r0, c0):
+        sc = jnp.einsum("bhgqd,bhkd->bhgqk", qb, kb,
+                        preferred_element_type=acc) * scale
+        i = r0 + jnp.arange(qb.shape[3])[:, None]
+        j = c0 + jnp.arange(kb.shape[2])[None, :]
+        keep = j <= i
+        if window:
+            keep &= i - j < window
+        sc = jnp.where(keep, sc, jnp.finfo(acc).min)
+        p = jax.nn.softmax(sc, axis=-1).astype(qb.dtype)
+        return jnp.einsum("bhgqk,bhkd->bhgqd", p, vb)
+
+    step = min(_BAND_BLOCK, s)
+    out = []
+    for r0 in range(0, s, step):
+        r1 = min(r0 + step, s)
+        c0 = max(0, r0 - window + 1) if window else 0
+        out.append(block(qg[:, :, :, r0:r1], k[:, :, c0:r1], v[:, :, c0:r1],
+                         r0, c0))
+    return jnp.concatenate(out, axis=3).reshape(b, hq, s, d)
+
+
 def _bias_broadcastable(mask, q, k) -> bool:
     """Can ``mask`` serve as the Pallas kernel's additive-bias ``ab``
     argument — i.e. broadcast to [B, H, Tq, Tk]?"""
@@ -94,13 +140,21 @@ def _bias_broadcastable(mask, q, k) -> bool:
 
 
 def attention_path(q, k, v, mask, causal, drop_active, use_pallas,
-                   min_seq=_PALLAS_MIN_SEQ_DEFAULT) -> str:
-    """Which lowering attention over these operands takes: ``fused_kernel``,
-    ``flash_kernel`` or ``xla``.  A function of shapes, dtypes and flags
-    alone (the operands may be ShapeDtypeStructs)."""
+                   min_seq=_PALLAS_MIN_SEQ_DEFAULT, window=0) -> str:
+    """Which lowering attention over these operands takes:
+    ``splash_kernel``, ``fused_kernel``, ``flash_kernel`` or ``xla``.  A
+    function of shapes, dtypes and flags alone (the operands may be
+    ShapeDtypeStructs)."""
     if not use_pallas:
         return "xla"
     seq = q.shape[-2]
+    grouped = k.shape[1] != q.shape[1]
+    if (window or grouped or seq >= min_seq) and causal and not drop_active:
+        from .pallas_kernels import splash_attention_supported
+        if splash_attention_supported(q, k, v, mask):
+            return "splash_kernel"
+    if window or grouped:
+        return "xla"
     if not causal and seq >= _FUSED_MIN_SEQ:
         from .pallas_kernels import fused_attention_supported
         if fused_attention_supported(q, k, v, mask):
@@ -113,7 +167,8 @@ def attention_path(q, k, v, mask, causal, drop_active, use_pallas,
 
 def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                     dropout_rate=0.0, dropout_key=None,
-                    dropout_upscale=True, prob_scale=None, use_pallas=None):
+                    dropout_upscale=True, prob_scale=None, use_pallas=None,
+                    window=0):
     """Dispatch to a Pallas TPU kernel where one covers the call, else XLA
     (``attention_path``).  ``use_pallas``: an op lowering passes
     ``ctx.pallas_ok()``; None (the shard_map bodies in parallel/) means
@@ -123,17 +178,38 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
     (anything broadcastable to [B, H, Tq, Tk], materialised at that size)
     and has no dropout; the fused kernel takes the [B, 1, 1, Tk] bias row
     as it is and drops probabilities in-kernel.
+
+    ``window`` > 0 (with ``causal``) keeps 0 <= i - j < window; ``k`` and
+    ``v`` may have fewer heads than ``q``, each shared by a group of query
+    heads.  Both are causal, mask-free and dropout-free: the splash kernel,
+    or ``_banded_attention`` in XLA.
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     drop_active = bool(dropout_rate) and dropout_key is not None
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    window = int(window or 0)
+    banded = bool(window) or k.shape[1] != q.shape[1]
+    if banded and (not causal or mask is not None or drop_active
+                   or prob_scale is not None):
+        raise ValueError("attention with a window or grouped key/value "
+                         "heads is causal, without mask or dropout")
     path = attention_path(q, k, v, mask, causal, drop_active, use_pallas,
-                          _pallas_min_seq())
-    if path == "flash_kernel" and (prob_scale is not None or scale == 0.0):
+                          _pallas_min_seq(), window)
+    if path in ("flash_kernel", "splash_kernel") \
+            and (prob_scale is not None or scale == 0.0):
         path = "xla"
     from ..fluid import trace
     trace.metrics().counter(f"attention.lowering.{path}").inc()
+    if causal:
+        trace.metrics().counter(
+            f"attention.lowering.{path}."
+            + ("window" if window else "full_causal")).inc()
+    if path == "splash_kernel":
+        from .pallas_kernels import splash_attention_tpu
+        return splash_attention_tpu(q, k, v, scale=scale, window=window)
+    if banded:
+        return _banded_attention(q, k, v, scale, window)
     if path == "fused_kernel":
         from .pallas_kernels import fused_attention_tpu
         return fused_attention_tpu(
@@ -182,7 +258,8 @@ def _fused_mha(ins, attrs, ctx):
                           causal=attrs.get("causal", False),
                           dropout_rate=rate, dropout_key=dropout_key,
                           dropout_upscale=upscale, prob_scale=prob_scale,
-                          use_pallas=ctx.pallas_ok())
+                          use_pallas=ctx.pallas_ok(),
+                          window=attrs.get("window", 0))
     return {"Out": [out]}
 
 

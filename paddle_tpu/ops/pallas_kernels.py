@@ -76,6 +76,58 @@ def flash_attention_tpu(q, k, v, scale=None, causal=False, ab=None):
 
 
 # ---------------------------------------------------------------------------
+# causal and sliding-window attention at long sequences: jax's splash
+# kernel, which visits only the blocks inside the mask's band (forward, dQ
+# and dK/dV), streams K/V through VMEM and shares each key/value head among
+# its group of query heads.  Scores, probabilities and the mask never exist
+# at [S, S]: the mask is a host-side description of which blocks are full,
+# partial or empty.
+# ---------------------------------------------------------------------------
+
+# rows and keys of one grid step: at 128 (the kernel's default) the step
+# overhead dominates; 512 x 512 float32 scores are 1 MiB of VMEM
+_SPLASH_BLOCK = 512
+
+
+def splash_attention_supported(q, k, v, mask) -> bool:
+    """Does the splash kernel cover attention over these operands: no
+    additive mask, a lane-aligned head, query heads a multiple of the
+    key/value heads, and whole blocks."""
+    seq = q.shape[2]
+    return (mask is None and q.ndim == 4 and k.shape == v.shape
+            and k.shape[2] == seq and q.shape[3] == k.shape[3]
+            and q.shape[3] % _LANES == 0 and q.shape[1] % k.shape[1] == 0
+            and seq % min(_SPLASH_BLOCK, seq) == 0 and seq % _LANES == 0)
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_kernel(seq, q_heads, window):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    mask = sm.LocalMask((seq, seq), (window - 1, 0), 0) if window \
+        else sm.CausalMask((seq, seq))
+    b = min(_SPLASH_BLOCK, seq)
+    blocks = sk.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    # the masks are numpy descriptions: build them outside any trace
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mha(sm.MultiHeadMask([mask] * q_heads),
+                                  block_sizes=blocks, head_shards=1,
+                                  q_seq_shards=1)
+
+
+def splash_attention_tpu(q, k, v, scale=None, window=0):
+    """Causal attention, q [B, Hq, S, D], k/v [B, Hkv, S, D] with Hq a
+    multiple of Hkv; ``window`` > 0 keeps 0 <= i - j < window."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    kernel = _splash_kernel(q.shape[2], q.shape[1], int(window))
+    # the kernel applies no scale of its own
+    return jax.vmap(kernel)((q * scale).astype(q.dtype), k, v)
+
+
+# ---------------------------------------------------------------------------
 # fused attention for the lengths BERT runs (S <= 512): whole score rows on
 # the core.
 #

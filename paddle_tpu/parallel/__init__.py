@@ -11,4 +11,4 @@ from .sharding import (ShardingPlan, build_plan, match_partition_rules,
                        tp_rules_for_program)
 from .ring_attention import ring_attention
 from .ulysses import ulysses_attention
-from .moe import init_moe_params, moe_ffn, top1_routing
+from .moe import expert_layer, moe_partition_rules

@@ -598,3 +598,25 @@ def test_host_timeline_carries_the_wall_clock_of_its_epoch(tmp_path):
     # drift by less than a millisecond over a test run)
     assert meta["epoch_unix_ns"] + ev["ts"] * 1e3 \
         == pytest.approx(wall, abs=50e6)
+
+
+def test_hlo_op_map_reads_instructions_printed_over_several_lines():
+    """A Mosaic call that takes a literal (the splash kernel's block masks)
+    is printed over several lines, the last of which starts with ``}}`` at
+    column 0 and carries the metadata: it neither ends the computation nor
+    loses its scope."""
+    text = """HloModule jit_fn, is_scheduled=true
+
+ENTRY %main.1 (p0: bf16[8,128]) -> bf16[8,128] {
+  %p0 = bf16[8,128]{1,0} parameter(0)
+  %splash_mha_fwd.1 = bf16[8,128]{1,0} custom-call(%p0), custom_call_target="tpu_custom_call", literal={ {
+    { 1, 0 },
+    { 1, 1 }
+}}, metadata={op_name="jit(fn)/pd:f:fused_multihead_attention:attn_0.tmp_0/vmap(jit(_splash_attention))/pallas_call"}
+  ROOT %add.2 = bf16[8,128]{1,0} add(%splash_mha_fwd.1, %p0), metadata={op_name="jit(fn)/pd:f:elementwise_add:tmp_3/add"}
+}
+"""
+    got = ds.hlo_op_map(text)
+    assert got["splash_mha_fwd.1"]["label"] == "fused_multihead_attention"
+    assert got["splash_mha_fwd.1"]["instance"] == "attn_0.tmp_0"
+    assert got["add.2"]["label"] == "elementwise_add"

@@ -535,6 +535,100 @@ class TestBertStepKeepsTheScoresOnTheCore:
         assert f"[{batch},12,{seq},{seq}]" not in text
 
 
+class TestCausalDecoderStepKeepsTheScoresOnTheCore:
+    """The benchmark's Mellum2 configuration (benchmark/configs/
+    mellum2_12b_a2_5b_train: window and full causal layers, grouped heads,
+    sparse experts), shrunk in everything but the mechanisms."""
+
+    def test_no_score_sized_buffer_in_the_step(self, monkeypatch):
+        """The training step at a sequence of 2048 (window 1024, 8:1 heads of
+        128, experts in whole kernel tiles), Program -> default passes + AMP ->
+        the Executor's step function, compiled for a described v5e: every
+        attention is the splash kernel, every grouped matmul the megablox
+        kernel, and no [.., 2048, 2048] array exists outside the custom calls."""
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        from paddle_tpu.ops.registry import LoweringContext
+        monkeypatch.setattr(LoweringContext, "pallas_ok",
+                            lambda self: not self.partitioned)
+        import os
+        from paddle_tpu.fluid import trace
+        from paddle_tpu.fluid.core import Scope, scope_guard
+        from benchmark.harness.registry import Registry, load_module
+        from benchmark.harness.strategy import build_strategy
+        REG = Registry()
+        cfg, cfg_dir = REG.config("mellum2_12b_a2_5b_train")
+        mix = REG.mix("causal_lm_seq8192")
+        model = load_module(os.path.join(cfg_dir, "model.py"))
+        seq = 2048
+        cfg.update({"hidden_size": 256, "num_attention_heads": 8,
+                    "num_key_value_heads": 1, "vocab_size": 512,
+                    "moe_intermediate_size": 128, "num_experts": 2,
+                    "num_experts_per_tok": 2, "num_hidden_layers": 4,
+                    "published": dict(cfg["published"], num_experts=8)})
+        cfg["layer_types"] = ["sliding_attention", "full_attention"] * 2
+        cfg["num_hidden_layers"] = 2
+        mix.update({"seq_len": seq, "samples_per_chip": 1})
+        kind = REG.module("traffic_kinds", mix["kind"] + ".py")
+        feed = kind.generate(mix, cfg, 0, 1, n_batches=1)[0]
+
+        def count(name):
+            return trace.metrics().counter(name).value
+        names = ("attention.lowering.splash_kernel.window",
+                 "attention.lowering.splash_kernel.full_causal",
+                 "attention.lowering.xla", "moe.gmm_lowering.megablox",
+                 "moe.gmm_lowering.ragged_dot")
+        reset_unique_name()
+        built = model.build(cfg, mix, train=True)
+        program = fluid.CompiledProgram(built["main"],
+                                        build_strategy=build_strategy(cfg, mix))
+        before = {n: count(n) for n in names}   # after the build's shape inference
+        exe = fluid.Executor()
+        with scope_guard(Scope()):
+            exe.run(built["startup"])
+            program._apply_ir_passes([built["loss"].name])
+            scope = fluid.global_scope()
+            step = exe._prepare(built["main"], feed, [built["loss"].name],
+                                scope, plan=None)
+            mut = {n: scope.find_var(n) for n in step.param_names
+                   if n in step.written_names}
+            ro = {n: scope.find_var(n) for n in step.param_names
+                  if n not in step.written_names}
+            compiled = compile_for_tpu(step.raw_fn, mut, ro, feed,
+                                       jax.random.PRNGKey(0))
+        moved = {n: count(n) - before[n] for n in names}
+        # the forward op and the grad op that re-traces it, per layer
+        assert moved["attention.lowering.splash_kernel.window"] == 2
+        assert moved["attention.lowering.splash_kernel.full_causal"] == 2
+        assert moved["attention.lowering.xla"] == 0
+        assert moved["moe.gmm_lowering.megablox"] == 2 * 2 * 3
+        assert moved["moe.gmm_lowering.ragged_dot"] == 0
+        text = compiled.as_text()
+        assert mosaic_call_count(compiled) >= 2 * (3 + 3 * 3)
+        assert f"{seq},{seq}]" not in text
+        exe.close()
+
+
+    @pytest.mark.parametrize("k, n", [(2304, 896), (896, 2304)])
+    def test_grouped_matmul_compiles_at_the_cells_widths(self, k, n):
+        """bf16, 16 experts held, forward and both backward kernels with
+        their own tile plans, for a described v5e (scoped VMEM included)."""
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        from paddle_tpu.parallel import moe
+        x = _sds(8192, k)
+        w = _sds(16, k, n)
+        sizes = jax.ShapeDtypeStruct((16,), jnp.int32)
+
+        def step(x, w, sizes, g):
+            out, vjp = jax.vjp(
+                lambda x, w: moe.grouped_matmul(x, w, sizes, True), x, w)
+            return (out,) + vjp(g)
+        compiled = compile_for_tpu(step, x, w, sizes, _sds(8192, n))
+        assert mosaic_call_count(compiled) == 3
+        assert (moe._tile(2304), moe._tile(896)) == (768, 896)
+
+
 # ---------------------------------------------------------------------------
 # fuse_sparse_embedding
 # ---------------------------------------------------------------------------

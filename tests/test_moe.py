@@ -1,0 +1,229 @@
+"""The sparse-expert layer (parallel/moe.py, the ``moe_*`` ops): top-k
+routing without dropping, a chip that holds a range of the experts, the
+shares adding up to the whole layer, and expert parallelism over ``ep``."""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+import paddle_tpu  # noqa: F401 — registers the lowerings
+from paddle_tpu.parallel import mesh as pmesh, moe
+from paddle_tpu.parallel.api import compat_shard_map as shard_map
+
+T, D, E, F, K = 64, 16, 8, 12, 3
+
+
+def _weights(seed=0, t=T, d=D, e=E, f=F):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(k[0], (t, d)), jax.random.normal(k[1], (d, e)),
+            jax.random.normal(k[2], (e, d, f)) * 0.3,
+            jax.random.normal(k[3], (e, d, f)) * 0.3,
+            jax.random.normal(k[4], (e, f, d)) * 0.3)
+
+
+def _dense(x, router, gate, up, down, top_k=K, held=None):
+    """Every expert over every token, masked: the plain spelling."""
+    top, chosen = jax.lax.top_k(x @ router, top_k)
+    weights = jax.nn.softmax(top, -1)
+    out = jnp.zeros_like(x)
+    for e in (range(gate.shape[0]) if held is None else held):
+        w = jnp.sum(jnp.where(chosen == e, weights, 0), -1, keepdims=True)
+        out = out + w * ((jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+    return out
+
+
+def _reference_module():
+    from benchmark.harness.registry import Registry, load_module
+    _, cfg_dir = Registry().config("mellum2_12b_a2_5b_train")
+    return load_module(os.path.join(cfg_dir, "reference.py"))
+
+
+def test_shapes_and_routing_on_one_device():
+    x, router, gate, up, down = _weights()
+    weights, experts = moe.route(x, router, K)
+    assert weights.shape == (T, K) and experts.shape == (T, K)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
+    assert experts.dtype == jnp.int32
+    # the K chosen are distinct and are the K largest logits
+    assert all(len(set(row)) == K for row in np.asarray(experts))
+    plan = moe.dispatch_plan(experts, 0, E)
+    assert int(plan.group_sizes.sum()) == T * K
+    counts = np.bincount(np.asarray(experts).ravel(), minlength=E)
+    np.testing.assert_array_equal(np.asarray(plan.group_sizes), counts)
+    # rows are sorted by expert, and pos is the inverse of order
+    by_row = np.asarray(experts).ravel()[np.asarray(plan.order)]
+    assert (np.diff(by_row) >= 0).all()
+    np.testing.assert_array_equal(
+        np.asarray(plan.order)[np.asarray(plan.pos).ravel()],
+        np.arange(T * K))
+    out = moe.expert_layer(x, router, gate, up, down, top_k=K)
+    assert out.shape == (T, D)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense(x, router, gate, up, down)),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_gradients_equal_the_dense_spelling():
+    args = _weights(1)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(
+        moe.expert_layer(*a, top_k=K))), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(_dense(*a))),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, r in zip(("x", "router", "gate", "up", "down"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+
+
+def test_no_drop_at_overflow():
+    """Every token's top choice is expert 0: a capacity router would keep
+    T / E of them.  Here all T rows reach it and each gets its result."""
+    x, router, gate, up, down = _weights(2)
+    x = jnp.abs(x)
+    router = jnp.zeros((D, E)).at[:, 0].set(10.0) + 0.01 * router
+    _, experts = moe.route(x, router, K)
+    assert (np.asarray(experts)[:, 0] == 0).all()
+    plan = moe.dispatch_plan(experts, 0, E)
+    assert int(plan.group_sizes[0]) == T
+    out = moe.expert_layer(x, router, gate, up, down, top_k=K)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense(x, router, gate, up, down)),
+                               rtol=2e-5, atol=2e-5)
+    assert (np.abs(np.asarray(out)).sum(-1) > 0).all()
+
+
+@pytest.mark.parametrize("case", ["all_to_one_held", "none_held"])
+def test_dropless_under_skew_on_a_share(case):
+    """A chip that holds experts 2..3 of 8: every token routed to held
+    expert 2 (among its K), or no token routed to a held expert at all."""
+    x, router, gate, up, down = _weights(3)
+    x = jnp.abs(x)
+    push = {"all_to_one_held": [2, 5, 6], "none_held": [0, 5, 6]}[case]
+    router = 0.01 * router
+    for e in push:
+        router = router.at[:, e].add(10.0)
+    first, held = 2, 2
+    _, experts = moe.route(x, router, K)
+    plan = moe.dispatch_plan(experts, first, held)
+    want_rows = T if case == "all_to_one_held" else 0
+    assert int(plan.group_sizes.sum()) == want_rows
+    out = moe.expert_layer(x, router, gate[first:first + held],
+                           up[first:first + held], down[first:first + held],
+                           top_k=K, first_expert=first)
+    want = _dense(x, router, gate, up, down,
+                  held=range(first, first + held))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    if case == "none_held":
+        assert not np.asarray(out).any()
+        grads = jax.grad(lambda g: jnp.sum(moe.expert_layer(
+            x, router, g, up[first:first + held], down[first:first + held],
+            top_k=K, first_expert=first)))(gate[first:first + held])
+        assert np.isfinite(np.asarray(grads)).all() \
+            and not np.asarray(grads).any()
+
+
+def test_the_four_shares_add_up_to_the_uncut_reference_layer():
+    """The share tied to the model: the configuration's reference given all
+    experts is the whole layer; four chips holding a quarter each, through
+    the program's layer, add up to it."""
+    ref = _reference_module()
+    x, router, gate, up, down = _weights(4)
+    whole = ref._held_experts(x, router, gate, up, down, K, 0)
+    held = E // 4
+    shares = [moe.expert_layer(x, router, gate[i:i + held], up[i:i + held],
+                               down[i:i + held], top_k=K, first_expert=i)
+              for i in range(0, E, held)]
+    np.testing.assert_allclose(np.asarray(sum(shares)), np.asarray(whole),
+                               rtol=2e-5, atol=2e-5)
+    # and each share is what the reference computes when given that share
+    for i, share in zip(range(0, E, held), shares):
+        want = ref._held_experts(x, router, gate[i:i + held],
+                                 up[i:i + held], down[i:i + held], K, i)
+        np.testing.assert_allclose(np.asarray(share), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    assert np.abs(np.asarray(shares[0] - whole)).max() > 1e-2
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+def test_expert_parallel_over_ep_equals_the_single_device_layer(skewed):
+    """Tokens and experts sharded over four devices, the two all-to-alls in
+    between: the exchange the one-chip cell leaves out.  Also when every
+    token goes to the experts of one device."""
+    mesh = pmesh.build_mesh({"ep": 4})
+    try:
+        x, router, gate, up, down = _weights(5, t=32)
+        if skewed:
+            x = jnp.abs(x)
+            router = 0.01 * router
+            for e in (0, 1, 4):
+                router = router.at[:, e].add(10.0)
+        sharded = P("ep", None, None)
+        layer = jax.jit(shard_map(
+            lambda *a: moe.expert_layer(*a, top_k=K, axis_name="ep"),
+            mesh=mesh, in_specs=(P("ep", None), P(), sharded, sharded,
+                                 sharded), out_specs=P("ep", None)))
+        want = moe.expert_layer(x, router, gate, up, down, top_k=K)
+        np.testing.assert_allclose(
+            np.asarray(layer(x, router, gate, up, down)), np.asarray(want),
+            rtol=2e-5, atol=2e-5)
+        got = jax.grad(lambda *a: jnp.sum(jnp.sin(layer(*a))),
+                       argnums=(0, 1, 2))(x, router, gate, up, down)
+        ref = jax.grad(lambda *a: jnp.sum(jnp.sin(
+            moe.expert_layer(*a, top_k=K))), argnums=(0, 1, 2))(
+                x, router, gate, up, down)
+        for a, r in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=1e-4, atol=1e-4)
+    finally:
+        pmesh.set_current_mesh(None)
+
+
+def test_partition_rules():
+    from paddle_tpu.parallel import sharding as shd
+    specs = shd.match_partition_rules(
+        moe.moe_partition_rules(),
+        {"layer_3.router.w": (16, 8), "layer_3.experts.gate": (8, 16, 32),
+         "layer_3.experts.up": (8, 16, 32),
+         "layer_3.experts.down": (8, 32, 16)}, on_unmatched="raise")
+    assert specs["layer_3.router.w"] == P()
+    for name in ("gate", "up", "down"):
+        assert specs[f"layer_3.experts.{name}"] == P("ep", None, None)
+
+
+def test_grouped_matmul_rows_past_the_last_group_are_zero():
+    x = jnp.ones((16, 4))
+    w = jnp.stack([jnp.eye(4) * (i + 1) for i in range(3)])
+    sizes = jnp.asarray([3, 0, 5], jnp.int32)
+    out = np.asarray(moe.grouped_matmul(x, w, sizes))
+    assert (out[:3] == 1).all() and (out[3:8] == 3).all()
+    assert not out[8:].any()
+    assert moe.gmm_lowering(True, jnp.ones((512, 128)),
+                            jnp.ones((2, 128, 256))) == "megablox"
+    assert moe.gmm_lowering(True, x, w) == "ragged_dot"
+    assert moe.gmm_lowering(False, jnp.ones((512, 128)),
+                            jnp.ones((2, 128, 256))) == "ragged_dot"
+
+
+def test_megablox_lowering_in_interpret_mode():
+    """The kernel path of grouped_matmul, forward and gradient, against
+    ragged_dot, with rows past the last group."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (1024, 128))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 128, 128)) * 0.1
+    sizes = jnp.asarray([100, 0, 413], jnp.int32)
+
+    def run(use_kernel):
+        f = lambda x, w: moe.grouped_matmul(x, w, sizes, use_kernel)
+        out, vjp = jax.vjp(f, x, w)
+        return (out,) + vjp(jnp.ones_like(out))
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
+        got = run(True)
+    want = run(False)
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-4,
+                                   atol=1e-4)
+    assert not np.asarray(got[0])[513:].any()
+    assert not np.asarray(got[1])[513:].any()

@@ -593,6 +593,57 @@ def build_specs():
         "kthvalue": dict(inputs={"X": _distinct(2, 5)}, grad_slots=["X"],
                          attrs={"k": 2}),
     }
+    # the causal decoder's ops (ops/decoder_ops.py), from a stream of their
+    # own: the specs above and the probes after them keep the inputs they
+    # always had
+    r26 = np.random.RandomState(26)
+
+    def _sym26(*shape):
+        return r26.uniform(-1.2, 1.2, shape).astype("float32")
+
+    def _x26(*shape):
+        return r26.uniform(0.6, 1.4, shape).astype("float32")
+
+    def _probs26(*shape):
+        a = _x26(*shape)
+        return a / a.sum(-1, keepdims=True)
+    # four tokens, two experts each of three, two of them held (0 and 1):
+    # rows past the last group belong to expert 2
+    from paddle_tpu.parallel.moe import dispatch_plan
+    _plan = dispatch_plan(np.array([[0, 1], [1, 2], [0, 2], [2, 1]],
+                                   np.int32), 0, 2)
+    _MOE_PLAN = {"Order": np.asarray(_plan.order),
+                 "Pos": np.asarray(_plan.pos),
+                 "GroupSizes": np.asarray(_plan.group_sizes)}
+    S.update({
+        "rms_norm": dict(inputs={"X": _sym26(2, 3, 8), "Scale": _x26(8)},
+                         grad_slots=["X", "Scale"], out_slot="Y",
+                         attrs={"epsilon": 1e-6}),
+        "rotary_embedding": dict(
+            inputs={"X": _sym26(2, 2, 4, 8)}, grad_slots=["X"],
+            attrs={"inv_freq": [1.0, 0.1, 0.01, 0.001], "scale": 1.2}),
+        "swiglu": dict(inputs={"X": _sym26(3, 4), "Y": _sym26(3, 4)},
+                       grad_slots=["X", "Y"]),
+        # logits ordered by expert with wide gaps: the top-k is the same at
+        # every finite-difference probe
+        "moe_route": dict(
+            inputs={"X": _x26(6, 4),
+                    "RouterWeight": np.tile(
+                        np.arange(1, 6, dtype=np.float32) * 0.5, (4, 1))
+                    + 0.05 * _sym26(4, 5)},
+            grad_slots=["X", "RouterWeight"], out_slot="TopKWeight",
+            attrs={"top_k": 2, "num_held": 5}),
+        "moe_dispatch": dict(inputs={"X": _sym26(4, 3), **_MOE_PLAN},
+                             grad_slots=["X"]),
+        "moe_grouped_matmul": dict(
+            inputs={"X": _sym26(8, 3), "W": _sym26(2, 3, 4),
+                    "GroupSizes": _MOE_PLAN["GroupSizes"]},
+            grad_slots=["X", "W"]),
+        "moe_combine": dict(
+            inputs={"X": _sym26(8, 3), "TopKWeight": _probs26(4, 2),
+                    **_MOE_PLAN},
+            grad_slots=["X", "TopKWeight"]),
+    })
     return S
 
 

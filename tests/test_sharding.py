@@ -248,12 +248,14 @@ def test_hybrid_schema_routes_through_rule_engine():
 def test_moe_rules_through_engine():
     from paddle_tpu.parallel.moe import moe_partition_rules
     specs = shd.match_partition_rules(
-        moe_partition_rules(), {"moe/gate_w": (16, 8),
-                                "moe/w_in": (8, 16, 32),
-                                "moe/w_out": (8, 32, 16)},
+        moe_partition_rules(), {"layer_0.router.w": (16, 8),
+                                "layer_0.experts.gate": (8, 16, 32),
+                                "layer_0.experts.up": (8, 16, 32),
+                                "layer_0.experts.down": (8, 32, 16)},
         on_unmatched="raise")
-    assert specs["moe/gate_w"] == P()
-    assert specs["moe/w_in"] == P("ep", None, None)
+    assert specs["layer_0.router.w"] == P()
+    assert specs["layer_0.experts.gate"] == P("ep", None, None)
+    assert specs["layer_0.experts.down"] == P("ep", None, None)
 
 
 # ---------------------------------------------------------------------------

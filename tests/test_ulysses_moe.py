@@ -1,6 +1,6 @@
-"""All-to-all sequence parallelism (Ulysses) + expert-parallel MoE over
-the 8-virtual-device CPU mesh — long-context/distributed capabilities
-beyond the reference (SURVEY §2.9 'NOT PRESENT' row)."""
+"""All-to-all sequence parallelism (Ulysses) over the 8-virtual-device CPU
+mesh — a long-context capability beyond the reference (SURVEY §2.9 'NOT
+PRESENT' row).  The sparse-expert layer's tests are tests/test_moe.py."""
 import math
 
 import numpy as np
@@ -15,7 +15,6 @@ from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.parallel import mesh as pmesh
 from paddle_tpu.parallel.ulysses import ulysses_attention
-from paddle_tpu.parallel.moe import init_moe_params, moe_ffn, top1_routing
 
 
 def _reference_attention(q, k, v, scale, causal=False):
@@ -64,84 +63,6 @@ class TestUlysses:
                 f(q)
         finally:
             pmesh.set_current_mesh(None)
-
-
-class TestMoE:
-    def test_single_device_routing_and_shapes(self):
-        t, d, f, e = 32, 8, 16, 4
-        key = jax.random.PRNGKey(0)
-        gate, w_in, w_out = init_moe_params(key, d, f, e)
-        x = jax.random.normal(jax.random.PRNGKey(1), (t, d))
-        out, aux = moe_ffn(x, gate, w_in, w_out, capacity_factor=2.0)
-        assert out.shape == (t, d)
-        assert np.isfinite(float(aux))
-        assert float(aux) > 0.0
-        # with generous capacity every token routes: output nonzero
-        assert float(jnp.abs(out).sum()) > 0.0
-
-    def test_capacity_drops_overflow_tokens(self):
-        # all tokens prefer expert 0 -> beyond capacity C they're dropped
-        t, d, f, e = 16, 4, 8, 4
-        gate = np.zeros((d, e), "float32")
-        gate[:, 0] = 10.0                    # everyone routes to expert 0
-        key = jax.random.PRNGKey(0)
-        _, w_in, w_out = init_moe_params(key, d, f, e)
-        x = jnp.ones((t, d))
-        capacity = max(1, int(math.ceil(t / e * 1.0)))   # cf=1 -> C=4
-        out, _ = moe_ffn(x, jnp.asarray(gate), w_in, w_out,
-                         capacity_factor=1.0)
-        # identical tokens: the first C get identical nonzero outputs,
-        # the rest (dropped) are exactly zero
-        norms = np.abs(np.asarray(out)).sum(axis=1)
-        assert (norms[:capacity] > 0).all()
-        assert np.allclose(norms[capacity:], 0.0)
-
-    def test_expert_parallel_matches_single_device(self):
-        """Tokens data-sharded over ep, experts weight-sharded over ep —
-        the deployment layout.  With ample capacity every shard's tokens
-        route independently, so results must equal running each token
-        shard against ALL experts on one device."""
-        mesh = pmesh.build_mesh({"ep": 4})
-        try:
-            t, d, f, e = 32, 8, 16, 8        # 2 experts per device
-            key = jax.random.PRNGKey(0)
-            gate, w_in, w_out = init_moe_params(key, d, f, e)
-            x = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (t, d)),
-                           np.float32)
-
-            # reference: each token shard through the full expert set
-            refs = []
-            for s in range(4):
-                r, _ = moe_ffn(jnp.asarray(x[s * 8:(s + 1) * 8]), gate,
-                               w_in, w_out, capacity_factor=16.0)
-                refs.append(np.asarray(r))
-            ref = np.concatenate(refs)
-
-            def body(x, gate, w_in_l, w_out_l):
-                out, aux = moe_ffn(x, gate, w_in_l, w_out_l,
-                                   axis_name="ep", capacity_factor=16.0)
-                return out, jax.lax.pmean(aux, "ep")
-
-            fsh = shard_map(
-                body, mesh=mesh,
-                in_specs=(P("ep", None), P(), P("ep", None, None),
-                          P("ep", None, None)),
-                out_specs=(P("ep", None), P()))
-            got, aux = jax.jit(fsh)(x, gate, w_in, w_out)
-            np.testing.assert_allclose(np.asarray(got), ref,
-                                       rtol=2e-4, atol=2e-5)
-            assert np.isfinite(float(aux))
-        finally:
-            pmesh.set_current_mesh(None)
-
-    def test_aux_loss_balanced_vs_skewed(self):
-        t, d, e = 64, 4, 4
-        balanced = jnp.tile(jnp.eye(e, dtype=jnp.float32) * 5.0,
-                            (t // e, 1))
-        skewed = jnp.zeros((t, e), jnp.float32).at[:, 0].set(5.0)
-        _, _, aux_b = top1_routing(balanced, capacity=t)
-        _, _, aux_s = top1_routing(skewed, capacity=t)
-        assert float(aux_s) > float(aux_b)   # imbalance is penalized
 
 
 class TestHybridUlyssesMode:
