@@ -776,9 +776,25 @@ def leg_dp4(size, devices, one_chip_losses, out):
             in_use.extend(d.memory_stats()["bytes_in_use"] for d in devices)
             assert all(b > 0 for b in in_use), in_use
 
+    from paddle_tpu.fluid import device_stats, trace
+    m = trace.metrics()
+    names = ("attention.lowering.fused_kernel", "attention.lowering.xla",
+             "kernel.shard_map_calls")
+    before = {k: m.counter(k).value for k in names}
     losses, first_s, step_ms = run_executor(prog, startup, loss, feed,
                                             devices[0], check_param)
     check_losses(losses, "executor dp4")
+    # the partitioned step took its Pallas kernels, once per chip under
+    # shard_map (LoweringContext.kernel_site): the default pipeline fused
+    # every attention chain, its lowering picked the kernel, and the
+    # compiled step holds Mosaic calls
+    lowered = {k: int(m.counter(k).value - before[k]) for k in names}
+    mosaic = sum(e["mosaic_calls"] for e in device_stats.op_maps())
+    if devices[0].platform == "tpu":
+        assert lowered["attention.lowering.fused_kernel"] \
+            == 2 * size["layers"] and not lowered["attention.lowering.xla"] \
+            and lowered["kernel.shard_map_calls"] > 0, lowered
+        assert mosaic > 0, "no Mosaic call in the data-parallel step"
     # same weights, the same 64 rows on every chip: the first steps agree
     # to dropout noise (the masks differ — other block shapes) and the
     # last one lands in the same place; in between Adam at lr 1e-3 is
@@ -791,7 +807,8 @@ def leg_dp4(size, devices, one_chip_losses, out):
                 "global_batch": size["batch"] * n,
                 "losses": [round(v, 4) for v in losses],
                 "max_rel_drift_vs_one_chip": round(drift, 4),
-                "bytes_in_use": in_use})
+                "bytes_in_use": in_use, "lowered": lowered,
+                "mosaic_calls": mosaic})
 
 
 def leg_hybrid(tiny, devices, out):

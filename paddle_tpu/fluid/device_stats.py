@@ -396,8 +396,11 @@ def attach_oom_report(exc: BaseException,
 #   instance  the op's first output variable, cleaned (``@`` cuts an HLO
 #             op_name short, ``/`` separates its components)
 # One regular expression finds it anywhere in an instruction's ``op_name``
-# (under ``jit(fn)``, a partitioned program's wrappers, ``transpose(jvp())``);
-# the innermost (last) scope of a path is the op's.
+# (under ``jit(fn)``, a partitioned program's wrappers, ``transpose(jvp())``,
+# the frames of a kernel that runs once per chip: ``pd:b:x_grad:i/
+# transpose(jvp(jit(body)))/shard_map/pallas_call``, and where the backward
+# repeats the scope inside ``transpose(..)``); the innermost (last) scope of
+# a path is the op's.
 #
 # The scopes are metadata, and jax's compile-cache key leaves metadata out:
 # renaming them changes no key, and a cache directory warmed by a tree with
@@ -564,7 +567,7 @@ def remember(key, jitted, example_args: Sequence,
         return
     entry = {"label": label or str(key), "jitted": jitted,
              "examples": [sds_tree(a) for a in example_args],
-             "module": None, "map": None}
+             "module": None, "map": None, "mosaic_calls": None}
     with _agg_lock:
         _remembered.pop(key, None)
         _remembered[key] = entry
@@ -581,15 +584,18 @@ def _fill_op_map(entry, compiled) -> None:
     """Parse ``compiled``'s optimized HLO into a remembered entry; the
     callable and the structs are dropped with it (the parsed map is all a
     reader needs)."""
+    from ..ops.pallas_preflight import MOSAIC_CALL
     text = compiled.as_text()
     entry["module"] = hlo_module_name(text)
     entry["map"] = hlo_op_map(text)
+    entry["mosaic_calls"] = text.count(MOSAIC_CALL)
     entry["jitted"] = entry["examples"] = None
 
 
 def op_maps() -> List[Dict[str, Any]]:
-    """``[{"label", "module", "map"}]`` of every remembered executable,
-    newest last.  An entry nobody asked about yet is lowered and compiled
+    """``[{"label", "module", "map", "mosaic_calls"}]`` of every remembered
+    executable, newest last (``mosaic_calls``: the Pallas kernels in it).
+    An entry nobody asked about yet is lowered and compiled
     here, once (``jitted.lower(*structs).compile().as_text()``: a
     persistent-cache load where that cache is on); one the backend refuses
     is left out."""
@@ -607,8 +613,8 @@ def op_maps() -> List[Dict[str, Any]]:
                 continue
             trace.metrics().histogram("xla.op_map_seconds").observe(
                 time.perf_counter() - t0)
-        out.append({"label": entry["label"], "module": entry["module"],
-                    "map": entry["map"]})
+        out.append({k: entry[k]
+                    for k in ("label", "module", "map", "mosaic_calls")})
     return out
 
 

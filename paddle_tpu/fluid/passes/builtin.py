@@ -651,11 +651,19 @@ class ShardCollectivesPass(Pass):
         return {"collectives_implied": implied}
 
 
+def _batch_alone(sharding) -> bool:
+    """Does a ``BuildStrategy.sharding`` value leave every activation whole
+    but for its batch dim?  Unset, and the "dp" and "fsdp" modes."""
+    return not sharding or (isinstance(sharding, str)
+                            and sharding.lower() in ("dp", "fsdp"))
+
+
 def passes_for_build_strategy(build_strategy) -> List[Pass]:
     """Instantiate the pass list a BuildStrategy's knobs select, in the
     canonical order: fold -> fuse -> kernel_tier -> clean -> amp -> dce
     -> coalesce.  ``fuse_attention`` needs no knob: an unpartitioned
-    program gets it for the attention chains a kernel covers.  The kernel
+    program and a data-parallel one (``sharding`` "dp" / "fsdp") get it
+    for the attention chains a kernel covers.  The kernel
     tier runs after the pairwise fusions (they never overlap its chains)
     and before AMP (the fused attention op is white-listed MXU compute,
     so the bf16 rewrite sees ONE op instead of the six-op chain); AMP
@@ -675,10 +683,12 @@ def passes_for_build_strategy(build_strategy) -> List[Pass]:
         specs.append(("fuse_bn_act", {}))
     if tier or getattr(bs, "fuse_attention", False):
         specs.append(("fuse_attention", {}))
-    elif not getattr(bs, "sharding", None):
-        # the default: chains whose fused op would lower to a kernel.  Not
-        # in a partitioned program, where a Mosaic call cannot run
-        # (LoweringContext.pallas_ok) and the rewrite would only swap one
+    elif _batch_alone(getattr(bs, "sharding", None)):
+        # the default: chains whose fused op would lower to a kernel, in
+        # an unpartitioned program and in one partitioned on the batch
+        # alone, where the kernel runs once per chip
+        # (LoweringContext.kernel_site).  Not under "tp" or custom rules:
+        # no Mosaic call runs there, and the rewrite would only swap one
         # XLA spelling for another.
         specs.append(("fuse_attention", {"where_kernel_runs": True}))
     if tier or getattr(bs, "fuse_paged_attention", False):

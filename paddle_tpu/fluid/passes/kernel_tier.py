@@ -48,8 +48,10 @@ Every pass counts ``kernel_tier.<pass>.rewrites``; wiring is the
 ``fuse_optimizer`` knobs plus the ``kernel_tier`` umbrella, appended by
 ``passes_for_build_strategy`` after the pairwise fusions and before AMP
 (docs/passes.md).  ``fuse_attention`` is also in the default pipeline of
-every unpartitioned program, there only for the chains whose fused op
-lowers to a kernel (``where_kernel_runs``).
+every unpartitioned program and of every program partitioned on the batch
+alone (``sharding`` "dp" / "fsdp": the kernel runs once per chip), there
+only for the chains whose fused op lowers to a kernel
+(``where_kernel_runs``).
 """
 from __future__ import annotations
 
@@ -211,25 +213,38 @@ class FuseAttentionPass(PatternRewritePass):
         return (p, rewrite)
 
     @staticmethod
-    def _kernel_runs(m, drop_op) -> bool:
+    def _kernel_runs(m, drop_op, plan=None) -> bool:
         """Would the fused op over this chain's operands lower to a kernel
         where kernels run?  Shapes and dtypes as the block declares them
-        (-1 for the batch)."""
+        (-1 for the batch); under a sharding ``plan`` a declared batch is
+        judged as the rows one chip holds."""
         from types import SimpleNamespace
         import jax.numpy as jnp
         from ...ops.attention import attention_path
+        shards = 1
+        if plan is not None:
+            from ...parallel.sharding import batch_shard_axis
+            axis = batch_shard_axis(plan.mesh)
+            if axis is None:
+                return False             # no kernel in this partitioning
+            shards = int(plan.mesh.shape[axis])
 
         def operand(name):
             v = m.block._find_var_recursive(m.var(name))
             if v is None or v.shape is None or v.dtype is None:
                 return None
-            return SimpleNamespace(shape=tuple(v.shape), ndim=len(v.shape),
+            shape = tuple(v.shape)
+            if shape and shape[0] > 1:
+                if shape[0] % shards:
+                    return None
+                shape = (shape[0] // shards,) + shape[1:]
+            return SimpleNamespace(shape=shape, ndim=len(shape),
                                    dtype=jnp.dtype(v.dtype))
 
         operands = [operand(n) for n in ("q", "k", "v", "mask")
                     if n in m.binding]
         if any(o is None for o in operands):
-            return False                 # undeclared shape: nothing to judge
+            return False     # undeclared or undividable: nothing to judge
         if len(operands) == 3:
             operands.append(None)        # no mask
         drop_active = drop_op is not None \
@@ -269,7 +284,8 @@ class FuseAttentionPass(PatternRewritePass):
             mask_out = (drop_op.outputs.get("Mask") or [None])[0]
             if mask_out and _consumers(block, mask_out):
                 return False
-        if self.where_kernel_runs and not self._kernel_runs(m, drop_op):
+        if self.where_kernel_runs and not self._kernel_runs(
+                m, drop_op, getattr(ctx, "sharding_plan", None)):
             return False
         if train:
             # grad chain intermediates are internal too, and the mask must

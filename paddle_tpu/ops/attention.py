@@ -4,8 +4,9 @@ Reference: paddle/fluid/operators/fused/multihead_matmul_op.cu (fused
 transformer attention) and math/bert_encoder_functor.cu (SURVEY §2.5 fused/).
 TPU-native: one `fused_multihead_attention` op; the `fuse_attention` pass
 (fluid/passes/kernel_tier.py, in the default pipeline of an unpartitioned
-program) PRODUCES it from the naive matmul→softmax→matmul chain, so plain
-static programs get the kernels without touching model code.  The lowering
+and of a data-parallel program) PRODUCES it from the naive
+matmul→softmax→matmul chain, so plain static programs get the kernels
+without touching model code.  The lowering
 picks one of four paths from what it can see (`attention_path`):
 
 * `splash_kernel` — `pallas_kernels.splash_attention_tpu`: causal attention
@@ -15,11 +16,16 @@ picks one of four paths from what it can see (`attention_path`):
 * `fused_kernel` — `pallas_kernels.fused_attention_tpu`: key lengths up to
   512 (BERT's), whole score rows on the core, dropout on the probabilities
   from the on-core PRNG, the padding bias passed as its [B, 1, 1, S] row;
+  in a program partitioned on the batch alone (`dp`, `fsdp`) once per
+  chip under `shard_map`, judged on the chip's own rows
+  (`LoweringContext.kernel_site`);
 * `flash_kernel` — jax's flash kernel, K/V streamed through VMEM: from
   `FLAGS_pallas_min_seq` (1024) up, dropout-free;
-* `xla` — `_reference_attention`, the XLA softmax(QK^T)V: the CPU, inside a
-  GSPMD-partitioned program (a Mosaic call cannot be partitioned), and every
-  shape the kernels do not cover.  With a `window` or grouped heads it is
+* `xla` — `_reference_attention`, the XLA softmax(QK^T)V: the CPU, a
+  program partitioned any other way (`tp`, a mesh with further axes: a
+  Mosaic call cannot be partitioned automatically, and only the fused
+  kernel has been taken under `shard_map`), and every shape the kernels do
+  not cover.  With a `window` or grouped heads it is
   `_banded_attention`: blocks of queries against the keys of their band,
   each block recomputed in backward, so that no [S, S] scores exist there
   either.
@@ -168,11 +174,14 @@ def attention_path(q, k, v, mask, causal, drop_active, use_pallas,
 def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                     dropout_rate=0.0, dropout_key=None,
                     dropout_upscale=True, prob_scale=None, use_pallas=None,
-                    window=0):
+                    window=0, site=None):
     """Dispatch to a Pallas TPU kernel where one covers the call, else XLA
-    (``attention_path``).  ``use_pallas``: an op lowering passes
-    ``ctx.pallas_ok()``; None (the shard_map bodies in parallel/) means
-    "on the tpu backend".
+    (``attention_path``).  ``use_pallas``: None (the shard_map bodies in
+    parallel/) means "on the tpu backend".  ``site``: an op lowering
+    passes ``ctx.kernel_site(q)``, which decides in ``use_pallas``'s place:
+    None is XLA; a site of several shards judges each chip's own rows and
+    runs the fused kernel once per shard (the other kernels have not been
+    taken there: XLA).
 
     The flash kernel takes additive-bias masks through its ``ab`` argument
     (anything broadcastable to [B, H, Tq, Tk], materialised at that size)
@@ -186,18 +195,24 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     drop_active = bool(dropout_rate) and dropout_key is not None
-    if use_pallas is None:
+    if site is not None:
+        use_pallas = True
+    elif use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    local = site.local if site is not None else (lambda x: x)
+    batched_mask = mask is not None and mask.shape[0] == q.shape[0]
     window = int(window or 0)
     banded = bool(window) or k.shape[1] != q.shape[1]
     if banded and (not causal or mask is not None or drop_active
                    or prob_scale is not None):
         raise ValueError("attention with a window or grouped key/value "
                          "heads is causal, without mask or dropout")
-    path = attention_path(q, k, v, mask, causal, drop_active, use_pallas,
-                          _pallas_min_seq(), window)
+    path = attention_path(local(q), local(k), local(v),
+                          local(mask) if batched_mask else mask, causal,
+                          drop_active, use_pallas, _pallas_min_seq(), window)
     if path in ("flash_kernel", "splash_kernel") \
-            and (prob_scale is not None or scale == 0.0):
+            and (prob_scale is not None or scale == 0.0
+                 or (site is not None and site.shards > 1)):
         path = "xla"
     from ..fluid import trace
     trace.metrics().counter(f"attention.lowering.{path}").inc()
@@ -216,7 +231,7 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
             q, k, v, mask, scale=scale,
             dropout_rate=dropout_rate if drop_active else 0.0,
             dropout_key=dropout_key, dropout_upscale=dropout_upscale,
-            prob_scale=prob_scale)
+            prob_scale=prob_scale, site=site)
     if path == "flash_kernel":
         from .pallas_kernels import flash_attention_tpu
         ab = None
@@ -258,7 +273,7 @@ def _fused_mha(ins, attrs, ctx):
                           causal=attrs.get("causal", False),
                           dropout_rate=rate, dropout_key=dropout_key,
                           dropout_upscale=upscale, prob_scale=prob_scale,
-                          use_pallas=ctx.pallas_ok(),
+                          use_pallas=False, site=ctx.kernel_site(q),
                           window=attrs.get("window", 0))
     return {"Out": [out]}
 

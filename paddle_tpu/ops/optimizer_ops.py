@@ -224,9 +224,11 @@ def _dpsgd(ins, attrs, ctx):
 # same-(family, dtype, attrs, PartitionSpec) per-param update ops.
 # Reference: framework/ir/fuse_optimizer_ops_pass/ (fuse_adam_op_pass,
 # fuse_momentum_op_pass) + coalesce_tensor semantics.  One op dispatch per
-# BUCKET instead of one per param.  Where kernels run
+# BUCKET instead of one per param.  Where a kernel may be called directly
 # (LoweringContext.pallas_ok: the tpu backend, outside GSPMD-partitioned
-# programs) an f32 adam/momentum bucket is packed into one row-aligned
+# programs; the buckets hold parameters, not batch rows, so they are not
+# among the kernels a data-parallel program runs per shard) an f32
+# adam/momentum bucket is packed into one row-aligned
 # [rows, 1024] buffer and updated by a single Pallas kernel
 # (ops/pallas_kernels.py) — element-for-element the SAME arithmetic as the
 # per-param ops, concatenation changes layout, never values; per-param

@@ -464,35 +464,45 @@ def _heads_to_lanes(x):
     return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
-@functools.partial(jax.jit, static_argnums=(5,))
-def _fused_attention_jit(q, k, v, bias, seed, statics):
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _fused_attention_jit(q, k, v, bias, seed, heads, statics):
     """One trace per (shapes, statics): a 12-layer program holds 24 calls
     (12 forward ops, 12 grad ops re-tracing them)."""
-    b, h, sq, d = q.shape
-    out = _fused_attention(_heads_to_lanes(q), _heads_to_lanes(k),
-                           _heads_to_lanes(v), bias, seed, h, statics)
-    return out.reshape(b, sq, h, d).transpose(0, 2, 1, 3)
+    return _fused_attention(q, k, v, bias, seed, heads, statics)
+
+
+def _fused_attention_keyed(q, k, v, bias, key, heads, statics):
+    seed = jnp.zeros((1,), jnp.int32) if key is None else _seed_from_key(key)
+    return _fused_attention_jit(q, k, v, bias, seed, heads, statics)
 
 
 def fused_attention_tpu(q, k, v, bias=None, scale=None, dropout_rate=0.0,
                         dropout_key=None, dropout_upscale=True,
-                        prob_scale=None):
+                        prob_scale=None, site=None):
     """softmax(q @ k.T * scale + bias) -> dropout -> @ v, q/k/v [B, H, S, D],
     ``bias`` [B or 1, 1, 1, S] passed as that row.  Dropout is active with
     a rate and a key; ``dropout_upscale`` scales the kept probabilities by
     1 / (1 - rate) (upscale_in_train), ``prob_scale`` scales all of them
     (downgrade_in_infer at test time).  Rate 0 is the same kernel without
-    the PRNG."""
+    the PRNG.  ``site`` (``LoweringContext.kernel_site``) runs the kernel
+    once per shard of the batch; the head transposes stay outside it, where
+    XLA cancels them against the caller's (across a ``shard_map`` boundary
+    it does not: 108 copies of 24 MiB a BERT-base step; AOT, PR 27)."""
+    from .registry import KernelSite
     if scale is None:
         scale = q.shape[-1] ** -0.5
     rate = float(dropout_rate) if dropout_key is not None else 0.0
     out_scale = 1.0 if prob_scale is None else float(prob_scale)
     if rate and dropout_upscale:
         out_scale /= 1.0 - rate
-    seed = _seed_from_key(dropout_key) if rate \
-        else jnp.zeros((1,), jnp.int32)
-    return _fused_attention_jit(q, k, v, bias, seed,
-                                (float(scale), rate, out_scale))
+    b, h, sq, d = q.shape
+    out = (site or KernelSite()).call(
+        _fused_attention_keyed,
+        [_heads_to_lanes(q), _heads_to_lanes(k), _heads_to_lanes(v), bias],
+        (True, True, True, bias is not None and bias.shape[0] == b),
+        key=dropout_key if rate else None,
+        static=(h, (float(scale), rate, out_scale)))
+    return out.reshape(b, sq, h, d).transpose(0, 2, 1, 3)
 
 
 def fused_attention_keep_mask(q_shape, sk, dropout_rate, dropout_key):

@@ -52,9 +52,13 @@ def compile_for_tpu(fn, *args):
         lowering_platforms=("tpu",)).compile()
 
 
+# how a Pallas TPU kernel reads in an executable's HLO text
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
 def mosaic_call_count(compiled) -> int:
     """Mosaic custom calls in a compiled executable's HLO."""
-    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    return compiled.as_text().count(MOSAIC_CALL)
 
 
 def assert_mosaic_lowerable(fn, *args, require_kernels=True):

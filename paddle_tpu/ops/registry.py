@@ -12,6 +12,7 @@ register a custom grad when vjp semantics are wrong (e.g. straight-through).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 # inputs/outputs are Dict[slot_name, List[jax.Array]] mirroring OpDesc's named
@@ -89,6 +90,66 @@ def all_ops() -> List[str]:
     return sorted(_OP_REGISTRY)
 
 
+class KernelSite:
+    """How a lowering calls its Pallas kernel (``LoweringContext.
+    kernel_site``): directly (``shards`` 1), or once per shard of the batch
+    under ``shard_map`` over the plan's mesh, each chip's call seeing the
+    rows it holds."""
+
+    def __init__(self, mesh=None, axis=None):
+        self.mesh, self.axis = mesh, axis
+        self.shards = 1 if mesh is None else int(mesh.shape[axis])
+
+    def local(self, x):
+        """What one call sees of the batch-major ``x``: its shape for the
+        kernel's ``*_supported`` check."""
+        if self.shards == 1:
+            return x
+        import jax
+        return jax.ShapeDtypeStruct(
+            (x.shape[0] // self.shards,) + tuple(x.shape[1:]), x.dtype)
+
+    def call(self, kernel, operands, batch_major=None, key=None, static=()):
+        """``kernel(*operands, key, *static)``: ``kernel`` a module-level
+        function, ``static`` hashable, ``key`` the op's PRNG key or None.
+        Per shard, the operands marked ``batch_major`` (default: all)
+        arrive split on dim 0 and the rest whole, every output is
+        batch-major, and the chip's index along the axis is folded into
+        ``key``: the on-core PRNG streams are seeded from (seed, grid
+        position) and every shard's grid starts at 0, so one key would
+        draw one mask on every chip.  The fold happens once, outside the
+        kernel's custom_vjp, so the seed it saves regenerates the same
+        mask in backward."""
+        if self.shards == 1:
+            return kernel(*operands, key, *static)
+        from ..fluid import trace
+        trace.metrics().counter("kernel.shard_map_calls").inc()
+        return _per_shard(
+            kernel, static, self.mesh, self.axis,
+            tuple(batch_major or (True,) * len(operands)),
+            key is not None)(key, *operands)
+
+
+@functools.lru_cache(maxsize=64)
+def _per_shard(kernel, static, mesh, axis, batch_major, keyed):
+    """The jitted ``shard_map`` of one kernel call.  One object per
+    (kernel, statics, mesh): the layers of a model trace, differentiate and
+    lower it once (74 call sites in a BERT-base step)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.api import compat_shard_map
+
+    def body(key, *local):
+        if keyed:
+            key = jax.random.fold_in(key, jax.lax.axis_index(axis))
+        return kernel(*local, key, *static)
+
+    return jax.jit(compat_shard_map(
+        body, mesh=mesh,
+        in_specs=(P(),) + tuple(P(axis) if b else P() for b in batch_major),
+        out_specs=P(axis)))
+
+
 class LoweringContext:
     """Per-compilation context handed to lowering rules.
 
@@ -116,17 +177,41 @@ class LoweringContext:
         # set by the executor when the whole block compiles as ONE
         # GSPMD-partitioned program over a multi-device mesh
         # (parallel/sharding.py wrap_with_plan, parallel/api.py
-        # wrap_with_mesh)
+        # wrap_with_mesh); ``mesh`` is the sharding plan's, None without
+        # a plan
         self.partitioned = False
+        self.mesh = None
 
     def pallas_ok(self) -> bool:
-        """May a lowering take its Pallas TPU kernel?  On the tpu backend,
-        and not inside a GSPMD-partitioned program: Mosaic calls cannot be
-        partitioned automatically (jax refuses at lowering), so there
-        every op keeps its XLA lowering.  Under shard_map each device runs
-        its own kernel and this stays True."""
+        """May a lowering call its Pallas TPU kernel directly?  On the tpu
+        backend, and not inside a GSPMD-partitioned program: Mosaic calls
+        cannot be partitioned automatically (jax refuses at lowering).
+        There a kernel runs only per shard, under ``shard_map``, which is
+        ``kernel_site``'s answer; a lowering that asks only this keeps
+        its XLA spelling in every partitioned program."""
         import jax
         return jax.default_backend() == "tpu" and not self.partitioned
+
+    def kernel_site(self, x) -> Optional["KernelSite"]:
+        """Where the Pallas kernel of an op over the batch-major ``x`` runs,
+        read from what the context holds: called directly where
+        ``pallas_ok``; once per shard where the program is partitioned
+        over a mesh that shards activations on the batch alone
+        (``sharding.batch_shard_axis``: the ``dp`` and ``fsdp`` plans) and
+        the axis divides ``x``'s leading dim; None, no kernel here, in
+        every other program (``tp``, meshes with further axes,
+        ``wrap_with_mesh``), off the chip, and for an op whose primary
+        input is a parameter (its rows are not the batch)."""
+        if self.pallas_ok():
+            return KernelSite()
+        import jax
+        if jax.default_backend() != "tpu" or self.cur_op_batch_major is False:
+            return None
+        from ..parallel.sharding import batch_shard_axis
+        axis = batch_shard_axis(self.mesh)
+        if axis is None or not x.ndim or x.shape[0] % self.mesh.shape[axis]:
+            return None
+        return KernelSite(self.mesh, axis)
 
     def batch_mask(self, dim0):
         """Row-validity mask (bool[dim0]) when ``dim0`` is the bucketed

@@ -169,6 +169,19 @@ def _data_axis_of(mesh: Optional[Mesh]) -> Optional[str]:
     return None
 
 
+def batch_shard_axis(mesh: Optional[Mesh]) -> Optional[str]:
+    """The axis of a mesh that shards activations on the batch and on
+    nothing else: its data axis, where every other axis has size 1 (the
+    ``"dp"`` and ``"fsdp"`` modes' default mesh).  There each chip's shard
+    of an activation is a whole one-chip tensor, which is what lets a
+    lowering run its Pallas kernel once per shard
+    (``LoweringContext.kernel_site``); None for every other mesh."""
+    axis = _data_axis_of(mesh)
+    if axis is None or mesh.shape[axis] != mesh.size:
+        return None
+    return axis
+
+
 # ---------------------------------------------------------------------------
 # rule sets per BuildStrategy.sharding mode
 # ---------------------------------------------------------------------------

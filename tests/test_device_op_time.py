@@ -125,6 +125,12 @@ def test_scope_round_trip(op, after_backward, want):
     # found anywhere in an op_name path, under any wrapper
     for path in (f"jit(fn)/{name}/dot_general",
                  f"jit(constrained)/jit(fn)/{name}/transpose(jvp())/mul",
+                 # a kernel that runs once per chip, and its backward
+                 f"jit(constrained)/{name}/jit(body)/shard_map/pallas_call",
+                 f"jit(constrained)/{name}/transpose(jvp(jit(body)))/"
+                 f"shard_map/pallas_call",
+                 f"jit(constrained)/{name}/transpose({name})/jvp()/"
+                 f"shard_map/jit(_fused_attention_jit)/pallas_call",
                  f"jit(fn)/{name}"):
         assert ds.parse_scope(path) == want
 
@@ -166,6 +172,16 @@ def test_a_path_without_a_scope_parses_to_none(path):
     ("dropout.7",
      {"label": "dropout", "role": "forward", "instance": "dropout_0.tmp_0",
       "opcode": "custom-call", "mxu": False, "also": []}),
+    # Mosaic kernels under a partitioned program's shard_map frame, as
+    # the data-parallel BERT step names them (compiled for v5e:2x2)
+    ("attention.4",
+     {"label": "fused_multihead_attention", "role": "forward",
+      "instance": "matmul_1.tmp_0", "opcode": "custom-call", "mxu": False,
+      "also": []}),
+    ("dropout_grad.5",
+     {"label": "dropout_grad", "role": "backward",
+      "instance": "fc_7.tmp_0.GRAD", "opcode": "custom-call", "mxu": False,
+      "also": []}),
     ("convolution.8",
      {"label": "mul", "role": "forward", "instance": "fc_0.tmp_0",
       "opcode": "convolution", "mxu": True, "also": []}),

@@ -5,6 +5,8 @@ bit-compared against per-param updates (incl. bf16 multi_precision
 masters and sharded bucket grouping), and the kernel-tier satellites
 (FLAGS_pallas_min_seq knob, additive-bias mask dispatch, interpret-mode
 kernel numerics)."""
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -207,7 +209,10 @@ _KERNEL_BERT = dict(hidden=128, heads=2, seq=512, layers=1, dropout=0.1)
 
 
 class TestFuseAttentionByDefault:
-    @pytest.mark.parametrize("fields", [{}, {"amp": True}])
+    @pytest.mark.parametrize("fields", [
+        {}, {"amp": True},
+        # partitioned on the batch alone: the kernel runs once per chip
+        {"sharding": "dp"}, {"sharding": "fsdp", "amp": True}])
     def test_rewrites_without_any_speed_field(self, fields):
         m, _, loss = build_bert_train_program(**_KERNEL_BERT)
         assert _default_pipeline(m, loss, **fields) == 1
@@ -216,7 +221,10 @@ class TestFuseAttentionByDefault:
         assert "softmax" not in types
 
     @pytest.mark.parametrize("why, model, fields", [
-        ("partitioned", _KERNEL_BERT, {"sharding": "dp"}),
+        ("no kernel under tensor parallelism", _KERNEL_BERT,
+         {"sharding": "tp"}),
+        ("no kernel under custom rules", _KERNEL_BERT,
+         {"sharding": [(r".*", ())]}),
         ("no kernel at this length", dict(_KERNEL_BERT, seq=16), {}),
         ("no kernel for this head width",
          dict(_KERNEL_BERT, hidden=48, heads=2), {}),
@@ -232,6 +240,42 @@ class TestFuseAttentionByDefault:
         its lowering will be."""
         m, _, loss = build_bert_train_program(**dict(_KERNEL_BERT, seq=16))
         assert _default_pipeline(m, loss, fuse_attention=True) == 1
+
+    @pytest.mark.parametrize("sharding, want", [
+        (None, {"where_kernel_runs": True}),
+        ("dp", {"where_kernel_runs": True}),
+        ("FSDP", {"where_kernel_runs": True}),
+        ("tp", None), ([(r".*", ())], None)])
+    def test_which_pipelines_hold_the_pass(self, sharding, want):
+        from paddle_tpu.fluid.passes import passes_for_build_strategy
+        found = [p for p in passes_for_build_strategy(
+            _tier_bs(sharding=sharding)) if p.name == "fuse_attention"]
+        assert len(found) == (want is not None)
+        if want:
+            assert found[0].where_kernel_runs is want["where_kernel_runs"]
+
+    @pytest.mark.parametrize("axes, batch, want", [
+        ({"dp": 4}, -1, 1),       # an undeclared batch is not judged
+        ({"dp": 4}, 8, 1),        # 2 rows a chip
+        ({"dp": 4}, 6, 0),        # the axis does not divide the batch
+        ({"dp": 2, "tp": 2}, 8, 0),   # no kernel in this partitioning
+    ])
+    def test_the_pass_judges_the_rows_one_chip_holds(self, axes, batch,
+                                                     want):
+        from types import SimpleNamespace as NS
+        from jax.sharding import Mesh
+        from paddle_tpu.fluid.passes.kernel_tier import FuseAttentionPass
+        shapes = {"q": (batch, 12, 128, 64), "k": (batch, 12, 128, 64),
+                  "v": (batch, 12, 128, 64), "mask": (batch, 1, 1, 128)}
+        block = NS(_find_var_recursive=lambda n: NS(shape=shapes[n],
+                                                    dtype="float32"))
+        m = NS(block=block, var=lambda n: n, binding=shapes)
+        n = int(np.prod(list(axes.values())))
+        plan = NS(mesh=Mesh(np.array(jax.devices()[:n]).reshape(
+            tuple(axes.values())), tuple(axes)))
+        assert FuseAttentionPass._kernel_runs(m, None, plan) is bool(want)
+        # without a plan the declared batch is one chip's
+        assert FuseAttentionPass._kernel_runs(m, None) is True
 
 
 def _sds(*shape, dtype=jnp.bfloat16):
@@ -478,6 +522,175 @@ class TestFusedAttentionKernel:
         compiled = compile_for_tpu(step, q, q, q, bias, key, q)
         assert mosaic_call_count(compiled) == 2
         assert f"[{b},{h},{s},{s}]" not in compiled.as_text()
+
+
+def _counter_keep(seed_ref, shape, threshold):
+    """``_keep_mask`` with ``_counter_bits`` for the on-core PRNG: seeded,
+    as the kernel seeds it, from (op seed, grid position)."""
+    from jax.experimental import pallas as pl
+    return _counter_bits(seed_ref, pl.program_id(0), shape) \
+        >= jnp.uint32(threshold)
+
+
+class TestKernelsPerShard:
+    """The dropout family and the attention op through their lowerings in
+    a context partitioned over a 4-device data axis (the TPU interpreter,
+    a counter-based generator for the on-core PRNG): every shard runs the
+    kernel on its own rows with its own stream, the one the unpartitioned
+    kernel draws from the op's key with the shard's index folded in,
+    forward and backward alike."""
+
+    SHARDS, RATE, SEED = 4, 0.25, 11
+
+    @pytest.fixture
+    def ctx(self, monkeypatch):
+        from jax.sharding import Mesh
+        from paddle_tpu.ops import pallas_kernels as pk
+        from paddle_tpu.ops import registry
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pk, "_keep_mask", _counter_keep)
+        monkeypatch.setattr(pk, "_head_bits", _counter_bits)
+        ctx = registry.LoweringContext(base_key=jax.random.PRNGKey(3))
+        ctx.mesh = Mesh(np.array(jax.devices()[:self.SHARDS]), ("dp",))
+        ctx.partitioned = True
+        # the jitted per-shard calls outlive a test: none traced with
+        # another PRNG comes in, none traced with this one stays
+        registry._per_shard.cache_clear()
+        yield ctx
+        registry._per_shard.cache_clear()
+
+    def _shard_key(self, ctx, i):
+        return jax.random.fold_in(ctx.key_for(self.SEED), i)
+
+    @staticmethod
+    def _run(f, *args):
+        """``f(*args)`` as ONE program, read back when it is done.  The
+        interpreter's callbacks start small programs of their own on device
+        0; an eager op of the test's, queued there behind the running
+        kernels whose output it waits for, would stand in their way for
+        good."""
+        out = jax.block_until_ready(jax.jit(f)(*args))
+        return [np.asarray(a) for a in out]
+
+    @pytest.mark.parametrize("op_type, slots", [
+        ("dropout", ("X",)),
+        ("fused_dropout_add", ("X", "Residual")),
+        ("fused_act_dropout", ("X",)),
+    ])
+    def test_dropout_family(self, ctx, op_type, slots):
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops import pallas_kernels as pk
+        from paddle_tpu.ops.registry import get_op
+        n, rows = self.SHARDS, 64
+        # every shard holds the same rows: what differs is the mask
+        one = [jnp.abs(jax.random.normal(jax.random.PRNGKey(i),
+                                         (rows, 128))) + 1.0
+               for i in range(len(slots))]
+        xs = [jnp.tile(x, (n, 1)) for x in one]
+        attrs = {"dropout_prob": self.RATE, "op_seed": self.SEED,
+                 "dropout_implementation": "upscale_in_train",
+                 "act": "relu"}
+        direct = {
+            "dropout": lambda key, x: pk.fused_dropout_tpu(
+                x, key, self.RATE, True)[0],
+            "fused_dropout_add": lambda key, x, r: pk.fused_dropout_add_tpu(
+                x, r, key, self.RATE, True),
+            "fused_act_dropout": lambda key, x: pk.fused_act_dropout_tpu(
+                x, key, self.RATE, True, "relu"),
+        }[op_type]
+
+        def lowered(*xs):
+            return get_op(op_type).fn(
+                {s: [x] for s, x in zip(slots, xs)}, attrs, ctx)["Out"][0]
+
+        def ones_vjp(f, *xs):
+            out, vjp = jax.vjp(f, *xs)
+            return (out,) + vjp(jnp.ones_like(out))
+
+        calls = _counter("kernel.shard_map_calls")
+        with pltpu.force_tpu_interpret_mode():
+            got = self._run(functools.partial(ones_vjp, lowered), *xs)
+            want = [self._run(functools.partial(
+                ones_vjp, functools.partial(direct, self._shard_key(ctx, i))),
+                *one) for i in range(n)]
+        assert _counter("kernel.shard_map_calls") - calls == 1
+        got = [a.reshape(n, rows, 128) for a in got]
+        for i in range(n):
+            for a, w in zip(got, want[i]):
+                np.testing.assert_array_equal(a[i], np.asarray(w))
+        # x > 0 everywhere: a zero of the gradient is a dropped element
+        keep = got[1] != 0
+        assert abs(keep.mean() - (1 - self.RATE)) < 0.02
+        for i in range(n):
+            for j in range(i):
+                assert (keep[i] != keep[j]).mean() > 0.2, (i, j)
+        if op_type == "dropout":
+            # the forward dropped what the backward drops
+            np.testing.assert_array_equal(got[0] != 0, keep)
+
+    def test_attention(self, ctx):
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops import pallas_kernels as pk
+        from paddle_tpu.ops.registry import get_op
+        n, h, s, d = self.SHARDS, 2, 128, 64
+        q1, k1, v1, w1, bias1 = _attn_operands(1, h, s, s, d, bias_batch=1)
+        q, k, v, w, bias = (jnp.tile(a, (n, 1, 1, 1))
+                            for a in (q1, k1, v1, w1, bias1))
+        attrs = {"scale": d ** -0.5, "dropout_rate": self.RATE,
+                 "dropout_seed": self.SEED,
+                 "dropout_implementation": "upscale_in_train"}
+
+        def lowered(bias):
+            return lambda q, k, v: get_op("fused_multihead_attention").fn(
+                {"Q": [q], "K": [k], "V": [v], "Mask": [bias]}, attrs,
+                ctx)["Out"][0]
+
+        before = _lowering_counts()
+        calls = _counter("kernel.shard_map_calls")
+        def direct(i, q, k, v):
+            return pk.fused_attention_tpu(
+                q, k, v, bias1, scale=d ** -0.5, dropout_rate=self.RATE,
+                dropout_key=self._shard_key(ctx, i))
+
+        def run(f, *args):
+            return self._run(functools.partial(_fwd_and_grads, f), *args)
+
+        with pltpu.force_tpu_interpret_mode():
+            got = run(lowered(bias), q, k, v, w)
+            # a [1, 1, 1, S] bias row goes to every shard whole
+            shared = run(lowered(bias1), q, k, v, w)
+            want = [run(functools.partial(direct, i), q1, k1, v1, w1)
+                    for i in range(n)]
+        assert _lowering_counts()["fused_kernel"] \
+            - before["fused_kernel"] == 2
+        assert _lowering_counts()["xla"] == before["xla"]
+        assert _counter("kernel.shard_map_calls") - calls == 2
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, shared):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+            for i in range(n):
+                np.testing.assert_array_equal(
+                    np.asarray(a)[i], np.asarray(want[i][
+                        ("out", "dq", "dk", "dv").index(name)])[0],
+                    err_msg=f"{name}, shard {i}")
+        out = np.asarray(got[0])
+        for i in range(n):
+            for j in range(i):
+                assert not np.allclose(out[i], out[j], atol=1e-3), (i, j)
+
+    def test_other_kernels_keep_their_xla_lowering(self, ctx):
+        """Only what a cell has run per shard runs per shard: a causal
+        sequence long enough for the splash kernel stays on XLA in a
+        partitioned program."""
+        from paddle_tpu.ops.attention import flash_attention
+        q = _sds(4, 4, 1024, 128)
+        for site, want in ((ctx.kernel_site(q), "xla"),
+                           (None, "splash_kernel")):
+            before = _counter(f"attention.lowering.{want}")
+            jax.eval_shape(
+                lambda q: flash_attention(q, q, q, causal=True,
+                                          use_pallas=True, site=site), q)
+            assert _counter(f"attention.lowering.{want}") - before == 1
 
 
 class TestBertStepKeepsTheScoresOnTheCore:

@@ -197,14 +197,17 @@ def test_routing_counts_leave_the_device_when_the_runner_drains():
 # the op stream (type, inputs, outputs, attribute names) the default
 # pipeline leaves of the other configurations' Programs, at a small size at
 # which the attention pass still fires, as the tree before this configuration
-# left it (PR 25)
+# left it (PR 25; dp4 as PR 27 left it)
 PARENT_STREAMS = {
     "bert_base_seq128": (230, 2, "a13010b473eb204e92d84e73adad87a97af0f4e14"
                                  "227c5ee00009dc63b188880"),
     "bert_base_seq512": (230, 2, "a13010b473eb204e92d84e73adad87a97af0f4e14"
                                  "227c5ee00009dc63b188880"),
-    "bert_base_seq128_dp4": (250, 0, "84f464f8bf20b4093649901dc4611e865ceac8"
-                                     "6e28127cac64e4cd44ee987510"),
+    # since PR 27 the data-parallel program gets the attention pass too (the
+    # kernel runs once per chip): its stream is seq128's, op for op (it was
+    # the unfused 250 ops, 84f464f8..87510)
+    "bert_base_seq128_dp4": (230, 2, "a13010b473eb204e92d84e73adad87a97af0f4"
+                                     "e14227c5ee00009dc63b188880"),
     "resnet50_b256": (328, 0, "f9cf2c507f06e0e6f0092f8eff59803575bbdaebaa1c1"
                               "dec518db55dab073595"),
 }

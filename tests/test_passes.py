@@ -572,9 +572,12 @@ def test_passes_for_build_strategy_mapping():
     assert names == ["constant_fold", "fuse_elewise_add_act",
                      "fuse_bn_act", "fuse_attention", "prune_identity",
                      "dce", "coalesce_allreduce"]
-    bs.sharding = "dp"               # no Mosaic call in a partitioned program
-    assert "fuse_attention" not in [
-        p.name for p in passes_for_build_strategy(bs)]
+    # partitioned on the batch alone the kernel runs once per chip; any
+    # other partitioning holds no Mosaic call
+    for sharding, holds in (("dp", True), ("fsdp", True), ("tp", False)):
+        bs.sharding = sharding
+        assert ("fuse_attention" in [
+            p.name for p in passes_for_build_strategy(bs)]) is holds
 
 
 def test_compiled_program_applies_passes_once():

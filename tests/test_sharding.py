@@ -686,3 +686,126 @@ def test_eight_device_resharded_checkpoint_roundtrip():
     info = _run_worker("reshard")
     assert info["ok"] and info["saved_devices"] == 8
     assert info["restored_devices"] == 4
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels inside a partitioned program: directly, per shard, or not
+# (ops/registry.py LoweringContext.kernel_site)
+# ---------------------------------------------------------------------------
+
+def _mesh(**axes):
+    n = int(np.prod(list(axes.values())))
+    return mesh_registry.build_mesh(axes, devices=jax.devices()[:n])
+
+
+@pytest.mark.parametrize("axes, want", [
+    ({"dp": 4}, "dp"), ({"fsdp": 4}, "fsdp"), ({"data": 2}, "data"),
+    ({"dp": 4, "tp": 1}, "dp"), ({"dp": 1}, "dp"),
+    ({"tp": 4}, None), ({"dp": 2, "tp": 2}, None), ({"pp": 2, "dp": 2}, None),
+])
+def test_batch_shard_axis(axes, want):
+    assert shd.batch_shard_axis(_mesh(**axes)) == want
+    assert shd.batch_shard_axis(None) is None
+
+
+@pytest.mark.parametrize("why, backend, mode, axes, batch, want", [
+    ("unpartitioned: the kernel is called directly",
+     "tpu", None, None, 6, 1),
+    ("a one-device plan is not partitioned", "tpu", "dp", {"dp": 1}, 6, 1),
+    ("dp: once per shard", "tpu", "dp", {"dp": 4}, 8, 4),
+    ("fsdp shards activations the same way", "tpu", "fsdp", {"dp": 4}, 8, 4),
+    ("a further axis of size 1 changes nothing",
+     "tpu", "dp", {"dp": 2, "tp": 1}, 8, 2),
+    ("tp: no kernel here", "tpu", "tp", {"tp": 4}, 8, None),
+    ("dp x tp: no kernel here", "tpu", "dp", {"dp": 2, "tp": 2}, 8, None),
+    ("a batch the axis does not divide", "tpu", "dp", {"dp": 4}, 6, None),
+    ("off the chip", "cpu", "dp", {"dp": 4}, 8, None),
+    ("off the chip, unpartitioned", "cpu", None, None, 8, None),
+])
+def test_kernel_site_is_read_from_the_plan(monkeypatch, why, backend, mode,
+                                           axes, batch, want):
+    from paddle_tpu.ops.registry import LoweringContext
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    ctx = LoweringContext()
+    if mode is not None:
+        m, _, _, _ = build_mlp_demo()
+        plan = shd.build_plan(m, mode=mode, mesh=_mesh(**axes))
+        # as fluid/executor.py sets them
+        ctx.mesh = plan.mesh
+        ctx.partitioned = plan.mesh.devices.size > 1
+    x = jax.ShapeDtypeStruct((batch, 128), np.float32)
+    site = ctx.kernel_site(x)
+    assert (site and site.shards) == want, why
+    # "a kernel may be called directly" is the narrower question
+    assert ctx.pallas_ok() == (want == 1)
+    if want:
+        assert site.local(x).shape == (batch // want, 128)
+
+
+def test_kernel_site_refuses_an_op_over_a_parameter(monkeypatch):
+    """Rows of a parameter are not the batch: sharding them for a kernel
+    would reshard a replicated array."""
+    from paddle_tpu.ops.registry import LoweringContext
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ctx = LoweringContext()
+    ctx.mesh, ctx.partitioned = _mesh(dp=4), True
+    x = jax.ShapeDtypeStruct((8, 128), np.float32)
+    assert ctx.kernel_site(x).shards == 4
+    ctx.cur_op_batch_major = False
+    assert ctx.kernel_site(x) is None
+    ctx.partitioned = False              # directly: no rows are split
+    assert ctx.kernel_site(x).shards == 1
+
+
+def _bert_step(sharding, dropout=0.0):
+    """One training step of a one-layer BERT at widths the fused attention
+    kernel covers; returns the loss, every gradient, and the counters'
+    movement."""
+    from paddle_tpu.models.static_graphs import (bert_demo_feed,
+                                                 build_bert_train_program)
+    reset_unique_name()
+    main, startup, loss = build_bert_train_program(
+        vocab=64, hidden=128, heads=2, seq=128, layers=1, dropout=dropout)
+    bs = fluid.BuildStrategy()
+    if sharding:
+        bs.sharding = sharding
+        bs.sharding_mesh = {"dp": 4}
+    prog = fluid.CompiledProgram(main, build_strategy=bs)
+    feed = bert_demo_feed(np.random.RandomState(0), batch=8, seq=128,
+                          vocab=64)
+    grads = [p.name + "@GRAD" for p in main.all_parameters()]
+    names = ("attention.lowering.fused_kernel", "attention.lowering.xla",
+             "kernel.shard_map_calls")
+    before = {n: trace.metrics().counter(n).value for n in names}
+    exe = fluid.Executor()
+    with scope_guard(Scope()):
+        exe.run(startup)
+        out = exe.run(prog, feed=feed, fetch_list=[loss] + grads)
+    moved = {n: trace.metrics().counter(n).value - before[n] for n in names}
+    types = [op.type for op in main.global_block().ops]
+    return [np.asarray(o) for o in out], moved, types
+
+
+@pytest.mark.parametrize("mode", ["dp", "fsdp"])
+def test_dp_program_runs_the_attention_kernel_per_shard(monkeypatch, mode):
+    """The same Program unpartitioned and partitioned on the batch over 4
+    devices, the kernels in the TPU interpreter: the default pipeline
+    fuses the attention chain in both, the partitioned step lowers it
+    under shard_map, and loss and gradients agree."""
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        one, moved_one, types_one = _bert_step(None)
+        dp, moved_dp, types_dp = _bert_step(mode)
+    assert types_dp == types_one
+    assert types_dp.count("fused_multihead_attention") == 1
+    # the forward op and the grad op that re-traces it
+    assert moved_one == {"attention.lowering.fused_kernel": 2,
+                         "attention.lowering.xla": 0,
+                         "kernel.shard_map_calls": 0}
+    assert moved_dp == {"attention.lowering.fused_kernel": 2,
+                        "attention.lowering.xla": 0,
+                        "kernel.shard_map_calls": 2}
+    np.testing.assert_allclose(dp[0], one[0], rtol=1e-5)
+    for got, want in zip(dp[1:], one[1:]):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-6)
