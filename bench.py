@@ -291,8 +291,7 @@ def dtype_mix():
 
 
 def report(metric, unit, rate, flops_rate, device, config=None,
-           extras=None, dtype="bfloat16", measured_flops_rate=None,
-           compile_stats=None):
+           extras=None, dtype="bfloat16", measured_flops_rate=None):
     """One JSON row naming its device.  Off the CPU, `mfu` is
     analytic-model-FLOPs over the device's published bf16 peak,
     `vs_baseline` is MFU / 0.35 (BASELINE.md north star), and
@@ -321,106 +320,8 @@ def report(metric, unit, rate, flops_rate, device, config=None,
     mix = dtype_mix()
     if mix:
         out["dtype_mix"] = mix
-    # a caller that ran extra legs after its measurement (the kernel-tier
-    # variant) passes its pre-leg snapshot so the headline row's compile
-    # tax is not polluted by the extra legs' compiles
-    out.update(compile_stats if compile_stats is not None
-               else _compile_stats())
+    out.update(_compile_stats())
     print(json.dumps(out))
-
-
-def _kernel_tier_variant(build_fn, feed, device, steps=8, warmup=2):
-    """Baseline-vs-kernel_tier evidence for a static demo program
-    (docs/performance.md "Custom kernel tier"): the same program trained
-    unrewritten and through BuildStrategy.kernel_tier, with the rewrite
-    counts, the ops_per_step drop, XLA-cost-analysis mfu_measured for
-    BOTH executables, the goodput device_compute share over each window,
-    and fp32 loss parity.  Returns a JSON-able dict."""
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core, trace
-    from paddle_tpu.fluid.core import Scope, scope_guard
-    from paddle_tpu.fluid.framework import reset_unique_name, \
-        in_dygraph_mode
-    from paddle_tpu.dygraph import base as dybase
-    if in_dygraph_mode():           # the dygraph legs leave eager mode on;
-        dybase.disable_dygraph()    # the static demo must trace a Program
-    core.set_flags({"FLAGS_device_cost_analysis": True})
-    m = trace.metrics()
-    passes = ("fuse_attention", "fuse_sparse_embedding", "fuse_optimizer")
-
-    def _flops_names():
-        return {n for n in m.names() if n.startswith("xla.cost.exe.")
-                and n.endswith(".flops")}
-
-    def run(tier):
-        reset_unique_name()
-        main, startup, loss = build_fn()
-        ex = fluid.Executor()
-        prog = main
-        if tier:
-            bs = fluid.BuildStrategy()
-            bs.kernel_tier = True
-            prog = fluid.CompiledProgram(main, build_strategy=bs)
-        with scope_guard(Scope()):
-            ex.run(startup)
-            # flops-gauge snapshot AFTER startup: the init program's
-            # one-shot executable must not count into per-step FLOPs
-            names0 = _flops_names()
-            for _ in range(max(warmup, 1)):
-                lv, = ex.run(prog, feed=feed, fetch_list=[loss])
-            float(np.asarray(lv).ravel()[0])
-            # compile-tax snapshot AFTER warmup: the share grades the
-            # measured window, where a late recompile is real badput
-            comp0 = m.histogram("executor.compile_seconds").stats()["total"]
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                lv, = ex.run(prog, feed=feed, fetch_list=[loss])
-            last = float(np.asarray(lv).ravel()[0])
-            dt = time.perf_counter() - t0
-            ops = m.gauge("executor.ops_per_step").value
-            step_flops = sum(m.gauge(n).value
-                             for n in _flops_names() - names0)
-        compile_s = m.histogram(
-            "executor.compile_seconds").stats()["total"] - comp0
-        ex.close()
-        return dict(dt=dt, ops=int(ops), flops=step_flops,
-                    compile_s=compile_s, loss=last)
-
-    base = run(False)
-    c0 = {p: trace.metrics().counter(
-        f"kernel_tier.{p}.rewrites").value for p in passes}
-    tier = run(True)
-    rewrites = {p: int(trace.metrics().counter(
-        f"kernel_tier.{p}.rewrites").value - c0[p]) for p in passes}
-    peak = None
-    if device["platform"] != "cpu":
-        peak = device_peaks(device["device_kind"])["bf16_flops"]
-
-    def row(r):
-        out = {"steps_per_sec": round(steps / r["dt"], 2),
-               "ops_per_step": r["ops"],
-               # device_compute share of the measured window: the
-               # metrics-estimate remainder (compile is the only
-               # badput this closed loop can accrue)
-               "device_compute_share": round(
-                   max(r["dt"] - r["compile_s"], 0.0) / r["dt"], 4)
-               if r["dt"] else 0.0}
-        if peak and r["flops"]:
-            out["mfu_measured"] = round(
-                r["flops"] * steps / r["dt"] / peak, 4)
-        return out
-
-    return {
-        "rewrites": {p: n for p, n in rewrites.items() if n},
-        "rewrites_total": int(sum(rewrites.values())),
-        "baseline": row(base), "kernel_tier": row(tier),
-        "speedup": round(base["dt"] / tier["dt"], 3)
-        if tier["dt"] else 0.0,
-        "ops_per_step_drop": base["ops"] - tier["ops"],
-        "loss_rel_err": round(
-            abs(base["loss"] - tier["loss"])
-            / max(abs(base["loss"]), 1e-9), 8),
-    }
 
 
 def main_resnet(quick, layout="nhwc"):
@@ -630,18 +531,6 @@ def main_ctr(quick):
     del _LAST_CHUNKS[:]
     _LAST_CHUNKS.extend(fp32_chunks)
 
-    # kernel-tier variant beside the BoxPS baseline: the lookup_table_v2 +
-    # sequence_pool CTR spelling through fuse_sparse_embedding +
-    # fuse_optimizer (the BoxPS leg's pull_box_sparse is host-tier, so the
-    # rewrite evidence rides its own demo program)
-    from paddle_tpu.models.static_graphs import (build_ctr_train_program,
-                                                 ctr_demo_feed)
-    tier = _kernel_tier_variant(
-        lambda: build_ctr_train_program(slots=slots, dim=dim),
-        ctr_demo_feed(np.random.RandomState(1), batch=min(batch, 256),
-                      slots=slots),
-        device, steps=4 if quick else 10)
-
     cache_rows = box.cache_rows
     box.end_pass(global_scope().find_var("bench_box@HBMCACHE"))
     ex_s = steps * batch / dt
@@ -661,7 +550,6 @@ def main_ctr(quick):
         # amp_dtype labels the HEADLINE value — the fp32 leg here; the
         # bf16 leg rides bf16_value/amp_speedup
         "amp_dtype": "float32",
-        "kernel_tier": tier,
     }
     out["autotune"] = _autotune_block()
     mix = dtype_mix()
@@ -945,25 +833,6 @@ def main_bert(quick):
     measured_rate = jstep.measured_flops(
         box["state"], (ids, mlm, nsp)) * steps / dt
 
-    # kernel-tier variant (fluid/passes/kernel_tier.py): the static BERT
-    # demo — naive attention chain + per-param adam — trained baseline vs
-    # BuildStrategy.kernel_tier.  Snapshot the headline's compile stats
-    # FIRST so the extra leg's compiles don't pollute the headline row.
-    headline_stats = _compile_stats()
-    from paddle_tpu.models.static_graphs import (build_bert_train_program,
-                                                 bert_demo_feed)
-    if quick:
-        kv, kh, khd, kseq, klay, kb, ksteps = 500, 64, 4, 32, 2, 8, 4
-    else:
-        kv, kh, khd, kseq, klay, kb, ksteps = 8000, 256, 8, 128, 4, 32, 10
-    tier = _kernel_tier_variant(
-        lambda: build_bert_train_program(vocab=kv, hidden=kh, heads=khd,
-                                         seq=kseq, layers=klay,
-                                         dropout=0.1),
-        bert_demo_feed(np.random.RandomState(1), batch=kb, seq=kseq,
-                       vocab=kv),
-        device, steps=ksteps)
-
     report("bert_base_pretrain_throughput", "tokens/sec/chip",
            tokens_per_sec,
            tokens_per_sec * flops_per_token(hidden, layers, ffn, seq, vocab),
@@ -974,10 +843,8 @@ def main_bert(quick):
            extras={"fp32_value": round(fp32_tokens_per_sec, 1),
                    "amp_speedup": round(
                        tokens_per_sec / fp32_tokens_per_sec, 3)
-                   if fp32_tokens_per_sec else 0.0,
-                   "kernel_tier": tier},
-           measured_flops_rate=measured_rate,
-           compile_stats=headline_stats)
+                   if fp32_tokens_per_sec else 0.0},
+           measured_flops_rate=measured_rate)
 
 
 LEGS = {"bert": main_bert, "resnet50": main_resnet, "nmt": main_nmt,

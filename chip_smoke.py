@@ -10,7 +10,8 @@ comes out by the repo's own means:
   outputs live on the TPU.
 * **executor** — ``models.static_graphs.build_bert_train_program`` run by
   ``fluid.Executor(fluid.TPUPlace(0))`` as a plain program, then as a
-  ``CompiledProgram`` with the AMP plane and the kernel tier.
+  ``CompiledProgram`` with the AMP plane, as a cell runs it: every
+  attention chain must have become the fused op.
 * **kernels** — every kernel in ``ops/pallas_kernels.__all__`` compiled by
   Mosaic and run once through its op lowering against an XLA reference.
 * **cache** — compile seconds, and the files in the compile cache before and
@@ -240,29 +241,26 @@ def leg_executor(size, device, out):
     feed = static_feed(size)
     main, startup, loss = build_static_bert(size)
     bs = fluid.BuildStrategy()
-    bs.amp = True
-    bs.kernel_tier = True
-    m = trace.metrics()
-    r0 = {p: m.counter(f"kernel_tier.{p}.rewrites").value
-          for p in ("fuse_attention", "fuse_optimizer")}
-    tier_losses, first_s, step_ms = run_executor(
+    bs.amp = True                   # what a cell sets, and nothing else
+    rewrites = trace.metrics().counter("kernel_tier.fuse_attention.rewrites")
+    r0 = rewrites.value
+    amp_losses, first_s, step_ms = run_executor(
         fluid.CompiledProgram(main, build_strategy=bs), startup, loss, feed,
         device)
-    check_losses(tier_losses, "executor amp+kernel_tier")
-    rewrites = {p: int(m.counter(f"kernel_tier.{p}.rewrites").value - r0[p])
-                for p in r0}
-    assert rewrites["fuse_attention"] == size["layers"], rewrites
-    assert rewrites["fuse_optimizer"] >= 1, rewrites
+    check_losses(amp_losses, "executor amp")
+    fused = int(rewrites.value - r0)
+    assert fused == size["layers"], fused
     types = [op.type for op in main.global_block().ops]
-    assert "fused_adam" in types and "softmax" not in types
+    assert "fused_multihead_attention" in types and "softmax" not in types
     # same weights, same batch: the bf16 rewritten program starts where
     # the plain one does
-    assert abs(tier_losses[0] - losses[0]) <= 0.05 * abs(losses[0]), \
-        (tier_losses[0], losses[0])
-    out["amp_kernel_tier"] = {
+    assert abs(amp_losses[0] - losses[0]) <= 0.05 * abs(losses[0]), \
+        (amp_losses[0], losses[0])
+    out["amp"] = {
         "first_step_s": first_s, "step_ms": step_ms,
-        "losses": [round(v, 4) for v in tier_losses], "rewrites": rewrites}
-    say(f"  amp + kernel tier: {json.dumps(out['amp_kernel_tier'])}")
+        "losses": [round(v, 4) for v in amp_losses],
+        "fuse_attention_rewrites": fused}
+    say(f"  amp: {json.dumps(out['amp'])}")
 
 
 # ---------------------------------------------------------------------------
@@ -524,101 +522,6 @@ def roll_dropout(tiny, tpu, key):
     return {"cases": rows, "mosaic": sum(r["mosaic"] for r in rows)}
 
 
-def bert_base_bucket(tiny):
-    """Parameter shapes of one optimizer bucket the size of BERT-base."""
-    if tiny:
-        return [(512, 128), (128, 256), (256, 128), (128,), (3,)]
-    return ([(30522, 768), (512, 768)]
-            + [(768, 768)] * 48 + [(768, 3072)] * 12 + [(3072, 768)] * 12
-            + [(768,)] * 100 + [(3072,)] * 12)
-
-
-def roll_optimizers(tiny, tpu, key):
-    import jax
-    import jax.numpy as jnp
-
-    shapes = bert_base_bucket(tiny)
-    n = len(shapes)
-
-    @jax.jit
-    def make(key):
-        ks = jax.random.split(key, 4 * n)
-        rnd = lambda i, s: jax.random.normal(ks[i], s, jnp.float32)  # noqa
-        return ([rnd(i, s) * 0.02 for i, s in enumerate(shapes)],
-                [rnd(n + i, s) for i, s in enumerate(shapes)],
-                [rnd(2 * n + i, s) * 0.1 for i, s in enumerate(shapes)],
-                [jnp.abs(rnd(3 * n + i, s)) * 0.01
-                 for i, s in enumerate(shapes)])
-
-    @jax.jit
-    def worst_rel_err(got, want):
-        """Largest per-array max-abs error over max-abs value, on device
-        (the bucket is 1.3 GB a side — not worth a trip to the host)."""
-        assert jax.tree_util.tree_structure(got) \
-            == jax.tree_util.tree_structure(want)
-        errs = [jnp.max(jnp.abs(a - b)) / jnp.maximum(
-            jnp.max(jnp.abs(b)), 1e-30) for a, b in zip(
-                jax.tree_util.tree_leaves(got),
-                jax.tree_util.tree_leaves(want)) if a.shape == b.shape]
-        assert len(errs) == len(jax.tree_util.tree_leaves(want))
-        return jnp.max(jnp.stack(errs))
-
-    ps, gs, ms, vs = make(key)
-    lr = jnp.asarray([1e-3], jnp.float32)
-    b1, b2, eps = 0.9, 0.999, 1e-8
-    # per-param beta-pow accumulators, as if each param were at step i%7+1
-    b1p = [jnp.asarray([b1 ** (i % 7 + 1)], jnp.float32) for i in range(n)]
-    b2p = [jnp.asarray([b2 ** (i % 7 + 1)], jnp.float32) for i in range(n)]
-
-    ins = {"Param": ps, "Grad": gs, "Moment1": ms, "Moment2": vs,
-           "Beta1Pow": b1p, "Beta2Pow": b2p, "LearningRate": [lr]}
-    outs, n_adam = run_lowered(
-        lower_op("fused_adam", {"beta1": b1, "beta2": b2, "epsilon": eps}),
-        ins, key, expect_mosaic=tpu)
-
-    @jax.jit
-    def adam_ref(ps, gs, ms, vs):
-        res = []
-        for p, g, m, v, p1, p2 in zip(ps, gs, ms, vs, b1p, b2p):
-            lrt = lr[0] * jnp.sqrt(1 - p2[0]) / (1 - p1[0])
-            m2 = b1 * m + (1 - b1) * g
-            v2 = b2 * v + (1 - b2) * jnp.square(g)
-            res.append((p - lrt * m2 / (jnp.sqrt(v2) + eps), m2, v2))
-        return res
-
-    want = adam_ref(ps, gs, ms, vs)
-    got = [(outs["ParamOut"][i], outs["Moment1Out"][i],
-            outs["Moment2Out"][i]) for i in range(n)]
-    err = float(worst_rel_err(got, want))
-    assert err < 1e-5, err
-    del outs, got, want
-
-    mu, l2 = 0.9, 1e-4
-    ins = {"Param": ps, "Grad": gs, "Velocity": ms, "LearningRate": [lr]}
-    outs, n_mom = run_lowered(
-        lower_op("fused_momentum",
-                 {"mu": mu, "use_nesterov": True,
-                  "regularization_method": "l2_decay",
-                  "regularization_coeff": l2}),
-        ins, key, expect_mosaic=tpu)
-
-    @jax.jit
-    def momentum_ref(ps, gs, vs):
-        res = []
-        for p, g, v in zip(ps, gs, vs):
-            g = g + l2 * p
-            v2 = mu * v + g
-            res.append((p - lr[0] * (g + mu * v2), v2))
-        return res
-
-    got = [(outs["ParamOut"][i], outs["VelocityOut"][i]) for i in range(n)]
-    err_m = float(worst_rel_err(got, momentum_ref(ps, gs, ms)))
-    assert err_m < 1e-5, err_m
-    return {"params": n, "elements": int(sum(np.prod(s) for s in shapes)),
-            "mosaic": n_adam + n_mom, "rel_err_adam": err,
-            "rel_err_momentum": err_m}
-
-
 def roll_embedding(tiny, tpu, key):
     """fused_embedding_pool and its fused gradient: a table that fits the
     kernels' VMEM gate takes Pallas, one far past it takes XLA — both
@@ -715,7 +618,6 @@ ROLL_CALL = [
     ("fused_attention", roll_fused_attention, ["fused_attention_tpu"]),
     ("dropout", roll_dropout, ["fused_dropout_tpu", "fused_dropout_add_tpu",
                                "fused_act_dropout_tpu"]),
-    ("optimizers", roll_optimizers, ["fused_adam_tpu", "fused_momentum_tpu"]),
     ("embedding", roll_embedding, ["fused_embedding_pool_tpu",
                                    "embedding_pool_grad_tpu"]),
     ("paged", roll_paged, ["paged_flash_attention_tpu"]),

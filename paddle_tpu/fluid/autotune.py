@@ -4,7 +4,7 @@ The reference fork ships ~50 runtime gflags plus BuildStrategy /
 ExecutionStrategy knobs and leaves their values to operator folklore;
 this repro grew an even larger surface (bucket edges, inflight depth,
 ``steps_per_dispatch``, allreduce bucket size, serving ``max_batch`` /
-``max_wait``, ``FLAGS_pallas_min_seq``) while PRs 1/2/9/16 built exactly
+``max_wait``) while PRs 1/2/9/16 built exactly
 the measurement plane needed to set them automatically.  This module
 closes that loop (ROADMAP item 4):
 
@@ -38,9 +38,7 @@ Two surfaces:
 
 * training — ``BuildStrategy.auto_tune = True`` (or ``FLAGS_auto_tune``)
   tunes a program ONCE per fingerprint on its first ``Executor.run``:
-  bucket edges, ``steps_per_dispatch``, inflight depth, and (for
-  kernel-tier programs) the ``FLAGS_pallas_min_seq`` flash-attention
-  crossover.
+  bucket edges, ``steps_per_dispatch`` and inflight depth.
 * serving — ``ServingEngine(auto_tune=True)`` (or the flag, reconciled
   by :func:`apply_flags` exactly like the PR-9 metrics-export pattern)
   hill-climbs ``max_batch``/``max_wait_us`` online against the live
@@ -216,11 +214,8 @@ def candidates(space: KnobSpace, seed: int = 0,
 
 def training_space(program=None, feed=None) -> KnobSpace:
     """The executor-side knob space for one program: bucket edges (when
-    bucketing is active), ``steps_per_dispatch`` + inflight depth (the
-    async-pipeline pair, probed through ``run_async``), and — for
-    programs the kernel tier rewrote — the ``FLAGS_pallas_min_seq``
-    flash-attention crossover, the sweep the round-3 BERT measurements
-    asked a future auto-tuner to own."""
+    bucketing is active) and ``steps_per_dispatch`` + inflight depth (the
+    async-pipeline pair, probed through ``run_async``)."""
     knobs: List[Knob] = []
     hints = getattr(program, "_hints", {}) if program is not None else {}
     want_bucketing = hints.get("shape_bucketing")
@@ -257,11 +252,6 @@ def training_space(program=None, feed=None) -> KnobSpace:
     cur_in = int(core.get_flag("max_inflight_steps", 2) or 2)
     knobs.append(Knob("max_inflight_steps",
                       [cur_in] + [d for d in (1, 2, 4) if d != cur_in]))
-    if program is not None and _has_fused_attention(program):
-        cur_seq = int(core.get_flag("pallas_min_seq", 1024) or 1024)
-        knobs.append(Knob("pallas_min_seq",
-                          [cur_seq] + [s for s in (512, 1024, 2048)
-                                       if s != cur_seq]))
     if program is not None and (
             getattr(program, "_sharding_plan", None) is not None
             or hints.get("sharding")):
@@ -274,14 +264,6 @@ def training_space(program=None, feed=None) -> KnobSpace:
                                       if v != cur_fg],
                           kind="hint"))
     return KnobSpace(knobs)
-
-
-def _has_fused_attention(program) -> bool:
-    try:
-        return any(op.type == "fused_multihead_attention"
-                   for b in program.blocks for op in b.ops)
-    except Exception:                   # noqa: BLE001
-        return False
 
 
 def serving_space(engine) -> KnobSpace:
@@ -506,8 +488,7 @@ def _price_training(exe, program, feed, fetch_names, scope, space, cands):
     priced = []
     try:
         for cand in cands:
-            sig = repr(sorted((k, v) for k, v in cand.items()
-                              if k in ("bucket_edges", "pallas_min_seq")))
+            sig = repr(cand.get("bucket_edges"))
             if sig not in memo:
                 space.apply(cand, program=program)
                 memo[sig] = exe.analyze(program, feed=feed,

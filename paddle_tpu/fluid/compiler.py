@@ -41,27 +41,21 @@ class BuildStrategy:
         # True -> constant_fold + prune_identity + dce passes (the 1.x
         # memory_optimize contract: shrink the live set / op stream)
         self.memory_optimize = None
-        # REAL since the kernel tier landed: legacy alias for
-        # fuse_optimizer (framework/ir/fuse_optimizer_ops_pass analog)
+        # parity only: upstream scripts set it; XLA already fuses each
+        # update into its dW matmul, so there is nothing to bucket
         self.fuse_all_optimizer_ops = False
         self.fuse_all_reduce_ops = False     # -> coalesce_allreduce pass
         self.fuse_grad_size_in_num = 32      # allreduce bucket size (ops)
         self.fuse_elewise_add_act_ops = False  # -> fuse_elewise_add_act
         self.fuse_bn_act_ops = False           # -> fuse_bn_act
-        # Pallas kernel tier (fluid/passes/kernel_tier.py,
-        # docs/performance.md "Custom kernel tier"): pattern-rewrite the
-        # naive attention chain onto fused_multihead_attention (a Pallas
-        # kernel on TPU), lookup_table+pool chains onto
-        # fused_embedding_pool (fused gather/scatter-add), and runs of
-        # per-param adam/lamb/momentum updates onto one fused bucket
-        # update.  kernel_tier=True is the umbrella for all three.
-        self.kernel_tier = False
-        # every chain; without the field an unpartitioned program still
-        # gets the pass for the chains a kernel covers
-        self.fuse_attention = False            # -> fuse_attention
+        # Pallas kernel tier (fluid/passes/kernel_tier.py).  The attention
+        # chain needs no field: fuse_attention is in every pipeline and
+        # rewrites a chain where its kernel runs (docs/passes.md "Where a
+        # kernel runs").  The two below rewrite the paged decode chain
+        # onto paged_attention and lookup_table+pool chains onto
+        # fused_embedding_pool; each waits for a cell to judge it.
         self.fuse_paged_attention = False      # -> fuse_paged_attention
         self.fuse_sparse_embedding = False     # -> fuse_sparse_embedding
-        self.fuse_optimizer = False            # -> fuse_optimizer
         self.enable_dce = False                # -> dce pass (fetch-seeded)
         self.constant_folding = False          # -> constant_fold pass
         # bf16 mixed precision as a compiler plane (passes/amp.py):
@@ -87,10 +81,9 @@ class BuildStrategy:
         self.sharding_mesh = None
         # profile-guided self-tuning (fluid/autotune.py,
         # docs/performance.md "Auto-tuning"): True opts this program
-        # into the executor-side search — bucket edges, dispatch
-        # fusion/inflight depth, and the kernel-tier crossover tune once
-        # per fingerprint on the first run, and persisted winners apply
-        # with zero probe cost on restart
+        # into the executor-side search — bucket edges and dispatch
+        # fusion/inflight depth tune once per fingerprint on the first
+        # run, and persisted winners apply with zero probe cost on restart
         self.auto_tune = False
         self.enable_sequential_execution = False
         self.remove_unnecessary_lock = True

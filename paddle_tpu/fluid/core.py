@@ -289,13 +289,6 @@ _FLAGS: Dict[str, object] = {
         "FLAGS_ps_wal_fsync", "0") not in ("0", "", "false", "False"),
     "ps_shard_vnodes": int(_os.environ.get(
         "FLAGS_ps_shard_vnodes", "64") or 64),
-    # kernel tier (fluid/passes/kernel_tier.py, ops/attention.py): minimum
-    # sequence length before attention dispatches to the Pallas flash
-    # kernel.  Default 1024; where the crossover with XLA's
-    # softmax(QK^T)V fusion sits is not measured on this code — the knob
-    # lets a chip run sweep it.
-    "pallas_min_seq": int(_os.environ.get(
-        "FLAGS_pallas_min_seq", "1024") or 1024),
     # profile-guided self-tuning runtime (fluid/autotune.py,
     # docs/performance.md "Auto-tuning"): auto_tune arms BOTH surfaces
     # (executor programs tune once per fingerprint on first run; serving

@@ -564,8 +564,6 @@ def _infer_op_shapes(block: "Block", op: "Operator"):
 _OPTIMIZER_OP_TYPES = frozenset({
     "sgd", "momentum", "adam", "adamw", "adagrad", "rmsprop", "lamb",
     "lars_momentum", "ftrl", "dpsgd", "dgc_momentum",
-    # bucketed kernel-tier updates (fluid/passes/kernel_tier.py)
-    "fused_adam", "fused_lamb", "fused_momentum",
 })
 
 # ops kept during pruning regardless of reachability: cross-device and

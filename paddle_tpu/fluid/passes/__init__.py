@@ -22,8 +22,7 @@ from .builtin import passes_for_build_strategy
 from .amp import AmpBf16Pass, PruneRedundantCastsPass
 from .inference import (FoldBatchNormPass, inference_passes,
                         INFERENCE_PASS_NAMES)
-from .kernel_tier import (FuseAttentionPass, FuseSparseEmbeddingPass,
-                          FuseOptimizerPass)
+from .kernel_tier import FuseAttentionPass, FuseSparseEmbeddingPass
 
 __all__ = [
     "Pass", "PassContext", "PassRegistry", "PassPipeline",
@@ -32,5 +31,5 @@ __all__ = [
     "program_to_dot", "dump_program", "passes_for_build_strategy",
     "AmpBf16Pass", "PruneRedundantCastsPass",
     "FoldBatchNormPass", "inference_passes", "INFERENCE_PASS_NAMES",
-    "FuseAttentionPass", "FuseSparseEmbeddingPass", "FuseOptimizerPass",
+    "FuseAttentionPass", "FuseSparseEmbeddingPass",
 ]

@@ -651,19 +651,13 @@ class ShardCollectivesPass(Pass):
         return {"collectives_implied": implied}
 
 
-def _batch_alone(sharding) -> bool:
-    """Does a ``BuildStrategy.sharding`` value leave every activation whole
-    but for its batch dim?  Unset, and the "dp" and "fsdp" modes."""
-    return not sharding or (isinstance(sharding, str)
-                            and sharding.lower() in ("dp", "fsdp"))
-
-
 def passes_for_build_strategy(build_strategy) -> List[Pass]:
     """Instantiate the pass list a BuildStrategy's knobs select, in the
     canonical order: fold -> fuse -> kernel_tier -> clean -> amp -> dce
-    -> coalesce.  ``fuse_attention`` needs no knob: an unpartitioned
-    program and a data-parallel one (``sharding`` "dp" / "fsdp") get it
-    for the attention chains a kernel covers.  The kernel
+    -> coalesce.  ``fuse_attention`` is in every pipeline: it rewrites the
+    attention chains whose fused op would lower to a kernel and leaves
+    every other chain, and every other program, op for op as it was
+    (docs/passes.md "Where a kernel runs").  The kernel
     tier runs after the pairwise fusions (they never overlap its chains)
     and before AMP (the fused attention op is white-listed MXU compute,
     so the bf16 rewrite sees ONE op instead of the six-op chain); AMP
@@ -673,7 +667,6 @@ def passes_for_build_strategy(build_strategy) -> List[Pass]:
     from . import kernel_tier as _kt  # noqa: F401 — registers the tier
     bs = build_strategy
     mem = bool(getattr(bs, "memory_optimize", None))
-    tier = bool(getattr(bs, "kernel_tier", False))
     specs = []
     if getattr(bs, "constant_folding", False) or mem:
         specs.append(("constant_fold", {}))
@@ -681,23 +674,11 @@ def passes_for_build_strategy(build_strategy) -> List[Pass]:
         specs.append(("fuse_elewise_add_act", {}))
     if getattr(bs, "fuse_bn_act_ops", False):
         specs.append(("fuse_bn_act", {}))
-    if tier or getattr(bs, "fuse_attention", False):
-        specs.append(("fuse_attention", {}))
-    elif _batch_alone(getattr(bs, "sharding", None)):
-        # the default: chains whose fused op would lower to a kernel, in
-        # an unpartitioned program and in one partitioned on the batch
-        # alone, where the kernel runs once per chip
-        # (LoweringContext.kernel_site).  Not under "tp" or custom rules:
-        # no Mosaic call runs there, and the rewrite would only swap one
-        # XLA spelling for another.
-        specs.append(("fuse_attention", {"where_kernel_runs": True}))
-    if tier or getattr(bs, "fuse_paged_attention", False):
+    specs.append(("fuse_attention", {}))
+    if getattr(bs, "fuse_paged_attention", False):
         specs.append(("fuse_paged_attention", {}))
-    if tier or getattr(bs, "fuse_sparse_embedding", False):
+    if getattr(bs, "fuse_sparse_embedding", False):
         specs.append(("fuse_sparse_embedding", {}))
-    if tier or getattr(bs, "fuse_optimizer", False) \
-            or getattr(bs, "fuse_all_optimizer_ops", False):
-        specs.append(("fuse_optimizer", {}))
     if mem:
         specs.append(("prune_identity", {}))
     if getattr(bs, "amp", False):

@@ -52,7 +52,7 @@ class PassContext:
         self.build_strategy = build_strategy
         # the resolved PR-10 ShardingPlan when the pipeline runs under a
         # sharded CompiledProgram (run() ensures the plan BEFORE the
-        # passes) — spec-aware passes (fuse_optimizer) group by it
+        # passes) — fuse_attention reads its mesh (KernelSite.on)
         self.sharding_plan = sharding_plan
         self.stats: Dict[str, Dict[str, int]] = {}
 

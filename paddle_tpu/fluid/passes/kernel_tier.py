@@ -2,25 +2,26 @@
 
 Reference: the qingshui/PaddleBox fork's identity is its fused ads/CTR
 operators (PAPER.md: ``operators/fused/``, ``multihead_matmul_op.cu``,
-``bert_encoder_functor.cu``, ``fused_embedding_seq_pool_op.cc``,
-``framework/ir/fuse_optimizer_ops_pass/``).  The seed shipped the KERNELS
-half of that story — ``ops/pallas_kernels.py`` behind the
-``fused_multihead_attention`` / ``fused_embedding_pool`` / ``fused_*``
-op boundaries — but nothing in the compiler ever *produced* those ops: a
-BERT program built from plain matmul/softmax layers lowered op-by-op.
-These three pattern-rewrite passes close the gap the same way PR 3/PR 5
-did for fusion and AMP: any existing program gets the kernels without
-touching model code.
+``bert_encoder_functor.cu``, ``fused_embedding_seq_pool_op.cc``).  The seed
+shipped the KERNELS half of that story — ``ops/pallas_kernels.py`` behind
+the ``fused_multihead_attention`` / ``fused_embedding_pool`` op boundaries
+— but nothing in the compiler ever *produced* those ops: a BERT program
+built from plain matmul/softmax layers lowered op-by-op.  These
+pattern-rewrite passes close the gap the same way PR 3/PR 5 did for fusion
+and AMP: any existing program gets the kernels without touching model code.
 
 * ``fuse_attention`` — the naive attention chain matmul(Q,Kᵀ) → scale →
   (+mask) → softmax → (dropout) → matmul(·,V), including the paired
   ``generic_grad`` ops of training programs, rewrites to ONE
-  ``fused_multihead_attention`` op (+ one fused generic_grad).  The
-  lowering picks a kernel on TPU (``ops.attention.attention_path``: the
-  fused kernel with in-kernel dropout up to S = 512, jax's flash kernel
-  from ``FLAGS_pallas_min_seq`` up) and the XLA-fused reference
-  elsewhere; an absorbed dropout op's seed is stamped into the fused op
-  so the XLA path regenerates the identical mask.
+  ``fused_multihead_attention`` op (+ one fused generic_grad) **where that
+  op would lower to a kernel on a chip** and nowhere else: the pass asks
+  the lowering's own two questions (``ops.registry.KernelSite.on`` over
+  the sharding plan's mesh, ``ops.attention.path_at`` over the declared
+  shapes; docs/passes.md "Where a kernel runs"), so a chain no kernel
+  covers, and every program without a chain, is left op for op as it was.
+  It is in every pipeline and has no switch.  An absorbed dropout op's
+  seed is stamped into the fused op so the XLA path (off the chip)
+  regenerates the identical mask.
 * ``fuse_paged_attention`` — the block-paged decode attend chain
   (serving/decode.py paged programs): page-table gather ×2 → reshape ×2
   → mul+reduce_sum scores → scale → exact-zero mask → softmax →
@@ -34,36 +35,24 @@ touching model code.
   rewrites to ``fused_embedding_pool``: Pallas fused gather+pool forward
   with a fused scatter-add (segment-sum) backward, XLA take/masked-sum
   fallback mirroring the unfused chain.
-* ``fuse_optimizer`` — consecutive same-(family, dtype, attrs, lr,
-  PartitionSpec-group) ``adam``/``lamb``/``momentum`` update ops bucket
-  into one ``fused_adam``/``fused_lamb``/``fused_momentum`` op: one
-  launch per bucket over a flattened param buffer, element-for-element
-  the same arithmetic (bit-compares against per-param updates), PR-5
-  MasterParam slots carried through, and — under a PR-10 sharding plan —
-  bucketing only within identical-spec groups so the whole-step pjit
-  path never pays a reshard.
 
-Every pass counts ``kernel_tier.<pass>.rewrites``; wiring is the
-``BuildStrategy.fuse_attention`` / ``fuse_sparse_embedding`` /
-``fuse_optimizer`` knobs plus the ``kernel_tier`` umbrella, appended by
-``passes_for_build_strategy`` after the pairwise fusions and before AMP
-(docs/passes.md).  ``fuse_attention`` is also in the default pipeline of
-every unpartitioned program and of every program partitioned on the batch
-alone (``sharding`` "dp" / "fsdp": the kernel runs once per chip), there
-only for the chains whose fused op lowers to a kernel
-(``where_kernel_runs``).
+Every pass counts ``kernel_tier.<pass>.rewrites``.  The last two are
+selected by their own ``BuildStrategy`` fields (``fuse_paged_attention``,
+``fuse_sparse_embedding``) until a serving and a CTR cell judge them;
+``passes_for_build_strategy`` places all three after the pairwise fusions
+and before AMP (docs/passes.md).
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 from .. import trace
 from ..framework import Operator, _op_reads
-from .core import Pass, PassContext, register_pass
+from .core import PassContext, register_pass
 from .pattern import Pattern, PatternRewritePass, writer_index as _widx
 
 __all__ = ["FuseAttentionPass", "FusePagedAttentionPass",
-           "FuseSparseEmbeddingPass", "FuseOptimizerPass"]
+           "FuseSparseEmbeddingPass"]
 
 
 def _consumers(block, name: str) -> List[Operator]:
@@ -122,14 +111,8 @@ class FuseAttentionPass(PatternRewritePass):
 
     name = "fuse_attention"
 
-    def __init__(self, where_kernel_runs: bool = False, **options):
-        """``where_kernel_runs``: rewrite only chains whose fused op would
-        lower to a Pallas kernel on a chip, judged from the variables'
-        static shapes (``ops.attention.attention_path``); every other
-        chain, and so every program without such a chain, is left op for
-        op as it was.  The default pipeline runs the pass this way."""
+    def __init__(self, **options):
         super().__init__(**options)
-        self.where_kernel_runs = bool(where_kernel_runs)
         for train in (True, False):
             for with_drop in (True, False):
                 for with_mask in (True, False):
@@ -215,42 +198,33 @@ class FuseAttentionPass(PatternRewritePass):
     @staticmethod
     def _kernel_runs(m, drop_op, plan=None) -> bool:
         """Would the fused op over this chain's operands lower to a kernel
-        where kernels run?  Shapes and dtypes as the block declares them
-        (-1 for the batch); under a sharding ``plan`` a declared batch is
-        judged as the rows one chip holds."""
+        on a chip?  The lowering's own two questions (``KernelSite.on``
+        over the ``plan``'s mesh, ``ops.attention.path_at``), asked of
+        the shapes and dtypes the block declares (-1 for the batch)."""
         from types import SimpleNamespace
         import jax.numpy as jnp
-        from ...ops.attention import attention_path
-        shards = 1
-        if plan is not None:
-            from ...parallel.sharding import batch_shard_axis
-            axis = batch_shard_axis(plan.mesh)
-            if axis is None:
-                return False             # no kernel in this partitioning
-            shards = int(plan.mesh.shape[axis])
+        from ...ops.attention import path_at
+        from ...ops.registry import KernelSite
 
         def operand(name):
             v = m.block._find_var_recursive(m.var(name))
             if v is None or v.shape is None or v.dtype is None:
                 return None
-            shape = tuple(v.shape)
-            if shape and shape[0] > 1:
-                if shape[0] % shards:
-                    return None
-                shape = (shape[0] // shards,) + shape[1:]
-            return SimpleNamespace(shape=shape, ndim=len(shape),
+            return SimpleNamespace(shape=tuple(v.shape), ndim=len(v.shape),
                                    dtype=jnp.dtype(v.dtype))
 
         operands = [operand(n) for n in ("q", "k", "v", "mask")
                     if n in m.binding]
         if any(o is None for o in operands):
-            return False     # undeclared or undividable: nothing to judge
+            return False                 # undeclared: nothing to judge
         if len(operands) == 3:
             operands.append(None)        # no mask
+        site = KernelSite.on(plan.mesh if plan is not None else None,
+                             operands[0])
         drop_active = drop_op is not None \
             and not drop_op.attrs.get("is_test", False) \
             and bool(drop_op.attrs.get("dropout_prob", 0.5))
-        return attention_path(*operands, False, drop_active, True) != "xla"
+        return path_at(site, *operands, False, drop_active) != "xla"
 
     # -- rewrite ------------------------------------------------------------
     def _rewrite(self, m, ctx, train, with_scale, with_mask,
@@ -284,8 +258,8 @@ class FuseAttentionPass(PatternRewritePass):
             mask_out = (drop_op.outputs.get("Mask") or [None])[0]
             if mask_out and _consumers(block, mask_out):
                 return False
-        if self.where_kernel_runs and not self._kernel_runs(
-                m, drop_op, getattr(ctx, "sharding_plan", None)):
+        if not self._kernel_runs(m, drop_op,
+                                 getattr(ctx, "sharding_plan", None)):
             return False
         if train:
             # grad chain intermediates are internal too, and the mask must
@@ -564,138 +538,3 @@ class FuseSparseEmbeddingPass(PatternRewritePass):
         _splice(block, fused, pool, [lookup, pool])
         _count_rewrite(self.name)
         return True
-
-
-# ---------------------------------------------------------------------------
-# fuse_optimizer
-# ---------------------------------------------------------------------------
-
-_FUSABLE_UPDATES: Dict[str, Dict] = {
-    "adam": {"fused": "fused_adam",
-             "ins": frozenset({"Param", "Grad", "Moment1", "Moment2",
-                               "Beta1Pow", "Beta2Pow", "LearningRate"}),
-             "outs": ("ParamOut", "Moment1Out", "Moment2Out",
-                      "Beta1PowOut", "Beta2PowOut")},
-    "lamb": {"fused": "fused_lamb",
-             "ins": frozenset({"Param", "Grad", "Moment1", "Moment2",
-                               "Beta1Pow", "Beta2Pow", "LearningRate"}),
-             "outs": ("ParamOut", "Moment1Out", "Moment2Out",
-                      "Beta1PowOut", "Beta2PowOut")},
-    "momentum": {"fused": "fused_momentum",
-                 "ins": frozenset({"Param", "Grad", "Velocity",
-                                   "LearningRate"}),
-                 "outs": ("ParamOut", "VelocityOut")},
-}
-
-_SHARED_SLOTS = ("LearningRate",)
-
-
-@register_pass
-class FuseOptimizerPass(Pass):
-    """Bucket consecutive same-family per-param update ops into one fused
-    update op (fuse_adam_op_pass / fuse_momentum_op_pass semantics).  The
-    bucket key is (op type, param dtype, multi-precision, the lr var, the
-    full attr set, and — when a PR-10 sharding plan is live — the param's
-    resolved PartitionSpec), so a bucket is always homogeneous: one
-    flattened buffer, one launch, zero implied reshards under pjit."""
-
-    name = "fuse_optimizer"
-
-    def __init__(self, bucket_size: int = 1024, **options):
-        super().__init__(**options)
-        self.bucket_size = max(int(bucket_size), 2)
-
-    # -- bucket keying ------------------------------------------------------
-    def _spec_group(self, block, ctx: PassContext, param: str) -> str:
-        plan = getattr(ctx, "sharding_plan", None)
-        if plan is None:
-            return ""
-        v = block._find_var_recursive(param)
-        if v is None or v.shape is None:
-            return f"?{param}"     # unknown shape: never buckets
-        try:
-            return repr(plan.spec_for(param, tuple(v.shape)))
-        except Exception:          # noqa: BLE001 — never block the rewrite
-            return f"?{param}"
-
-    def _key(self, block, ctx: PassContext, op) -> Optional[tuple]:
-        spec = _FUSABLE_UPDATES.get(op.type)
-        if spec is None:
-            return None
-        slots = set(op.inputs)
-        has_master = "MasterParam" in slots
-        want = spec["ins"] | ({"MasterParam"} if has_master else set())
-        if slots != want:
-            return None            # SkipUpdate or exotic wiring: leave it
-        if any(len(names) != 1 for names in op.inputs.values()):
-            return None
-        param = op.inputs["Param"][0]
-        v = block._find_var_recursive(param)
-        dtype = v.dtype if v is not None else None
-        attr_sig = tuple(sorted((k, repr(val)) for k, val in op.attrs.items()
-                                if k not in ("op_role", "op_seed")))
-        return (op.type, str(dtype), has_master,
-                op.inputs["LearningRate"][0], attr_sig,
-                self._spec_group(block, ctx, param))
-
-    # -- rewriting ----------------------------------------------------------
-    def _fuse_run(self, block, seg, out_ops) -> int:
-        """Fuse one same-key run; returns the number of ops removed."""
-        if len(seg) < 2:
-            out_ops.extend(seg)
-            return 0
-        spec = _FUSABLE_UPDATES[seg[0].type]
-        # per-param vars must be pairwise disjoint (params shared between
-        # two update ops would race inside one fused op)
-        per_param = [n for op in seg for slot, names in op.inputs.items()
-                     if slot not in _SHARED_SLOTS for n in names]
-        if len(set(per_param)) != len(per_param):
-            out_ops.extend(seg)
-            return 0
-        removed = 0
-        for lo in range(0, len(seg), self.bucket_size):
-            chunk = seg[lo:lo + self.bucket_size]
-            if len(chunk) < 2:
-                out_ops.extend(chunk)
-                continue
-            ins = {slot: [op.inputs[slot][0] for op in chunk]
-                   for slot in chunk[0].inputs if slot not in _SHARED_SLOTS}
-            ins["LearningRate"] = list(chunk[0].inputs["LearningRate"])
-            out_slots = list(spec["outs"])
-            if "MasterParam" in chunk[0].inputs:
-                out_slots.append("MasterParamOut")
-            outs = {slot: [op.outputs[slot][0] for op in chunk]
-                    for slot in out_slots}
-            out_ops.append(Operator(
-                block, spec["fused"], ins, outs, dict(chunk[0].attrs)))
-            removed += len(chunk) - 1
-            _count_rewrite(self.name)
-        return removed
-
-    def apply_block(self, block, ctx: PassContext) -> Dict[str, int]:
-        out_ops: list = []
-        seg: list = []
-        seg_key = None
-        removed = 0
-
-        def flush():
-            nonlocal removed
-            if seg:
-                removed += self._fuse_run(block, seg, out_ops)
-                seg.clear()
-
-        for op in block.ops:
-            key = self._key(block, ctx, op)
-            if key is not None:
-                if seg and key != seg_key:
-                    flush()
-                seg_key = key
-                seg.append(op)
-            else:
-                flush()
-                out_ops.append(op)
-        flush()
-        if removed:
-            block.ops = out_ops
-            block.program._bump_version()
-        return {"ops_removed": removed}

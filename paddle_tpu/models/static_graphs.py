@@ -4,8 +4,8 @@ The kernel-tier passes (fluid/passes/kernel_tier.py) rewrite *naive* op
 chains — these builders spell BERT attention and the CTR embedding path
 exactly the way plain fluid layers emit them (matmul → scale → +mask →
 softmax → dropout → matmul; lookup_table_v2 → sequence_pool), so the
-same programs serve as the rewrite targets for tools/ci_smoke.py, the
-bench kernel-tier legs (bench.py), and tests/test_kernel_tier.py.
+same programs serve as the rewrite targets for chip_smoke.py's Executor
+leg, bench.py's demo legs and tests/test_kernel_tier.py.
 Reference: the qingshui fork's BERT/ERNIE encoder and the PaddleBox
 wide&deep CTR net (PAPER.md layers 2 and 6).
 """
@@ -56,7 +56,7 @@ def build_bert_train_program(vocab=64, hidden=32, heads=4, seq=16,
             am = fluid.data("attn_mask", [-1, seq])
             am = L.reshape(am, [0, 1, 1, seq])
             # (m - 1) * 10000: zeros where attended, -1e4 where padded
-            mask = L.scale(am, scale=10000.0, bias=-10000.0,
+            mask = L.scale(am, scale=10000.0, bias=-1.0,
                            bias_after_scale=False)
         h = L.embedding(ids, size=[vocab, hidden])
         for _ in range(layers):

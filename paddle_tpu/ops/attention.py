@@ -3,14 +3,16 @@
 Reference: paddle/fluid/operators/fused/multihead_matmul_op.cu (fused
 transformer attention) and math/bert_encoder_functor.cu (SURVEY §2.5 fused/).
 TPU-native: one `fused_multihead_attention` op; the `fuse_attention` pass
-(fluid/passes/kernel_tier.py, in the default pipeline of an unpartitioned
-and of a data-parallel program) PRODUCES it from the naive
-matmul→softmax→matmul chain, so plain static programs get the kernels
-without touching model code.  The lowering
-picks one of four paths from what it can see (`attention_path`):
+(fluid/passes/kernel_tier.py, in every pipeline) PRODUCES it from the naive
+matmul→softmax→matmul chain wherever the op would lower to a kernel, so
+plain static programs get the kernels without touching model code.  Where
+a kernel runs is answered in two places and nowhere else (docs/passes.md
+"Where a kernel runs"): `KernelSite.on` (ops/registry.py) says how a call
+sees the batch on the program's mesh, `attention_path` here says which of
+four paths one chip's operands take:
 
 * `splash_kernel` — `pallas_kernels.splash_attention_tpu`: causal attention
-  from `FLAGS_pallas_min_seq` up, and every length with a sliding `window`
+  from `_STREAM_MIN_SEQ` (1024) up, and every length with a sliding `window`
   or with fewer key/value heads than query heads; only the blocks inside the
   causal band are visited, nothing of size [S, S] exists;
 * `fused_kernel` — `pallas_kernels.fused_attention_tpu`: key lengths up to
@@ -20,7 +22,7 @@ picks one of four paths from what it can see (`attention_path`):
   chip under `shard_map`, judged on the chip's own rows
   (`LoweringContext.kernel_site`);
 * `flash_kernel` — jax's flash kernel, K/V streamed through VMEM: from
-  `FLAGS_pallas_min_seq` (1024) up, dropout-free;
+  `_STREAM_MIN_SEQ` up, dropout-free;
 * `xla` — `_reference_attention`, the XLA softmax(QK^T)V: the CPU, a
   program partitioned any other way (`tp`, a mesh with further axes: a
   Mosaic call cannot be partitioned automatically, and only the fused
@@ -42,12 +44,14 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .registry import register_op
+from .registry import KernelSite, register_op
 
-_PALLAS_MIN_SEQ_DEFAULT = 1024
-# From this length up the flash kernel streams K/V blocks through VMEM; the
-# crossover against XLA is not measured on this code, FLAGS_pallas_min_seq
-# exists so a chip run can sweep it.
+# From this length up causal attention takes the splash kernel and
+# dropout-free non-causal attention jax's flash kernel, both streaming K/V
+# blocks through VMEM.  Untimed: the crossover against XLA has not been
+# measured on this code (no cell has a non-causal call this long; the
+# causal cell sits at 8192).
+_STREAM_MIN_SEQ = 1024
 
 # Shortest sequence that takes the fused kernel: it beats XLA's chain at
 # every length it covers (v5e, forward + backward of one BERT-base layer
@@ -55,16 +59,6 @@ _PALLAS_MIN_SEQ_DEFAULT = 1024
 # 1.83, [32, 12, 512, 64] 1.32 against 7.70; my chip runs, PR 25), so the
 # floor is the shortest lane-aligned length.
 _FUSED_MIN_SEQ = 128
-
-
-def _pallas_min_seq() -> int:
-    """Runtime crossover knob: FLAGS_pallas_min_seq (default 1024)."""
-    try:
-        from ..fluid import core
-        v = core.get_flag("pallas_min_seq", _PALLAS_MIN_SEQ_DEFAULT)
-        return int(v) if v is not None else _PALLAS_MIN_SEQ_DEFAULT
-    except Exception:               # noqa: BLE001 — dispatch must not die
-        return _PALLAS_MIN_SEQ_DEFAULT
 
 
 def _reference_attention(q, k, v, mask, scale, causal,
@@ -145,17 +139,15 @@ def _bias_broadcastable(mask, q, k) -> bool:
     return all(m == 1 or m == t for m, t in zip(mask.shape, target))
 
 
-def attention_path(q, k, v, mask, causal, drop_active, use_pallas,
-                   min_seq=_PALLAS_MIN_SEQ_DEFAULT, window=0) -> str:
-    """Which lowering attention over these operands takes:
+def attention_path(q, k, v, mask, causal, drop_active, window=0) -> str:
+    """Which lowering attention over one chip's operands takes on a chip:
     ``splash_kernel``, ``fused_kernel``, ``flash_kernel`` or ``xla``.  A
-    function of shapes, dtypes and flags alone (the operands may be
-    ShapeDtypeStructs)."""
-    if not use_pallas:
-        return "xla"
+    function of shapes and dtypes alone (the operands may be
+    ShapeDtypeStructs, or a block's declared variables)."""
     seq = q.shape[-2]
     grouped = k.shape[1] != q.shape[1]
-    if (window or grouped or seq >= min_seq) and causal and not drop_active:
+    if (window or grouped or seq >= _STREAM_MIN_SEQ) and causal \
+            and not drop_active:
         from .pallas_kernels import splash_attention_supported
         if splash_attention_supported(q, k, v, mask):
             return "splash_kernel"
@@ -165,23 +157,37 @@ def attention_path(q, k, v, mask, causal, drop_active, use_pallas,
         from .pallas_kernels import fused_attention_supported
         if fused_attention_supported(q, k, v, mask):
             return "fused_kernel"
-    if seq >= min_seq and not drop_active \
+    if seq >= _STREAM_MIN_SEQ and not drop_active \
             and (mask is None or _bias_broadcastable(mask, q, k)):
         return "flash_kernel"
     return "xla"
 
 
+def path_at(site, q, k, v, mask, causal, drop_active, window=0) -> str:
+    """The path of a call whose kernel would run at ``site``
+    (``KernelSite.on`` / ``LoweringContext.kernel_site``; None: XLA):
+    ``attention_path`` of the rows one call sees.  Only the fused kernel
+    has been taken under ``shard_map``; the others keep XLA per shard."""
+    if site is None:
+        return "xla"
+    batched_mask = mask is not None and mask.shape[0] == q.shape[0]
+    path = attention_path(site.local(q), site.local(k), site.local(v),
+                          site.local(mask) if batched_mask else mask,
+                          causal, drop_active, window)
+    if site.shards > 1 and path != "fused_kernel":
+        return "xla"
+    return path
+
+
 def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                     dropout_rate=0.0, dropout_key=None,
-                    dropout_upscale=True, prob_scale=None, use_pallas=None,
-                    window=0, site=None):
+                    dropout_upscale=True, prob_scale=None, window=0,
+                    site=None):
     """Dispatch to a Pallas TPU kernel where one covers the call, else XLA
-    (``attention_path``).  ``use_pallas``: None (the shard_map bodies in
-    parallel/) means "on the tpu backend".  ``site``: an op lowering
-    passes ``ctx.kernel_site(q)``, which decides in ``use_pallas``'s place:
-    None is XLA; a site of several shards judges each chip's own rows and
-    runs the fused kernel once per shard (the other kernels have not been
-    taken there: XLA).
+    (``path_at``).  ``site`` says where the call's kernel runs: None (the
+    default) is XLA; an op lowering passes ``ctx.kernel_site(q)``; a
+    caller that is already inside a per-chip body (the ``shard_map``
+    bodies in parallel/) passes ``registry.chip_site()``.
 
     The flash kernel takes additive-bias masks through its ``ab`` argument
     (anything broadcastable to [B, H, Tq, Tk], materialised at that size)
@@ -195,24 +201,15 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     drop_active = bool(dropout_rate) and dropout_key is not None
-    if site is not None:
-        use_pallas = True
-    elif use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    local = site.local if site is not None else (lambda x: x)
-    batched_mask = mask is not None and mask.shape[0] == q.shape[0]
     window = int(window or 0)
     banded = bool(window) or k.shape[1] != q.shape[1]
     if banded and (not causal or mask is not None or drop_active
                    or prob_scale is not None):
         raise ValueError("attention with a window or grouped key/value "
                          "heads is causal, without mask or dropout")
-    path = attention_path(local(q), local(k), local(v),
-                          local(mask) if batched_mask else mask, causal,
-                          drop_active, use_pallas, _pallas_min_seq(), window)
+    path = path_at(site, q, k, v, mask, causal, drop_active, window)
     if path in ("flash_kernel", "splash_kernel") \
-            and (prob_scale is not None or scale == 0.0
-                 or (site is not None and site.shards > 1)):
+            and (prob_scale is not None or scale == 0.0):
         path = "xla"
     from ..fluid import trace
     trace.metrics().counter(f"attention.lowering.{path}").inc()
@@ -273,8 +270,8 @@ def _fused_mha(ins, attrs, ctx):
                           causal=attrs.get("causal", False),
                           dropout_rate=rate, dropout_key=dropout_key,
                           dropout_upscale=upscale, prob_scale=prob_scale,
-                          use_pallas=False, site=ctx.kernel_site(q),
-                          window=attrs.get("window", 0))
+                          window=attrs.get("window", 0),
+                          site=ctx.kernel_site(q))
     return {"Out": [out]}
 
 
@@ -342,5 +339,5 @@ def _multihead_matmul(ins, attrs, ctx):
     qkv = x.reshape(b, t, 3, h, d).transpose(2, 0, 3, 1, 4)
     out = flash_attention(qkv[0], qkv[1], qkv[2], bias_qk,
                           scale=attrs.get("alpha", None),
-                          use_pallas=ctx.pallas_ok())
+                          site=KernelSite() if ctx.pallas_ok() else None)
     return {"Out": [out.transpose(0, 2, 1, 3).reshape(b, t, h * d)]}

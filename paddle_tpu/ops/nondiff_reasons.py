@@ -55,8 +55,7 @@ REASONS = {
         "dgc_momentum", "dpsgd", "proximal_adagrad", "proximal_gd",
         "localsgd_select", "average_accumulates", "check_finite_and_unscale",
         "update_loss_scaling", "lookup_sparse_table_fuse_adam",
-        "lookup_sparse_table_fuse_sgd",
-        "fused_adam", "fused_lamb", "fused_momentum")},
+        "lookup_sparse_table_fuse_sgd")},
     # -- integer / boolean / index outputs ----------------------------------
     **{op: "int_output" for op in (
         "equal", "equal_all", "not_equal", "less_than", "less_equal",

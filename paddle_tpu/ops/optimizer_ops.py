@@ -219,160 +219,6 @@ def _dpsgd(ins, attrs, ctx):
 
 
 # ---------------------------------------------------------------------------
-# bucketed (fused) optimizer updates — the kernel-tier ops the
-# fuse_optimizer pass (fluid/passes/kernel_tier.py) produces from runs of
-# same-(family, dtype, attrs, PartitionSpec) per-param update ops.
-# Reference: framework/ir/fuse_optimizer_ops_pass/ (fuse_adam_op_pass,
-# fuse_momentum_op_pass) + coalesce_tensor semantics.  One op dispatch per
-# BUCKET instead of one per param.  Where a kernel may be called directly
-# (LoweringContext.pallas_ok: the tpu backend, outside GSPMD-partitioned
-# programs; the buckets hold parameters, not batch rows, so they are not
-# among the kernels a data-parallel program runs per shard) an f32
-# adam/momentum bucket is packed into one row-aligned
-# [rows, 1024] buffer and updated by a single Pallas kernel
-# (ops/pallas_kernels.py) — element-for-element the SAME arithmetic as the
-# per-param ops, concatenation changes layout, never values; per-param
-# bias-correction scalars (each param owns its beta-pow accumulators)
-# broadcast over their rows.  Everywhere else the bucket op IS
-# the N per-param lowerings (bit-identical to the unfused program; XLA fuses
-# the elementwise stages across params within the one computation) — a
-# coalesced buffer only exists to feed the kernel.
-# ---------------------------------------------------------------------------
-
-_LANES = 1024        # 8 sublanes x 128 lanes: one full f32 tile row
-
-
-def _rows_of(x) -> int:
-    return -(-int(x.size) // _LANES)
-
-
-def _flat(xs, dtype):
-    """The bucket as ONE [rows, _LANES] buffer, every param zero-padded to
-    whole rows.  Never a flat 1-D buffer: on TPU, XLA is free to give a
-    long 1-D array an [N, 2] tiled layout that pads 64x — 13 GiB of
-    temporaries for a BERT-base bucket (XLA memory analysis of the
-    compiled step, PR 21)."""
-    pieces = []
-    for x in xs:
-        flat = x.reshape(-1).astype(dtype)
-        pad = _rows_of(x) * _LANES - flat.size
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        pieces.append(flat.reshape(-1, _LANES))
-    return jnp.concatenate(pieces, axis=0)
-
-
-def _unflat(buf, templates):
-    out, row = [], 0
-    for t in templates:
-        n = _rows_of(t)
-        out.append(buf[row:row + n].reshape(-1)[:t.size].reshape(t.shape))
-        row += n
-    return out
-
-
-def _bucket_params(ins):
-    """(compute params, widened grads, low-precision params or None): the
-    _mp_param() contract over the whole bucket."""
-    masters = ins.get("MasterParam")
-    lo = ins["Param"]
-    ps = masters if masters else lo
-    gs = [g.astype(p.dtype) if g.dtype != p.dtype else g
-          for g, p in zip(ins["Grad"], ps)]
-    return ps, gs, (lo if masters else None)
-
-
-def _kernel_bucket(ins, ctx):
-    """The bucket's (params, grads, low-precision params) when it takes
-    the Pallas kernel — f32 compute params where kernels run — else
-    None."""
-    ps, gs, lo = _bucket_params(ins)
-    if ctx.pallas_ok() and ps[0].dtype == jnp.float32:
-        return ps, gs, lo
-    return None
-
-
-def _bucket_param_outs(outs, lo, new_ps):
-    if lo is not None:
-        outs["ParamOut"] = [p.astype(l.dtype) for p, l in zip(new_ps, lo)]
-        outs["MasterParamOut"] = list(new_ps)
-    else:
-        outs["ParamOut"] = list(new_ps)
-    return outs
-
-
-def _per_param(lowering, ins, attrs, ctx):
-    """A bucket op as its N per-param updates: every slot but the shared
-    LearningRate carries one entry per param."""
-    outs = {}
-    for i in range(len(ins["Param"])):
-        sub = {s: (v if s == "LearningRate" else [v[i]])
-               for s, v in ins.items() if v}
-        for k, o in lowering(sub, attrs, ctx).items():
-            outs.setdefault(k, []).extend(o)
-    return outs
-
-
-@register_op("fused_adam", differentiable=False)
-def _fused_adam(ins, attrs, ctx):
-    bucket = _kernel_bucket(ins, ctx)
-    if bucket is None:
-        return _per_param(_adam, ins, attrs, ctx)
-    from .pallas_kernels import fused_adam_tpu
-    ps, gs, lo = bucket
-    ms, vs = ins["Moment1"], ins["Moment2"]
-    b1ps, b2ps = ins["Beta1Pow"], ins["Beta2Pow"]
-    lr = _p(ins, "LearningRate").reshape(())
-    b1 = attrs.get("beta1", 0.9)
-    b2 = attrs.get("beta2", 0.999)
-    eps = attrs.get("epsilon", 1e-8)
-    # per-param bias-corrected lr, broadcast over each param's rows
-    lrt = jnp.concatenate(
-        [jnp.broadcast_to(
-            lr * jnp.sqrt(1 - p2.reshape(())) / (1 - p1.reshape(())),
-            (_rows_of(p), _LANES))
-         for p, p1, p2 in zip(ps, b1ps, b2ps)], axis=0)
-    f32 = jnp.float32
-    p_new, m_new, v_new = fused_adam_tpu(
-        _flat(ps, f32), _flat(gs, f32), _flat(ms, f32), _flat(vs, f32),
-        lrt.astype(f32), b1, b2, eps)
-    outs = {"Moment1Out": _unflat(m_new, ms),
-            "Moment2Out": _unflat(v_new, vs),
-            "Beta1PowOut": [(p1.reshape(()) * b1).reshape(1)
-                            for p1 in b1ps],
-            "Beta2PowOut": [(p2.reshape(()) * b2).reshape(1)
-                            for p2 in b2ps]}
-    return _bucket_param_outs(outs, lo, _unflat(p_new, ps))
-
-
-@register_op("fused_momentum", differentiable=False)
-def _fused_momentum(ins, attrs, ctx):
-    bucket = _kernel_bucket(ins, ctx)
-    if bucket is None:
-        return _per_param(_momentum, ins, attrs, ctx)
-    from .pallas_kernels import fused_momentum_tpu
-    ps, gs, lo = bucket
-    vs = ins["Velocity"]
-    rd = attrs.get("regularization_coeff", 0.0)
-    l2 = rd if attrs.get("regularization_method", "") == "l2_decay" else 0.0
-    f32 = jnp.float32
-    p_new, v_new = fused_momentum_tpu(
-        _flat(ps, f32), _flat(gs, f32), _flat(vs, f32),
-        _p(ins, "LearningRate").reshape(()), attrs.get("mu", 0.9),
-        attrs.get("use_nesterov", False), l2)
-    outs = {"VelocityOut": _unflat(v_new, vs)}
-    return _bucket_param_outs(outs, lo, _unflat(p_new, ps))
-
-
-@register_op("fused_lamb", differentiable=False)
-def _fused_lamb(ins, attrs, ctx):
-    """Bucketed LAMB: one op dispatch over the bucket.  The trust-ratio
-    norms are PER-PARAM reductions by definition, so the lowering keeps
-    per-param arrays on every backend."""
-    return _per_param(_lamb, ins, attrs, ctx)
-
-
-# ---------------------------------------------------------------------------
 # AMP dynamic loss scaling (operators/amp/*)
 # ---------------------------------------------------------------------------
 @register_op("check_finite_and_unscale", differentiable=False)

@@ -36,7 +36,6 @@ from jax.experimental.pallas.ops.tpu.flash_attention import (
 __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_dropout_add_tpu", "fused_act_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
-           "fused_adam_tpu", "fused_momentum_tpu",
            "paged_flash_attention_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
@@ -986,69 +985,3 @@ def embedding_pool_grad_tpu(g, ids, wgt, vocab):
             out_specs=pl.BlockSpec((vocab, d), lambda i, *_: (0, 0))),
         out_shape=jax.ShapeDtypeStruct((vocab, d), g.dtype),
     )(ids_f, wgt_f, g)
-
-
-# ---------------------------------------------------------------------------
-# bucketed optimizer updates: one elementwise kernel over a flattened
-# same-(dtype, family, PartitionSpec) parameter bucket (fuse_optimizer pass).
-# The math is element-for-element identical to the per-param update ops —
-# concatenation changes layout, never values — so the rewrite bit-compares
-# against N separate launches.  lr_t rides in as a per-element tensor
-# because Adam's bias correction is a per-PARAM scalar (each param owns its
-# beta-pow accumulators); broadcasting it outside the kernel keeps the
-# kernel a pure 5-in/3-out elementwise map.
-# ---------------------------------------------------------------------------
-
-def _fused_adam_kernel(p_ref, g_ref, m_ref, v_ref, lrt_ref,
-                       po_ref, mo_ref, vo_ref, *, beta1, beta2, eps):
-    g = g_ref[...]
-    m_new = beta1 * m_ref[...] + (1.0 - beta1) * g
-    v_new = beta2 * v_ref[...] + (1.0 - beta2) * jnp.square(g)
-    po_ref[...] = p_ref[...] - lrt_ref[...] * m_new / (jnp.sqrt(v_new) + eps)
-    mo_ref[...] = m_new
-    vo_ref[...] = v_new
-
-
-def fused_adam_tpu(p2d, g2d, m2d, v2d, lrt2d, beta1, beta2, eps):
-    """(p, m, v) updated over a padded [rows, lanes] bucket in one launch."""
-    m, n = p2d.shape
-    bm = _block_rows(m, 8 * n * p2d.dtype.itemsize)
-    spec = pl.BlockSpec((bm, n), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_fused_adam_kernel, beta1=float(beta1),
-                          beta2=float(beta2), eps=float(eps)),
-        grid=(pl.cdiv(m, bm),),
-        in_specs=[spec] * 5,
-        out_specs=[spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((m, n), p2d.dtype)] * 3,
-    )(p2d, g2d, m2d, v2d, lrt2d)
-
-
-def _fused_momentum_kernel(lr_ref, p_ref, g_ref, v_ref, po_ref, vo_ref, *,
-                           mu, use_nesterov, l2_decay):
-    g = g_ref[...]
-    p = p_ref[...]
-    if l2_decay:
-        g = g + p.dtype.type(l2_decay) * p
-    v_new = p.dtype.type(mu) * v_ref[...] + g
-    lr = lr_ref[0]
-    if use_nesterov:
-        po_ref[...] = p - lr * (g + p.dtype.type(mu) * v_new)
-    else:
-        po_ref[...] = p - lr * v_new
-    vo_ref[...] = v_new
-
-
-def fused_momentum_tpu(p2d, g2d, v2d, lr, mu, use_nesterov, l2_decay):
-    m, n = p2d.shape
-    bm = _block_rows(m, 5 * n * p2d.dtype.itemsize)
-    spec = pl.BlockSpec((bm, n), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_fused_momentum_kernel, mu=float(mu),
-                          use_nesterov=bool(use_nesterov),
-                          l2_decay=float(l2_decay)),
-        grid=(pl.cdiv(m, bm),),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [spec] * 3,
-        out_specs=[spec] * 2,
-        out_shape=[jax.ShapeDtypeStruct((m, n), p2d.dtype)] * 2,
-    )(lr.reshape(1).astype(p2d.dtype), p2d, g2d, v2d)

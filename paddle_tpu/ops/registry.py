@@ -100,10 +100,31 @@ class KernelSite:
         self.mesh, self.axis = mesh, axis
         self.shards = 1 if mesh is None else int(mesh.shape[axis])
 
+    @classmethod
+    def on(cls, mesh, x) -> Optional["KernelSite"]:
+        """The site, on a chip, of a kernel over the batch-major ``x`` in a
+        program compiled over ``mesh`` (a sharding plan's; None or one
+        device: not partitioned).  Direct where nothing is partitioned;
+        once per shard where the mesh shards activations on the batch alone
+        (``sharding.batch_shard_axis``: the ``dp`` and ``fsdp`` plans) and
+        the axis divides ``x``'s leading dim (a declared -1 is whatever
+        the feed brings); None, no kernel, on every other mesh.  The one
+        place that answers this: the lowerings ask it through
+        ``LoweringContext.kernel_site``, the ``fuse_attention`` pass with
+        the plan it is handed."""
+        if mesh is None or mesh.devices.size <= 1:
+            return cls()
+        from ..parallel.sharding import batch_shard_axis
+        axis = batch_shard_axis(mesh)
+        if axis is None or not x.shape \
+                or (x.shape[0] > 0 and x.shape[0] % mesh.shape[axis]):
+            return None
+        return cls(mesh, axis)
+
     def local(self, x):
         """What one call sees of the batch-major ``x``: its shape for the
         kernel's ``*_supported`` check."""
-        if self.shards == 1:
+        if self.shards == 1 or x.shape[0] < 0:
             return x
         import jax
         return jax.ShapeDtypeStruct(
@@ -128,6 +149,14 @@ class KernelSite:
             kernel, static, self.mesh, self.axis,
             tuple(batch_major or (True,) * len(operands)),
             key is not None)(key, *operands)
+
+
+def chip_site() -> Optional[KernelSite]:
+    """A direct site on the tpu backend, None (XLA) off it: for code that
+    already runs per chip (a ``shard_map`` body in parallel/) and has no
+    ``LoweringContext`` to ask."""
+    import jax
+    return KernelSite() if jax.default_backend() == "tpu" else None
 
 
 @functools.lru_cache(maxsize=64)
@@ -195,23 +224,18 @@ class LoweringContext:
     def kernel_site(self, x) -> Optional["KernelSite"]:
         """Where the Pallas kernel of an op over the batch-major ``x`` runs,
         read from what the context holds: called directly where
-        ``pallas_ok``; once per shard where the program is partitioned
-        over a mesh that shards activations on the batch alone
-        (``sharding.batch_shard_axis``: the ``dp`` and ``fsdp`` plans) and
-        the axis divides ``x``'s leading dim; None, no kernel here, in
-        every other program (``tp``, meshes with further axes,
-        ``wrap_with_mesh``), off the chip, and for an op whose primary
-        input is a parameter (its rows are not the batch)."""
+        ``pallas_ok``; on the tpu backend in a program partitioned over a
+        sharding plan's mesh, ``KernelSite.on(mesh, x)``'s answer; None,
+        no kernel here, off the chip, under ``wrap_with_mesh`` (no plan,
+        no mesh on the context) and for an op whose primary input is a
+        parameter (its rows are not the batch)."""
         if self.pallas_ok():
             return KernelSite()
         import jax
-        if jax.default_backend() != "tpu" or self.cur_op_batch_major is False:
+        if jax.default_backend() != "tpu" or self.mesh is None \
+                or self.cur_op_batch_major is False:
             return None
-        from ..parallel.sharding import batch_shard_axis
-        axis = batch_shard_axis(self.mesh)
-        if axis is None or not x.ndim or x.shape[0] % self.mesh.shape[axis]:
-            return None
-        return KernelSite(self.mesh, axis)
+        return KernelSite.on(self.mesh, x)
 
     def batch_mask(self, dim0):
         """Row-validity mask (bool[dim0]) when ``dim0`` is the bucketed

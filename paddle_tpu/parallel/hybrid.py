@@ -175,7 +175,8 @@ def _layer(x, lp, cfg: TransformerConfig, sp_live: bool, tp_live: bool):
                 f"unknown sp_mode {cfg.sp_mode!r}: use 'ring' or 'ulysses'")
     else:
         from ..ops.attention import flash_attention
-        a = flash_attention(q, k, v, causal=cfg.causal)
+        from ..ops.registry import chip_site
+        a = flash_attention(q, k, v, causal=cfg.causal, site=chip_site())
     o = jnp.einsum("bhte,hed->btd", a, lp["wo"])
     if tp_live:
         o = lax.psum(o, "tp")            # row-parallel proj (c_allreduce_sum)

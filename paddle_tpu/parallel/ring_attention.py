@@ -93,4 +93,6 @@ def local_or_ring_attention(q, k, v, axis_name=None, causal=False, scale=None,
     if axis_name is not None:
         return ring_attention(q, k, v, axis_name, causal=causal, scale=scale)
     from ..ops.attention import flash_attention
-    return flash_attention(q, k, v, mask=mask, scale=scale, causal=causal)
+    from ..ops.registry import chip_site
+    return flash_attention(q, k, v, mask=mask, scale=scale, causal=causal,
+                           site=chip_site())

@@ -39,7 +39,9 @@ def _default_attention(q, k, v, scale, causal):
     # profitable, XLA-fused reference attention otherwise — this is what
     # makes Ulysses kernel-agnostic for free
     from ..ops.attention import flash_attention
-    return flash_attention(q, k, v, scale=scale, causal=causal)
+    from ..ops.registry import chip_site
+    return flash_attention(q, k, v, scale=scale, causal=causal,
+                           site=chip_site())
 
 
 def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,

@@ -24,7 +24,7 @@ def tune_env(tmp_path):
               # the flag-kind knobs a committed winner writes: which
               # candidate wins is a timing question, and a leaked
               # max_inflight_steps=4 fails test_checkpoint_elastic
-              "max_inflight_steps", "pallas_min_seq")}
+              "max_inflight_steps",)}
     core._FLAGS.update({"auto_tune": False,
                         "auto_tune_dir": str(tmp_path),
                         "auto_tune_probe_steps": 2,

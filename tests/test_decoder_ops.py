@@ -190,13 +190,16 @@ def test_window_needs_causal_and_no_mask():
     (1024, 64, 12, 12, 0, False, "flash_kernel"),
 ])
 def test_attention_path(seq, d, heads, kv_heads, window, causal, want):
-    from paddle_tpu.ops.attention import attention_path
+    from paddle_tpu.ops.attention import attention_path, path_at
+    from paddle_tpu.ops.registry import KernelSite
     q = jax.ShapeDtypeStruct((1, heads, seq, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((1, kv_heads, seq, d), jnp.bfloat16)
-    assert attention_path(q, k, k, None, causal, False, True,
+    assert attention_path(q, k, k, None, causal, False,
                           window=window) == want
-    assert attention_path(q, k, k, None, causal, False, False,
-                          window=window) == "xla"
+    assert path_at(KernelSite(), q, k, k, None, causal, False,
+                   window=window) == want
+    assert path_at(None, q, k, k, None, causal, False,
+                   window=window) == "xla"
 
 
 def test_lowering_counters_name_the_mask():
@@ -207,9 +210,9 @@ def test_lowering_counters_name_the_mask():
         return trace.metrics().counter("attention.lowering." + name).value
     before = {n: count(n) for n in ("xla", "xla.window", "xla.full_causal")}
     q = _rand(1, 2, 8, 16)
-    flash_attention(q, q, q, causal=True, window=4, use_pallas=False)
-    flash_attention(q, q, q, causal=True, use_pallas=False)
-    flash_attention(q, q, q, use_pallas=False)
+    flash_attention(q, q, q, causal=True, window=4)
+    flash_attention(q, q, q, causal=True)
+    flash_attention(q, q, q)
     assert count("xla") - before["xla"] == 3
     assert count("xla.window") - before["xla.window"] == 1
     assert count("xla.full_causal") - before["xla.full_causal"] == 1

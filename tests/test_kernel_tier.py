@@ -1,9 +1,8 @@
 """Pallas kernel tier as compiler passes (fluid/passes/kernel_tier.py):
-fuse_attention / fuse_sparse_embedding / fuse_optimizer pattern-rewrites,
-their negative cases (patterns must NOT fire), fused-optimizer numerics
-bit-compared against per-param updates (incl. bf16 multi_precision
-masters and sharded bucket grouping), and the kernel-tier satellites
-(FLAGS_pallas_min_seq knob, additive-bias mask dispatch, interpret-mode
+fuse_attention / fuse_sparse_embedding / fuse_paged_attention
+pattern-rewrites, their negative cases (patterns must NOT fire), where a
+kernel runs (the pass and the lowering ask the same two functions), and
+the kernel-tier satellites (additive-bias mask dispatch, interpret-mode
 kernel numerics)."""
 import functools
 
@@ -25,8 +24,14 @@ from paddle_tpu.models.static_graphs import (
 
 @pytest.fixture(autouse=True)
 def _fresh_names():
+    # a sharding plan installs its mesh as the process's: every test's
+    # default mesh must be its own
+    from paddle_tpu.parallel import mesh as mesh_registry
+    prev = mesh_registry.current_mesh()
+    mesh_registry.set_current_mesh(None)
     reset_unique_name()
     yield
+    mesh_registry.set_current_mesh(prev)
 
 
 def _counter(name):
@@ -64,6 +69,11 @@ def _op_types(program):
 # fuse_attention — positive
 # ---------------------------------------------------------------------------
 
+# the pass rewrites a chain where its kernel runs: a length and a head
+# width the fused attention kernel covers (S = 128, two heads of 64)
+_COVERED = dict(hidden=128, heads=2, seq=128)
+
+
 class TestFuseAttention:
     @pytest.mark.parametrize("dropout,with_mask", [
         (0.0, True), (0.1, True), (0.0, False), (0.1, False)])
@@ -72,14 +82,14 @@ class TestFuseAttention:
         trajectory is bit-identical on the CPU fallback — the absorbed
         dropout regenerates the same mask from the same op seed."""
         rng = np.random.RandomState(0)
-        feed = bert_demo_feed(rng, with_mask=with_mask)
-        kw = dict(layers=2, dropout=dropout, with_mask=with_mask)
-        l_off, p_off = _train(*build_bert_train_program(**kw), feed)
+        feed = bert_demo_feed(rng, batch=4, seq=_COVERED["seq"],
+                              with_mask=with_mask)
+        kw = dict(_COVERED, layers=2, dropout=dropout, with_mask=with_mask)
+        l_off, p_off = _train(*build_bert_train_program(**kw), feed, n=4)
         reset_unique_name()
         r0 = _counter("kernel_tier.fuse_attention.rewrites")
         m, s, loss = build_bert_train_program(**kw)
-        l_on, p_on = _train(m, s, loss, feed,
-                            build=_tier_bs(fuse_attention=True))
+        l_on, p_on = _train(m, s, loss, feed, n=4, build=_tier_bs())
         assert _counter("kernel_tier.fuse_attention.rewrites") - r0 == 2
         types = _op_types(m)
         assert types.count("fused_multihead_attention") == 2
@@ -93,13 +103,13 @@ class TestFuseAttention:
         fwd-only rules."""
         m, s = fluid.Program(), fluid.Program()
         with fluid.program_guard(m, s):
-            ids = fluid.data("ids", [-1, 8], dtype="int64")
-            h = L.embedding(ids, size=[32, 16])
+            ids = fluid.data("ids", [-1, 128], dtype="int64")
+            h = L.embedding(ids, size=[32, 128])
             from paddle_tpu.models.static_graphs import _naive_attention
-            h = _naive_attention(h, 16, 2)
+            h = _naive_attention(h, 128, 2)
             out = L.reduce_mean(h, dim=1)
         rng = np.random.RandomState(1)
-        feed = {"ids": rng.randint(0, 32, (4, 8)).astype("int64")}
+        feed = {"ids": rng.randint(0, 32, (4, 128)).astype("int64")}
         ex = fluid.Executor()
         with scope_guard(Scope()):
             ex.run(s)
@@ -111,7 +121,7 @@ class TestFuseAttention:
         assert np.array_equal(np.asarray(want), np.asarray(got))
 
     def test_rewrite_is_idempotent(self):
-        m, s, loss = build_bert_train_program(layers=1)
+        m, s, loss = build_bert_train_program(layers=1, **_COVERED)
         pipe = PassPipeline([create_pass("fuse_attention")])
         stats1 = pipe.apply(m, targets=[loss.name])
         assert stats1["fuse_attention"]["ops_fused"] == 1
@@ -123,12 +133,12 @@ class TestFuseAttention:
 
     def test_fused_op_carries_scale_and_dropout_attrs(self):
         m, s, loss = build_bert_train_program(layers=1, dropout=0.25,
-                                              hidden=32, heads=4)
+                                              hidden=128, heads=4, seq=128)
         PassPipeline([create_pass("fuse_attention")]).apply(
             m, targets=[loss.name])
         op = next(o for o in m.global_block().ops
                   if o.type == "fused_multihead_attention")
-        assert op.attrs["scale"] == pytest.approx((32 // 4) ** -0.5)
+        assert op.attrs["scale"] == pytest.approx((128 // 4) ** -0.5)
         assert op.attrs["dropout_rate"] == pytest.approx(0.25)
         assert op.attrs["dropout_seed"] > 0
         assert "Mask" in op.inputs
@@ -138,7 +148,8 @@ class TestFuseAttention:
 # fuse_attention — the patterns must NOT fire
 # ---------------------------------------------------------------------------
 
-def _qkv_data(seq=8, heads=2, dh=8):
+def _qkv_data(seq=128, heads=2, dh=64):
+    # a shape the kernel covers: a chain declines for the reason tested
     q = fluid.data("q", [-1, heads, seq, dh])
     k = fluid.data("k", [-1, heads, seq, dh])
     v = fluid.data("v", [-1, heads, seq, dh])
@@ -176,6 +187,17 @@ class TestFuseAttentionNegative:
             m, targets=[out.name])
         assert stats["fuse_attention"].get("ops_fused", 0) == 0
 
+    def test_the_same_chain_rewrites_when_nothing_else_reads_it(self):
+        """The control for the cases around it: at this shape the plain
+        chain is rewritten, so each of them declines for its own reason."""
+        m, s = fluid.Program(), fluid.Program()
+        with fluid.program_guard(m, s):
+            q, k, v = _qkv_data()
+            out = L.matmul(L.softmax(L.matmul(q, k, transpose_y=True)), v)
+        stats = PassPipeline([create_pass("fuse_attention")]).apply(
+            m, targets=[out.name])
+        assert stats["fuse_attention"]["ops_fused"] == 1
+
     def test_fetched_probability_tensor_declines(self):
         """Fetching the softmax output keeps it protected: no rewrite."""
         m, s = fluid.Program(), fluid.Program()
@@ -194,18 +216,19 @@ class TestFuseAttentionNegative:
 # ---------------------------------------------------------------------------
 
 def _default_pipeline(main, loss, **fields):
-    """What CompiledProgram would run with only ``fields`` set; returns
-    the rewrites counted."""
-    from paddle_tpu.fluid.passes import passes_for_build_strategy
-    bs = _tier_bs(**fields)
+    """What CompiledProgram runs with only ``fields`` set (its plan, then
+    its passes, as ``Executor.run`` asks for them); returns the rewrites
+    counted."""
+    prog = fluid.CompiledProgram(main, build_strategy=_tier_bs(**fields))
     r0 = _counter("kernel_tier.fuse_attention.rewrites")
-    PassPipeline(passes_for_build_strategy(bs)).apply(
-        main, targets=[loss.name], build_strategy=bs)
+    prog._ensure_sharding_plan()
+    prog._apply_ir_passes([loss.name])
     return _counter("kernel_tier.fuse_attention.rewrites") - r0
 
 
 # a BERT layer at a length and head width the fused kernel covers
 _KERNEL_BERT = dict(hidden=128, heads=2, seq=512, layers=1, dropout=0.1)
+_CUSTOM_RULES = [(r".*", ())]
 
 
 class TestFuseAttentionByDefault:
@@ -222,9 +245,9 @@ class TestFuseAttentionByDefault:
 
     @pytest.mark.parametrize("why, model, fields", [
         ("no kernel under tensor parallelism", _KERNEL_BERT,
-         {"sharding": "tp"}),
-        ("no kernel under custom rules", _KERNEL_BERT,
-         {"sharding": [(r".*", ())]}),
+         {"sharding": "tp", "sharding_mesh": {"tp": 2}}),
+        ("no kernel on a mesh with a further axis", _KERNEL_BERT,
+         {"sharding": "dp", "sharding_mesh": {"dp": 2, "tp": 2}}),
         ("no kernel at this length", dict(_KERNEL_BERT, seq=16), {}),
         ("no kernel for this head width",
          dict(_KERNEL_BERT, hidden=48, heads=2), {}),
@@ -233,26 +256,25 @@ class TestFuseAttentionByDefault:
         m, _, loss = build_bert_train_program(**model)
         before = _op_types(m)
         assert _default_pipeline(m, loss, **fields) == 0
-        assert _op_types(m) == before
+        assert [t for t in _op_types(m) if t != "shard_constraint"] \
+            == before
 
-    def test_the_field_still_means_on(self):
-        """BuildStrategy.fuse_attention rewrites every chain, whatever
-        its lowering will be."""
-        m, _, loss = build_bert_train_program(**dict(_KERNEL_BERT, seq=16))
-        assert _default_pipeline(m, loss, fuse_attention=True) == 1
-
-    @pytest.mark.parametrize("sharding, want", [
-        (None, {"where_kernel_runs": True}),
-        ("dp", {"where_kernel_runs": True}),
-        ("FSDP", {"where_kernel_runs": True}),
-        ("tp", None), ([(r".*", ())], None)])
-    def test_which_pipelines_hold_the_pass(self, sharding, want):
+    @pytest.mark.parametrize("sharding, mesh, rewrites", [
+        (None, None, 1), ("dp", None, 1), ("FSDP", None, 1),
+        ("tp", {"tp": 2}, 0),
+        # custom rules on the default one-axis mesh: the mesh shards the
+        # batch alone, so the chain follows the dropout ops beside it
+        (_CUSTOM_RULES, None, 1)])
+    def test_which_pipelines_hold_the_pass(self, sharding, mesh, rewrites):
+        """Every pipeline holds the pass, once and without options; what
+        it does under a sharding is the mesh's answer."""
         from paddle_tpu.fluid.passes import passes_for_build_strategy
-        found = [p for p in passes_for_build_strategy(
-            _tier_bs(sharding=sharding)) if p.name == "fuse_attention"]
-        assert len(found) == (want is not None)
-        if want:
-            assert found[0].where_kernel_runs is want["where_kernel_runs"]
+        fields = dict(sharding=sharding, sharding_mesh=mesh)
+        found = [p for p in passes_for_build_strategy(_tier_bs(**fields))
+                 if p.name == "fuse_attention"]
+        assert len(found) == 1
+        m, _, loss = build_bert_train_program(**_KERNEL_BERT)
+        assert _default_pipeline(m, loss, **fields) == rewrites
 
     @pytest.mark.parametrize("axes, batch, want", [
         ({"dp": 4}, -1, 1),       # an undeclared batch is not judged
@@ -278,6 +300,63 @@ class TestFuseAttentionByDefault:
         assert FuseAttentionPass._kernel_runs(m, None) is True
 
 
+def _attention_chain(batch, seq, heads=2, dh=64):
+    """matmul -> scale -> softmax -> matmul over declared [B, H, S, D]."""
+    m, s = fluid.Program(), fluid.Program()
+    with fluid.program_guard(m, s):
+        q, k, v = (fluid.data(n, [batch, heads, seq, dh]) for n in "qkv")
+        sc = L.scale(L.matmul(q, k, transpose_y=True), scale=dh ** -0.5)
+        out = L.matmul(L.softmax(sc), v)
+    return m, out
+
+
+class TestTheJudgesAgree:
+    """The pass rewrites a chain exactly where the lowering of the fused
+    op would take a kernel: both ask ``KernelSite.on`` and ``path_at``."""
+
+    @pytest.mark.parametrize("axes, batch, seq, want", [
+        (None, 8, 128, True),
+        (None, 8, 16, False),             # no kernel at this length
+        (None, 8, 1024, True),            # jax's flash kernel, called directly
+        ({"dp": 4}, 8, 128, True),        # 2 rows a chip, once per shard
+        ({"dp": 4}, 6, 128, False),       # the axis does not divide the batch
+        ({"dp": 4}, 8, 1024, False),      # only the fused kernel per shard
+        ({"dp": 2, "tp": 1}, 8, 512, True),
+        ({"dp": 2, "tp": 2}, 8, 128, False),
+        ({"tp": 4}, 8, 128, False),
+        ("custom rules", 8, 128, True),   # the default one-axis mesh
+    ])
+    def test_pass_and_lowering(self, monkeypatch, axes, batch, seq, want):
+        from types import SimpleNamespace as NS
+        from jax.sharding import Mesh
+        from paddle_tpu.ops.attention import path_at
+        from paddle_tpu.ops.registry import LoweringContext
+        from paddle_tpu.parallel import sharding as shd
+        m, out = _attention_chain(batch, seq)
+        if axes == "custom rules":
+            mesh = shd.build_plan(m, mode=_CUSTOM_RULES).mesh
+            assert len(mesh.axis_names) == 1 and mesh.devices.size > 1
+        elif axes is not None:
+            n = int(np.prod(list(axes.values())))
+            mesh = Mesh(np.array(jax.devices()[:n]).reshape(
+                tuple(axes.values())), tuple(axes))
+        else:
+            mesh = None
+        stats = PassPipeline([create_pass("fuse_attention")]).apply(
+            m, targets=[out.name],
+            sharding_plan=None if mesh is None else NS(mesh=mesh))
+        rewritten = stats["fuse_attention"].get("ops_fused", 0) == 1
+        # the lowering's side, on a chip, as fluid/executor.py sets it up
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        ctx = LoweringContext()
+        ctx.mesh = mesh
+        ctx.partitioned = mesh is not None and mesh.devices.size > 1
+        q = _sds(batch, 2, seq, 64, dtype=jnp.float32)
+        takes_a_kernel = path_at(ctx.kernel_site(q), q, q, q, None, False,
+                                 False) != "xla"
+        assert rewritten == takes_a_kernel == want
+
+
 def _sds(*shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -288,8 +367,9 @@ def _lowering_counts():
 
 
 class TestAttentionDispatch:
-    """``flash_attention`` picks its path from shapes, dtypes and flags
-    alone, and counts the pick; traced abstractly, so no kernel runs."""
+    """``flash_attention`` picks its path from its site and from shapes
+    and dtypes alone, and counts the pick; traced abstractly, so no
+    kernel runs."""
 
     @pytest.mark.parametrize("seq, d, bias, drop, on_chip, want", [
         (512, 64, "row", True, True, "fused_kernel"),
@@ -298,38 +378,56 @@ class TestAttentionDispatch:
         (128, 64, "row", True, True, "fused_kernel"),
         (512, 64, "full", False, True, "xla"),     # a [B,H,S,S] bias
         (512, 64, "full", True, True, "xla"),
+        (640, 64, "row", False, True, "xla"),      # past the fused kernel,
+        (896, 64, None, False, True, "xla"),       # short of the streamed
         (1024, 64, "row", False, True, "flash_kernel"),
         (1024, 64, "full", False, True, "flash_kernel"),
         (1024, 128, "row", True, True, "xla"),     # flash has no dropout
-        (512, 64, "row", True, False, "xla"),      # partitioned, or the CPU
+        (512, 64, "row", True, False, "xla"),      # no site: the CPU, tp
         (1024, 64, None, False, False, "xla"),
     ])
     def test_path_and_counter(self, seq, d, bias, drop, on_chip, want):
         import functools
         from paddle_tpu.ops import attention
+        from paddle_tpu.ops.registry import KernelSite
         b, h = 2, 4
         q = _sds(b, h, seq, d)
         mask = {None: None, "row": _sds(b, 1, 1, seq, dtype=jnp.float32),
                 "full": _sds(b, h, seq, seq, dtype=jnp.float32)}[bias]
-        assert attention.attention_path(q, q, q, mask, False, drop,
-                                        on_chip) == want
+        site = KernelSite() if on_chip else None
+        assert attention.path_at(site, q, q, q, mask, False, drop) == want
+        if on_chip:
+            assert attention.attention_path(q, q, q, mask, False,
+                                            drop) == want
         before = _lowering_counts()
         f = functools.partial(
             attention.flash_attention, dropout_rate=0.1 if drop else 0.0,
-            dropout_key=jax.random.PRNGKey(0) if drop else None,
-            use_pallas=on_chip)
+            dropout_key=jax.random.PRNGKey(0) if drop else None, site=site)
         out = jax.eval_shape(f, q, q, q, mask)
         assert (out.shape, out.dtype) == (q.shape, q.dtype)
         after = _lowering_counts()
         assert {p: after[p] - before[p] for p in after} \
             == {p: int(p == want) for p in after}
 
+    @pytest.mark.parametrize("seq, kv_heads, window, want", [
+        (512, 8, 0, "xla"),                # causal, short of the constant
+        (896, 8, 0, "xla"),
+        (1024, 8, 0, "splash_kernel"),     # at the constant
+        (512, 8, 128, "splash_kernel"),    # a window: at every length
+        (512, 2, 0, "splash_kernel"),      # grouped heads: likewise
+    ])
+    def test_where_causal_attention_starts_to_stream(self, seq, kv_heads,
+                                                     window, want):
+        from paddle_tpu.ops.attention import attention_path
+        q, k = _sds(1, 8, seq, 128), _sds(1, kv_heads, seq, 128)
+        assert attention_path(q, k, k, None, True, False, window) == want
+
     def test_causal_and_unaligned_lengths_stay_off_the_fused_kernel(self):
         from paddle_tpu.ops.attention import attention_path
         q = _sds(2, 4, 512, 64)
-        assert attention_path(q, q, q, None, True, False, True) == "xla"
+        assert attention_path(q, q, q, None, True, False) == "xla"
         q = _sds(2, 4, 200, 64)
-        assert attention_path(q, q, q, None, False, False, True) == "xla"
+        assert attention_path(q, q, q, None, False, False) == "xla"
 
     def test_the_op_lowering_asks_the_context(self, monkeypatch):
         """Inside a partitioned program the op takes XLA on a TPU too."""
@@ -510,6 +608,7 @@ class TestFusedAttentionKernel:
         from paddle_tpu.ops.attention import flash_attention
         from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
                                                      mosaic_call_count)
+        from paddle_tpu.ops.registry import KernelSite
         q = _sds(b, h, s, d)
         bias = _sds(b, 1, 1, s, dtype=jnp.float32)
         key = jax.random.PRNGKey(0)
@@ -517,7 +616,7 @@ class TestFusedAttentionKernel:
         def step(q, k, v, bias, key, w):
             attend = functools.partial(
                 flash_attention, mask=bias, dropout_rate=0.1,
-                dropout_key=key, use_pallas=True)
+                dropout_key=key, site=KernelSite())
             return _fwd_and_grads(attend, q, k, v, w)
         compiled = compile_for_tpu(step, q, q, q, bias, key, q)
         assert mosaic_call_count(compiled) == 2
@@ -683,13 +782,14 @@ class TestKernelsPerShard:
         sequence long enough for the splash kernel stays on XLA in a
         partitioned program."""
         from paddle_tpu.ops.attention import flash_attention
+        from paddle_tpu.ops.registry import KernelSite
         q = _sds(4, 4, 1024, 128)
         for site, want in ((ctx.kernel_site(q), "xla"),
-                           (None, "splash_kernel")):
+                           (KernelSite(), "splash_kernel")):
             before = _counter(f"attention.lowering.{want}")
             jax.eval_shape(
                 lambda q: flash_attention(q, q, q, causal=True,
-                                          use_pallas=True, site=site), q)
+                                          site=site), q)
             assert _counter(f"attention.lowering.{want}") - before == 1
 
 
@@ -921,7 +1021,7 @@ class TestFuseSparseEmbedding:
 
 
 # ---------------------------------------------------------------------------
-# fuse_optimizer — numerics bit-compared against per-param updates
+# what went with the switches, the pipeline's order, and satellites
 # ---------------------------------------------------------------------------
 
 def _mlp(optimizer):
@@ -937,194 +1037,57 @@ def _mlp(optimizer):
     return m, s, loss
 
 
-_OPTS = {
-    "adam": lambda: fluid.optimizer.AdamOptimizer(1e-2),
-    "momentum": lambda: fluid.optimizer.MomentumOptimizer(0.05, 0.9),
-    "nesterov": lambda: fluid.optimizer.MomentumOptimizer(
-        0.05, 0.9, use_nesterov=True),
-    "lamb": lambda: fluid.optimizer.LambOptimizer(1e-2),
-}
+class TestNoSwitchDecidesWhereAKernelRuns:
+    @pytest.mark.parametrize("field", ["fuse_attention", "kernel_tier",
+                                       "fuse_optimizer"])
+    def test_build_strategy_has_no_such_field(self, field):
+        assert not hasattr(fluid.BuildStrategy(), field)
 
+    def test_no_crossover_flag(self):
+        assert not [k for k in fluid.core._FLAGS if k.startswith("pallas")]
 
-class TestFuseOptimizer:
-    @pytest.mark.parametrize("opt", sorted(_OPTS))
-    def test_bucketed_update_bit_identical(self, opt):
-        rng = np.random.RandomState(0)
-        feed = {"x": rng.randn(8, 16).astype("float32"),
-                "y": rng.randint(0, 10, (8, 1)).astype("int64")}
-        l_off, p_off = _train(*_mlp(_OPTS[opt]), feed, n=8)
-        reset_unique_name()
-        m, s, loss = _mlp(_OPTS[opt])
-        l_on, p_on = _train(m, s, loss, feed, n=8,
-                            build=_tier_bs(fuse_optimizer=True))
-        types = _op_types(m)
-        fused_type = {"adam": "fused_adam", "momentum": "fused_momentum",
-                      "nesterov": "fused_momentum",
-                      "lamb": "fused_lamb"}[opt]
-        assert types.count(fused_type) == 1
-        assert not any(t in types for t in ("adam", "momentum", "lamb"))
-        assert l_on == l_off
-        for name in p_off:
-            assert np.array_equal(p_off[name], p_on[name]), name
-
-    def test_bf16_multi_precision_masters_bit_identical(self):
-        """A bucket of bf16 params with fp32 masters: the fused update
-        computes on the masters and writes back bit-identical masters +
-        bf16 views."""
-        def build():
-            m, s = fluid.Program(), fluid.Program()
-            with fluid.program_guard(m, s):
-                x = fluid.data("x", [-1, 4])
-                gb = m.global_block()
-                for nm in ("Wa_lo", "Wb_lo"):
-                    gb.create_parameter(nm, [4, 4], dtype="bfloat16")
-                    sb = s.global_block()
-                    sb.create_var(name=nm, shape=[4, 4], dtype="bfloat16",
-                                  persistable=True)
-                    sb.append_op("fill_constant", outputs={"Out": [nm]},
-                                 attrs={"shape": [4, 4],
-                                        "dtype": "bfloat16", "value": 1.0})
-                h = L.matmul(x, gb.vars["Wa_lo"])
-                h = L.matmul(h, gb.vars["Wb_lo"])
-                loss = L.mean(h)
-                fluid.optimizer.AdamOptimizer(
-                    1e-3, multi_precision=True,
-                    parameter_list=[gb.vars["Wa_lo"],
-                                    gb.vars["Wb_lo"]]).minimize(loss)
-            return m, s, loss
-
-        feed = {"x": np.ones((2, 4), "float32")}
-
-        def run(fuse):
-            reset_unique_name()
-            m, s, loss = build()
-            ex = fluid.Executor()
-            with scope_guard(Scope()):
-                ex.run(s)
-                prog = m
-                if fuse:
-                    prog = fluid.CompiledProgram(
-                        m, build_strategy=_tier_bs(fuse_optimizer=True))
-                for _ in range(20):
-                    ex.run(prog, feed=feed, fetch_list=[loss])
-                scope = fluid.global_scope()
-                state = {n: np.asarray(scope.find_var(n)).view(np.uint16)
-                         if "lo" in n else np.asarray(scope.find_var(n))
-                         for n in m.global_block().vars
-                         if "master_weight" in n or n.endswith("_lo")}
-            return m, state
-
-        m_off, st_off = run(False)
-        m_on, st_on = run(True)
-        op = next(o for o in m_on.global_block().ops
-                  if o.type == "fused_adam")
-        assert len(op.inputs["MasterParam"]) == 2
-        assert st_off and sorted(st_off) == sorted(st_on)
-        for name in st_off:
-            assert np.array_equal(st_off[name], st_on[name]), name
-
-    def test_sharded_bucket_grouping_by_partition_spec(self):
-        """Under a PR-10 plan, params with different PartitionSpecs must
-        never share a bucket — the whole-step pjit path would otherwise
-        pay a reshard inside the fused op."""
-        from jax.sharding import PartitionSpec as P
-        from paddle_tpu.parallel import mesh as mesh_registry
-        from paddle_tpu.parallel.sharding import ShardingPlan
-        m, s, loss = _mlp(_OPTS["adam"])
-        mesh = mesh_registry.build_mesh({"dp": 1},
-                                        devices=jax.devices()[:1])
-        # adam op order is b_0, b_1, b_2, w_0, w_1, w_2; w_0 gets its own
-        # spec, so the weights' run splits [w_0] | [w_1, w_2]
-        plan = ShardingPlan(
-            mesh, [(r"w_0$", P("dp")), (r".*", P())],
-            param_names=[p.name for p in m.all_parameters()])
-        assert _op_types(m).count("adam") == 6
-        pipe = PassPipeline([create_pass("fuse_optimizer")])
-        pipe.apply(m, targets=[loss.name], sharding_plan=plan)
-        types = _op_types(m)
-        # bias bucket + [w_1, w_2] bucket; w_0 stays per-param (a bucket
-        # of one is no bucket)
-        assert types.count("fused_adam") == 2
-        assert types.count("adam") == 1
-        bare = next(o for o in m.global_block().ops if o.type == "adam")
-        assert bare.inputs["Param"] == ["fc.w_0"]
-        fused = [o for o in m.global_block().ops
-                 if o.type == "fused_adam"]
-        groups = [sorted(o.inputs["Param"]) for o in fused]
-        assert ["fc.b_0", "fc.b_1", "fc.b_2"] in groups
-        assert ["fc.w_1", "fc.w_2"] in groups
-
-    def test_mixed_family_runs_split(self):
-        """Adjacent adam ops with different attrs (two optimizers) never
-        share a bucket."""
-        m, s = fluid.Program(), fluid.Program()
-        with fluid.program_guard(m, s):
-            x = fluid.data("x", [-1, 8])
-            h = L.fc(x, 8)
-            logits = L.fc(h, 4)
-            loss = L.mean(logits)
-            pg = fluid.backward.append_backward(loss)
-            opt1 = fluid.optimizer.AdamOptimizer(1e-2)
-            opt2 = fluid.optimizer.AdamOptimizer(5e-3, beta1=0.8)
-            half = len(pg) // 2
-            opt1.apply_gradients(pg[:half])
-            opt2.apply_gradients(pg[half:])
-        pipe = PassPipeline([create_pass("fuse_optimizer")])
-        pipe.apply(m, targets=[loss.name])
-        types = _op_types(m)
-        # each optimizer's run buckets separately (2 params each)
-        assert types.count("fused_adam") == 2
-
-
-# ---------------------------------------------------------------------------
-# kernel-tier umbrella + satellites
-# ---------------------------------------------------------------------------
-
-class TestKernelTierUmbrella:
-    def test_umbrella_knob_enables_all_three(self):
-        bs = _tier_bs(kernel_tier=True)
+    @pytest.mark.parametrize("optimizer", [
+        lambda: fluid.optimizer.AdamOptimizer(1e-2),
+        lambda: fluid.optimizer.MomentumOptimizer(0.05, 0.9)],
+        ids=["adam", "momentum"])
+    def test_fuse_all_optimizer_ops_is_a_parity_field(self, optimizer):
+        """Upstream scripts set it; it selects no pass and the program
+        stays op for op (XLA fuses each update into its dW matmul)."""
         from paddle_tpu.fluid.passes import passes_for_build_strategy
-        names = [p.name for p in passes_for_build_strategy(bs)]
-        assert names == ["fuse_attention", "fuse_paged_attention",
-                         "fuse_sparse_embedding", "fuse_optimizer"]
+        bs = _tier_bs(fuse_all_optimizer_ops=True)
+        assert [p.name for p in passes_for_build_strategy(bs)] \
+            == ["fuse_attention"]
+        m, _, loss = _mlp(optimizer)
+        before = _op_types(m)
+        assert _default_pipeline(m, loss, fuse_all_optimizer_ops=True) == 0
+        assert _op_types(m) == before
 
     def test_canonical_order_with_amp(self):
-        bs = _tier_bs(kernel_tier=True, amp=True, enable_dce=True,
+        bs = _tier_bs(fuse_sparse_embedding=True, amp=True, enable_dce=True,
                       fuse_elewise_add_act_ops=True)
         from paddle_tpu.fluid.passes import passes_for_build_strategy
         names = [p.name for p in passes_for_build_strategy(bs)]
         assert names.index("fuse_elewise_add_act") \
-            < names.index("fuse_attention") < names.index("amp_bf16") \
-            < names.index("dce")
+            < names.index("fuse_attention") \
+            < names.index("fuse_sparse_embedding") \
+            < names.index("amp_bf16") < names.index("dce")
 
-    def test_legacy_fuse_all_optimizer_ops_alias(self):
-        bs = _tier_bs(fuse_all_optimizer_ops=True)
-        from paddle_tpu.fluid.passes import passes_for_build_strategy
-        assert [p.name for p in passes_for_build_strategy(bs)] \
-            == ["fuse_attention", "fuse_optimizer"]
 
-    def test_ops_per_step_drops_under_tier(self):
-        rng = np.random.RandomState(0)
-        feed = bert_demo_feed(rng)
-        _, _ = _train(*build_bert_train_program(), feed, n=1)
-        off = trace.metrics().gauge("executor.ops_per_step").value
-        reset_unique_name()
-        m, s, loss = build_bert_train_program()
-        _train(m, s, loss, feed, n=1, build=_tier_bs(kernel_tier=True))
-        on = trace.metrics().gauge("executor.ops_per_step").value
-        assert on < off
+def test_the_demo_programs_mask_is_berts_bias_row():
+    """(m - 1) * 10000: 0 where attended, -10000 where padded."""
+    m, s, _ = build_bert_train_program(layers=1)
+    scale = next(o for o in m.global_block().ops if o.type == "scale")
+    feed = bert_demo_feed(np.random.RandomState(0))
+    with scope_guard(Scope()):
+        ex = fluid.Executor()
+        ex.run(s)
+        bias, = ex.run(m, feed=feed, fetch_list=[scale.outputs["Out"][0]])
+    want = np.where(feed["attn_mask"] > 0, 0.0, -10000.0)
+    assert np.array_equal(np.asarray(bias)[:, 0, 0, :], want)
+    assert (want == -10000.0).any() and (want == 0.0).any()
 
 
 class TestSatellites:
-    def test_pallas_min_seq_flag(self):
-        from paddle_tpu.ops.attention import _pallas_min_seq
-        assert _pallas_min_seq() == 1024          # documented default
-        fluid.core.set_flags({"FLAGS_pallas_min_seq": 256})
-        try:
-            assert _pallas_min_seq() == 256
-        finally:
-            fluid.core.set_flags({"FLAGS_pallas_min_seq": 1024})
-
     def test_bias_broadcastable_gate(self):
         from paddle_tpu.ops.attention import _bias_broadcastable
         q = jnp.zeros((2, 4, 16, 8))
@@ -1163,27 +1126,18 @@ class TestSatellites:
                                    rtol=1e-5, atol=1e-5)
 
     def test_new_kernels_pass_mosaic_preflight(self):
-        """Every pallas_call in the fused embedding/optimizer kernels
-        compiles through Mosaic offline."""
-        import functools
+        """Every pallas_call in the fused embedding kernels compiles
+        through Mosaic offline."""
         from paddle_tpu.ops import pallas_kernels as pk
         from paddle_tpu.ops.pallas_preflight import assert_mosaic_lowerable
         w = jnp.zeros((64, 128), jnp.float32)
         ids = jnp.zeros((2, 4), jnp.int32)
         wgt = jnp.ones((2, 4), jnp.float32)
         g = jnp.zeros((2, 128), jnp.float32)
-        p = jnp.zeros((1000, 1024), jnp.float32)   # ragged last row block
         assert_mosaic_lowerable(pk.fused_embedding_pool_tpu, w, ids, wgt)
         assert_mosaic_lowerable(
             lambda g_, i_, w_: pk.embedding_pool_grad_tpu(g_, i_, w_, 64),
             g, ids, wgt)
-        assert_mosaic_lowerable(
-            functools.partial(pk.fused_adam_tpu, beta1=0.9, beta2=0.999,
-                              eps=1e-8), p, p, p, p, p)
-        assert_mosaic_lowerable(
-            functools.partial(pk.fused_momentum_tpu, mu=0.9,
-                              use_nesterov=True, l2_decay=1e-4),
-            p, p, p, jnp.asarray(0.1))
 
     def test_kernels_are_off_inside_a_partitioned_program(self,
                                                            monkeypatch):
@@ -1293,9 +1247,6 @@ class TestFusePagedAttention:
         names = [p.name for p in passes_for_build_strategy(
             _tier_bs(fuse_paged_attention=True))]
         assert "fuse_paged_attention" in names
-        names_tier = [p.name for p in passes_for_build_strategy(
-            _tier_bs(kernel_tier=True))]
-        assert "fuse_paged_attention" in names_tier
         names_off = [p.name for p in passes_for_build_strategy(
             _tier_bs())]
         assert "fuse_paged_attention" not in names_off

@@ -340,87 +340,6 @@ def main():
     print(f"[smoke]   amp: {inserted} casts inserted, {pruned} pruned "
           f"({pruned/inserted:.0%}), 1 compile, loss parity OK", flush=True)
 
-    step("kernel tier: >=1 rewrite and loss parity on mlp/BERT/CTR demos")
-    from paddle_tpu.models.static_graphs import (
-        build_bert_train_program, build_ctr_train_program,
-        bert_demo_feed, ctr_demo_feed)
-    from paddle_tpu.fluid.core import Scope as _KScope, \
-        scope_guard as _kscope_guard
-
-    # the rewrite passes fire on each demo (>=1 rewrite counted), drop
-    # ops_per_step strictly, and keep fp32 loss parity over >=10 train
-    # steps vs the unrewritten program (the kernels themselves compile
-    # through Mosaic in tests/test_kernel_tier.py)
-    from paddle_tpu.fluid import trace as trK
-    _kt_rng = np.random.RandomState(0)
-
-    def tier_demo(build_fn, feed, n_steps=10):
-        def run(tier):
-            reset_unique_name()
-            mp, sp, lo = build_fn()
-            exK = fluid.Executor()
-            with _kscope_guard(_KScope()):
-                exK.run(sp)
-                prog = mp
-                if tier:
-                    bsK = fluid.BuildStrategy()
-                    bsK.kernel_tier = True
-                    prog = fluid.CompiledProgram(mp, build_strategy=bsK)
-                lvs = [float(np.asarray(exK.run(
-                    prog, feed=feed, fetch_list=[lo])[0]).ravel()[0])
-                    for _ in range(n_steps)]
-                nops = trK.metrics().gauge("executor.ops_per_step").value
-            return lvs, nops
-
-        passes = ("fuse_attention", "fuse_sparse_embedding",
-                  "fuse_optimizer")
-        c0 = {p: trK.metrics().counter(
-            f"kernel_tier.{p}.rewrites").value for p in passes}
-        l_off, ops_off = run(False)
-        l_on, ops_on = run(True)
-        rewrites = {p: int(trK.metrics().counter(
-            f"kernel_tier.{p}.rewrites").value - c0[p]) for p in passes}
-        assert np.allclose(l_off, l_on, rtol=1e-5, atol=1e-6), \
-            (l_off, l_on)
-        assert ops_on < ops_off, (ops_off, ops_on)
-        return rewrites, int(ops_off), int(ops_on)
-
-    # mlp: the optimizer bucket is the only rewrite surface (adam — the
-    # shared build_demo trains SGD, which the tier leaves per-param)
-    def build_mlp_adam():
-        mp, sp = fluid.Program(), fluid.Program()
-        with fluid.program_guard(mp, sp):
-            xd = fluid.data("xd", [-1, 16])
-            yd = fluid.data("yd", [-1, 1], dtype="int64")
-            h = fluid.layers.fc(xd, 32, act="relu")
-            logits = fluid.layers.fc(h, 10)
-            lo = fluid.layers.mean(
-                fluid.layers.softmax_with_cross_entropy(logits, yd))
-            fluid.optimizer.AdamOptimizer(1e-2).minimize(lo)
-        return mp, sp, lo
-
-    rw_mlp, mo0, mo1 = tier_demo(build_mlp_adam, demo_feed)
-    assert rw_mlp["fuse_optimizer"] >= 1, rw_mlp
-    # BERT: EVERY attention block rewrites (forward + grad), one per layer
-    bert_layers = 2
-    rw_bert, bo0, bo1 = tier_demo(
-        lambda: build_bert_train_program(layers=bert_layers, dropout=0.1),
-        bert_demo_feed(_kt_rng))
-    assert rw_bert["fuse_attention"] == bert_layers, rw_bert
-    assert rw_bert["fuse_optimizer"] >= 1, rw_bert
-    # CTR: embedding chains + the optimizer bucket
-    rw_ctr, co0, co1 = tier_demo(
-        lambda: build_ctr_train_program(),
-        ctr_demo_feed(_kt_rng))
-    assert rw_ctr["fuse_sparse_embedding"] >= 1, rw_ctr
-    assert rw_ctr["fuse_optimizer"] >= 1, rw_ctr
-    print(f"[smoke]   kernel tier: rewrites "
-          f"mlp={rw_mlp['fuse_optimizer']} "
-          f"bert={rw_bert['fuse_attention']}+{rw_bert['fuse_optimizer']} "
-          f"ctr={rw_ctr['fuse_sparse_embedding']}+"
-          f"{rw_ctr['fuse_optimizer']}; ops/step {mo0}->{mo1} / "
-          f"{bo0}->{bo1} / {co0}->{co1}, loss parity OK", flush=True)
-
     step("elastic: crash-safe save, warm-restart SLO, no step-window stall")
     import json
     import shutil
