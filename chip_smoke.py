@@ -11,7 +11,9 @@ comes out by the repo's own means:
 * **executor** — ``models.static_graphs.build_bert_train_program`` run by
   ``fluid.Executor(fluid.TPUPlace(0))`` as a plain program, then as a
   ``CompiledProgram`` with the AMP plane, as a cell runs it: every
-  attention chain must have become the fused op.
+  attention chain must have become the fused op, and the step launches its
+  kernel once forward and once backward per layer (the grad op applies the
+  vjp the forward op kept: ``backward.vjp_kept``).
 * **kernels** — every kernel in ``ops/pallas_kernels.__all__`` compiled by
   Mosaic and run once through its op lowering against an XLA reference.
 * **cache** — compile seconds, and the files in the compile cache before and
@@ -214,6 +216,34 @@ def run_executor(program, startup, loss, feed, device, check_param=None):
     return losses, round(first_s, 1), round(step_ms, 2)
 
 
+def attention_kernel_calls(layers):
+    """The custom calls of the newest remembered executable that holds the
+    fused attention op, by the Program op they are charged to; asserts one
+    backward kernel per layer under the grad op (a grad op that traced its
+    forward again would launch the forward kernel a second time, charged to
+    the grad op: two per layer there).  The forward op's count is a floor:
+    under ``shard_map`` the per-chip PRNG key's ``X64Combine`` custom call
+    carries its scope too."""
+    from paddle_tpu.fluid import device_stats
+    for entry in reversed(device_stats.op_maps()):
+        calls = {}
+        for v in entry["map"].values():
+            if v and v["opcode"] == "custom-call" \
+                    and v["label"].startswith("fused_multihead_attention"):
+                calls[v["label"]] = calls.get(v["label"], 0) + 1
+        if calls:
+            assert calls.get("fused_multihead_attention_grad") == layers \
+                and calls.get("fused_multihead_attention", 0) >= layers, calls
+            return dict(calls, step_mosaic_calls=entry["mosaic_calls"])
+    raise AssertionError("no remembered executable holds the attention op")
+
+
+def vjp_counts():
+    from paddle_tpu.fluid import trace
+    return {n: trace.metrics().counter("backward.vjp_" + n).value
+            for n in ("kept", "retraced")}
+
+
 def executor_plain(size, device, out):
     """The plain (unrewritten, f32) program on one chip; returns its
     losses, the reference the rewritten and the sharded runs track."""
@@ -244,6 +274,7 @@ def leg_executor(size, device, out):
     bs.amp = True                   # what a cell sets, and nothing else
     rewrites = trace.metrics().counter("kernel_tier.fuse_attention.rewrites")
     r0 = rewrites.value
+    vjp0 = vjp_counts()
     amp_losses, first_s, step_ms = run_executor(
         fluid.CompiledProgram(main, build_strategy=bs), startup, loss, feed,
         device)
@@ -256,10 +287,15 @@ def leg_executor(size, device, out):
     # the plain one does
     assert abs(amp_losses[0] - losses[0]) <= 0.05 * abs(losses[0]), \
         (amp_losses[0], losses[0])
+    # every generic_grad but the loss's (custom_grad) applied a kept vjp
+    vjp = {n: v - vjp0[n] for n, v in vjp_counts().items()}
+    assert vjp["retraced"] == 1 and vjp["kept"] >= 2 * size["layers"], vjp
     out["amp"] = {
         "first_step_s": first_s, "step_ms": step_ms,
         "losses": [round(v, 4) for v in amp_losses],
-        "fuse_attention_rewrites": fused}
+        "fuse_attention_rewrites": fused, "vjp": vjp}
+    if device.platform == "tpu":
+        out["amp"]["kernel_calls"] = attention_kernel_calls(size["layers"])
     say(f"  amp: {json.dumps(out['amp'])}")
 
 
@@ -683,20 +719,26 @@ def leg_dp4(size, devices, one_chip_losses, out):
     names = ("attention.lowering.fused_kernel", "attention.lowering.xla",
              "kernel.shard_map_calls")
     before = {k: m.counter(k).value for k in names}
+    vjp0 = vjp_counts()
     losses, first_s, step_ms = run_executor(prog, startup, loss, feed,
                                             devices[0], check_param)
     check_losses(losses, "executor dp4")
+    vjp = {k: v - vjp0[k] for k, v in vjp_counts().items()}
+    assert vjp["retraced"] == 1 and vjp["kept"] >= 2 * size["layers"], vjp
     # the partitioned step took its Pallas kernels, once per chip under
     # shard_map (LoweringContext.kernel_site): the default pipeline fused
-    # every attention chain, its lowering picked the kernel, and the
-    # compiled step holds Mosaic calls
+    # every attention chain, its lowering (once per layer: the grad op
+    # applies the vjp the forward op kept) picked the kernel, and the
+    # compiled step launches it once forward and once backward per layer
     lowered = {k: int(m.counter(k).value - before[k]) for k in names}
     mosaic = sum(e["mosaic_calls"] for e in device_stats.op_maps())
+    kernel_calls = None
     if devices[0].platform == "tpu":
         assert lowered["attention.lowering.fused_kernel"] \
-            == 2 * size["layers"] and not lowered["attention.lowering.xla"] \
+            == size["layers"] and not lowered["attention.lowering.xla"] \
             and lowered["kernel.shard_map_calls"] > 0, lowered
         assert mosaic > 0, "no Mosaic call in the data-parallel step"
+        kernel_calls = attention_kernel_calls(size["layers"])
     # same weights, the same 64 rows on every chip: the first steps agree
     # to dropout noise (the masks differ — other block shapes) and the
     # last one lands in the same place; in between Adam at lr 1e-3 is
@@ -709,8 +751,8 @@ def leg_dp4(size, devices, one_chip_losses, out):
                 "global_batch": size["batch"] * n,
                 "losses": [round(v, 4) for v in losses],
                 "max_rel_drift_vs_one_chip": round(drift, 4),
-                "bytes_in_use": in_use, "lowered": lowered,
-                "mosaic_calls": mosaic})
+                "bytes_in_use": in_use, "lowered": lowered, "vjp": vjp,
+                "mosaic_calls": mosaic, "kernel_calls": kernel_calls})
 
 
 def leg_hybrid(tiny, devices, out):

@@ -6,18 +6,26 @@ the ops of a ProgramDesc and asks each op's C++ GradOpDescMaker
 `sum` ops for fan-in.  TPU-native difference: there are no hand-written grad
 ops.  One *generic* grad op (`generic_grad`) computes input cotangents with
 `jax.vjp` over the forward op's own lowering rule — correctness is inherited
-from JAX's AD instead of 676 hand-derived kernels, and XLA's CSE dedups the
-vjp-recomputed forward with the original forward in the same compiled block.
-Ops with special grad semantics register `custom_grad` (registry.py).
+from JAX's AD instead of 676 hand-derived kernels.  The forward runs once:
+where `run_block_ops` finds a forward op and its `generic_grad` in the op
+list it is tracing (`pair_grads`) it lowers the forward under `jax.vjp`
+(`lower_under_vjp`) and the grad op applies the vjp that call kept.  Every
+other route to a `generic_grad` (the eager tape's direct call, an `ops=`
+subset that holds the grads without their forwards, an input overwritten
+between the two, a `call_op` override) traces the forward lowering again
+over `I_<slot>` and leaves the copy to XLA's CSE, which merges its own ops
+and does not merge two Mosaic kernel calls.  Ops with special grad semantics
+register `custom_grad` (registry.py) and stay on that second route.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Set
+from typing import Any, Dict, List, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.registry import register_op, get_op, has_op
+from . import trace
 from .framework import Program, Block, Variable, Parameter
 
 GRAD_SUFFIX = "@GRAD"
@@ -35,52 +43,74 @@ def _is_float(x) -> bool:
 # ---------------------------------------------------------------------------
 # the generic grad op
 # ---------------------------------------------------------------------------
-@register_op("generic_grad", differentiable=False)
-def _generic_grad(ins, attrs, ctx):
-    """ins:  I_<slot> forward inputs, G_<slot> output cotangents.
-    outs: GI_<slot> input cotangents (only for slots listed in grad_slots).
-    """
-    fwd_def = get_op(attrs["fwd_type"])
-    fwd_attrs = attrs["fwd_attrs"]
-    grad_slots: List[str] = attrs["grad_slots"]         # slots needing grads
-    in_slots: List[str] = attrs["in_slots"]
+def lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx, grad_slots):
+    """Call the forward op's lowering once, under ``jax.vjp`` over its
+    differentiable inputs: ``(outs, (primal_outs, vjp_fn))``.  ``outs`` is
+    everything the lowering returned (what ``run_block_ops`` writes to
+    ``env``); ``primal_outs`` its differentiable part (float outputs outside
+    ``nondiff_outputs``, the shape of the cotangents) and ``vjp_fn`` maps
+    those cotangents to the input cotangents of ``grad_slots``.
 
-    fwd_ins = {s: list(ins.get("I_" + s, [])) for s in in_slots}
-
-    # split differentiable vs closed-over inputs (per-arg, by runtime dtype)
+    Differentiable: the float args of ``grad_slots`` outside
+    ``nondiff_inputs`` (per arg, by runtime dtype); every other input is
+    closed over, non-float and ``nondiff_outputs`` outputs ride as aux."""
     diff_tree, closed = {}, {}
-    for s in in_slots:
-        args = fwd_ins[s]
+    for s, args in fwd_ins.items():
         if s in fwd_def.nondiff_inputs or s not in grad_slots:
             closed[s] = args
             continue
         diff_tree[s] = [a if _is_float(a) else None for a in args]
         closed[s] = [None if _is_float(a) else a for a in args]
 
-    def merge(diff):
-        out = {}
-        for s in in_slots:
-            ca = closed[s]
-            da = diff.get(s, [None] * len(ca))
-            out[s] = [d if d is not None else c for d, c in zip(da, ca)]
-        return out
-
     def fwd_fn(diff):
-        outs = fwd_def.fn(merge(diff), fwd_attrs, ctx)
+        merged = {}
+        for s, ca in closed.items():
+            da = diff.get(s, [None] * len(ca))
+            merged[s] = [d if d is not None else c for d, c in zip(da, ca)]
+        outs = fwd_def.fn(merged, fwd_attrs, ctx)
         return {s: [o if _is_float(o) else None for o in v]
-                for s, v in outs.items() if s not in fwd_def.nondiff_outputs}
+                for s, v in outs.items()
+                if s not in fwd_def.nondiff_outputs}, outs
 
-    if fwd_def.custom_grad is not None:
-        fwd_outs = fwd_def.fn(merge(diff_tree), fwd_attrs, ctx)
-        out_grads = {}
-        for s in fwd_outs:
-            gs = ins.get("G_" + s)
-            out_grads[s] = gs[0] if gs else None
-        in_grads = fwd_def.custom_grad(merge(diff_tree), fwd_outs, out_grads,
-                                       fwd_attrs, ctx)
-        return {"GI_" + s: v for s, v in in_grads.items() if s in grad_slots}
+    primal_outs, vjp_fn, outs = jax.vjp(fwd_fn, diff_tree, has_aux=True)
+    return outs, (primal_outs, vjp_fn)
 
-    primal_outs, vjp_fn = jax.vjp(fwd_fn, diff_tree)
+
+@register_op("generic_grad", differentiable=False)
+def _generic_grad(ins, attrs, ctx):
+    """ins:  I_<slot> forward inputs, G_<slot> output cotangents.
+    outs: GI_<slot> input cotangents (only for slots listed in grad_slots).
+
+    Applies the ``jax.vjp`` that ``run_block_ops`` kept when it lowered the
+    forward partner (``ctx.kept_vjp``); with none handed over, or for an op
+    with ``custom_grad``, traces the forward lowering again over ``I_<slot>``
+    (``backward.vjp_kept`` / ``backward.vjp_retraced`` count which).
+    """
+    fwd_def = get_op(attrs["fwd_type"])
+    fwd_attrs = attrs["fwd_attrs"]
+    grad_slots: List[str] = attrs["grad_slots"]         # slots needing grads
+    kept = getattr(ctx, "kept_vjp", None)
+    if kept is not None:
+        ctx.kept_vjp = None
+    trace.metrics().counter("backward.vjp_retraced" if kept is None
+                            else "backward.vjp_kept").inc()
+
+    if kept is None:
+        fwd_ins = {s: list(ins.get("I_" + s, [])) for s in attrs["in_slots"]}
+        if fwd_def.custom_grad is not None:
+            fwd_outs = fwd_def.fn(fwd_ins, fwd_attrs, ctx)
+            out_grads = {}
+            for s in fwd_outs:
+                gs = ins.get("G_" + s)
+                out_grads[s] = gs[0] if gs else None
+            in_grads = fwd_def.custom_grad(fwd_ins, fwd_outs, out_grads,
+                                           fwd_attrs, ctx)
+            return {"GI_" + s: v for s, v in in_grads.items()
+                    if s in grad_slots}
+        _, kept = lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx,
+                                  grad_slots)
+
+    primal_outs, vjp_fn = kept
     cotangents = {}
     for s, outs_ in primal_outs.items():
         gs = ins.get("G_" + s, [])
@@ -102,6 +132,61 @@ def _generic_grad(ins, attrs, ctx):
         result["GI_" + s] = [g if g is not None
                              else jnp.zeros((), jnp.float32) for g in grads]
     return result
+
+
+def _folded_casts(op, slot, prefix=""):
+    """The dtypes ``prune_redundant_casts`` folded onto ``slot``'s args
+    (``__amp_cast__``), without the trailing args it left alone."""
+    dts = list((op.attrs.get("__amp_cast__") or {}).get(prefix + slot) or ())
+    while dts and dts[-1] is None:
+        dts.pop()
+    return dts
+
+
+def _same_attrs(fwd_attrs, op_attrs) -> bool:
+    """The attrs ``append_backward`` copied are still the forward op's
+    (the folded casts are compared per slot, by ``pair_grads``)."""
+    try:
+        return ({k: v for k, v in fwd_attrs.items() if k != "__amp_cast__"}
+                == {k: v for k, v in op_attrs.items() if k != "__amp_cast__"})
+    except ValueError:      # an array-valued attr that is another object
+        return False
+
+
+def pair_grads(op_list) -> Dict[int, Any]:
+    """``{id(forward op): its generic_grad}`` over one traced op list, read
+    from what the ops hold: the grad names the forward's type, its
+    ``I_<slot>`` mirrors are the forward's inputs name for name, under the
+    same folded AMP casts, its ``fwd_attrs`` are the forward's attrs, and
+    it comes later.  One grad per forward; an op with ``custom_grad`` has
+    none (its grad needs the forward's outputs, not a vjp).  A
+    ``generic_grad`` left out here re-traces its forward."""
+    grads: Dict[tuple, List[tuple]] = {}
+    for j, g in enumerate(op_list):
+        if g.type != "generic_grad":
+            continue
+        key = (g.attrs["fwd_type"],
+               tuple(sorted((s, tuple(g.inputs.get("I_" + s, ())))
+                            for s in g.attrs["in_slots"])))
+        grads.setdefault(key, []).append((j, g))
+    pairs: Dict[int, Any] = {}
+    if not grads:
+        return pairs
+    for i, f in enumerate(op_list):
+        if f.type == "generic_grad" or not has_op(f.type) \
+                or get_op(f.type).custom_grad is not None:
+            continue
+        key = (f.type, tuple(sorted((s, tuple(ns))
+                                    for s, ns in f.inputs.items())))
+        waiting = grads.get(key, ())
+        for k, (j, g) in enumerate(waiting):
+            if j > i and _same_attrs(g.attrs["fwd_attrs"], f.attrs) \
+                    and all(_folded_casts(f, s) == _folded_casts(g, s, "I_")
+                            for s in f.inputs):
+                pairs[id(f)] = g
+                del waiting[k]
+                break
+    return pairs
 
 
 # ---------------------------------------------------------------------------

@@ -396,11 +396,14 @@ def attach_oom_report(exc: BaseException,
 #   instance  the op's first output variable, cleaned (``@`` cuts an HLO
 #             op_name short, ``/`` separates its components)
 # One regular expression finds it anywhere in an instruction's ``op_name``
-# (under ``jit(fn)``, a partitioned program's wrappers, ``transpose(jvp())``,
-# the frames of a kernel that runs once per chip: ``pd:b:x_grad:i/
-# transpose(jvp(jit(body)))/shard_map/pallas_call``, and where the backward
-# repeats the scope inside ``transpose(..)``); the innermost (last) scope of
-# a path is the op's.
+# (under ``jit(fn)``, a partitioned program's wrappers, ``jvp()`` and
+# ``transpose(jvp())``, the frames of a kernel that runs once per chip:
+# ``pd:b:x_grad:i/transpose(jvp(jit(body)))/shard_map/pallas_call``); the
+# innermost (last) scope of the path is the op's.  What a transformation
+# wraps in its parentheses is the name stack of the function it transformed,
+# not a frame of the path: the backward rule of a ``custom_vjp`` reads
+# ``pd:b:dropout_grad:g/transpose(pd:f:dropout:o)/jvp()/pallas_call`` when the
+# grad op applies the vjp the forward op kept, and is the grad op's.
 #
 # The scopes are metadata, and jax's compile-cache key leaves metadata out:
 # renaming them changes no key, and a cache directory warmed by a tree with
@@ -410,6 +413,7 @@ def attach_oom_report(exc: BaseException,
 ROLES = {"f": "forward", "b": "backward", "o": "optimizer"}
 _SCOPE = re.compile(r"pd:([fbo]):([A-Za-z0-9_]+):([A-Za-z0-9_.\-]*)")
 _UNCLEAN = re.compile(r"[^A-Za-z0-9_.\-]")
+_WRAPPED = re.compile(r"\([^()]*\)")
 
 
 def scope_name(label: str, role: str, instance: str = "") -> str:
@@ -434,8 +438,12 @@ def op_scope(op, after_backward: bool = False) -> str:
 
 def parse_scope(op_name: str) -> Optional[Tuple[str, str, str]]:
     """``(label, role, instance)`` of the innermost Program-op scope in an
-    HLO ``op_name`` path, or None where it holds none."""
-    found = _SCOPE.findall(op_name or "")
+    HLO ``op_name`` path (outside any transformation's parentheses), or
+    None where it holds none."""
+    path, n = _WRAPPED.subn("", op_name or "")
+    while n:
+        path, n = _WRAPPED.subn("", path)
+    found = _SCOPE.findall(path)
     if not found:
         return None
     role, label, instance = found[-1]

@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import backward
 from . import compile_cache
 from . import core
 from . import device_stats
@@ -191,9 +192,17 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
     `ops` restricts execution to an explicit op list (pipeline stages /
     recompute segments); `call_op` overrides how a lowering rule is invoked
     (the functional-autodiff path wraps custom_grad ops in jax.custom_vjp).
+
+    A forward op whose ``generic_grad`` is in the same op list
+    (``backward.pair_grads``) is lowered once, under ``jax.vjp``; the grad
+    applies that vjp if the inputs' ``env`` values are still the objects
+    the forward read, and traces the forward again otherwise.
     """
     from . import control_flow_impl
     op_list = block.ops if ops is None else ops
+    grad_of = backward.pair_grads(op_list[:stop_at]) \
+        if call_op is None else {}
+    kept_vjps: Dict[int, Any] = {}   # id(grad op) -> (vjp, inputs read)
     debug_nan = getattr(ctx, "debug_nan", False)
     # observability plane: ONE boolean read for the whole loop; when off the
     # per-op cost is a single `if` (acceptance: no measurable overhead).
@@ -276,9 +285,19 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
         # (platform/profiler.h:127 RecordEvent placement, operator.cc:1077);
         # the host span below keeps the plain op type
         _t0 = trace.now() if tr_on else 0
+        grad_op = grad_of.get(id(op)) if op_attrs is op.attrs else None
+        if op.type == "generic_grad":
+            vjp, read = kept_vjps.pop(id(op), (None, ()))
+            ctx.kept_vjp = vjp if all(env.get(n) is v for n, v in read) \
+                else None
         with jax.named_scope(op_scope):
             if call_op is not None:
                 outs = call_op(opdef, ins, op_attrs, ctx)
+            elif grad_op is not None:
+                read = [(n, env[n]) for n in op.input_arg_names if n in env]
+                outs, vjp = backward.lower_under_vjp(
+                    opdef, ins, op_attrs, ctx, grad_op.attrs["grad_slots"])
+                kept_vjps[id(grad_op)] = (vjp, read)
             else:
                 if "SkipUpdate" in ins:   # GradientMerge k-step gate
                     from ..ops.optimizer_ops import apply_skip_update

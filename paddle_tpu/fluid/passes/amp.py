@@ -16,8 +16,10 @@ a side-door program mutation:
   fan-in) get fp32 back, gray ops follow their inputs (a bf16 operand
   pulls fp32 float operands down so the bias-add after a bf16 matmul never
   promotes the activation back — 2x HBM traffic otherwise).  Grad halves:
-  each forward op is paired with its ``generic_grad`` (the vjp recompute
-  must see the SAME input dtypes as the forward), the ``I_<slot>`` mirrors
+  each forward op is paired with its ``generic_grad`` (the grad op applies
+  the vjp the forward op kept only where both read the SAME inputs under
+  the SAME folded casts, ``backward.pair_grads``; a grad that traces the
+  forward again must see those dtypes too), the ``I_<slot>`` mirrors
   get their own casts, and ``GI_<slot>`` cotangents are cast back to the
   original var dtype — so parameter gradients land in fp32 no matter how
   deep the bf16 region is, and multi-step training is numerically stable.
@@ -96,7 +98,8 @@ class AmpBf16Pass(Pass):
     def _pair_grads(block) -> Dict[int, List[Operator]]:
         """id(forward op) -> its generic_grad ops: the grad's I_<slot>
         mirrors must equal the forward's input lists (how append_backward
-        builds them), so the vjp recompute sees the forward's exact
+        builds them), so forward and grad stay one pair for
+        ``backward.pair_grads`` and a re-trace sees the forward's exact
         values."""
         pairs: Dict[int, List[Operator]] = {}
         grads = [op for op in block.ops if op.type == "generic_grad"]

@@ -33,8 +33,9 @@ four paths one chip's operands take:
   either.
 
 `attention.lowering.<path>` in `trace.metrics()` counts the picks, once per
-lowering of an op (a training program lowers each attention twice: the
-forward op and the grad op that re-traces it); a causal op also counts
+lowering of an op (a training program lowers each attention once: its grad
+op applies the vjp the forward op kept; twice where the grad op has to trace
+the forward again, `backward.vjp_retraced`); a causal op also counts
 `attention.lowering.<path>.window` or `.full_causal`.
 """
 from __future__ import annotations

@@ -465,8 +465,8 @@ def _heads_to_lanes(x):
 
 @functools.partial(jax.jit, static_argnums=(5, 6))
 def _fused_attention_jit(q, k, v, bias, seed, heads, statics):
-    """One trace per (shapes, statics): a 12-layer program holds 24 calls
-    (12 forward ops, 12 grad ops re-tracing them)."""
+    """One trace per (shapes, statics), shared by the layers of a
+    program."""
     return _fused_attention(q, k, v, bias, seed, heads, statics)
 
 

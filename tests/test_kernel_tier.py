@@ -839,9 +839,9 @@ class TestBertStepKeepsTheScoresOnTheCore:
         ops = [op.type for op in main.global_block().ops]
         assert ops.count("fused_multihead_attention") == layers
         assert "softmax" not in ops
-        # the forward op and the grad op that re-traces it, per layer
+        # once per layer: the grad op applies the vjp the forward op kept
         after = _lowering_counts()
-        assert after["fused_kernel"] - before["fused_kernel"] == 2 * layers
+        assert after["fused_kernel"] - before["fused_kernel"] == layers
         assert after["xla"] == before["xla"]
         text = compiled.as_text()
         assert mosaic_call_count(compiled) >= 2 * layers
@@ -910,11 +910,11 @@ class TestCausalDecoderStepKeepsTheScoresOnTheCore:
             compiled = compile_for_tpu(step.raw_fn, mut, ro, feed,
                                        jax.random.PRNGKey(0))
         moved = {n: count(n) - before[n] for n in names}
-        # the forward op and the grad op that re-traces it, per layer
-        assert moved["attention.lowering.splash_kernel.window"] == 2
-        assert moved["attention.lowering.splash_kernel.full_causal"] == 2
+        # each op lowered once: its grad op applies the vjp it kept
+        assert moved["attention.lowering.splash_kernel.window"] == 1
+        assert moved["attention.lowering.splash_kernel.full_causal"] == 1
         assert moved["attention.lowering.xla"] == 0
-        assert moved["moe.gmm_lowering.megablox"] == 2 * 2 * 3
+        assert moved["moe.gmm_lowering.megablox"] == 2 * 3
         assert moved["moe.gmm_lowering.ragged_dot"] == 0
         text = compiled.as_text()
         assert mosaic_call_count(compiled) >= 2 * (3 + 3 * 3)
