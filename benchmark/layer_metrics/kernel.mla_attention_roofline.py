@@ -1,0 +1,18 @@
+"""Layer: kernels.  Latent attention's share of its roofline, in percent:
+``kernel.causal_attention_roofline``'s reading (3 x the forward's FLOPs over
+the unmasked pairs and the bytes of q, k, v, the output and their gradients,
+over the device time of the ``fused_multihead_attention`` ops and their
+grads a step) of a configuration whose ``attention_flops_per_sample`` /
+``attention_bytes_per_sample`` count a head of two widths: scores over
+qk_nope + qk_rope numbers, values of v_head_dim.  A head padded to a wider
+one earns nothing: the FLOPs are the required ones.  ``None`` where that
+reader finds nothing to read."""
+import os
+
+from benchmark.harness import registry
+
+
+def read(ctx):
+    return registry.load_module(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)),
+        "kernel.causal_attention_roofline.py")).read(ctx)

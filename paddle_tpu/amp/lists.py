@@ -38,6 +38,10 @@ BLACK_OPS = {
     # input would move; the router's own matmul, top-k and softmax are
     # float32
     "rms_norm", "moe_route",
+    # the residual path of several streams: the streams, the token's mixing
+    # coefficients and the 20 normalisations behind them stay float32 (a
+    # branch's bf16 output is cast as it is merged in)
+    "hyper_connection_mix", "hyper_connection_merge",
 }
 # matmul/conv-family ops deliberately kept fp32: recurrent cells whose
 # hidden-state chains drift in bf16, int8-quantized kernels, gather-heavy

@@ -482,7 +482,9 @@ def rotary_embedding(input, inv_freq, scale=1.0, name=None):
 
 def fused_multihead_attention(q, k, v, scale=None, causal=False, window=0,
                               name=None):
-    """softmax(q k^T * scale) v over [B, heads, S, D] operands.
+    """softmax(q k^T * scale) v over [B, heads, S, D] operands; ``scale``
+    defaults to D ** -0.5.  ``v`` may be [B, heads, S, Dv] with another
+    width than the scores' D (the output then has Dv).
     ``k`` and ``v`` may have fewer heads than ``q`` (each shared by a group
     of query heads); ``causal`` attends j <= i, ``window`` > 0 only
     0 <= i - j < window."""
@@ -498,7 +500,10 @@ def fused_multihead_attention(q, k, v, scale=None, causal=False, window=0,
 
 def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
                  num_held=None, router_attr=None, gate_attr=None,
-                 up_attr=None, down_attr=None, name=None):
+                 up_attr=None, down_attr=None, name=None, scoring="softmax",
+                 routed_scaling_factor=1.0, correction_bias_attr=None,
+                 shared_size=0, shared_gate_attr=None, shared_up_attr=None,
+                 shared_down_attr=None):
     """Sparse experts over tokens ``input`` [T, D] without dropping: the
     router scores all ``num_experts``, each token goes to its ``top_k`` best,
     and this program holds the ``num_held`` experts from ``first_expert`` on
@@ -507,7 +512,15 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
     of op and the ``swiglu`` gate between the grouped matmuls.  The routing's counts accumulate
     on the device in ``<name>.tokens_per_expert`` [num_held] and
     ``<name>.steps`` [1], published as gauges of ``trace.metrics()`` under
-    ``moe.<name>.…`` when an ``AsyncStepRunner`` drains."""
+    ``moe.<name>.…`` when an ``AsyncStepRunner`` drains.
+
+    ``scoring`` ``"softmax"``: top-k of the logits, softmax over the chosen.
+    ``"sigmoid"``: top-k of ``sigmoid(logits) + correction bias`` (a
+    parameter [num_experts] that no gradient reaches, zero unless
+    ``correction_bias_attr`` says otherwise), weights the chosen sigmoids
+    over their sum.  Both times ``routed_scaling_factor``.  ``shared_size``
+    > 0 adds a shared expert of that width, a gated FFN every token takes
+    at weight 1."""
     from .tensor import create_global_var
     from ..framework import default_main_program
     name = name or unique_name("expert_layer")
@@ -535,14 +548,23 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
     weight = var("float32")
     plan = {"Order": [var("int32", True)], "Pos": [var("int32", True)],
             "GroupSizes": [var("int32", True)]}
+    route_inputs = {"X": [input], "RouterWeight": [router],
+                    "Counts": [counts], "Steps": [steps]}
+    if scoring == "sigmoid":
+        from ..param_attr import ParamAttr
+        given = ParamAttr._to_attr(correction_bias_attr)
+        route_inputs["CorrectionBias"] = [helper.create_parameter(
+            ParamAttr(name=given.name, initializer=given.initializer,
+                      trainable=False),
+            [num_experts], "float32",
+            default_initializer=ConstantInitializer(0.0))]
     helper.append_op(
-        "moe_route",
-        inputs={"X": [input], "RouterWeight": [router], "Counts": [counts],
-                "Steps": [steps]},
+        "moe_route", inputs=route_inputs,
         outputs={"TopKWeight": [weight], "CountsOut": [counts],
                  "StepsOut": [steps], **plan},
         attrs={"top_k": int(top_k), "first_expert": int(first_expert),
-               "num_held": int(num_held)})
+               "num_held": int(num_held), "scoring": str(scoring),
+               "routed_scaling_factor": float(routed_scaling_factor)})
     rows = _append_single(helper, "moe_dispatch", {"X": [input], **plan},
                           input.dtype)
 
@@ -555,10 +577,94 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
         helper, "swiglu",
         {"X": [grouped(rows, w_gate)], "Y": [grouped(rows, w_up)]},
         input.dtype)
-    return _append_single(
+    out = _append_single(
         helper, "moe_combine",
         {"X": [grouped(hidden, w_down)], "TopKWeight": [weight], **plan},
         input.dtype)
+    if shared_size:
+        out = out + gated_ffn(input, shared_size, shared_gate_attr,
+                              shared_up_attr, shared_down_attr,
+                              num_flatten_dims=1, name=name + ".shared")
+    return out
+
+
+def gated_ffn(input, size, gate_attr=None, up_attr=None, down_attr=None,
+              num_flatten_dims=2, name=None):
+    """``W_down(silu(W_gate x) * W_up x)`` of width ``size``, no biases: the
+    dense FFN of a decoder layer, and an expert every token shares.  Under a
+    ``name`` every output is ``<name>.…`` (the three matmuls' ``<name>.gate``,
+    ``.up``, ``.down``), which is how a trace tells the block's rows."""
+    helper = LayerHelper("gated_ffn", name=name)
+
+    def dense(x, width, attr, part):
+        return fc(x, width, num_flatten_dims=num_flatten_dims,
+                  param_attr=attr, bias_attr=False,
+                  name=name and f"{name}.{part}")
+    hidden = _append_single(
+        helper, "swiglu", {"X": [dense(input, size, gate_attr, "gate")],
+                           "Y": [dense(input, size, up_attr, "up")]},
+        input.dtype)
+    return dense(hidden, int(input.shape[-1]), down_attr, "down")
+
+
+def hyper_connection_mix(stream, n, epsilon=1e-6, sinkhorn_iters=20,
+                         hc_eps=1e-6, clamp=(-30.0, 30.0), alpha_init=0.01,
+                         res_init_diagonal=8.0, phi_attr=None,
+                         alpha_attr=None, b_attr=None, name=None):
+    """Before a branch of a residual path of ``n`` streams (``stream`` [...,
+    n * d] float32, stream i in columns i * d .. (i + 1) * d): returns
+    ``(y, post, c)``, the branch's input ``y`` [..., d] = sum_i pre_i
+    stream[i] and the token's coefficients for ``hyper_connection_merge``.
+    Parameters (named by their attrs): phi [n * d, n * n + 2 n], alpha [3]
+    (``alpha_init`` unless its attr brings an initializer) and b [n * n +
+    2 n] (likewise: pre = 1 / n, post = 1 and ``res_init_diagonal`` on the
+    diagonal of the mixing logits, which is the plain residual ``stream[i]
+    + z`` while the streams are equal).  The largest ``|row sum of C - 1|``
+    of a step stays on the device in ``<name>.res_row_sum_error`` [1] and is
+    published as the gauge ``hc.<name>.res_row_sum_error`` when an
+    ``AsyncStepRunner`` drains."""
+    import math
+    from ..framework import default_main_program
+    from ..initializer import NumpyArrayInitializer
+    name = name or unique_name("hyper_connection")
+    helper = LayerHelper("hyper_connection_mix", name=name)
+    width = int(stream.shape[-1])
+    k = n * n + 2 * n
+    b0 = np.concatenate([np.full(n, -math.log(n - 1.0)), np.zeros(n),
+                         res_init_diagonal * np.eye(n).ravel()]
+                        ).astype("float32")
+    phi = helper.create_parameter(phi_attr, [width, k], "float32")
+    alpha = helper.create_parameter(
+        alpha_attr, [3], "float32",
+        default_initializer=ConstantInitializer(alpha_init))
+    b = helper.create_parameter(b_attr, [k], "float32",
+                                default_initializer=NumpyArrayInitializer(b0))
+    error = helper.block().create_var(
+        name=name + ".res_row_sum_error", shape=[1], dtype="float32",
+        persistable=True, stop_gradient=True)
+    default_main_program()._hints.setdefault("device_counters", {})[
+        error.name] = "hc." + error.name
+    y, post, c = (helper.create_variable_for_type_inference(dtype="float32")
+                  for _ in range(3))
+    helper.append_op(
+        "hyper_connection_mix",
+        inputs={"X": [stream], "Phi": [phi], "Alpha": [alpha], "B": [b]},
+        outputs={"Y": [y], "Post": [post], "C": [c],
+                 "RowSumError": [error]},
+        attrs={"n": int(n), "epsilon": float(epsilon),
+               "sinkhorn_iters": int(sinkhorn_iters),
+               "hc_eps": float(hc_eps), "clamp_min": float(clamp[0]),
+               "clamp_max": float(clamp[1])})
+    return y, post, c
+
+
+def hyper_connection_merge(stream, z, post, c, name=None):
+    """After the branch: the new streams ``out[i] = post_i z + sum_j c[i, j]
+    stream[j]``, [..., n * d] float32."""
+    helper = LayerHelper("hyper_connection_merge", name=name)
+    return _append_single(
+        helper, "hyper_connection_merge",
+        {"X": [stream], "Z": [z], "Post": [post], "C": [c]}, "float32")
 
 
 def _append_single(helper, op_type, inputs, dtype, attrs=None,

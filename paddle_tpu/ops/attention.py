@@ -106,6 +106,7 @@ def _banded_attention(q, k, v, scale, window):
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     qg = q.reshape(b, hkv, hq // hkv, s, d)
+    dv = v.shape[-1]
     acc = jnp.float32
 
     @jax.checkpoint
@@ -128,7 +129,7 @@ def _banded_attention(q, k, v, scale, window):
         c0 = max(0, r0 - window + 1) if window else 0
         out.append(block(qg[:, :, :, r0:r1], k[:, :, c0:r1], v[:, :, c0:r1],
                          r0, c0))
-    return jnp.concatenate(out, axis=3).reshape(b, hq, s, d)
+    return jnp.concatenate(out, axis=3).reshape(b, hq, s, dv)
 
 
 def _bias_broadcastable(mask, q, k) -> bool:
@@ -158,7 +159,7 @@ def attention_path(q, k, v, mask, causal, drop_active, window=0) -> str:
         from .pallas_kernels import fused_attention_supported
         if fused_attention_supported(q, k, v, mask):
             return "fused_kernel"
-    if seq >= _STREAM_MIN_SEQ and not drop_active \
+    if seq >= _STREAM_MIN_SEQ and not drop_active and k.shape == v.shape \
             and (mask is None or _bias_broadcastable(mask, q, k)):
         return "flash_kernel"
     return "xla"

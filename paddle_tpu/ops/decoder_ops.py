@@ -7,6 +7,12 @@ permutation around them.  The attention of such a block is the
 key/value heads than query heads (ops/attention.py).  The arithmetic of the
 expert layer lives in ``parallel/moe.py``; gradients come from
 ``generic_grad`` over these lowerings.
+
+A residual path of several streams (manifold-constrained hyper-connections,
+arXiv 2512.24880 over 2409.19606) is two ops around each branch:
+``hyper_connection_mix`` reads the streams and gives the branch its input
+and the token's mixing coefficients, ``hyper_connection_merge`` writes the
+streams the branch's output and the mixed old streams.
 """
 from __future__ import annotations
 
@@ -70,18 +76,24 @@ def _plan(ins):
 _PLAN_SLOTS = ("Order", "Pos", "GroupSizes")
 
 
-@register_op("moe_route", nondiff_inputs=("Counts", "Steps"),
+@register_op("moe_route",
+             nondiff_inputs=("Counts", "Steps", "CorrectionBias"),
              nondiff_outputs=("Order", "Pos", "GroupSizes", "CountsOut",
                               "StepsOut"))
 def _moe_route(ins, attrs, ctx):
     """Router and plan.  X [T, D], RouterWeight [D, E] -> TopKWeight
     [T, top_k] float32 and the plan of the held experts ``[first_expert,
     first_expert + num_held)``: Order [T * top_k], Pos [T, top_k], GroupSizes
-    [num_held].  Counts [num_held] and Steps [1] (int32, persistable) are the
-    device's own counters: tokens per held expert and calls, added to here
-    and read by the host when a runner drains."""
-    weights, experts = moe.route(ins["X"][0], ins["RouterWeight"][0],
-                                 int(attrs["top_k"]))
+    [num_held].  ``scoring`` (``softmax``, the default, or ``sigmoid``),
+    ``routed_scaling_factor`` and the optional CorrectionBias [E] are
+    ``parallel.moe.route``'s.  Counts [num_held] and Steps [1] (int32,
+    persistable) are the device's own counters: tokens per held expert and
+    calls, added to here and read by the host when a runner drains."""
+    bias = ins["CorrectionBias"][0] if ins.get("CorrectionBias") else None
+    weights, experts = moe.route(
+        ins["X"][0], ins["RouterWeight"][0], int(attrs["top_k"]),
+        attrs.get("scoring", "softmax"), bias,
+        float(attrs.get("routed_scaling_factor", 1.0)))
     plan = moe.dispatch_plan(experts, int(attrs.get("first_expert", 0)),
                              int(attrs["num_held"]))
     out = {"TopKWeight": [weights], "Order": [plan.order],
@@ -115,3 +127,85 @@ def _moe_combine(ins, attrs, ctx):
     held assignments."""
     return {"Out": [moe.combine(ins["X"][0], ins["TopKWeight"][0],
                                 _plan(ins))]}
+
+
+# ---------------------------------------------------------------------------
+# hyper-connections: a residual path of ``n`` streams.  The streams of a
+# token lie side by side in one row, X [..., n * d] (stream i is columns
+# i * d .. (i + 1) * d): every slice is whole lane groups and no array has a
+# short second-minor axis that the chip's tiling would pad.
+# ---------------------------------------------------------------------------
+
+def _streams(x, n):
+    d = x.shape[-1] // n
+    return [x[..., i * d:(i + 1) * d] for i in range(n)]
+
+
+def hyper_connection_coefficients(m, alpha, b, n, iters, hc_eps, clamp):
+    """(pre [T, n], post [T, n], C [T, n * n] row-major, the largest |row
+    sum of C - 1|) of the tokens' projections ``m`` [T, n * n + 2 n]: ``pre
+    = sigmoid(alpha_0 m[:n] + b[:n])``, ``post = 2 sigmoid(alpha_1 m[n:2n]
+    + b[n:2n])``, ``C`` = Sinkhorn-Knopp of ``exp(clip(alpha_2 m[2n:] +
+    b[2n:]))``: ``iters`` times rows over (their sum + ``hc_eps``), then
+    columns likewise.  The small arithmetic runs with the tokens on the
+    last axis."""
+    mt = m.T                                                 # [n*n + 2n, T]
+    bt = b[:, None]
+    pre = jax.nn.sigmoid(alpha[0] * mt[:n] + bt[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * mt[n:2 * n] + bt[n:2 * n])
+    c = jnp.exp(jnp.clip(alpha[2] * mt[2 * n:] + bt[2 * n:], *clamp))
+    c = c.reshape(n, n, -1)                                  # [row, col, T]
+    for _ in range(iters):
+        c = c / (jnp.sum(c, axis=1, keepdims=True) + hc_eps)
+        c = c / (jnp.sum(c, axis=0, keepdims=True) + hc_eps)
+    row_error = jnp.max(jnp.abs(jnp.sum(jax.lax.stop_gradient(c), axis=1)
+                                - 1.0))
+    return pre.T, post.T, c.reshape(n * n, -1).T, row_error
+
+
+@register_op("hyper_connection_mix", nondiff_outputs=("RowSumError",))
+def _hyper_connection_mix(ins, attrs, ctx):
+    """X [..., n * d] float32 streams, Phi [n * d, n * n + 2 n], Alpha [3],
+    B [n * n + 2 n] -> Y [..., d] = sum_i pre_i X[i], the branch's input;
+    Post [..., n]; C [..., n * n].  ``m = (x Phi) rsqrt(mean(x^2) +
+    epsilon)`` over the token's whole row, the matmul at the highest
+    precision (its 24 outputs decide how every stream moves).  RowSumError
+    [1] is the largest ``|row sum of C - 1|`` over the call's tokens, kept
+    on the device and read by the host when a runner drains."""
+    x = ins["X"][0].astype(jnp.float32)
+    n = int(attrs["n"])
+    lead = x.shape[:-1]
+    rows = x.reshape(-1, x.shape[-1])
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(rows), axis=-1, keepdims=True)
+                        + float(attrs.get("epsilon", 1e-6)))
+    m = jnp.dot(rows, ins["Phi"][0].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST) * inv
+    # the 20 iterations are recomputed in backward: 16 numbers a token
+    pre, post, c, row_error = jax.checkpoint(
+        hyper_connection_coefficients, static_argnums=(3, 4, 5, 6))(
+        m, ins["Alpha"][0].astype(jnp.float32),
+        ins["B"][0].astype(jnp.float32), n,
+        int(attrs["sinkhorn_iters"]), float(attrs["hc_eps"]),
+        (float(attrs["clamp_min"]), float(attrs["clamp_max"])))
+    y = sum(pre[:, i:i + 1] * xi for i, xi in enumerate(_streams(rows, n)))
+    return {"Y": [y.reshape(lead + (y.shape[-1],))],
+            "Post": [post.reshape(lead + (n,))],
+            "C": [c.reshape(lead + (n * n,))],
+            "RowSumError": [row_error.reshape(1)]}
+
+
+@register_op("hyper_connection_merge")
+def _hyper_connection_merge(ins, attrs, ctx):
+    """X [..., n * d], Z [..., d] (the branch's output), Post [..., n], C
+    [..., n * n] -> Out [..., n * d]: ``Out[i] = post_i Z + sum_j C[i, j]
+    X[j]``, float32."""
+    x = ins["X"][0].astype(jnp.float32)
+    z = ins["Z"][0].astype(jnp.float32)
+    post = ins["Post"][0].astype(jnp.float32)
+    c = ins["C"][0].astype(jnp.float32)
+    n = post.shape[-1]
+    xs = _streams(x, n)
+    out = [post[..., i:i + 1] * z
+           + sum(c[..., i * n + j:i * n + j + 1] * xs[j] for j in range(n))
+           for i in range(n)]
+    return {"Out": [jnp.concatenate(out, axis=-1)]}

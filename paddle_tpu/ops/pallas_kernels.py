@@ -90,12 +90,22 @@ _SPLASH_BLOCK = 512
 
 def splash_attention_supported(q, k, v, mask) -> bool:
     """Does the splash kernel cover attention over these operands: no
-    additive mask, a lane-aligned head, query heads a multiple of the
-    key/value heads, and whole blocks."""
+    additive mask, query heads a multiple of the key/value heads, whole
+    blocks, and a head the lanes take.  A head has two widths, the score
+    width (the last axis of ``q`` and ``k``, contracted over) and the value
+    width (the last axis of ``v`` and of the output); they may differ
+    (latent attention scores over 192 = 128 + 64 rotary numbers and carries
+    values of 128).  The value width is whole 128-lane groups, because the
+    output and its accumulator are tiled by it; the score width is whole
+    half groups of 64 from 128 up, because it is only ever contracted over
+    (Mosaic compiles 192 as it stands, no operand is padded with zeros by
+    the caller).  For one width this is the old rule, a multiple of 128."""
     seq = q.shape[2]
-    return (mask is None and q.ndim == 4 and k.shape == v.shape
-            and k.shape[2] == seq and q.shape[3] == k.shape[3]
-            and q.shape[3] % _LANES == 0 and q.shape[1] % k.shape[1] == 0
+    score, value = q.shape[3], v.shape[3]
+    return (mask is None and q.ndim == 4 and k.shape[:3] == v.shape[:3]
+            and k.shape[2] == seq and k.shape[3] == score
+            and value % _LANES == 0 and score % (_LANES // 2) == 0
+            and score >= _LANES and q.shape[1] % k.shape[1] == 0
             and seq % min(_SPLASH_BLOCK, seq) == 0 and seq % _LANES == 0)
 
 
@@ -117,8 +127,9 @@ def _splash_kernel(seq, q_heads, window):
 
 
 def splash_attention_tpu(q, k, v, scale=None, window=0):
-    """Causal attention, q [B, Hq, S, D], k/v [B, Hkv, S, D] with Hq a
-    multiple of Hkv; ``window`` > 0 keeps 0 <= i - j < window."""
+    """Causal attention, q [B, Hq, S, D], k [B, Hkv, S, D], v [B, Hkv, S,
+    Dv] with Hq a multiple of Hkv (``splash_attention_supported`` has the
+    rule for D and Dv); ``window`` > 0 keeps 0 <= i - j < window."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     kernel = _splash_kernel(q.shape[2], q.shape[1], int(window))

@@ -65,16 +65,38 @@ class Plan(NamedTuple):
     group_sizes: jax.Array    # [E_held] int32
 
 
-def route(x, router_w, top_k: int):
+def route(x, router_w, top_k: int, scoring: str = "softmax", bias=None,
+          scale: float = 1.0):
     """(weights [T, top_k] float32, experts [T, top_k] int32) of tokens ``x``
     [T, D] under router ``router_w`` [D, E].  Logits in float32 at the
     highest matmul precision whatever ``x`` is: a bf16 logit moves the
-    eighth-best expert.  The weights are the softmax over the chosen logits
-    (= softmax over all, top-k, renormalised)."""
+    last-chosen expert.  Two scorings, one function:
+
+    ``softmax``: the ``top_k`` largest logits, weighted by the softmax over
+    those (= softmax over all, top-k, renormalised).
+    ``sigmoid`` (DeepSeek-V3's ``noaux_tc``): scores ``s = sigmoid(logits)``;
+    the ``top_k`` largest of ``s + bias`` (``bias`` [E], the correction that
+    balances load without a loss term; it chooses and does not weigh); the
+    weights are the chosen ``s`` over their sum.
+
+    Both times ``scale`` (the routed scaling factor)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    top, experts = lax.top_k(logits, top_k)
-    return jax.nn.softmax(top, axis=-1), experts.astype(jnp.int32)
+    if scoring == "softmax":
+        top, experts = lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(top, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, experts = lax.top_k(biased, top_k)
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"route: scoring {scoring!r} is not 'softmax' or "
+                         f"'sigmoid'")
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts.astype(jnp.int32)
 
 
 def dispatch_plan(experts, first_expert: int, num_held: int) -> Plan:
@@ -237,7 +259,8 @@ def held_ffn(xs, group_sizes, w_gate, w_up, w_down, use_kernel=False):
 
 def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                  first_expert: int = 0, axis_name: Optional[str] = None,
-                 use_kernel: bool = False):
+                 use_kernel: bool = False, scoring: str = "softmax",
+                 bias=None, scale: float = 1.0):
     """The layer over tokens ``x`` [T, D]: router ``router_w`` [D, E], held
     experts ``w_gate``/``w_up`` [E_held, D, F] and ``w_down`` [E_held, F, D].
 
@@ -246,8 +269,12 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     ``ep`` axis bound (inside ``shard_map``; ``first_expert`` is then the
     axis index times ``E_held``, taken from the axis), ``x`` is this chip's
     tokens and the result is the whole layer for them: tokens travel to the
-    chips that hold their experts and back."""
-    weights, experts = route(x, router_w, top_k)
+    chips that hold their experts and back.
+
+    ``scoring``, ``bias``, ``scale``: ``route``'s.  A shared expert is no
+    part of this function: every chip holds it whole beside the routed ones
+    (``fluid.layers.expert_layer(shared_size=)``)."""
+    weights, experts = route(x, router_w, top_k, scoring, bias, scale)
     num_held = w_gate.shape[0]
     if axis_name is None:
         plan = dispatch_plan(experts, first_expert, num_held)

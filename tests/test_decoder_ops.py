@@ -239,3 +239,279 @@ def test_splash_kernel_numerics_in_interpret_mode():
         for a, r in zip(grads, ref_vjp(got)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                        rtol=5e-3, atol=5e-3)
+
+
+# ---------------------------------------------------------------------------
+# a head whose score width differs from its value width (latent attention)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("seq, score, value, causal, want", [
+    (4096, 192, 128, True, "splash_kernel"),    # MLA: 128 + 64 rotary
+    (1024, 192, 128, True, "splash_kernel"),
+    (4096, 256, 128, True, "splash_kernel"),
+    (4096, 192, 256, True, "splash_kernel"),
+    (512, 192, 128, True, "xla"),       # ungrouped, under the streaming floor
+    (4096, 192, 64, True, "xla"),       # values not whole lane groups
+    (4096, 160, 128, True, "xla"),      # scores not whole half groups
+    (4096, 64, 128, True, "xla"),       # scores under one group
+    (4096, 192, 128, False, "xla"),     # no kernel of two widths but splash
+    (512, 192, 128, False, "xla"),
+])
+def test_attention_path_two_widths(seq, score, value, causal, want):
+    from paddle_tpu.ops.attention import attention_path, path_at
+    from paddle_tpu.ops.registry import KernelSite
+    q = jax.ShapeDtypeStruct((1, 32, seq, score), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 32, seq, value), jnp.bfloat16)
+    assert attention_path(q, q, v, None, causal, False) == want
+    assert path_at(KernelSite(), q, q, v, None, causal, False) == want
+    assert path_at(None, q, q, v, None, causal, False) == "xla"
+
+
+def test_attention_op_two_widths_and_scale():
+    """The op with values narrower than the scores and a scale that is not
+    1/sqrt(width), forward and gradients, on the XLA path."""
+    q, k = _rand(2, 4, 12, 24, scale=0.5), _rand(2, 4, 12, 24, seed=1,
+                                                 scale=0.5)
+    v = _rand(2, 4, 12, 16, seed=2)
+    attrs = {"causal": True, "scale": 0.37}
+    ins = {"Q": q, "K": k, "V": v}
+    out = _op("fused_multihead_attention", ins, attrs)
+    assert out.shape == (2, 4, 12, 16)
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: _attention_ref(q, k, v, 0.37, 0), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    cot = _rand(*out.shape, seed=7)
+    for got, ref in zip(_op_grads("fused_multihead_attention", ins, attrs,
+                                  cot, ["Q", "K", "V"]), ref_vjp(cot)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_splash_kernel_two_widths_in_interpret_mode():
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_kernels as pk
+    q = _rand(1, 2, 256, 192, scale=0.5)
+    k, v = _rand(1, 2, 256, 192, seed=1, scale=0.5), \
+        _rand(1, 2, 256, 128, seed=2)
+    assert pk.splash_attention_supported(q, k, v, None)
+    f = lambda q, k, v: pk.splash_attention_tpu(q, k, v, 0.12)
+    with pltpu.force_tpu_interpret_mode():
+        got, vjp = jax.vjp(f, q, k, v)
+        grads = vjp(got)
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: _attention_ref(q, k, v, 0.12, 0), q, k, v)
+    assert got.shape == (1, 2, 256, 128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+    for a, r in zip(grads, ref_vjp(got)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=5e-3, atol=5e-3)
+
+
+def test_latent_attention_equals_an_uncompressed_multi_head_spelling():
+    """MLA as the configuration spells it from fluid.layers (latents,
+    latent norms, one shared rotary key, concatenated score halves) against
+    a head-by-head NumPy multi-head attention whose per-head matrices are
+    cut from the same latent matrices."""
+    import os
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import layers as L
+    from paddle_tpu.fluid.core import Scope, scope_guard
+    from benchmark.harness.registry import Registry, load_module
+    reg = Registry()
+    cfg, cfg_dir = reg.config("xing4_29b_a4b_train")
+    model = load_module(os.path.join(cfg_dir, "model.py"))
+    heads, nope, rope, vd, ql, kvl, hidden, seq = 3, 8, 4, 6, 10, 7, 20, 9
+    cfg.update(hidden_size=hidden, num_attention_heads=heads,
+               num_key_value_heads=heads, qk_nope_head_dim=nope,
+               qk_rope_head_dim=rope, v_head_dim=vd, q_lora_rank=ql,
+               kv_lora_rank=kvl, num_hidden_layers=1, vocab_size=32,
+               intermediate_size=16)
+    built = model.build(cfg, {"seq_len": seq, "samples_per_chip": 1},
+                        train=False)
+    block = built["main"].global_block()
+    h_name = next(op.input_arg_names[0] for op in block.ops
+                  if op.output_arg_names[0].startswith(
+                      "layer_0.attention.q_a."))
+    out_name = next(op.output_arg_names[0] for op in block.ops
+                    if op.output_arg_names[0].startswith(
+                        "layer_0.attention.output."))
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, 32, (1, seq)).astype("int64")
+    exe = fluid.Executor()
+    with scope_guard(Scope()):
+        built["startup"].random_seed = 3
+        exe.run(built["startup"])
+        h, got = exe.run(built["main"],
+                         feed={"input_ids": ids, "labels": ids},
+                         fetch_list=[h_name, out_name])
+        w = {n: np.asarray(fluid.global_scope().find_var(
+            "layer_0.attention." + n), np.float64)
+            for n in ("q_a.w", "q_a_norm.scale", "q_b.w", "kv_a.w",
+                      "kv_a_norm.scale", "kv_b.w", "output.w")}
+    exe.close()
+    h = np.asarray(h, np.float64)[0]                         # [seq, hidden]
+
+    def rms(x, scale):
+        return x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * scale
+
+    def rot(x):
+        inv = np.asarray(model.rotary_frequencies(cfg))
+        ang = np.arange(seq)[:, None] * np.concatenate([inv, inv])
+        half = x.shape[-1] // 2
+        return x * np.cos(ang) + np.concatenate(
+            [-x[:, half:], x[:, :half]], -1) * np.sin(ang)
+
+    c_q = rms(h @ w["q_a.w"], w["q_a_norm.scale"])
+    c_kv = rms((h @ w["kv_a.w"])[:, :kvl], w["kv_a_norm.scale"])
+    k_rope = rot((h @ w["kv_a.w"])[:, kvl:])
+    scale = model.softmax_scale(cfg)
+    assert scale == pytest.approx((nope + rope) ** -0.5
+                                  * (0.1 * math.log(64) + 1) ** 2)
+    ctx = []
+    for head in range(heads):
+        wq = w["q_b.w"][:, head * (nope + rope):(head + 1) * (nope + rope)]
+        wkv = w["kv_b.w"][:, head * (nope + vd):(head + 1) * (nope + vd)]
+        q = np.concatenate([c_q @ wq[:, :nope], rot(c_q @ wq[:, nope:])], -1)
+        k = np.concatenate([c_kv @ wkv[:, :nope], k_rope], -1)
+        scores = q @ k.T * scale
+        scores[np.triu_indices(seq, 1)] = -np.inf
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        ctx.append(p / p.sum(-1, keepdims=True) @ (c_kv @ wkv[:, nope:]))
+    want = np.concatenate(ctx, -1) @ w["output.w"]
+    np.testing.assert_allclose(np.asarray(got)[0], want, rtol=2e-4,
+                               atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the mixer of a multi-stream residual path
+# ---------------------------------------------------------------------------
+
+def _mixer_numpy(x, phi, alpha, b, n, iters, hc_eps, clamp, eps=1e-6):
+    """x [T, n, d] -> (y, post, C) in float64."""
+    x = np.asarray(x, np.float64)
+    flat = x.reshape(x.shape[0], -1)
+    m = flat @ np.asarray(phi, np.float64) \
+        / np.sqrt((flat * flat).mean(-1, keepdims=True) + eps)
+    sig = lambda a: 1.0 / (1.0 + np.exp(-a))
+    pre = sig(alpha[0] * m[:, :n] + b[:n])
+    post = 2.0 * sig(alpha[1] * m[:, n:2 * n] + b[n:2 * n])
+    c = np.exp(np.clip(alpha[2] * m[:, 2 * n:] + b[2 * n:], *clamp))
+    c = c.reshape(-1, n, n)
+    for _ in range(iters):
+        c = c / (c.sum(-1, keepdims=True) + hc_eps)
+        c = c / (c.sum(-2, keepdims=True) + hc_eps)
+    return np.einsum("tn,tnd->td", pre, x), post, c
+
+
+_MIX_ATTRS = {"n": 4, "epsilon": 1e-6, "sinkhorn_iters": 20, "hc_eps": 1e-6,
+              "clamp_min": -30.0, "clamp_max": 30.0}
+
+
+def _mixer_inputs(alpha=(1.0, 0.7, 1.3), t=10, d=8, seed=0):
+    n = 4
+    return {"X": _rand(t, n * d, seed=seed),
+            "Phi": _rand(n * d, n * n + 2 * n, seed=seed + 1, scale=0.3),
+            "Alpha": jnp.asarray(alpha, jnp.float32),
+            "B": _rand(n * n + 2 * n, seed=seed + 2, scale=0.5)}
+
+
+def test_mixer_equals_a_numpy_spelling_and_c_is_doubly_stochastic():
+    ins = _mixer_inputs()
+    out = get_op("hyper_connection_mix").fn(
+        {k: [v] for k, v in ins.items()}, _MIX_ATTRS, CTX)
+    y, post, c = _mixer_numpy(
+        np.asarray(ins["X"]).reshape(10, 4, 8), ins["Phi"],
+        np.asarray(ins["Alpha"]), np.asarray(ins["B"]), 4, 20, 1e-6,
+        (-30.0, 30.0))
+    np.testing.assert_allclose(np.asarray(out["Y"][0]), y, rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out["Post"][0]), post, rtol=2e-5)
+    got_c = np.asarray(out["C"][0]).reshape(10, 4, 4)
+    np.testing.assert_allclose(got_c, c, rtol=1e-4, atol=1e-6)
+    # columns last: they add up to one exactly, the rows after 20
+    # iterations to a few 1e-3 for a random start
+    np.testing.assert_allclose(got_c.sum(-2), 1.0, atol=1e-5)
+    row_error = np.abs(got_c.sum(-1) - 1.0).max()
+    assert row_error < 1e-2
+    assert float(out["RowSumError"][0][0]) == pytest.approx(row_error,
+                                                            rel=1e-3)
+    # one iteration is not enough: the gauge would show it
+    once = get_op("hyper_connection_mix").fn(
+        {k: [v] for k, v in ins.items()},
+        dict(_MIX_ATTRS, sinkhorn_iters=1), CTX)
+    assert float(once["RowSumError"][0][0]) > 10 * row_error
+
+
+def test_mixer_and_merge_gradients_equal_jax_of_the_numpy_spelling():
+    ins = _mixer_inputs(seed=3)
+    z = _rand(10, 8, seed=9)
+
+    def spelled(x, phi, alpha, b, z):
+        flat = x
+        m = (flat @ phi) * jax.lax.rsqrt(
+            jnp.mean(flat * flat, -1, keepdims=True) + 1e-6)
+        pre = jax.nn.sigmoid(alpha[0] * m[:, :4] + b[:4])
+        post = 2 * jax.nn.sigmoid(alpha[1] * m[:, 4:8] + b[4:8])
+        c = jnp.exp(jnp.clip(alpha[2] * m[:, 8:] + b[8:], -30, 30))
+        c = c.reshape(-1, 4, 4)
+        for _ in range(20):
+            c = c / (c.sum(-1, keepdims=True) + 1e-6)
+            c = c / (c.sum(-2, keepdims=True) + 1e-6)
+        xs = x.reshape(-1, 4, 8)
+        y = jnp.einsum("tn,tnd->td", pre, xs)
+        new = post[..., None] * (z + jnp.tanh(y))[:, None, :] \
+            + jnp.einsum("tij,tjd->tid", c, xs)
+        return new.reshape(x.shape)
+
+    def through_ops(x, phi, alpha, b, z):
+        mixed = get_op("hyper_connection_mix").fn(
+            {"X": [x], "Phi": [phi], "Alpha": [alpha], "B": [b]},
+            _MIX_ATTRS, CTX)
+        return get_op("hyper_connection_merge").fn(
+            {"X": [x], "Z": [z + jnp.tanh(mixed["Y"][0])],
+             "Post": mixed["Post"], "C": mixed["C"]}, {}, CTX)["Out"][0]
+
+    args = (ins["X"], ins["Phi"], ins["Alpha"], ins["B"], z)
+    with jax.default_matmul_precision("highest"):
+        want, ref_vjp = jax.vjp(spelled, *args)
+        got, vjp = jax.vjp(through_ops, *args)
+        cot = _rand(*want.shape, seed=5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5)
+        for a, r in zip(vjp(cot), ref_vjp(cot)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=2e-3, atol=2e-5)
+
+
+def test_mixer_starts_as_the_plain_residual():
+    """With the layer's default parameters (pre = 1/n, post = 1, a diagonal
+    of 8) equal streams stay equal and each becomes stream + branch."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import layers as L
+    from paddle_tpu.fluid.core import Scope, scope_guard
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        e = fluid.data("e", [-1, 6, 8], dtype="float32")
+        stream = L.expand(e, [1, 1, 4])
+        y, post, c = L.hyper_connection_mix(stream, 4, name="l0.attn")
+        branch = L.scale(y, scale=3.0)
+        out = L.hyper_connection_merge(stream, branch, post, c)
+    ev = np.random.RandomState(1).randn(2, 6, 8).astype("float32")
+    exe = fluid.Executor()
+    with scope_guard(Scope()):
+        exe.run(startup)
+        yv, cv, ov = exe.run(main, feed={"e": ev}, fetch_list=[y, c, out])
+        err = np.asarray(fluid.global_scope().find_var(
+            "l0.attn.res_row_sum_error"))
+    exe.close()
+    # alpha starts at 0.01, not 0: the token moves its gates by a percent
+    np.testing.assert_allclose(yv, ev, rtol=3e-2, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(cv).reshape(2, 6, 4, 4),
+                               np.broadcast_to(np.eye(4), (2, 6, 4, 4)),
+                               atol=2e-3)
+    for i in range(4):
+        np.testing.assert_allclose(ov[..., i * 8:(i + 1) * 8], ev + 3 * ev,
+                                   rtol=5e-2, atol=5e-3)
+    assert err.shape == (1,) and 0 <= err[0] < 1e-4

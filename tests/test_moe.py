@@ -66,6 +66,61 @@ def test_shapes_and_routing_on_one_device():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("scale", [1.0, 2.0])
+def test_sigmoid_scoring_chooses_by_the_biased_score_and_weighs_without_it(
+        scale):
+    x, router, _, _, _ = _weights()
+    bias = jnp.asarray(np.random.RandomState(1).randn(E) * 0.5, jnp.float32)
+    weights, experts = moe.route(x, router, K, "sigmoid", bias, scale)
+    scores = np.asarray(jax.nn.sigmoid(x @ router), np.float64)
+    want = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :K]
+    np.testing.assert_array_equal(np.sort(np.asarray(experts), -1),
+                                  np.sort(want, -1))
+    chosen = np.take_along_axis(scores, np.asarray(experts), -1)
+    np.testing.assert_allclose(
+        np.asarray(weights), scale * chosen / chosen.sum(-1, keepdims=True),
+        rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), scale, rtol=1e-5)
+    # the bias moves the choice: without it other experts are kept
+    _, unbiased = moe.route(x, router, K, "sigmoid", None, scale)
+    assert (np.sort(np.asarray(unbiased), -1)
+            != np.sort(np.asarray(experts), -1)).any()
+
+
+def test_softmax_scoring_is_the_default_and_unknown_scorings_are_refused():
+    x, router, _, _, _ = _weights()
+    weights, experts = moe.route(x, router, K)
+    named, same = moe.route(x, router, K, "softmax", None, 1.0)
+    np.testing.assert_array_equal(np.asarray(weights), np.asarray(named))
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(same))
+    top = np.sort(np.asarray(x @ router), -1)[:, ::-1][:, :K]
+    e = np.exp(top - top.max(-1, keepdims=True))
+    np.testing.assert_allclose(np.asarray(weights),
+                               e / e.sum(-1, keepdims=True), rtol=2e-5)
+    doubled, _ = moe.route(x, router, K, "softmax", None, 2.0)
+    np.testing.assert_allclose(np.asarray(doubled), 2 * np.asarray(weights),
+                               rtol=1e-6)
+    with pytest.raises(ValueError, match="scoring"):
+        moe.route(x, router, K, "tanh")
+
+
+def test_sigmoid_layer_equals_the_dense_spelling():
+    x, router, gate, up, down = _weights()
+    bias = 0.2 * jax.random.normal(jax.random.PRNGKey(9), (E,))
+    out = moe.expert_layer(x, router, gate, up, down, top_k=K,
+                           scoring="sigmoid", bias=bias, scale=2.0)
+    scores = jax.nn.sigmoid(x @ router)
+    _, chosen = jax.lax.top_k(scores + bias, K)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = 2.0 * picked / picked.sum(-1, keepdims=True)
+    want = jnp.zeros_like(x)
+    for e in range(E):
+        w = jnp.sum(jnp.where(chosen == e, weights, 0), -1, keepdims=True)
+        want = want + w * ((jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+
+
 def test_gradients_equal_the_dense_spelling():
     args = _weights(1)
     got = jax.grad(lambda *a: jnp.sum(jnp.sin(

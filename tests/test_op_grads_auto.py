@@ -644,6 +644,26 @@ def build_specs():
                     **_MOE_PLAN},
             grad_slots=["X", "TopKWeight"]),
     })
+    # the mixer of a two-stream residual path (ops/decoder_ops.py), from a
+    # stream of its own; three Sinkhorn iterations keep the probes smooth
+    r30 = np.random.RandomState(30)
+
+    def _sym30(*shape):
+        return r30.uniform(-1.0, 1.0, shape).astype("float32")
+    _HC = {"n": 2, "epsilon": 1e-6, "sinkhorn_iters": 3, "hc_eps": 1e-6,
+           "clamp_min": -30.0, "clamp_max": 30.0}
+    _hc_in = {"X": _sym30(3, 8), "Phi": 0.5 * _sym30(8, 8),
+              "Alpha": np.asarray([0.8, 1.1, 0.9], "float32"),
+              "B": 0.5 * _sym30(8)}
+    S.update({
+        "hyper_connection_mix": dict(
+            inputs=_hc_in, grad_slots=["X", "Phi", "Alpha", "B"],
+            out_slot="C", attrs=_HC),
+        "hyper_connection_merge": dict(
+            inputs={"X": _sym30(3, 8), "Z": _sym30(3, 4),
+                    "Post": _sym30(3, 2) + 1.5, "C": _sym30(3, 4) + 1.0},
+            grad_slots=["X", "Z", "Post", "C"]),
+    })
     return S
 
 
