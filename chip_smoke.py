@@ -648,6 +648,70 @@ def roll_paged(tiny, tpu, key):
         "mosaic": n, "rel_err": err}
 
 
+def roll_hyper_connection(tiny, tpu, key):
+    """``hyper_connection_mix`` and ``hyper_connection_merge`` through their
+    lowerings, forward and every gradient, against the ``jnp`` spelling the
+    same lowerings take where the context allows no kernel."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    class NoKernel(LoweringContext):
+        def pallas_ok(self):
+            return False
+
+    n = 4
+    k = n * n + 2 * n
+    attrs = {"n": n, "epsilon": 1e-6, "sinkhorn_iters": 20, "hc_eps": 1e-6,
+             "clamp_min": -30.0, "clamp_max": 30.0}
+
+    def fwd_bwd(ctx_type, w):
+        def f(x, phi, alpha, b, z):
+            ctx = ctx_type(base_key=key)
+            mixed = get_op("hyper_connection_mix").fn(
+                {"X": [x], "Phi": [phi], "Alpha": [alpha], "B": [b]},
+                attrs, ctx)
+            out = get_op("hyper_connection_merge").fn(
+                {"X": [x], "Z": [z], "Post": mixed["Post"],
+                 "C": mixed["C"]}, {}, ctx)["Out"][0]
+            # Y reaches the result in float32: through a bfloat16 branch a
+            # last-bit difference in Y would read as a bfloat16 step in Out
+            return (out + jnp.tile(jnp.tanh(mixed["Y"][0]), n),
+                    mixed["RowSumError"][0])
+
+        def g(*args):
+            out, vjp, err = jax.vjp(f, *args, has_aux=True)
+            return (out, err) + vjp(w)
+        return jax.jit(g)
+
+    names = ("out", "row_sum_error", "dx", "dphi", "dalpha", "db", "dz")
+    # float32 sums in another order; dz is rounded to the branch's bfloat16
+    limits = dict.fromkeys(names, 1e-4)
+    limits.update(dphi=2e-3, dalpha=2e-3, db=2e-3, dz=2e-2)
+    cases = []
+    # the training cell's streams, then a ragged last tile
+    for tokens, d in [(256, 128)] if tiny else [(4096, 3584), (128 + 40, 256)]:
+        ks = jax.random.split(jax.random.fold_in(key, tokens), 5)
+        x = jax.random.normal(ks[0], (1, tokens, n * d), jnp.float32)
+        phi = 0.02 * jax.random.normal(ks[1], (n * d, k), jnp.float32)
+        alpha = jnp.asarray([1.0, 0.7, 1.3], jnp.float32)
+        b = 0.5 * jax.random.normal(ks[2], (k,), jnp.float32)
+        z = jax.random.normal(ks[3], (1, tokens, d), jnp.bfloat16)
+        w = jax.random.normal(ks[4], (1, tokens, n * d), jnp.float32)
+        args = (x, phi, alpha, b, z)
+        got, n_calls = run_lowered(fwd_bwd(LoweringContext, w), *args,
+                                   expect_mosaic=tpu)
+        want = fwd_bwd(NoKernel, w)(*args)
+        errs = {name: rel_err(a, r) for name, a, r in zip(names, got, want)}
+        assert all(errs[name] < limits[name] for name in names), errs
+        if tpu:
+            assert n_calls == 5, n_calls    # mix 1 + 2, merge 1 + 1
+        cases.append({"streams": [tokens, n, d], "mosaic": n_calls,
+                      "rel_err": {k_: float(f"{v:.2e}")
+                                  for k_, v in errs.items()}})
+    return {"cases": cases}
+
+
 # which of pallas_kernels.__all__ each roll-call entry drives
 ROLL_CALL = [
     ("flash", roll_flash, ["flash_attention_tpu"]),
@@ -657,6 +721,8 @@ ROLL_CALL = [
     ("embedding", roll_embedding, ["fused_embedding_pool_tpu",
                                    "embedding_pool_grad_tpu"]),
     ("paged", roll_paged, ["paged_flash_attention_tpu"]),
+    ("hyper_connection", roll_hyper_connection,
+     ["hyper_connection_mix_tpu", "hyper_connection_merge_tpu"]),
 ]
 
 

@@ -141,16 +141,15 @@ def _streams(x, n):
     return [x[..., i * d:(i + 1) * d] for i in range(n)]
 
 
-def hyper_connection_coefficients(m, alpha, b, n, iters, hc_eps, clamp):
-    """(pre [T, n], post [T, n], C [T, n * n] row-major, the largest |row
-    sum of C - 1|) of the tokens' projections ``m`` [T, n * n + 2 n]: ``pre
-    = sigmoid(alpha_0 m[:n] + b[:n])``, ``post = 2 sigmoid(alpha_1 m[n:2n]
-    + b[n:2n])``, ``C`` = Sinkhorn-Knopp of ``exp(clip(alpha_2 m[2n:] +
-    b[2n:]))``: ``iters`` times rows over (their sum + ``hc_eps``), then
-    columns likewise.  The small arithmetic runs with the tokens on the
-    last axis."""
-    mt = m.T                                                 # [n*n + 2n, T]
-    bt = b[:, None]
+def hyper_connection_coefficients_t(mt, alpha, bt, n, iters, hc_eps, clamp):
+    """The mixers' small arithmetic with the tokens on the last axis, as
+    XLA runs it and as the Pallas kernel runs it on a tile.  ``mt`` [n * n +
+    2 n, T] the tokens' projections, ``alpha`` three scalars (an array or an
+    SMEM ref), ``bt`` [n * n + 2 n, 1] -> ``pre`` [n, T] = sigmoid(alpha_0
+    m[:n] + b[:n]), ``post`` [n, T] = 2 sigmoid(alpha_1 m[n:2n] + b[n:2n]),
+    ``c`` [n * n, T] row-major = Sinkhorn-Knopp of ``exp(clip(alpha_2 m[2n:]
+    + b[2n:]))``: ``iters`` times rows over (their sum + ``hc_eps``), then
+    columns likewise; and ``|row sum of c - 1|`` [n, T]."""
     pre = jax.nn.sigmoid(alpha[0] * mt[:n] + bt[:n])
     post = 2.0 * jax.nn.sigmoid(alpha[1] * mt[n:2 * n] + bt[n:2 * n])
     c = jnp.exp(jnp.clip(alpha[2] * mt[2 * n:] + bt[2 * n:], *clamp))
@@ -158,9 +157,31 @@ def hyper_connection_coefficients(m, alpha, b, n, iters, hc_eps, clamp):
     for _ in range(iters):
         c = c / (jnp.sum(c, axis=1, keepdims=True) + hc_eps)
         c = c / (jnp.sum(c, axis=0, keepdims=True) + hc_eps)
-    row_error = jnp.max(jnp.abs(jnp.sum(jax.lax.stop_gradient(c), axis=1)
-                                - 1.0))
-    return pre.T, post.T, c.reshape(n * n, -1).T, row_error
+    row_error = jnp.abs(jnp.sum(jax.lax.stop_gradient(c), axis=1) - 1.0)
+    return pre, post, c.reshape(n * n, -1), row_error
+
+
+def hyper_connection_coefficients(m, alpha, b, n, iters, hc_eps, clamp):
+    """(pre [T, n], post [T, n], C [T, n * n] row-major, the largest |row
+    sum of C - 1|) of the tokens' projections ``m`` [T, n * n + 2 n]:
+    ``hyper_connection_coefficients_t`` with the tokens moved to the last
+    axis for the small arithmetic."""
+    pre, post, c, row_error = hyper_connection_coefficients_t(
+        m.T, alpha, b[:, None], n, iters, hc_eps, clamp)
+    return pre.T, post.T, c.T, jnp.max(row_error)
+
+
+def _mixer_kernels(ctx, x, n):
+    """``pallas_kernels`` where the mixers' kernels run: on the chip, outside
+    a partitioned program, over streams they cover; else None, the ``jnp``
+    spelling.  Counted once per lowering, ``hyper_connection.lowering.
+    <pallas|xla>`` in ``trace.metrics()``."""
+    from ..fluid import trace
+    from . import pallas_kernels as pk
+    use = ctx.pallas_ok() and pk.hyper_connection_supported(x, n)
+    trace.metrics().counter(
+        f"hyper_connection.lowering.{'pallas' if use else 'xla'}").inc()
+    return pk if use else None
 
 
 @register_op("hyper_connection_mix", nondiff_outputs=("RowSumError",))
@@ -172,22 +193,31 @@ def _hyper_connection_mix(ins, attrs, ctx):
     precision (its 24 outputs decide how every stream moves).  RowSumError
     [1] is the largest ``|row sum of C - 1|`` over the call's tokens, kept
     on the device and read by the host when a runner drains."""
-    x = ins["X"][0].astype(jnp.float32)
+    x = ins["X"][0]
     n = int(attrs["n"])
     lead = x.shape[:-1]
-    rows = x.reshape(-1, x.shape[-1])
-    inv = jax.lax.rsqrt(jnp.mean(jnp.square(rows), axis=-1, keepdims=True)
-                        + float(attrs.get("epsilon", 1e-6)))
-    m = jnp.dot(rows, ins["Phi"][0].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST) * inv
-    # the 20 iterations are recomputed in backward: 16 numbers a token
-    pre, post, c, row_error = jax.checkpoint(
-        hyper_connection_coefficients, static_argnums=(3, 4, 5, 6))(
-        m, ins["Alpha"][0].astype(jnp.float32),
-        ins["B"][0].astype(jnp.float32), n,
-        int(attrs["sinkhorn_iters"]), float(attrs["hc_eps"]),
-        (float(attrs["clamp_min"]), float(attrs["clamp_max"])))
-    y = sum(pre[:, i:i + 1] * xi for i, xi in enumerate(_streams(rows, n)))
+    phi, alpha, b = ins["Phi"][0], ins["Alpha"][0], ins["B"][0]
+    epsilon = float(attrs.get("epsilon", 1e-6))
+    iters, hc_eps = int(attrs["sinkhorn_iters"]), float(attrs["hc_eps"])
+    clamp = (float(attrs["clamp_min"]), float(attrs["clamp_max"]))
+    pk = _mixer_kernels(ctx, x, n)
+    if pk:
+        y, post, c, row_error = pk.hyper_connection_mix_tpu(
+            x.reshape(-1, x.shape[-1]), phi, alpha, b, n, epsilon, iters,
+            hc_eps, clamp)
+    else:
+        rows = x.astype(jnp.float32).reshape(-1, x.shape[-1])
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(rows), axis=-1,
+                                     keepdims=True) + epsilon)
+        m = jnp.dot(rows, phi.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST) * inv
+        # the 20 iterations are recomputed in backward: 16 numbers a token
+        pre, post, c, row_error = jax.checkpoint(
+            hyper_connection_coefficients, static_argnums=(3, 4, 5, 6))(
+            m, alpha.astype(jnp.float32), b.astype(jnp.float32), n, iters,
+            hc_eps, clamp)
+        y = sum(pre[:, i:i + 1] * xi
+                for i, xi in enumerate(_streams(rows, n)))
     return {"Y": [y.reshape(lead + (y.shape[-1],))],
             "Post": [post.reshape(lead + (n,))],
             "C": [c.reshape(lead + (n * n,))],
@@ -199,12 +229,16 @@ def _hyper_connection_merge(ins, attrs, ctx):
     """X [..., n * d], Z [..., d] (the branch's output), Post [..., n], C
     [..., n * n] -> Out [..., n * d]: ``Out[i] = post_i Z + sum_j C[i, j]
     X[j]``, float32."""
-    x = ins["X"][0].astype(jnp.float32)
-    z = ins["Z"][0].astype(jnp.float32)
-    post = ins["Post"][0].astype(jnp.float32)
-    c = ins["C"][0].astype(jnp.float32)
+    x, z, post, c = (ins[slot][0] for slot in ("X", "Z", "Post", "C"))
     n = post.shape[-1]
-    xs = _streams(x, n)
+    pk = _mixer_kernels(ctx, x, n)
+    if pk:
+        out = pk.hyper_connection_merge_tpu(
+            x.reshape(-1, x.shape[-1]), z.reshape(-1, z.shape[-1]),
+            post.reshape(-1, n), c.reshape(-1, n * n))
+        return {"Out": [out.reshape(x.shape)]}
+    z, post, c = (a.astype(jnp.float32) for a in (z, post, c))
+    xs = _streams(x.astype(jnp.float32), n)
     out = [post[..., i:i + 1] * z
            + sum(c[..., i * n + j:i * n + j + 1] * xs[j] for j in range(n))
            for i in range(n)]

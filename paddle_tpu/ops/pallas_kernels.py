@@ -13,13 +13,20 @@
   hardware PRNG (pltpu.prng_random_bits) and the backward pass RE-SEEDS the
   same PRNG to regenerate it — zero mask bytes written, zero residuals
   saved.  What this buys per step is not measured on this code.
+* hyper-connection mixers — a token's n float32 residual streams lie in one
+  row [n * d], and XLA reads that row once per use (the projection, the mean
+  square, the branch input, each again in backward: 6.5 / 11.3 / 2.9 / 6.3
+  passes over the streams for mix, its gradient, merge, its gradient).  Five
+  kernels hold a tile of whole rows in VMEM and do all an op needs of it in
+  one visit (1.25 / 3.5 / 2.25 / 3.5 passes); the Sinkhorn iterations run on
+  the core forward and stay in XLA, on [24, T] arrays, backward.
 * paged decode attention, fused embedding gather+pool, bucketed optimizer
   updates — see each section.
 
 The callers (ops/attention.py, ops/nn_ops.py, ops/ctr_ops.py,
-ops/optimizer_ops.py) take these on the ``tpu`` backend only; every kernel
-here is compiled by Mosaic and checked against its XLA reference by
-``chip_smoke.py``'s kernel roll-call.
+ops/optimizer_ops.py, ops/decoder_ops.py) take these on the ``tpu`` backend
+only; every kernel here is compiled by Mosaic and checked against its XLA
+reference by ``chip_smoke.py``'s kernel roll-call.
 """
 from __future__ import annotations
 
@@ -36,7 +43,8 @@ from jax.experimental.pallas.ops.tpu.flash_attention import (
 __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_dropout_add_tpu", "fused_act_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
-           "paged_flash_attention_tpu"]
+           "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
+           "hyper_connection_merge_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
 # VMEM default is 16 MiB: one block of every operand together stays under
@@ -884,6 +892,472 @@ def fused_act_dropout_tpu(x, key, rate, upscale_in_train, act):
     out = _fused_act_dropout(x.reshape(-1, n), seed, float(rate),
                              bool(upscale_in_train), act)
     return out.reshape(shape)
+
+
+# ---------------------------------------------------------------------------
+# hyper-connection mixers: one pass over the streams per kernel.
+#
+# A token's ``n`` streams lie side by side in one float32 row [n * d] (56 KiB
+# at n = 4, d = 3584), and the two mixer ops around a branch touch every
+# stream of every token.  As XLA fuses the ``jnp`` spelling it reads the row
+# once for the projection, once for the mean square, once for the branch
+# input, and again for each in backward: 6.5 / 11.3 / 2.9 / 6.3 passes for
+# mix, its gradient, merge, its gradient (ledger, PR 30).  Here a grid step
+# holds a tile of ``_HC_TILE`` whole rows in VMEM and does everything the op
+# needs of them in one visit: 1.25 / 3.5 / 2.25 / 3.5 passes.
+#
+# Small per-token arrays (the 24 projections, pre, post, the n x n matrix)
+# travel with the tokens on the LAST axis, [rows, T]: lane-dense in HBM (a
+# [T, 4] array pads to 128 lanes there) and elementwise on the core.  One
+# aligned transpose per tile (``_rows_to_cols``) turns them into per-token
+# columns for the stream arithmetic, one more brings the per-token reductions
+# back.  The stream arithmetic walks the tile in chunks
+# of ``_HC_ROWS`` rows x ``_HC_CHUNK`` lanes so that its operands stay in
+# registers.
+#
+# Precision: everything float32, the three matmuls of the mix (x Phi, its two
+# transposes in backward) at ``Precision.HIGHEST``, 20 Sinkhorn iterations,
+# rows before columns: ``decoder_ops.hyper_connection_coefficients_t``, which
+# the forward kernel calls on its tile and XLA differentiates on the [24, T]
+# arrays in backward (the iterations' gradient stays out of the kernels).
+# ---------------------------------------------------------------------------
+
+# tokens per grid step: whole lane groups, because the small arrays are
+# blocked [rows, _HC_TILE] with the tokens on the lanes
+_HC_TILE = 128
+_HC_ROWS = 8
+_HC_CHUNK = 512
+# the widest kernel (merge backward) pipelines three row-wide and two
+# stream-wide float32 blocks, 49 MiB double-buffered at n = 4, d = 3584: they
+# and the matmuls' temporaries stay under the limit.  The limit is half the
+# core's 128 MiB and no more: what a call reserves XLA cannot prefetch into
+# around it, and with 100 MiB reserved the training step's schedule held
+# 77 MB more of HBM (AOT, PR 31)
+_HC_PIPELINE_BYTES = 56 << 20
+_HC_VMEM_LIMIT_BYTES = 64 << 20
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def _hc_rows(n):
+    """(k, kp, cp): the projections a token has, and the sublane-padded row
+    counts of the two packed small arrays: [kp, T] holds the k projections
+    and up to n + 1 rows more (the mean square's rsqrt; pre and the rsqrt's
+    gradient term in backward), [cp, T] holds post (n) and C (n * n)."""
+    k = n * n + 2 * n
+    return k, _round_up(k + n + 1, 8), _round_up(n * n + n, 8)
+
+
+def hyper_connection_supported(x, n) -> bool:
+    """Do the mixer kernels cover streams ``x`` [..., n * d]: float32, each
+    stream whole 128-lane groups, at least one tile of ``_HC_TILE`` tokens
+    (the last may be ragged), a tile of the widest kernel's blocks inside the
+    VMEM budget, and few enough coefficients a token that one 128-wide
+    transpose carries them."""
+    if x.ndim < 2 or x.dtype != jnp.float32 or n < 1 or x.shape[-1] % n:
+        return False
+    d = x.shape[-1] // n
+    tokens = x.size // x.shape[-1] if x.shape[-1] else 0
+    return (d > 0 and d % _LANES == 0 and tokens >= _HC_TILE
+            and _hc_rows(n)[1] <= _LANES
+            and 2 * _HC_TILE * (3 * n + 2) * d * 4 <= _HC_PIPELINE_BYTES)
+
+
+def _rows_to_cols(rows):
+    """[r, t] (r <= 128, tokens on the lanes) -> [t, 128] whose column i is
+    row i: one aligned transpose."""
+    r, t = rows.shape
+    if r < _LANES:
+        rows = jnp.concatenate(
+            [rows, jnp.zeros((_LANES - r, t), rows.dtype)], axis=0)
+    return jnp.transpose(rows)
+
+
+def _place_cols(cols):
+    """[rows, 1] columns -> [rows, 128] with column i in lane i."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (cols[0].shape[0], _LANES), 1)
+    out = jnp.zeros(lane.shape, jnp.float32)
+    for i, col in enumerate(cols):
+        out = jnp.where(lane == i, col, out)
+    return out
+
+
+def _hc_chunks(d):
+    return [(s, min(_HC_CHUNK, d - s)) for s in range(0, d, _HC_CHUNK)]
+
+
+def _fold_lanes(a):
+    """[r, m * 128] -> [r, 128]: the lane groups added up (vector adds; the
+    one cross-lane reduction comes after the last chunk)."""
+    out = a[:, :_LANES]
+    for g in range(1, a.shape[1] // _LANES):
+        out = out + a[:, g * _LANES:(g + 1) * _LANES]
+    return out
+
+
+def _for_row_chunks(tile, body):
+    """``body(rows)`` for the tile's chunks of ``_HC_ROWS`` token rows."""
+    def step(r, carry):
+        body(pl.ds(pl.multiple_of(r * _HC_ROWS, _HC_ROWS), _HC_ROWS))
+        return carry
+    jax.lax.fori_loop(0, tile // _HC_ROWS, step, 0)
+
+
+def _valid_lanes(tile, tokens):
+    """[1, tile] bool: which of the grid step's tokens exist (the last tile
+    of a ragged grid is padded with whatever the buffer held)."""
+    at = pl.program_id(0) * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (1, tile), 1)
+    return at < tokens
+
+
+def _hc_mix_fwd_kernel(alpha_ref, x_ref, phit_ref, bt_ref, y_ref, m_ref,
+                       coef_ref, err_ref, cols_ref, *, n, statics, tokens):
+    epsilon, iters, hc_eps, clamp = statics
+    tile, width = x_ref.shape
+    d = width // n
+    k = _hc_rows(n)[0]
+
+    def mean_square(rows):
+        acc = jnp.zeros((_HC_ROWS, _LANES), jnp.float32)
+        for s, w in _hc_chunks(width):
+            xs = x_ref[rows, s:s + w]
+            acc = acc + _fold_lanes(xs * xs)
+        cols_ref[rows, :] = jnp.broadcast_to(
+            jnp.sum(acc, axis=1, keepdims=True) / width, acc.shape)
+
+    _for_row_chunks(tile, mean_square)
+    inv = jax.lax.rsqrt(jnp.transpose(cols_ref[...])[:1] + epsilon)
+    mt = jax.lax.dot_general(phit_ref[...], x_ref[...], _NT,
+                             precision=_HIGHEST,
+                             preferred_element_type=jnp.float32) * inv
+    from .decoder_ops import hyper_connection_coefficients_t
+    pre, post, c, row_error = hyper_connection_coefficients_t(
+        mt[:k], alpha_ref, bt_ref[:k], n, iters, hc_eps, clamp)
+    m_ref[...] = mt
+    m_ref[k:k + 1, :] = inv
+    coef_ref[...] = jnp.zeros(coef_ref.shape, jnp.float32)
+    coef_ref[:n, :] = post
+    coef_ref[n:n + n * n, :] = c
+    if tokens % tile:
+        row_error = jnp.where(_valid_lanes(tile, tokens), row_error, 0.0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _first():
+        err_ref[...] = jnp.zeros(err_ref.shape, jnp.float32)
+
+    err_ref[...] = jnp.maximum(
+        err_ref[...], jnp.max(row_error, axis=(0, 1), keepdims=True))
+    cols_ref[...] = _rows_to_cols(pre)
+
+    def branch_input(rows):
+        pre_c = cols_ref[rows, :]
+        for s, w in _hc_chunks(d):
+            y_ref[rows, s:s + w] = sum(
+                pre_c[:, i:i + 1] * x_ref[rows, i * d + s:i * d + s + w]
+                for i in range(n))
+
+    _for_row_chunks(tile, branch_input)
+
+
+def _hc_mix_dpre_kernel(x_ref, dy_ref, dpre_ref, cols_ref, *, n):
+    tile, d = dy_ref.shape
+
+    def reduce(rows):
+        acc = [jnp.zeros((_HC_ROWS, _LANES), jnp.float32) for _ in range(n)]
+        for s, w in _hc_chunks(d):
+            dy = dy_ref[rows, s:s + w]
+            for i in range(n):
+                acc[i] = acc[i] + _fold_lanes(
+                    dy * x_ref[rows, i * d + s:i * d + s + w])
+        cols_ref[rows, :] = _place_cols(
+            [jnp.sum(a, axis=1, keepdims=True) for a in acc])
+
+    _for_row_chunks(tile, reduce)
+    dpre_ref[...] = jnp.transpose(cols_ref[...])[:dpre_ref.shape[0]]
+
+
+def _hc_mix_dx_kernel(x_ref, dy_ref, g_ref, phit_ref, dx_ref, dphit_ref,
+                      cols_ref, *, n, tokens):
+    """``g_ref`` [kp, tile]: rows [0, k) the projections' gradient times the
+    rsqrt, rows [k, k + n) pre, row k + n the rsqrt's own term; ``phit_ref``
+    has zeros from row k on, so the extra rows add nothing to dX (their rows
+    of dPhi^T are dropped by the caller)."""
+    tile, d = dy_ref.shape
+    k = _hc_rows(n)[0]
+    g, x = g_ref[...], x_ref[...]
+    if tokens % tile:
+        valid = _valid_lanes(tile, tokens)
+        g = jnp.where(valid, g, 0.0)
+        x = jnp.where(_row_to_col(valid.astype(jnp.float32)) > 0, x, 0.0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _first():
+        dphit_ref[...] = jnp.zeros(dphit_ref.shape, jnp.float32)
+
+    dphit_ref[...] += jax.lax.dot_general(
+        g, x, _NN, precision=_HIGHEST, preferred_element_type=jnp.float32)
+    dx_ref[...] = jax.lax.dot_general(
+        g, phit_ref[...], _TN, precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
+    cols_ref[...] = _rows_to_cols(g)
+
+    def finish(rows):
+        col = cols_ref[rows, :]
+        rs = col[:, k + n:k + n + 1]
+        for s, w in _hc_chunks(d):
+            dy = dy_ref[rows, s:s + w]
+            for i in range(n):
+                at = slice(i * d + s, i * d + s + w)
+                dx_ref[rows, at] += (col[:, k + i:k + i + 1] * dy
+                                     + rs * x_ref[rows, at])
+
+    _for_row_chunks(tile, finish)
+
+
+def _hc_merge_fwd_kernel(x_ref, z_ref, coef_ref, o_ref, cols_ref, *, n):
+    tile, d = z_ref.shape
+    cols_ref[...] = _rows_to_cols(coef_ref[...])
+
+    def merge(rows):
+        col = cols_ref[rows, :]
+        for s, w in _hc_chunks(d):
+            z = z_ref[rows, s:s + w].astype(jnp.float32)
+            xs = [x_ref[rows, j * d + s:j * d + s + w] for j in range(n)]
+            for i in range(n):
+                o_ref[rows, i * d + s:i * d + s + w] = col[:, i:i + 1] * z \
+                    + sum(col[:, n + i * n + j:n + i * n + j + 1] * xs[j]
+                          for j in range(n))
+
+    _for_row_chunks(tile, merge)
+
+
+def _hc_merge_bwd_kernel(do_ref, x_ref, z_ref, coef_ref, dx_ref, dz_ref,
+                         dcoef_ref, cols_ref, red_ref, *, n):
+    tile, d = z_ref.shape
+    cols_ref[...] = _rows_to_cols(coef_ref[...])
+
+    def step(rows):
+        col = cols_ref[rows, :]
+        acc = [jnp.zeros((_HC_ROWS, _LANES), jnp.float32)
+               for _ in range(n + n * n)]
+        for s, w in _hc_chunks(d):
+            z = z_ref[rows, s:s + w].astype(jnp.float32)
+            dos = [do_ref[rows, i * d + s:i * d + s + w] for i in range(n)]
+            dz_ref[rows, s:s + w] = sum(
+                col[:, i:i + 1] * dos[i] for i in range(n)
+            ).astype(dz_ref.dtype)
+            for i in range(n):
+                acc[i] = acc[i] + _fold_lanes(dos[i] * z)
+            for j in range(n):
+                xj = x_ref[rows, j * d + s:j * d + s + w]
+                dx_ref[rows, j * d + s:j * d + s + w] = sum(
+                    col[:, n + i * n + j:n + i * n + j + 1] * dos[i]
+                    for i in range(n))
+                for i in range(n):
+                    acc[n + i * n + j] = acc[n + i * n + j] \
+                        + _fold_lanes(dos[i] * xj)
+        red_ref[rows, :] = _place_cols(
+            [jnp.sum(a, axis=1, keepdims=True) for a in acc])
+
+    _for_row_chunks(tile, step)
+    dcoef_ref[...] = jnp.transpose(red_ref[...])[:dcoef_ref.shape[0]]
+
+
+def _hc_call(kernel, tokens, tile, in_specs, out_specs, out_shape, scratch,
+             operands, smem_first=False):
+    """One grid step per tile of tokens, in order (the accumulators of the
+    mix need it; v5e has one core).  Specs are ``("row", width)`` for a
+    [T, width] array blocked by token rows, ``("lane", rows)`` for a
+    [rows, T] array blocked by token lanes, ``("whole", shape)`` for an
+    array every step sees whole."""
+    def spec(kind, arg):
+        if kind == "row":
+            return pl.BlockSpec((tile, arg), lambda i: (i, 0))
+        if kind == "lane":
+            return pl.BlockSpec((arg, tile), lambda i: (0, i))
+        return pl.BlockSpec(arg, lambda i: (0,) * len(arg))
+    specs = [spec(*s) for s in in_specs]
+    if smem_first:
+        specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(tokens, tile),), in_specs=specs,
+        out_specs=[spec(*s) for s in out_specs], out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((tile, _LANES), jnp.float32)
+                        for _ in range(scratch)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_HC_VMEM_LIMIT_BYTES),
+    )(*operands)
+
+
+def _hc_phit(phi, n):
+    """Phi [n * d, k] as the kernels hold it: transposed and padded to
+    [kp, n * d] (1.8 MiB; as it stands it would pad to 128 lanes, 7 MiB)."""
+    k, kp, _ = _hc_rows(n)
+    return jnp.pad(phi.astype(jnp.float32).T, ((0, kp - k), (0, 0)))
+
+
+def _hc_mix_forward(x, phi, alpha, b, n, statics, tile):
+    tokens, width = x.shape
+    k, kp, cp = _hc_rows(n)
+    f32 = jnp.float32
+    bt = jnp.pad(b.astype(f32), (0, kp - k)).reshape(kp, 1)
+    y, m, coef, err = _hc_call(
+        functools.partial(_hc_mix_fwd_kernel, n=n, statics=statics,
+                          tokens=tokens),
+        tokens, tile,
+        [("row", width), ("whole", (kp, width)), ("whole", (kp, 1))],
+        [("row", width // n), ("lane", kp), ("lane", cp),
+         ("whole", (8, _LANES))],
+        [jax.ShapeDtypeStruct((tokens, width // n), f32),
+         jax.ShapeDtypeStruct((kp, tokens), f32),
+         jax.ShapeDtypeStruct((cp, tokens), f32),
+         jax.ShapeDtypeStruct((8, _LANES), f32)],
+        1, [alpha.astype(f32), x, _hc_phit(phi, n), bt], smem_first=True)
+    return (y, coef[:n].T, coef[n:n + n * n].T, err[0, :1]), m
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _hc_mix(x, phi, alpha, b, n, statics, tile):
+    return _hc_mix_forward(x, phi, alpha, b, n, statics, tile)[0]
+
+
+def _hc_mix_fwd(x, phi, alpha, b, n, statics, tile):
+    out, m = _hc_mix_forward(x, phi, alpha, b, n, statics, tile)
+    return out, (x, phi, alpha, b, m)
+
+
+def _hc_mix_bwd(n, statics, tile, res, cts):
+    x, phi, alpha, b, m = res
+    dy, dpost, dc, _ = cts
+    tokens, width = x.shape
+    k, kp, _ = _hc_rows(n)
+    f32 = jnp.float32
+    _, iters, hc_eps, clamp = statics
+    dpre, = _hc_call(
+        functools.partial(_hc_mix_dpre_kernel, n=n), tokens, tile,
+        [("row", width), ("row", width // n)],
+        [("lane", _round_up(n, 8))],
+        [jax.ShapeDtypeStruct((_round_up(n, 8), tokens), f32)],
+        1, [x, dy])
+    # the 20 iterations again, and their gradient, on [24, T] arrays in XLA
+    mt, inv = m[:k], m[k:k + 1]
+    from .decoder_ops import hyper_connection_coefficients_t
+    (pre, _, _, _), vjp = jax.vjp(
+        lambda mt, alpha, bt: hyper_connection_coefficients_t(
+            mt, alpha, bt, n, iters, hc_eps, clamp),
+        mt, alpha.astype(f32), b.astype(f32).reshape(k, 1))
+    dmt, dalpha, dbt = vjp((dpre[:n], dpost.T.astype(f32),
+                            dc.T.astype(f32), jnp.zeros((n, tokens), f32)))
+    # m = (x Phi) inv, inv = rsqrt(mean(x^2) + epsilon): the gradient
+    # reaches x through the matmul (g) and through inv (rs x)
+    g = dmt * inv
+    rs = -jnp.sum(dmt * mt, axis=0, keepdims=True) * inv * inv / width
+    packed = jnp.concatenate(
+        [g, pre, rs, jnp.zeros((kp - k - n - 1, tokens), f32)], axis=0)
+    dx, dphit = _hc_call(
+        functools.partial(_hc_mix_dx_kernel, n=n, tokens=tokens),
+        tokens, tile,
+        [("row", width), ("row", width // n), ("lane", kp),
+         ("whole", (kp, width))],
+        [("row", width), ("whole", (kp, width))],
+        [jax.ShapeDtypeStruct((tokens, width), f32),
+         jax.ShapeDtypeStruct((kp, width), f32)],
+        1, [x, dy, packed, _hc_phit(phi, n)])
+    return (dx, dphit[:k].T.astype(phi.dtype), dalpha.astype(alpha.dtype),
+            dbt.reshape(k).astype(b.dtype))
+
+
+_hc_mix.defvjp(_hc_mix_fwd, _hc_mix_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _hc_mix_jit(x, phi, alpha, b, n, statics, tile):
+    """One trace per (shapes, statics), shared by a program's mixers."""
+    return _hc_mix(x, phi, alpha, b, n, statics, tile)
+
+
+def hyper_connection_mix_tpu(x, phi, alpha, b, n, epsilon, iters, hc_eps,
+                             clamp, tile=None):
+    """The mixer before a branch (``hyper_connection_mix``'s contract):
+    ``x`` [T, n * d] float32 streams -> (Y [T, d], Post [T, n], C [T, n *
+    n], RowSumError [1]).  Forward is one kernel (1.25 passes over the
+    streams); backward is two with the coefficients' gradient between them
+    in XLA (3.5 passes)."""
+    statics = (float(epsilon), int(iters), float(hc_eps),
+               (float(clamp[0]), float(clamp[1])))
+    return _hc_mix_jit(x, phi, alpha, b, int(n), statics, tile or _HC_TILE)
+
+
+def _hc_merge_coef(post, c, n):
+    """Post [T, n], C [T, n * n] -> the packed [cp, T] the kernels read."""
+    cp = _hc_rows(n)[2]
+    f32 = jnp.float32
+    return jnp.pad(jnp.concatenate([post.astype(f32).T, c.astype(f32).T]),
+                   ((0, cp - n - n * n), (0, 0)))
+
+
+def _hc_merge_forward(x, z, post, c, tile):
+    tokens, width = x.shape
+    n = post.shape[-1]
+    cp = _hc_rows(n)[2]
+    out, = _hc_call(
+        functools.partial(_hc_merge_fwd_kernel, n=n), tokens, tile,
+        [("row", width), ("row", width // n), ("lane", cp)],
+        [("row", width)],
+        [jax.ShapeDtypeStruct((tokens, width), jnp.float32)],
+        1, [x, z, _hc_merge_coef(post, c, n)])
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _hc_merge(x, z, post, c, tile):
+    return _hc_merge_forward(x, z, post, c, tile)
+
+
+def _hc_merge_fwd(x, z, post, c, tile):
+    return _hc_merge_forward(x, z, post, c, tile), (x, z, post, c)
+
+
+def _hc_merge_bwd(tile, res, do):
+    x, z, post, c = res
+    tokens, width = x.shape
+    n = post.shape[-1]
+    cp = _hc_rows(n)[2]
+    f32 = jnp.float32
+    dx, dz, dcoef = _hc_call(
+        functools.partial(_hc_merge_bwd_kernel, n=n), tokens, tile,
+        [("row", width), ("row", width), ("row", width // n), ("lane", cp)],
+        [("row", width), ("row", width // n), ("lane", cp)],
+        [jax.ShapeDtypeStruct((tokens, width), f32),
+         jax.ShapeDtypeStruct(z.shape, z.dtype),
+         jax.ShapeDtypeStruct((cp, tokens), f32)],
+        2, [do.astype(f32), x, z, _hc_merge_coef(post, c, n)])
+    return (dx, dz, dcoef[:n].T.astype(post.dtype),
+            dcoef[n:n + n * n].T.astype(c.dtype))
+
+
+_hc_merge.defvjp(_hc_merge_fwd, _hc_merge_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _hc_merge_jit(x, z, post, c, tile):
+    """One trace per shapes, shared by a program's mixers."""
+    return _hc_merge(x, z, post, c, tile)
+
+
+def hyper_connection_merge_tpu(x, z, post, c, tile=None):
+    """The mixer after a branch (``hyper_connection_merge``'s contract):
+    ``x`` [T, n * d] float32, ``z`` [T, d] in the dtype it arrives in
+    (widened on the core), ``post`` [T, n], ``c`` [T, n * n] -> Out [T, n *
+    d], ``Out[i] = post_i z + sum_j c[i, j] x[j]``: 2.25 passes forward,
+    3.5 backward (one kernel each)."""
+    return _hc_merge_jit(x, z, post, c, tile or _HC_TILE)
 
 
 # ---------------------------------------------------------------------------

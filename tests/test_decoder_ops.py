@@ -515,3 +515,158 @@ def test_mixer_starts_as_the_plain_residual():
         np.testing.assert_allclose(ov[..., i * 8:(i + 1) * 8], ev + 3 * ev,
                                    rtol=5e-2, atol=5e-3)
     assert err.shape == (1,) and 0 <= err[0] < 1e-4
+
+
+# ---------------------------------------------------------------------------
+# the mixers' Pallas kernels (ops/pallas_kernels.py) against the jnp spelling
+# of the two lowerings above, under the TPU interpreter
+# ---------------------------------------------------------------------------
+
+_MIXER_OUTS = ("Y", "Post", "C", "RowSumError", "Out")
+_MIXER_GRADS = ("dX", "dPhi", "dAlpha", "dB", "dZ", "dPost", "dC")
+
+
+@pytest.fixture(scope="module")
+def mixer_kernels_and_spelling():
+    """{name: (kernel's, spelling's)} for every output and gradient of a
+    mix + merge at n = 4, d = 256, 2 x 40 tokens in tiles of 32: the last
+    tile is ragged, and what the interpreter pads it with must reach
+    neither dPhi nor RowSumError."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_kernels as pk
+    n, d = 4, 256
+    ins = _mixer_inputs(t=80, d=d, seed=11)
+    ins["Phi"] = ins["Phi"] * 0.2
+    x3 = ins["X"].reshape(2, 40, n * d)
+    z = _rand(2, 40, d, seed=12).astype(jnp.bfloat16)
+
+    def spelled(x, phi, alpha, b, z):
+        mixed = get_op("hyper_connection_mix").fn(
+            {"X": [x], "Phi": [phi], "Alpha": [alpha], "B": [b]},
+            _MIX_ATTRS, CTX)
+        y, post, c = (mixed[s][0] for s in ("Y", "Post", "C"))
+        # post and c reach the result twice: through the merge and as they
+        # are, so that dPost and dC of the merge are in the gradient
+        out = get_op("hyper_connection_merge").fn(
+            {"X": [x], "Z": [z], "Post": [post * 1.0], "C": [c * 1.0]},
+            {}, CTX)["Out"][0]
+        return y, post, c, mixed["RowSumError"][0], out
+
+    def kernels(x, phi, alpha, b, z):
+        rows = x.reshape(-1, n * d)
+        y, post, c, err = pk.hyper_connection_mix_tpu(
+            rows, phi, alpha, b, n, 1e-6, 20, 1e-6, (-30.0, 30.0), tile=32)
+        out = pk.hyper_connection_merge_tpu(
+            rows, z.reshape(-1, d), post * 1.0, c * 1.0, tile=32)
+        return (y.reshape(2, 40, d), post.reshape(2, 40, n),
+                c.reshape(2, 40, n * n), err, out.reshape(x.shape))
+
+    args = (x3, ins["Phi"], ins["Alpha"], ins["B"], z)
+    with jax.default_matmul_precision("highest"):
+        want, ref_vjp = jax.vjp(spelled, *args)
+        cot = tuple(_rand(*w.shape, seed=20 + i).astype(w.dtype)
+                    for i, w in enumerate(want))
+        want_g = ref_vjp(cot)
+        with pltpu.force_tpu_interpret_mode():
+            got, vjp = jax.vjp(kernels, *args)
+            got_g = vjp(cot)
+
+        # the merge's own gradients with respect to Post and C
+        def merge_of(fn):
+            return jax.vjp(fn, want[1], want[2])[1](cot[4])
+        want_pc = merge_of(lambda p, c: get_op("hyper_connection_merge").fn(
+            {"X": [x3], "Z": [z], "Post": [p], "C": [c]}, {}, CTX)["Out"][0])
+        with pltpu.force_tpu_interpret_mode():
+            got_pc = merge_of(lambda p, c: pk.hyper_connection_merge_tpu(
+                x3.reshape(-1, n * d), z.reshape(-1, d), p.reshape(-1, n),
+                c.reshape(-1, n * n), tile=32).reshape(x3.shape))
+    pairs = dict(zip(_MIXER_OUTS, zip(got, want)))
+    pairs.update(zip(_MIXER_GRADS[:5], zip(got_g, want_g)))
+    pairs.update(zip(_MIXER_GRADS[5:], zip(got_pc, want_pc)))
+    return pairs
+
+
+@pytest.mark.parametrize("name", _MIXER_OUTS + _MIXER_GRADS)
+def test_mixer_kernels_equal_the_jnp_spelling(mixer_kernels_and_spelling,
+                                              name):
+    got, want = mixer_kernels_and_spelling[name]
+    assert got.shape == want.shape and got.dtype == want.dtype, name
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all(), name
+    # float32 sums in another order; dZ is rounded to the branch's bfloat16
+    tol = 1e-2 if name == "dZ" else 2e-5
+    np.testing.assert_allclose(got, want, rtol=tol,
+                               atol=tol * np.abs(want).max())
+
+
+def test_mixer_lowerings_take_the_kernels_where_the_context_allows():
+    """Through the two op lowerings with a context that answers as the chip
+    does: [2, 128, 4 x 128] streams are two whole tiles, the kernels run
+    (under the interpreter), the counter says so and the outputs keep the
+    ops' shapes."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.fluid import trace
+
+    class OnTheChip(LoweringContext):
+        def pallas_ok(self):
+            return True
+
+    ins = _mixer_inputs(t=256, d=128, seed=4)
+    x3 = ins["X"].reshape(2, 128, 512)
+    z = _rand(2, 128, 128, seed=5)
+
+    def run(ctx):
+        mixed = get_op("hyper_connection_mix").fn(
+            {"X": [x3], "Phi": [ins["Phi"]], "Alpha": [ins["Alpha"]],
+             "B": [ins["B"]]}, _MIX_ATTRS, ctx)
+        out = get_op("hyper_connection_merge").fn(
+            {"X": [x3], "Z": [z], "Post": mixed["Post"], "C": mixed["C"]},
+            {}, ctx)["Out"][0]
+        return [mixed[s][0] for s in ("Y", "Post", "C", "RowSumError")] \
+            + [out]
+
+    counters = {p: trace.metrics().counter(f"hyper_connection.lowering.{p}")
+                for p in ("pallas", "xla")}
+    before = {p: c.value for p, c in counters.items()}
+    with jax.default_matmul_precision("highest"):
+        want = run(CTX)
+        with pltpu.force_tpu_interpret_mode():
+            got = run(OnTheChip(base_key=jax.random.PRNGKey(0)))
+    assert {p: c.value - before[p] for p, c in counters.items()} \
+        == {"pallas": 2, "xla": 2}
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5)
+
+
+def test_mixer_lowerings_count_xla_on_the_cpu():
+    from paddle_tpu.fluid import trace
+    counters = {p: trace.metrics().counter(f"hyper_connection.lowering.{p}")
+                for p in ("pallas", "xla")}
+    before = {p: c.value for p, c in counters.items()}
+    ins = _mixer_inputs(t=128, d=128)
+    mixed = get_op("hyper_connection_mix").fn(
+        {k: [v] for k, v in ins.items()}, _MIX_ATTRS, CTX)
+    get_op("hyper_connection_merge").fn(
+        {"X": [ins["X"]], "Z": [_rand(128, 128)], "Post": mixed["Post"],
+         "C": mixed["C"]}, {}, CTX)
+    assert {p: c.value - before[p] for p, c in counters.items()} \
+        == {"pallas": 0, "xla": 2}
+
+
+@pytest.mark.parametrize("dtype, tokens, d, n, want", [
+    ("float32", 4096, 3584, 4, True),      # the cell's streams
+    ("float32", 4096, 3584, 2, True),
+    ("bfloat16", 4096, 3584, 4, False),    # the streams are float32
+    ("float32", 4096, 200, 4, False),      # a stream is whole lane groups
+    ("float32", 4096, 200, 2, False),
+    ("float32", 4096 + 40, 3584, 4, True),  # the last tile may be ragged
+    ("float32", 80, 256, 4, False),        # at least one tile of tokens
+    ("float32", 256, 16384, 4, False),     # a tile past the VMEM budget
+    ("float32", 256, 128, 10, False),      # 131 rows in one transpose
+])
+def test_hyper_connection_supported(dtype, tokens, d, n, want):
+    from paddle_tpu.ops.pallas_kernels import hyper_connection_supported
+    x = jax.ShapeDtypeStruct((1, tokens, n * d), jnp.dtype(dtype))
+    assert hyper_connection_supported(x, n) is want

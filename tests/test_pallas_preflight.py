@@ -141,3 +141,35 @@ class TestRepoKernels:
             lambda k: pk.fused_attention_keep_mask((8, 12, 512, 64), 512,
                                                    0.1, k), KEY)
 
+
+    @pytest.mark.parametrize("op, tokens, z_dtype", [
+        ("mix", 4096, None), ("merge", 4096, "float32"),
+        ("mix", 4096 + 40, None), ("merge", 4096 + 40, "bfloat16")])
+    def test_hyper_connection_mixers_fwd_bwd(self, op, tokens, z_dtype):
+        """Both mixer ops, forward and backward (five kernels), at
+        ``xing4_train_seq4096``'s shape: 4096 tokens of four float32 streams
+        of 3584, ``Phi`` [14336, 24], the branch's output widened by XLA
+        before the merge sees it; and with a ragged last tile, the branch's
+        output in bfloat16."""
+        f32 = jnp.float32
+        n, d = 4, 3584
+        x = jax.ShapeDtypeStruct((tokens, n * d), f32)
+        assert pk.hyper_connection_supported(x, n)
+        if op == "mix":
+            def f(x, phi, alpha, b):
+                return pk.hyper_connection_mix_tpu(
+                    x, phi, alpha, b, n, 1e-6, 20, 1e-6, (-30.0, 30.0))[:3]
+            args = (x, jax.ShapeDtypeStruct((n * d, n * n + 2 * n), f32),
+                    jax.ShapeDtypeStruct((3,), f32),
+                    jax.ShapeDtypeStruct((n * n + 2 * n,), f32))
+        else:
+            f = pk.hyper_connection_merge_tpu
+            args = (x, jax.ShapeDtypeStruct((tokens, d), jnp.dtype(z_dtype)),
+                    jax.ShapeDtypeStruct((tokens, n), f32),
+                    jax.ShapeDtypeStruct((tokens, n * n), f32))
+        assert_mosaic_lowerable(f, *args)
+
+        def grads(*a):
+            out, vjp = jax.vjp(f, *a)
+            return vjp(out)
+        assert_mosaic_lowerable(grads, *args)
