@@ -712,6 +712,80 @@ def roll_hyper_connection(tiny, tpu, key):
     return {"cases": cases}
 
 
+def roll_sparse_attention(tiny, tpu, key):
+    """The indexer's selection and the attention over it through their
+    lowerings at the training cell's shape (32 : 4 heads of 128, 16 index
+    heads of 64, 16384 tokens that keep 2048 keys), forward and every
+    gradient of the attention, against the ``jnp`` path the same lowerings
+    take where the context allows no kernel; and the selection's count."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    class NoKernel(LoweringContext):
+        def kernel_site(self, x):
+            return None
+
+        def pallas_ok(self):
+            return False
+
+    seq, topk = (2048, 128) if tiny else (16384, 2048)
+    hq, hkv, d, hi, di = (4, 2, 128, 2, 64) if tiny else (32, 4, 128, 16, 64)
+    ks = jax.random.split(key, 8)
+    q = jax.random.normal(ks[0], (1, hq, seq, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, hkv, seq, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, hkv, seq, d), jnp.bfloat16)
+    w_out = jax.random.normal(ks[3], (1, hq, seq, d), jnp.float32)
+    index_in = {"QI": [jax.random.normal(ks[4], (1, hi, seq, di),
+                                         jnp.bfloat16)],
+                "KI": [jax.random.normal(ks[5], (1, seq, di), jnp.bfloat16)],
+                "W": [jax.random.normal(ks[6], (1, seq, hi), jnp.float32)]}
+    chosen, _ = run_lowered(
+        jax.jit(lambda ins: get_op("sparse_attention_index").fn(
+            ins, {"topk": topk}, LoweringContext(base_key=key))),
+        index_in, expect_mosaic=False if tpu else None)
+    sel = chosen["Selection"][0]
+    mean = float(chosen["SelectedKeysMean"][0][0])
+    want_mean = (topk * (topk + 1) / 2 + (seq - topk) * topk) / seq
+    assert abs(mean - want_mean) < 0.01, (mean, want_mean)
+
+    def fwd_bwd(ctx_type):
+        """The attention and the indexer's loss behind it: the loss reads
+        the attention's log-sum-exp."""
+        def f(q, k, v, qi, ki, w):
+            ctx = ctx_type(base_key=key)
+            attended = get_op("fused_multihead_attention").fn(
+                {"Q": [q], "K": [k], "V": [v], "Selection": [sel]},
+                {"causal": True, "scale": d ** -0.5}, ctx)
+            loss = get_op("sparse_attention_index_loss").fn(
+                {"QI": [qi], "KI": [ki], "W": [w], "Q": [q], "K": [k],
+                 "LSE": attended["LSE"], "Selection": [sel]},
+                {"scale": d ** -0.5}, ctx)["Loss"][0]
+            return attended["Out"][0], loss
+
+        def g(*args):
+            (out, loss), vjp = jax.vjp(f, *args)
+            return (out, loss) + vjp((w_out.astype(out.dtype),
+                                      jnp.ones_like(loss)))
+        return jax.jit(g)
+
+    args = (q, k, v, index_in["QI"][0], index_in["KI"][0], index_in["W"][0])
+    got, n_calls = run_lowered(fwd_bwd(LoweringContext), *args,
+                               expect_mosaic=tpu)
+    want = fwd_bwd(NoKernel)(*args)
+    names = ("out", "index_loss", "dq", "dk", "dv", "dqi", "dki", "dw")
+    errs = {name: rel_err(a, r) for name, a, r in zip(names, got, want)}
+    # bfloat16 operands on both sides, float32 sums in another order
+    assert all(e < 2e-2 for e in errs.values()), errs
+    if tpu:
+        # forward, dq, dk/dv, and the loss's probabilities a super block
+        assert n_calls == 3 + seq // 2048, n_calls
+    return {"shape": [hq, hkv, seq, d], "topk": topk, "mosaic": n_calls,
+            "selected_keys_mean": mean,
+            "tile_occupancy": float(chosen["TileOccupancy"][0][0]),
+            "rel_err": {k_: float(f"{e:.2e}") for k_, e in errs.items()}}
+
+
 # which of pallas_kernels.__all__ each roll-call entry drives
 ROLL_CALL = [
     ("flash", roll_flash, ["flash_attention_tpu"]),
@@ -723,6 +797,8 @@ ROLL_CALL = [
     ("paged", roll_paged, ["paged_flash_attention_tpu"]),
     ("hyper_connection", roll_hyper_connection,
      ["hyper_connection_mix_tpu", "hyper_connection_merge_tpu"]),
+    ("sparse_attention", roll_sparse_attention,
+     ["selected_attention_tpu", "selected_probability_mean_tpu"]),
 ]
 
 

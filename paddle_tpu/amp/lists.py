@@ -25,10 +25,29 @@ WHITE_OPS = {
     # the combine gathers the experts' bf16 rows and sums them in float32
     # under the routing weights, which stay float32 (KEEP_FP32_SLOTS)
     "moe_grouped_matmul", "moe_dispatch", "moe_combine",
+    # attention over a learned key set (ops/sparse_attention.py): the index
+    # scores take bf16 queries and keys with float32 accumulation (the
+    # published models run their indexers in fp8), and the indexer's loss
+    # the attention's own bf16 q and k; the ReLU, the per-head weights, the
+    # sum over heads, the selection and the loss's softmaxes are float32
+    # inside the lowerings.  Both ops see the same casts, so the loss scores
+    # the pairs exactly as the selection did.  (The loss's float32 output is
+    # summed by `sum`, a black op.)
+    "sparse_attention_index", "sparse_attention_index_loss",
+    # a decoder's head with its loss (ops/decoder_ops.py): the matmul as
+    # `mul` runs it, bf16 operands and float32 logits; the log-sum-exp and
+    # the loss are float32 inside the lowering
+    "linear_cross_entropy",
 }
 # input slots of white ops that keep float32 all the same: small operands
 # whose precision decides the result
-KEEP_FP32_SLOTS = {"moe_combine": ("TopKWeight",)}
+KEEP_FP32_SLOTS = {"moe_combine": ("TopKWeight",),
+                   # positions up to the context length: bfloat16 holds
+                   # whole numbers up to 256 only (at 16384 it rounds to
+                   # multiples of 64 and every angle is wrong)
+                   "rotary_embedding": ("Positions",),
+                   "sparse_attention_index": ("W",),
+                   "sparse_attention_index_loss": ("W",)}
 BLACK_OPS = {
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
     "reduce_mean", "reduce_sum", "mean", "sum", "exp",

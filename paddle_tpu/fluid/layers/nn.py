@@ -470,30 +470,138 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
                           {"epsilon": epsilon}, out_slot="Y")
 
 
-def rotary_embedding(input, inv_freq, scale=1.0, name=None):
+def rotary_embedding(input, inv_freq, scale=1.0, name=None, positions=None,
+                     sections=None):
     """Rotary position embedding of ``input`` [..., S, D] (rotate-half
     convention) under the D/2 frequencies ``inv_freq``, cos and sin times
-    ``scale``."""
+    ``scale``.  ``positions`` [R, S] replaces 0..S-1: frequency ``i`` turns
+    by the row that ``sections`` (how many consecutive frequencies each row
+    takes; they add up to D/2) gives it."""
     helper = LayerHelper("rotary_embedding", name=name)
-    return _append_single(
-        helper, "rotary_embedding", {"X": [input]}, input.dtype,
-        {"inv_freq": [float(f) for f in inv_freq], "scale": float(scale)})
+    inputs = {"X": [input]}
+    attrs = {"inv_freq": [float(f) for f in inv_freq], "scale": float(scale)}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+        attrs["sections"] = [int(n) for n in sections or [len(inv_freq)]]
+    return _append_single(helper, "rotary_embedding", inputs, input.dtype,
+                          attrs)
+
+
+def linear_cross_entropy(input, label, size, param_attr=None, name=None):
+    """A decoder's head and its loss as one op: ``-log softmax(input W)
+    [label]`` of every token, ``input`` [..., H], ``label`` [..., 1] ->
+    [..., 1] float32, with ``W`` [H, ``size``] this layer's parameter.
+    What ``fc`` + ``softmax_with_cross_entropy`` compute, in blocks of
+    tokens: the [tokens, size] logits, their softmax and their gradient never
+    exist whole (at 16384 tokens and 18992 classes they are 2.3 GiB of the
+    step's fullest moment), at the price of the head's matmul once more in
+    backward."""
+    helper = LayerHelper("linear_cross_entropy", name=name)
+    w = helper.create_parameter(param_attr, [int(input.shape[-1]), size],
+                                "float32")
+    return _append_single(helper, "linear_cross_entropy",
+                          {"X": [input], "W": [w], "Label": [label]},
+                          "float32", out_slot="Loss")
+
+
+def sparse_attention_index(qi, ki, w, topk, gauges=None, name=None):
+    """The indexer of attention over a learned key set (ops/
+    sparse_attention.py): index queries ``qi`` [B, HI, S, DI], one index key
+    a token ``ki`` [B, S, DI], per-token head weights ``w`` [B, S, HI] ->
+    the selection [B, S, S / 8] uint8 (a bit a pair) of the ``min(t + 1,
+    topk)`` best keys ``s <= t`` of every query by ``sum_j w[t, j] relu(qi[t, j] . ki[s])``,
+    exact, ties to the smaller ``s``; no gradient passes.  With ``gauges``
+    (a name such as ``layer_3``) the mean number of selected keys and the
+    share of causal 512 x 512 tiles that hold a selected pair stay on the
+    device in ``<gauges>.selected_keys_mean`` and ``.tile_occupancy`` and
+    are published as ``dsa.<gauges>.…`` when an ``AsyncStepRunner``
+    drains."""
+    helper = LayerHelper("sparse_attention_index", name=name)
+    sel = helper.create_variable_for_type_inference(dtype="uint8",
+                                                    stop_gradient=True)
+    outputs = {"Selection": [sel]}
+    if gauges:
+        outputs.update(_device_gauges(helper, gauges, {
+            "SelectedKeysMean": "selected_keys_mean",
+            "TileOccupancy": "tile_occupancy"}))
+    helper.append_op("sparse_attention_index",
+                     inputs={"QI": [qi], "KI": [ki], "W": [w]},
+                     outputs=outputs, attrs={"topk": int(topk)})
+    return sel
+
+
+def sparse_attention_index_loss(qi, ki, w, q, k, lse, selection, scale,
+                                weight=1.0, gauges=None, name=None):
+    """What trains the indexer: ``weight`` x the mean over queries of ``KL(p
+    || softmax over the selected keys of the index scores)``, ``p`` the
+    selected attention's probabilities (of ``q`` [B, Hq, S, D] and ``k``
+    [B, Hkv, S, D] under ``scale``, as the attention op sees them, and its
+    log-sum-exp ``lse``: ``fused_multihead_attention(return_lse=True)``)
+    averaged over the heads and held constant: [1] float32, whose gradient
+    reaches ``qi``, ``ki`` and ``w`` and nothing else.  With ``gauges`` the
+    unweighted loss is published as ``dsa.<gauges>.index_kl``."""
+    helper = LayerHelper("sparse_attention_index_loss", name=name)
+    loss = helper.create_variable_for_type_inference(dtype="float32")
+    outputs = {"Loss": [loss]}
+    if gauges:
+        outputs.update(_device_gauges(helper, gauges,
+                                      {"IndexKL": "index_kl"}))
+    helper.append_op(
+        "sparse_attention_index_loss",
+        inputs={"QI": [qi], "KI": [ki], "W": [w], "Q": [q], "K": [k],
+                "LSE": [lse], "Selection": [selection]},
+        outputs=outputs, attrs={"scale": float(scale),
+                                "weight": float(weight)})
+    return loss
+
+
+def _device_gauges(helper, prefix, slots):
+    """{slot: [a persistable float32 [1] variable ``<prefix>.<suffix>``]},
+    each entered among the program's device counters as ``dsa.<prefix>.
+    <suffix>`` (``AsyncStepRunner`` publishes them when it drains)."""
+    from ..framework import default_main_program
+    counters = default_main_program()._hints.setdefault("device_counters",
+                                                        {})
+    out = {}
+    for slot, suffix in slots.items():
+        var = helper.block().create_var(
+            name=f"{prefix}.{suffix}", shape=[1], dtype="float32",
+            persistable=True, stop_gradient=True)
+        counters[var.name] = "dsa." + var.name
+        out[slot] = [var]
+    return out
 
 
 def fused_multihead_attention(q, k, v, scale=None, causal=False, window=0,
-                              name=None):
+                              name=None, selection=None, return_lse=False):
     """softmax(q k^T * scale) v over [B, heads, S, D] operands; ``scale``
     defaults to D ** -0.5.  ``v`` may be [B, heads, S, Dv] with another
     width than the scores' D (the output then has Dv).
     ``k`` and ``v`` may have fewer heads than ``q`` (each shared by a group
     of query heads); ``causal`` attends j <= i, ``window`` > 0 only
-    0 <= i - j < window."""
+    0 <= i - j < window; ``selection`` [B, S, S / 8] uint8
+    (``sparse_attention_index``'s; with ``causal``) only the keys it
+    marks.  ``return_lse`` (with a ``selection``): also the log-sum-exp of
+    every query's scores over its keys, [B, heads, S] float32, which no
+    gradient passes through."""
     helper = LayerHelper("fused_multihead_attention", name=name)
     inputs = {"Q": [q], "K": [k], "V": [v]}
+    if selection is not None:
+        inputs["Selection"] = [selection]
     attrs = {"causal": bool(causal), "window": int(window),
              "num_kv_heads": int(k.shape[1])}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if return_lse:
+        if selection is None:
+            raise ValueError("return_lse: only a call with a selection "
+                             "hands its log-sum-exp over")
+        out = helper.create_variable_for_type_inference(dtype=q.dtype)
+        lse = helper.create_variable_for_type_inference(
+            dtype="float32", stop_gradient=True)
+        helper.append_op("fused_multihead_attention", inputs=inputs,
+                         outputs={"Out": [out], "LSE": [lse]}, attrs=attrs)
+        return out, lse
     return _append_single(helper, "fused_multihead_attention", inputs,
                           q.dtype, attrs)
 
@@ -503,7 +611,7 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
                  up_attr=None, down_attr=None, name=None, scoring="softmax",
                  routed_scaling_factor=1.0, correction_bias_attr=None,
                  shared_size=0, shared_gate_attr=None, shared_up_attr=None,
-                 shared_down_attr=None):
+                 shared_down_attr=None, max_held_rows=None):
     """Sparse experts over tokens ``input`` [T, D] without dropping: the
     router scores all ``num_experts``, each token goes to its ``top_k`` best,
     and this program holds the ``num_held`` experts from ``first_expert`` on
@@ -520,7 +628,9 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
     ``correction_bias_attr`` says otherwise), weights the chosen sigmoids
     over their sum.  Both times ``routed_scaling_factor``.  ``shared_size``
     > 0 adds a shared expert of that width, a gated FFN every token takes
-    at weight 1."""
+    at weight 1.  ``max_held_rows`` sizes the buffers of held assignments
+    (default: the worst case, T * ``top_k``); a step that routes more to
+    the held experts fails (NaN) and drops nothing."""
     from .tensor import create_global_var
     from ..framework import default_main_program
     name = name or unique_name("expert_layer")
@@ -564,7 +674,9 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
                  "StepsOut": [steps], **plan},
         attrs={"top_k": int(top_k), "first_expert": int(first_expert),
                "num_held": int(num_held), "scoring": str(scoring),
-               "routed_scaling_factor": float(routed_scaling_factor)})
+               "routed_scaling_factor": float(routed_scaling_factor),
+               **({"max_rows": int(max_held_rows)} if max_held_rows
+                  else {})})
     rows = _append_single(helper, "moe_dispatch", {"X": [input], **plan},
                           input.dtype)
 

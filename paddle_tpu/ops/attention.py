@@ -9,7 +9,7 @@ plain static programs get the kernels without touching model code.  Where
 a kernel runs is answered in two places and nowhere else (docs/passes.md
 "Where a kernel runs"): `KernelSite.on` (ops/registry.py) says how a call
 sees the batch on the program's mesh, `attention_path` here says which of
-four paths one chip's operands take:
+five paths one chip's operands take:
 
 * `splash_kernel` — `pallas_kernels.splash_attention_tpu`: causal attention
   from `_STREAM_MIN_SEQ` (1024) up, and every length with a sliding `window`
@@ -23,6 +23,10 @@ four paths one chip's operands take:
   (`LoweringContext.kernel_site`);
 * `flash_kernel` — jax's flash kernel, K/V streamed through VMEM: from
   `_STREAM_MIN_SEQ` up, dropout-free;
+* `selected_kernel` — `pallas_kernels.selected_attention_tpu`: a call that
+  brings a `Selection` (a bit a (query, key) pair, [S, S / 8] uint8 a
+  sequence, chosen on the device by the `sparse_attention_index` op: ops/sparse_attention.py), the
+  splash kernel with that mask as data, shared by all the heads;
 * `xla` — `_reference_attention`, the XLA softmax(QK^T)V: the CPU, a
   program partitioned any other way (`tp`, a mesh with further axes: a
   Mosaic call cannot be partitioned automatically, and only the fused
@@ -30,13 +34,15 @@ four paths one chip's operands take:
   not cover.  With a `window` or grouped heads it is
   `_banded_attention`: blocks of queries against the keys of their band,
   each block recomputed in backward, so that no [S, S] scores exist there
-  either.
+  either; with a `Selection` `sparse_attention.selected_attention`, blocks
+  of queries likewise.
 
 `attention.lowering.<path>` in `trace.metrics()` counts the picks, once per
 lowering of an op (a training program lowers each attention once: its grad
 op applies the vjp the forward op kept; twice where the grad op has to trace
 the forward again, `backward.vjp_retraced`); a causal op also counts
-`attention.lowering.<path>.window` or `.full_causal`.
+`attention.lowering.<path>.window` or `.full_causal`; a call with a
+selection counts `sparse_attention.lowering.<path>` as well.
 """
 from __future__ import annotations
 
@@ -141,11 +147,18 @@ def _bias_broadcastable(mask, q, k) -> bool:
     return all(m == 1 or m == t for m, t in zip(mask.shape, target))
 
 
-def attention_path(q, k, v, mask, causal, drop_active, window=0) -> str:
+def attention_path(q, k, v, mask, causal, drop_active, window=0,
+                   selection=None) -> str:
     """Which lowering attention over one chip's operands takes on a chip:
-    ``splash_kernel``, ``fused_kernel``, ``flash_kernel`` or ``xla``.  A
-    function of shapes and dtypes alone (the operands may be
-    ShapeDtypeStructs, or a block's declared variables)."""
+    ``splash_kernel``, ``fused_kernel``, ``flash_kernel``,
+    ``selected_kernel`` (only a call that brings a ``selection``, and every
+    such call the kernel covers) or ``xla``.  A function of shapes and
+    dtypes alone (the operands may be ShapeDtypeStructs, or a block's
+    declared variables)."""
+    if selection is not None:
+        from .pallas_kernels import selected_attention_supported
+        return "selected_kernel" if mask is None and not drop_active \
+            and selected_attention_supported(q, k, v, selection) else "xla"
     seq = q.shape[-2]
     grouped = k.shape[1] != q.shape[1]
     if (window or grouped or seq >= _STREAM_MIN_SEQ) and causal \
@@ -165,7 +178,8 @@ def attention_path(q, k, v, mask, causal, drop_active, window=0) -> str:
     return "xla"
 
 
-def path_at(site, q, k, v, mask, causal, drop_active, window=0) -> str:
+def path_at(site, q, k, v, mask, causal, drop_active, window=0,
+            selection=None) -> str:
     """The path of a call whose kernel would run at ``site``
     (``KernelSite.on`` / ``LoweringContext.kernel_site``; None: XLA):
     ``attention_path`` of the rows one call sees.  Only the fused kernel
@@ -175,7 +189,9 @@ def path_at(site, q, k, v, mask, causal, drop_active, window=0) -> str:
     batched_mask = mask is not None and mask.shape[0] == q.shape[0]
     path = attention_path(site.local(q), site.local(k), site.local(v),
                           site.local(mask) if batched_mask else mask,
-                          causal, drop_active, window)
+                          causal, drop_active, window,
+                          None if selection is None
+                          else site.local(selection))
     if site.shards > 1 and path != "fused_kernel":
         return "xla"
     return path
@@ -184,7 +200,7 @@ def path_at(site, q, k, v, mask, causal, drop_active, window=0) -> str:
 def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                     dropout_rate=0.0, dropout_key=None,
                     dropout_upscale=True, prob_scale=None, window=0,
-                    site=None):
+                    site=None, selection=None):
     """Dispatch to a Pallas TPU kernel where one covers the call, else XLA
     (``path_at``).  ``site`` says where the call's kernel runs: None (the
     default) is XLA; an op lowering passes ``ctx.kernel_site(q)``; a
@@ -200,6 +216,14 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
     ``v`` may have fewer heads than ``q``, each shared by a group of query
     heads.  Both are causal, mask-free and dropout-free: the splash kernel,
     or ``_banded_attention`` in XLA.
+
+    ``selection`` [B, S, S / 8] uint8 (with ``causal``; no window, mask or
+    dropout) keeps, for query t, the keys s whose bit of row t is set
+    (ops/sparse_attention.py chooses them among s <= t): the selected
+    kernel, or ``sparse_attention.selected_attention`` in XLA.  Such a call
+    returns ``(out, lse)``, ``lse`` [B, Hq, S] float32 the log-sum-exp of
+    every query's scaled scores over its keys (no gradient passes through
+    it): what the indexer's loss forms the probabilities from.
     """
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     drop_active = bool(dropout_rate) and dropout_key is not None
@@ -209,12 +233,25 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                    or prob_scale is not None):
         raise ValueError("attention with a window or grouped key/value "
                          "heads is causal, without mask or dropout")
-    path = path_at(site, q, k, v, mask, causal, drop_active, window)
-    if path in ("flash_kernel", "splash_kernel") \
+    if selection is not None and (window or not causal
+                                  or mask is not None or drop_active
+                                  or prob_scale is not None):
+        raise ValueError("attention over a selection is causal, without "
+                         "window, mask or dropout")
+    path = path_at(site, q, k, v, mask, causal, drop_active, window,
+                   selection)
+    if path in ("flash_kernel", "splash_kernel", "selected_kernel") \
             and (prob_scale is not None or scale == 0.0):
         path = "xla"
     from ..fluid import trace
     trace.metrics().counter(f"attention.lowering.{path}").inc()
+    if selection is not None:
+        trace.metrics().counter(f"sparse_attention.lowering.{path}").inc()
+        if path == "selected_kernel":
+            from .pallas_kernels import selected_attention_tpu as attend
+        else:
+            from .sparse_attention import selected_attention as attend
+        return attend(q, k, v, selection, scale)
     if causal:
         trace.metrics().counter(
             f"attention.lowering.{path}."
@@ -248,7 +285,8 @@ def flash_attention(q, k, v, mask=None, scale=None, causal=False,
                                 dropout_key, dropout_upscale, prob_scale)
 
 
-@register_op("fused_multihead_attention", nondiff_inputs=("Mask",))
+@register_op("fused_multihead_attention",
+             nondiff_inputs=("Mask", "Selection"), nondiff_outputs=("LSE",))
 def _fused_mha(ins, attrs, ctx):
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     mask = ins["Mask"][0] if ins.get("Mask") else None
@@ -267,13 +305,16 @@ def _fused_mha(ins, attrs, ctx):
                 prob_scale = 1.0 - rate
         else:
             dropout_key = ctx.key_for(attrs.get("dropout_seed", 0))
+    selection = ins["Selection"][0] if ins.get("Selection") else None
     out = flash_attention(q, k, v, mask,
                           scale=attrs.get("scale", None),
                           causal=attrs.get("causal", False),
                           dropout_rate=rate, dropout_key=dropout_key,
                           dropout_upscale=upscale, prob_scale=prob_scale,
                           window=attrs.get("window", 0),
-                          site=ctx.kernel_site(q))
+                          site=ctx.kernel_site(q), selection=selection)
+    if selection is not None:
+        return {"Out": [out[0]], "LSE": [out[1]]}
     return {"Out": [out]}
 
 

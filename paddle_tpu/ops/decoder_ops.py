@@ -16,8 +16,11 @@ streams the branch's output and the mixed old streams.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..parallel import moe
 from .registry import register_op
@@ -36,22 +39,35 @@ def _rms_norm(ins, attrs, ctx):
     return {"Y": [y.astype(x.dtype)]}
 
 
-@register_op("rotary_embedding")
+@register_op("rotary_embedding", nondiff_inputs=("Positions",))
 def _rotary_embedding(ins, attrs, ctx):
     """Rotate the halves of X [..., S, D] by position: with ``inv_freq`` the
     D/2 frequencies (an attribute: a layer's frequencies are data, not code)
     and ``scale`` the factor on cos and sin (YaRN's attention factor),
     ``Out = X * cos + rotate_half(X) * sin``, cos and sin over
-    ``position * concat(inv_freq, inv_freq)``, positions 0..S-1.  Angles,
-    cos and sin in float32; the result in X's dtype."""
+    ``position * concat(inv_freq, inv_freq)``.  Positions are 0..S-1, or
+    the optional input Positions [R, S]: frequency ``i`` then turns by row
+    ``r(i)`` of it, ``sections`` giving how many consecutive frequencies
+    each row takes (multimodal rotary: temporal, height, width; one row
+    needs no ``sections``).  Angles, cos and sin in float32; the result in
+    X's dtype."""
     x = ins["X"][0]
     s, d = x.shape[-2], x.shape[-1]
     inv_freq = jnp.asarray(attrs["inv_freq"], jnp.float32)
     if inv_freq.shape != (d // 2,):
         raise ValueError(f"rotary_embedding: {inv_freq.shape[0]} frequencies "
                          f"for a head of {d}")
-    pos = jnp.arange(s, dtype=jnp.float32)
-    angle = pos[:, None] * jnp.concatenate([inv_freq, inv_freq])[None, :]
+    if ins.get("Positions"):
+        pos = ins["Positions"][0].astype(jnp.float32)
+        sections = [int(n) for n in attrs.get("sections") or [d // 2]]
+        if pos.shape != (len(sections), s) or sum(sections) != d // 2:
+            raise ValueError(f"rotary_embedding: positions {pos.shape} and "
+                             f"sections {sections} for [{s}, {d}]")
+        row = np.repeat(np.arange(len(sections)), sections)
+        half = pos[row].T * inv_freq[None, :]
+    else:
+        half = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angle = jnp.concatenate([half, half], axis=-1)
     scale = float(attrs.get("scale", 1.0))
     cos, sin = jnp.cos(angle) * scale, jnp.sin(angle) * scale
     xf = x.astype(jnp.float32)
@@ -67,6 +83,80 @@ def _swiglu(ins, attrs, ctx):
     xf = x.astype(jnp.float32)
     return {"Out": [(xf * jax.nn.sigmoid(xf)
                      * y.astype(jnp.float32)).astype(x.dtype)]}
+
+
+# tokens of one block of ``linear_cross_entropy``: [2048, V] float32 logits
+# are 148 MiB at 18992 classes, where the whole [16384, V] array and the
+# softmax beside it are 2.3 GiB at the step's fullest moment
+_HEAD_ROWS = 2048
+
+
+def _head_blocks(x, labels):
+    t = x.shape[0]
+    rows = min(_HEAD_ROWS, t)
+    while t % rows:
+        rows -= 1
+    return x.reshape(t // rows, rows, -1), labels.reshape(t // rows, rows)
+
+
+@jax.custom_vjp
+def linear_cross_entropy(x, w, labels):
+    """``-log softmax(x w)[label]`` of every token, x [T, H], w [H, V],
+    labels [T] int -> [T] float32, in blocks of tokens: the logits of a
+    block exist while it is worked on, forward and backward (which computes
+    them again: one more pass of the matmul), and only the tokens'
+    log-sum-exp is kept."""
+    return _linear_cross_entropy_fwd(x, w, labels)[0]
+
+
+def _block_logits(xb, w):
+    return jnp.dot(xb, w.astype(xb.dtype), preferred_element_type=jnp.float32)
+
+
+def _linear_cross_entropy_fwd(x, w, labels):
+    def block(_, args):
+        xb, lb = args
+        logits = _block_logits(xb, w)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
+        return None, (lse - picked, lse)
+    _, (loss, lse) = jax.lax.scan(block, None, _head_blocks(x, labels))
+    return loss.reshape(-1), (x, w, labels, lse)
+
+
+def _linear_cross_entropy_bwd(res, g):
+    x, w, labels, lse = res
+
+    def block(dw, args):
+        xb, lb, lse_b, gb = args
+        p = jnp.exp(_block_logits(xb, w) - lse_b[:, None])
+        hit = lb[:, None] == jnp.arange(p.shape[-1], dtype=lb.dtype)[None, :]
+        d = ((p - hit) * gb[:, None]).astype(xb.dtype)
+        dw = dw + jnp.dot(xb.T, d, preferred_element_type=jnp.float32)
+        return dw, jnp.dot(d, w.astype(xb.dtype).T,
+                           preferred_element_type=jnp.float32)
+    xs, ls = _head_blocks(x, labels)
+    dw, dx = jax.lax.scan(
+        block, jnp.zeros(w.shape, jnp.float32),
+        (xs, ls, lse, g.astype(jnp.float32).reshape(lse.shape)))
+    return dx.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype), None
+
+
+linear_cross_entropy.defvjp(_linear_cross_entropy_fwd,
+                            _linear_cross_entropy_bwd)
+
+
+@register_op("linear_cross_entropy", nondiff_inputs=("Label",))
+def _linear_cross_entropy(ins, attrs, ctx):
+    """X [..., H], W [H, V], Label [..., 1] (or [...]) -> Loss [..., 1]
+    float32: a decoder's head and its loss as one op, so that the [tokens,
+    V] logits, their softmax and their gradient never exist whole
+    (``linear_cross_entropy``)."""
+    x, w = ins["X"][0], ins["W"][0]
+    labels = ins["Label"][0].reshape(x.shape[:-1]).astype(jnp.int32)
+    loss = linear_cross_entropy(x.reshape(-1, x.shape[-1]), w,
+                                labels.reshape(-1))
+    return {"Loss": [loss.reshape(x.shape[:-1] + (1,))]}
 
 
 def _plan(ins):
@@ -88,14 +178,22 @@ def _moe_route(ins, attrs, ctx):
     ``routed_scaling_factor`` and the optional CorrectionBias [E] are
     ``parallel.moe.route``'s.  Counts [num_held] and Steps [1] (int32,
     persistable) are the device's own counters: tokens per held expert and
-    calls, added to here and read by the host when a runner drains."""
+    calls, added to here and read by the host when a runner drains.
+    ``max_rows`` > 0 bounds the buffer of held assignments (Order is
+    [max_rows], not [T * top_k]); a step that routes more than that to the
+    held experts gets NaN weights, so it fails and drops nothing in
+    silence."""
     bias = ins["CorrectionBias"][0] if ins.get("CorrectionBias") else None
     weights, experts = moe.route(
         ins["X"][0], ins["RouterWeight"][0], int(attrs["top_k"]),
         attrs.get("scoring", "softmax"), bias,
         float(attrs.get("routed_scaling_factor", 1.0)))
+    max_rows = int(attrs.get("max_rows", 0) or 0)
     plan = moe.dispatch_plan(experts, int(attrs.get("first_expert", 0)),
-                             int(attrs["num_held"]))
+                             int(attrs["num_held"]), max_rows or None)
+    if max_rows:
+        weights = jnp.where(jnp.sum(plan.group_sizes) > max_rows, jnp.nan,
+                            weights)
     out = {"TopKWeight": [weights], "Order": [plan.order],
            "Pos": [plan.pos], "GroupSizes": [plan.group_sizes]}
     if ins.get("Counts"):

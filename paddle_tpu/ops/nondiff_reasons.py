@@ -58,6 +58,10 @@ REASONS = {
         "lookup_sparse_table_fuse_sgd")},
     # -- integer / boolean / index outputs ----------------------------------
     **{op: "int_output" for op in (
+        # the key set of attention over a learned selection: a piecewise
+        # constant function of the index scores (the indexer is trained by
+        # sparse_attention_index_loss, not through the choice)
+        "sparse_attention_index",
         "equal", "equal_all", "not_equal", "less_than", "less_equal",
         "greater_than", "greater_equal", "allclose", "isfinite",
         "isfinite_v2", "isinf_v2", "isnan_v2", "logical_and", "logical_or",

@@ -20,6 +20,9 @@
   kernels hold a tile of whole rows in VMEM and do all an op needs of it in
   one visit (1.25 / 3.5 / 2.25 / 3.5 passes); the Sinkhorn iterations run on
   the core forward and stay in XLA, on [24, T] arrays, backward.
+* attention over a key set the device chose a moment ago — a flash kernel
+  whose mask is data: one [S, S] int8 selection, each tile of it read once
+  for all the query heads of a key/value group.
 * paged decode attention, fused embedding gather+pool, bucketed optimizer
   updates — see each section.
 
@@ -35,6 +38,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -44,7 +48,8 @@ __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_dropout_add_tpu", "fused_act_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
            "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
-           "hyper_connection_merge_tpu"]
+           "hyper_connection_merge_tpu", "selected_attention_tpu",
+           "selected_probability_mean_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
 # VMEM default is 16 MiB: one block of every operand together stays under
@@ -143,6 +148,336 @@ def splash_attention_tpu(q, k, v, scale=None, window=0):
     kernel = _splash_kernel(q.shape[2], q.shape[1], int(window))
     # the kernel applies no scale of its own
     return jax.vmap(kernel)((q * scale).astype(q.dtype), k, v)
+
+
+# ---------------------------------------------------------------------------
+# attention over a key set chosen per query on the device (ops/
+# sparse_attention.py).  The selection is data, shared by all the heads: a
+# bit a pair as it is kept, a byte a pair ([S, S] int8, unpacked by one XLA
+# pass before each of forward and backward) as the kernels read it.  A grid
+# step holds one 512 x 512 tile of it beside one key/value head's block and the query blocks of ALL the
+# heads of that group (8 for 32 : 4), so the tile and the keys are read
+# once for 8 heads; blocks above the diagonal hold no causal pair and are
+# neither fetched nor computed.  Every tile at or under the diagonal is
+# computed whole and masked: with seeded random weights nearly every such
+# tile holds a selected pair (``dsa.layer_<i>.tile_occupancy``), so skipping
+# empty tiles would find nothing to skip.  (jax's splash kernel under a
+# dynamic mask was tried first: it wants the mask tiled per head as int32,
+# 1 GiB a kernel call at 16384 tokens, read again by every head:
+# docs/sparse_attention.md has the numbers.)  Forward keeps the log-sum-exp;
+# backward is two kernels, dq over the key blocks of a query block and
+# dk/dv over the query blocks of a key block, summed over the group's heads
+# on the core.
+# ---------------------------------------------------------------------------
+
+_SEL_BLOCK = 512
+_SEL_MASKED = -0.7 * float(np.finfo(np.float32).max)
+_NT = (((1,), (1,)), ((), ()))          # a @ b.T
+
+
+def selected_attention_supported(q, k, v, sel) -> bool:
+    """Does the selected-attention kernel cover these operands: grouped or
+    equal heads of whole 128-lane groups, one width for scores and values,
+    whole 512 x 512 tiles, one packed [S, S / 8] selection a sequence."""
+    if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape:
+        return False
+    b, hq, seq, d = q.shape
+    return (k.shape[0] == b and k.shape[2] == seq and k.shape[3] == d
+            and hq % k.shape[1] == 0 and d % _LANES == 0
+            and seq % _SEL_BLOCK == 0
+            and tuple(sel.shape) == (b, seq, seq // 8))
+
+
+def _sel_params(name):
+    return dict(
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name=name)
+
+
+def _sel_bytes(sel):
+    """The packed selection [S, S / 8] as the kernels read it: [S, S] int8."""
+    from .sparse_attention import unpack_selection
+    return unpack_selection(sel).astype(jnp.int8)
+
+
+def _sel_keep(mask_ref):
+    return mask_ref[...].astype(jnp.int32) != 0
+
+
+def _sel_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                    acc_ref, m_ref, l_ref, *, group):
+    i, j = pl.program_id(1), pl.program_id(2)
+    bk = k_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _SEL_MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j <= i)
+    def _():
+        keep = _sel_keep(mask_ref)
+        k, v = k_ref[...], v_ref[...]
+        for g in range(group):
+            s = jax.lax.dot_general(q_ref[g], k, _NT,
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(keep, s, _SEL_MASKED)
+            m_prev, l_prev = m_ref[g], l_ref[g]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
+            p = jnp.exp(s - jnp.tile(m_next, (1, bk // _LANES)))
+            alpha = jnp.exp(m_prev - m_next)
+            l_ref[g] = alpha * l_prev + jax.lax.broadcast_in_dim(
+                p.sum(axis=-1), l_prev.shape, (0,))
+            m_ref[g] = m_next
+            acc_ref[g] = acc_ref[g] * jnp.tile(
+                alpha, (1, acc_ref.shape[-1] // _LANES)) + jnp.dot(
+                    p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        for g in range(group):
+            l = l_ref[g]
+            o_ref[g] = (acc_ref[g] * jnp.tile(
+                1.0 / l, (1, acc_ref.shape[-1] // _LANES))
+            ).astype(o_ref.dtype)
+            lse_ref[g] = jnp.log(l) + m_ref[g]
+
+
+def _sel_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, di_ref,
+                   dq_ref, acc_ref, *, group):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j <= i)
+    def _():
+        keep = _sel_keep(mask_ref)
+        k, v = k_ref[...], v_ref[...]
+        for g in range(group):
+            s = jax.lax.dot_general(q_ref[g], k, _NT,
+                                    preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(keep, s, _SEL_MASKED)
+                        - jnp.expand_dims(lse_ref[g], -1))
+            dp = jax.lax.dot_general(do_ref[g], v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (dp - jnp.expand_dims(di_ref[g], -1)) * p
+            acc_ref[g] += jnp.dot(ds.astype(k.dtype), k,
+                                  preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _sel_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, di_ref,
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, group):
+    j, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(i >= j)
+    def _():
+        # scores with the keys on the rows: the tile is turned once, for all
+        # the heads, and the queries' log-sum-exp is a row
+        keep = mask_ref[...].astype(jnp.float32).T != 0.0
+        k, v = k_ref[...], v_ref[...]
+        for g in range(group):
+            q, do = q_ref[g], do_ref[g]
+            s = jax.lax.dot_general(k, q, _NT,
+                                    preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(keep, s, _SEL_MASKED) - lse_ref[g:g + 1, :])
+            dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (dp - di_ref[g:g + 1, :]) * p
+            dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
+                                   preferred_element_type=jnp.float32)
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _sel_specs(group, d, rows_then_cols):
+    """Block specs of one grid step: ``rows_then_cols`` says whether the
+    grid is (head, query block, key block) or (head, key block, query
+    block).  The block of the inner axis is clamped to the diagonal, so the
+    steps that compute nothing fetch nothing new."""
+    b = _SEL_BLOCK
+    if rows_then_cols:
+        def at(h, i, j):
+            return h, i, jnp.minimum(i, j)
+    else:
+        def at(h, j, i):
+            return h, jnp.maximum(i, j), j
+    heads = pl.BlockSpec((None, group, b, d),
+                         lambda *g: (at(*g)[0], 0, at(*g)[1], 0))
+    keys = pl.BlockSpec((None, b, d), lambda *g: (at(*g)[0], at(*g)[2], 0))
+    mask = pl.BlockSpec((b, b), lambda *g: at(*g)[1:])
+    rows = pl.BlockSpec((None, group, b), lambda *g: (at(*g)[0], 0,
+                                                      at(*g)[1]))
+    return heads, keys, mask, rows
+
+
+@jax.custom_vjp
+def _selected_attention_one(q, k, v, sel):
+    """(out, the queries' log-sum-exp [Hkv, G, S]); no gradient passes
+    through the log-sum-exp."""
+    return _selected_attention_fwd(q, k, v, sel)[0]
+
+
+def _selected_attention_fwd(q, k, v, sel):
+    """q [Hkv, G, S, D] (already scaled), k, v [Hkv, S, D], sel [S, S / 8]
+    uint8 (a bit a pair) -> out [Hkv, G, S, D]."""
+    hkv, group, seq, d = q.shape
+    pairs = _sel_bytes(sel)
+    n = seq // _SEL_BLOCK
+    heads, keys, mask, _ = _sel_specs(group, d, True)
+    out, lse = pl.pallas_call(
+        functools.partial(_sel_fwd_kernel, group=group),
+        grid=(hkv, n, n),
+        in_specs=[heads, keys, keys, mask],
+        out_specs=[heads, pl.BlockSpec(
+            (None, group, _SEL_BLOCK, _LANES), lambda h, i, j: (h, 0, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((hkv, group, seq, _LANES),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((group, _SEL_BLOCK, d), jnp.float32),
+                        pltpu.VMEM((group, _SEL_BLOCK, _LANES), jnp.float32),
+                        pltpu.VMEM((group, _SEL_BLOCK, _LANES), jnp.float32)],
+        **_sel_params("selected_attention_fwd"))(q, k, v, pairs)
+    lse = lse[..., 0]
+    return (out, lse), (q, k, v, sel, out, lse)
+
+
+def _selected_attention_bwd(res, cotangents):
+    q, k, v, sel, out, lse = res
+    do = cotangents[0]
+    hkv, group, seq, d = q.shape
+    n = seq // _SEL_BLOCK
+    # unpacked again, and only once the output's gradient is there: without
+    # the barrier XLA merges this with the forward's unpacking and keeps the
+    # byte-a-pair array (256 MiB a layer at 16384 tokens) across the step
+    sel, do = jax.lax.optimization_barrier((sel, do))
+    pairs = _sel_bytes(sel)
+    di = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    do = do.astype(q.dtype)
+    heads, keys, mask, rows = _sel_specs(group, d, True)
+    dq = pl.pallas_call(
+        functools.partial(_sel_dq_kernel, group=group),
+        grid=(hkv, n, n),
+        in_specs=[heads, keys, keys, mask, heads, rows, rows],
+        out_specs=heads,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((group, _SEL_BLOCK, d), jnp.float32)],
+        **_sel_params("selected_attention_dq"))(q, k, v, pairs, do, lse, di)
+    heads, keys, mask, rows = _sel_specs(group, d, False)
+    dk, dv = pl.pallas_call(
+        functools.partial(_sel_dkv_kernel, group=group),
+        grid=(hkv, n, n),
+        in_specs=[heads, keys, keys, mask, heads, rows, rows],
+        out_specs=[keys, keys],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((_SEL_BLOCK, d), jnp.float32),
+                        pltpu.VMEM((_SEL_BLOCK, d), jnp.float32)],
+        **_sel_params("selected_attention_dkv"))(q, k, v, pairs, do, lse, di)
+    return dq, dk, dv, None
+
+
+_selected_attention_one.defvjp(_selected_attention_fwd,
+                               _selected_attention_bwd)
+
+
+def selected_attention_tpu(q, k, v, sel, scale=None):
+    """Softmax attention over each query's selected keys: q [B, Hq, S, D],
+    k, v [B, Hkv, S, D], sel [B, S, S / 8] uint8 (``sparse_attention.
+    pack_selection``: bit set, query t attends key s <= t; every query
+    attends at least one key) -> (out [B, Hq, S, D], the log-sum-exp of
+    every query's scaled scores over its keys [B, Hq, S] float32)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    b, hq, seq, d = q.shape
+    hkv = k.shape[1]
+    qs = (q * scale).astype(q.dtype).reshape(b, hkv, hq // hkv, seq, d)
+    # one sequence after the other, traced once whatever the batch
+    out, lse = jax.lax.map(lambda one: _selected_attention_one(*one),
+                           (qs, k, v, sel))
+    # no gradient passes through the log-sum-exp (and a cotangent for it
+    # would not get through the ``lax.map`` above: jax hands the kernel's vjp
+    # the stacked zeros)
+    return (out.reshape(b, hq, seq, d),
+            jax.lax.stop_gradient(lse.reshape(b, hq, seq)))
+
+
+def _sel_pbar_kernel(q_ref, k_ref, lse_ref, mask_ref, o_ref, *, first_block,
+                     scale):
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j <= first_block + i)
+    def _():
+        keep = _sel_keep(mask_ref)
+        hkv, group = q_ref.shape[0], q_ref.shape[1]
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
+        for h in range(hkv):
+            k = k_ref[h]
+            for g in range(group):
+                s = jax.lax.dot_general(
+                    q_ref[h, g], k, _NT,
+                    preferred_element_type=jnp.float32) * scale
+                acc += jnp.exp(jnp.where(keep, s, _SEL_MASKED)
+                               - jnp.expand_dims(lse_ref[h, g], -1))
+        o_ref[...] = acc * (1.0 / (hkv * group))
+
+    @pl.when(j > first_block + i)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def selected_probability_mean_tpu(qg, k, lse, sel, scale, r0, rows, extent):
+    """The heads' mean attention probability of the queries ``r0 .. r0 +
+    rows`` for the keys ``0 .. extent``: [rows, extent] float32, ``exp(q . k
+    * scale - lse)`` where the pair is selected and 0 elsewhere, averaged
+    over all the heads on the core (``sparse_attention.probability_mean`` is
+    the ``jnp`` spelling).  ``qg`` [Hkv, G, S, D], ``k`` [Hkv, S, D],
+    ``lse`` [Hkv, G, S] float32, ``sel`` [S, S / 8] uint8; ``r0``, ``rows``
+    and ``extent`` static, whole 512-blocks.  A grid step holds one 512 x
+    512 tile of the selection, the query blocks of all the heads and one
+    key block of every key/value head."""
+    hkv, group, seq, d = qg.shape
+    b = _SEL_BLOCK
+    first = r0 // b
+    pairs = _sel_bytes(sel[r0:r0 + rows])                   # [rows, S] int8
+
+    def col(i, j):
+        return jnp.minimum(j, first + i)
+    return pl.pallas_call(
+        functools.partial(_sel_pbar_kernel, first_block=first,
+                          scale=float(scale)),
+        grid=(rows // b, extent // b),
+        in_specs=[
+            pl.BlockSpec((hkv, group, b, d),
+                         lambda i, j: (0, 0, first + i, 0)),
+            pl.BlockSpec((hkv, b, d), lambda i, j: (0, col(i, j), 0)),
+            pl.BlockSpec((hkv, group, b), lambda i, j: (0, 0, first + i)),
+            pl.BlockSpec((b, b), lambda i, j: (i, col(i, j)))],
+        out_specs=pl.BlockSpec((b, b), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((rows, extent), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name="selected_probability_mean")(qg, k, lse, pairs)
 
 
 # ---------------------------------------------------------------------------

@@ -99,10 +99,15 @@ def route(x, router_w, top_k: int, scoring: str = "softmax", bias=None,
     return weights, experts.astype(jnp.int32)
 
 
-def dispatch_plan(experts, first_expert: int, num_held: int) -> Plan:
+def dispatch_plan(experts, first_expert: int, num_held: int,
+                  max_rows: Optional[int] = None) -> Plan:
     """Sort the assignments ``experts`` [T, top_k] by held expert (stable, so
     a group keeps token order); those of experts outside ``[first_expert,
-    first_expert + num_held)`` go last."""
+    first_expert + num_held)`` go last.  ``max_rows`` keeps only that many
+    rows of the buffer (a chip that holds an eighth of the experts fills an
+    eighth of the worst case): the held assignments must fit, which is the
+    caller's to check against ``group_sizes`` (``moe_route`` fails the step
+    where they do not)."""
     local = experts - first_expert
     key = jnp.where((local >= 0) & (local < num_held), local,
                     num_held).reshape(-1)
@@ -110,6 +115,8 @@ def dispatch_plan(experts, first_expert: int, num_held: int) -> Plan:
     pos = jnp.argsort(order).astype(jnp.int32).reshape(experts.shape)
     group_sizes = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :],
                           axis=0, dtype=jnp.int32)
+    if max_rows is not None:
+        order = order[:max_rows]
     return Plan(order, pos, group_sizes)
 
 

@@ -664,6 +664,30 @@ def build_specs():
                     "Post": _sym30(3, 2) + 1.5, "C": _sym30(3, 4) + 1.0},
             grad_slots=["X", "Z", "Post", "C"]),
     })
+    # the indexer's loss (ops/sparse_attention.py), from a stream of its
+    # own: the selection is an input, so every probe scores the same pairs
+    r32 = np.random.RandomState(32)
+
+    def _sym32(*shape):
+        return r32.uniform(-1.0, 1.0, shape).astype("float32")
+    _sel = np.tril(np.ones((8, 8), np.uint8))
+    _sel[4, 1] = _sel[5, 0] = _sel[5, 3] = 0
+    _sel = np.packbits(_sel, axis=-1, bitorder="little")
+    S.update({
+        "sparse_attention_index_loss": dict(
+            inputs={"QI": _sym32(1, 2, 8, 4), "KI": _sym32(1, 8, 4),
+                    "W": _sym32(1, 8, 2), "Q": _sym32(1, 2, 8, 4),
+                    "K": _sym32(1, 1, 8, 4),
+                    # any log-sum-exp gives a smooth loss of QI, KI, W
+                    "LSE": 1.0 + _sym32(1, 2, 8), "Selection": _sel[None]},
+            grad_slots=["QI", "KI", "W"], out_slot="Loss",
+            attrs={"scale": 0.5, "weight": 1.5}),
+        "linear_cross_entropy": dict(
+            inputs={"X": _sym32(2, 3, 4), "W": _sym32(4, 5),
+                    "Label": np.array([[[0], [4], [2]], [[1], [1], [3]]],
+                                      np.int64)},
+            grad_slots=["X", "W"], out_slot="Loss"),
+    })
     return S
 
 
