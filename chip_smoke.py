@@ -335,6 +335,13 @@ def rel_err(got, want):
     return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
+def rel_l2(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want)
+                 / max(np.linalg.norm(want), 1e-30))
+
+
 def roll_flash(tiny, tpu, key):
     import jax
     import jax.numpy as jnp
@@ -716,8 +723,11 @@ def roll_sparse_attention(tiny, tpu, key):
     """The indexer's selection and the attention over it through their
     lowerings at the training cell's shape (32 : 4 heads of 128, 16 index
     heads of 64, 16384 tokens that keep 2048 keys), forward and every
-    gradient of the attention, against the ``jnp`` path the same lowerings
-    take where the context allows no kernel; and the selection's count."""
+    gradient of the attention, and behind it the indexer's loss with its
+    gradients of QI, KI and W (the loss's own kernels: the heads' mean
+    probabilities and the two passes over the index scores), against the
+    ``jnp`` path the same lowerings take where the context allows no kernel;
+    and the selection's count."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.registry import LoweringContext, get_op
@@ -777,13 +787,21 @@ def roll_sparse_attention(tiny, tpu, key):
     errs = {name: rel_err(a, r) for name, a, r in zip(names, got, want)}
     # bfloat16 operands on both sides, float32 sums in another order
     assert all(e < 2e-2 for e in errs.values()), errs
+    # the loss's own kernels: the loss and its three gradients within 1 %
+    # (as a whole: one bfloat16 step of the largest element is 0.8 %)
+    loss_errs = {name: rel_l2(a, r) for name, a, r in zip(names, got, want)
+                 if name in ("index_loss", "dqi", "dki", "dw")}
+    assert all(e < 1e-2 for e in loss_errs.values()), loss_errs
     if tpu:
-        # forward, dq, dk/dv, and the loss's probabilities a super block
-        assert n_calls == 3 + seq // 2048, n_calls
+        # forward, dq, dk/dv, and a super block of the loss: the heads' mean
+        # probabilities, the row statistics with the KL, the gradients
+        assert n_calls == 3 + 3 * (seq // 2048), n_calls
     return {"shape": [hq, hkv, seq, d], "topk": topk, "mosaic": n_calls,
             "selected_keys_mean": mean,
             "tile_occupancy": float(chosen["TileOccupancy"][0][0]),
-            "rel_err": {k_: float(f"{e:.2e}") for k_, e in errs.items()}}
+            "rel_err": {k_: float(f"{e:.2e}") for k_, e in errs.items()},
+            "loss_rel_l2": {k_: float(f"{e:.2e}")
+                            for k_, e in loss_errs.items()}}
 
 
 # which of pallas_kernels.__all__ each roll-call entry drives
@@ -798,7 +816,8 @@ ROLL_CALL = [
     ("hyper_connection", roll_hyper_connection,
      ["hyper_connection_mix_tpu", "hyper_connection_merge_tpu"]),
     ("sparse_attention", roll_sparse_attention,
-     ["selected_attention_tpu", "selected_probability_mean_tpu"]),
+     ["selected_attention_tpu", "selected_probability_mean_tpu",
+      "index_kl_tpu"]),
 ]
 
 

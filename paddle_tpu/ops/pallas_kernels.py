@@ -22,7 +22,10 @@
   the core forward and stay in XLA, on [24, T] arrays, backward.
 * attention over a key set the device chose a moment ago — a flash kernel
   whose mask is data: one [S, S] int8 selection, each tile of it read once
-  for all the query heads of a key/value group.
+  for all the query heads of a key/value group; and the loss that trains
+  the chooser, whose [heads, rows, keys] index scores and their gradient
+  XLA wrote to HBM a 128-query block at a time: two passes that keep a
+  tile's scores on the core.
 * paged decode attention, fused embedding gather+pool, bucketed optimizer
   updates — see each section.
 
@@ -49,7 +52,7 @@ __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
            "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
            "hyper_connection_merge_tpu", "selected_attention_tpu",
-           "selected_probability_mean_tpu"]
+           "selected_probability_mean_tpu", "index_kl_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
 # VMEM default is 16 MiB: one block of every operand together stays under
@@ -478,6 +481,289 @@ def selected_probability_mean_tpu(qg, k, lse, sel, scale, r0, rows, extent):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 << 20),
         name="selected_probability_mean")(qg, k, lse, pairs)
+
+
+# ---------------------------------------------------------------------------
+# the indexer's loss (ops/sparse_attention.py, ``index_kl_loss``): the KL
+# from the heads' mean attention probabilities ``p`` to the softmax over the
+# set of the index scores ``I[t, s] = sum_h W[t, h] relu(QI[h, t] . KI[s])``,
+# and its gradients for QI, KI and W.  XLA wrote every [heads, rows, keys]
+# float32 intermediate of the scores and of their vjp to HBM (128 MiB each a
+# 128-query block at 16384 keys).  Here a grid step holds one tile of ``p``
+# and of the selection and forms the heads' scores of that tile on the core,
+# one head after the other: nothing with a head axis and a key axis leaves
+# VMEM.  Two passes over the causal tiles of a super block of queries:
+#
+# 1. ``_idx_stats_kernel``: I, an online max / sum over the key tiles for
+#    ``lse_I[t]``, and two running sums that give the loss without a second
+#    pass: KL[t] = sum p (log p - I) + lse_I[t] sum p over the held pairs.
+# 2. ``_idx_grad_kernel``: I again (the heads' pre-activations z_h kept in
+#    VMEM), dI = keep (exp(I - lse_I) sum p - p), and with u_h = dI (z_h > 0),
+#    the head's weight left out: G[h] = sum over the key tiles of KI^T u_h
+#    gives both dQI[h] = W[:, h] G[h] and dW[:, h] = QI[h] . G[h] (relu(z_h) =
+#    (z_h > 0) QI[h] . KI): two [DI, rows] products a row block instead of
+#    three more passes over every tile of every head; the weight reaches dKI
+#    through its other operand, dKI += u_h (W[:, h] QI[h]), into the whole
+#    [extent, DI] float32 gradient, which stays resident for the call.
+#
+# Layout: a tile is worked on with the KEYS on the rows and the queries on
+# the lanes (``p`` and the selection tile are turned once a grid step, as the
+# selected attention's dk/dv kernel turns its tile).  Every per-query
+# quantity (a head's weights, lse_I, sum p, the KL, dW) is then a row over
+# the lanes, and all three matmuls of a head are plain or transposed-right
+# ones: z_h^T = KI QI[h]^T, G[h]^T += KI^T u_h, dKI += u_h (W QI[h])^T.  The
+# gradient pass takes QI and hands dQI over as [HI, DI, S], the queries on
+# the lanes: that is how XLA itself lays out a [.., S, 64] array, while a
+# row-major [S, 64] bfloat16 array lies in 128 lanes, and what the kernels
+# ask decides QI's layout in the whole step and with it its gradient's (64
+# MiB a layer from the forward to the backward pass, or 32).  The first pass
+# takes QI row-major as the Program has it (its matmul is a transposed-right
+# one either way).  What the step reserves at its fullest moment, the start
+# of backward, by what the two passes are given (AOT for v5e at the cell's
+# shape, PR 33; the parent 9.563 GB): both row-major 9.737 (the gradients
+# padded), both turned 9.930 (XLA's rematerialisation then keeps one more
+# 512 MiB gather of the expert layers: it stops wherever it is under its
+# limit), this mix 9.654.
+#
+# Precision as the ``jnp`` spelling: QI, KI in the caller's dtype as matmul
+# operands with float32 accumulation; everything between in float32; u_h and
+# W[:, h] QI[h] rounded to the operand dtype only as operands of the gradient
+# matmuls.
+# ---------------------------------------------------------------------------
+
+# query rows (lanes) of one grid step: the gradient pass keeps 16 heads'
+# [512, rows] float32 pre-activations (8 MiB at 256) beside the resident
+# dKI.  The loops over the heads are unrolled whole: rolled (``fori_loop``)
+# the scheduler cannot put a head's vector work under the next head's matmul
+# and the two passes take 26.5 ms a layer at 16384 tokens, in groups of 2 /
+# 4 / 8 heads 20.4 / 17.6 / 16.2, unrolled 14.0 (my chip runs, PR 33, on the
+# first version, queries on the rows; 11.8 as they stand)
+_IDX_STAT_ROWS = 512
+_IDX_GRAD_ROWS = 256
+
+
+def index_loss_supported(qi, ki, w, q, k, sel, super_rows) -> bool:
+    """Do the indexer-loss kernels cover these operands: what the selected
+    attention covers of ``q``, ``k`` and ``sel``, super blocks of whole 512
+    x 512 tiles, and index heads ``qi`` [B, HI, S, DI], ``ki`` [B, S, DI]
+    of one dtype with DI whole half lane groups (64: what the chip has run)
+    and ``w`` [B, S, HI] with HI whole sublane groups."""
+    if not selected_attention_supported(q, k, k, sel) or qi.ndim != 4:
+        return False
+    b, hi, seq, di = qi.shape
+    return (super_rows % _SEL_BLOCK == 0 and qi.dtype == ki.dtype
+            and (b, seq) == (q.shape[0], q.shape[2])
+            and ki.shape == (b, seq, di) and w.shape == (b, seq, hi)
+            and di % (_LANES // 2) == 0 and hi % 8 == 0)
+
+
+def _idx_last_block(i, rows, first_block):
+    """The key tile that holds the diagonal of row block ``i``."""
+    return first_block + (i * rows) // _SEL_BLOCK
+
+
+def _idx_tile(p_ref, mask_ref):
+    """(p, keep) of the grid step with the keys on the rows."""
+    return p_ref[...].T, mask_ref[...].astype(jnp.float32).T != 0.0
+
+
+def _idx_scores(z_of, hi, wt_ref, z_ref=None):
+    """The tile's index scores [keys, rows] float32, a head after the
+    other, ``z_of(h)`` head ``h``'s pre-activations, which go to
+    ``z_ref[h]`` where one is given."""
+    acc = None
+    for h in range(hi):
+        z = z_of(h)
+        if z_ref is not None:
+            z_ref[h] = z
+        term = jnp.maximum(z, 0.0) * wt_ref[h:h + 1, :]
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _idx_stats_kernel(qi_ref, ki_ref, wt_ref, p_ref, mask_ref, kl_ref,
+                      lse_ref, sump_ref, m_ref, l_ref, d_ref, c_ref, *,
+                      first_block):
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _SEL_MASKED)
+        for ref in (l_ref, d_ref, c_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    @pl.when(j <= _idx_last_block(i, p_ref.shape[0], first_block))
+    def _():
+        p, keep = _idx_tile(p_ref, mask_ref)
+        ki = ki_ref[...]
+        s = _idx_scores(lambda h: jax.lax.dot_general(
+            ki, qi_ref[h], _NT, preferred_element_type=jnp.float32),
+            qi_ref.shape[0], wt_ref)
+        masked = jnp.where(keep, s, _SEL_MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, masked.max(axis=0, keepdims=True))
+        e = jnp.where(keep, jnp.exp(masked - m_next), 0.0)
+        l_ref[...] = jnp.exp(m_prev - m_next) * l_ref[...] \
+            + e.sum(axis=0, keepdims=True)
+        m_ref[...] = m_next
+        held = keep & (p > 0.0)
+        d_ref[...] += jnp.where(
+            held, p * (jnp.log(jnp.where(held, p, 1.0)) - s),
+            0.0).sum(axis=0, keepdims=True)
+        c_ref[...] += jnp.where(held, p, 0.0).sum(axis=0, keepdims=True)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        lse = jnp.log(l_ref[...]) + m_ref[...]
+        lse_ref[...] = lse
+        sump_ref[...] = c_ref[...]
+        kl_ref[...] = d_ref[...] + lse * c_ref[...]
+
+
+def _idx_grad_kernel(qit_ref, ki_ref, wt_ref, p_ref, mask_ref, lse_ref,
+                     sump_ref, kit_ref, dwt_ref, dqit_ref, dki_ref, z_ref,
+                     qitw_ref, g_acc, *, first_block):
+    i, j = pl.program_id(0), pl.program_id(1)
+    hi = qit_ref.shape[0]
+    bk, di = ki_ref.shape
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dki_ref[...] = jnp.zeros_like(dki_ref)
+
+    @pl.when(j == 0)
+    def _():
+        for h in range(hi):
+            qitw_ref[h] = (qit_ref[h].astype(jnp.float32)
+                           * wt_ref[h:h + 1, :]).astype(qitw_ref.dtype)
+        g_acc[...] = jnp.zeros_like(g_acc)
+
+    @pl.when(j <= _idx_last_block(i, p_ref.shape[0], first_block))
+    def _():
+        p, keep = _idx_tile(p_ref, mask_ref)
+        ki, kit = ki_ref[...], kit_ref[...]
+        s = _idx_scores(lambda h: jnp.dot(
+            ki, qit_ref[h], preferred_element_type=jnp.float32),
+            hi, wt_ref, z_ref)
+        # d KL / d I = softmax_set(I) sum(p) - p over the set
+        d_i = jnp.where(keep, jnp.exp(jnp.where(keep, s, _SEL_MASKED)
+                                      - lse_ref[...]) * sump_ref[...] - p,
+                        0.0)
+        dki = jnp.zeros((bk, di), jnp.float32)
+        for h in range(hi):
+            u = jnp.where(z_ref[h] > 0.0, d_i, 0.0).astype(ki.dtype)
+            g_acc[h] += jnp.dot(kit, u, preferred_element_type=jnp.float32)
+            dki += jax.lax.dot_general(u, qitw_ref[h], _NT,
+                                       preferred_element_type=jnp.float32)
+        dki_ref[pl.ds(pl.multiple_of(j * bk, bk), bk), :] += dki
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        for h in range(hi):
+            g = g_acc[h]                                    # [DI, rows]
+            dqit_ref[h] = (g * wt_ref[h:h + 1, :]).astype(dqit_ref.dtype)
+            dwt_ref[h:h + 1, :] = (qit_ref[h].astype(jnp.float32)
+                                   * g).sum(axis=0, keepdims=True)
+
+
+def _idx_specs(hi, di, rows, r0):
+    """Block specs of one grid step (row block i, key tile j) of the super
+    block that starts at query ``r0``, by operand: ``qi`` [HI, S, DI] or
+    turned ``qit`` [HI, DI, S], ``ki`` [S, DI] or turned ``kit``, ``wt``
+    [HI, S], a ``tile`` of p [sup, extent] and of the selection's bytes
+    [sup, S], a per-query ``row`` [1, sup], and the gradients' ``dwt`` [HI,
+    sup] and ``dqit`` [HI, DI, sup].  The key tile is clamped to the
+    diagonal's, so the steps that compute nothing fetch nothing new."""
+    b, at = _SEL_BLOCK, r0 // rows
+
+    def col(i, j):
+        return jnp.minimum(j, _idx_last_block(i, rows, r0 // b))
+    return {
+        "qi": pl.BlockSpec((hi, rows, di), lambda i, j: (0, at + i, 0)),
+        "qit": pl.BlockSpec((hi, di, rows), lambda i, j: (0, 0, at + i)),
+        "ki": pl.BlockSpec((b, di), lambda i, j: (col(i, j), 0)),
+        "kit": pl.BlockSpec((di, b), lambda i, j: (0, col(i, j))),
+        "wt": pl.BlockSpec((hi, rows), lambda i, j: (0, at + i)),
+        "tile": pl.BlockSpec((rows, b), lambda i, j: (i, col(i, j))),
+        "row": pl.BlockSpec((1, rows), lambda i, j: (0, i)),
+        "dwt": pl.BlockSpec((hi, rows), lambda i, j: (0, i)),
+        "dqit": pl.BlockSpec((hi, di, rows), lambda i, j: (0, 0, i))}
+
+
+def _idx_call(kernel, name, grid, first_block, in_specs, operands, out_specs,
+              out_shape, scratch):
+    """One pass over the causal tiles of a super block: tiles above the
+    diagonal are neither fetched nor computed."""
+    return pl.pallas_call(
+        functools.partial(kernel, first_block=first_block),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name=name)(*operands)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _index_kl_jit(qi, ki, w, qg, k, lse, sel, scale, super_rows, with_grads):
+    """One trace per (shapes, statics), shared by a program's layers."""
+    hi, seq, di = qi.shape
+    f32, b = jnp.float32, _SEL_BLOCK
+    qit, kit, wt = jnp.swapaxes(qi, 1, 2), ki.T, w.astype(f32).T
+    kl, dqit, dwt = [], [], []
+    dki = jnp.zeros(ki.shape, f32)
+    for r0 in range(0, seq, super_rows):
+        extent = r0 + super_rows
+        p = selected_probability_mean_tpu(qg, k, lse, sel, scale, r0,
+                                          super_rows, extent)
+        pairs = _sel_bytes(sel[r0:r0 + super_rows])
+        rows = _IDX_STAT_ROWS
+        at = _idx_specs(hi, di, rows, r0)
+        kl_row, lse_i, sum_p = _idx_call(
+            _idx_stats_kernel, "index_kl_stats",
+            (super_rows // rows, extent // b), r0 // b,
+            [at["qi"], at["ki"], at["wt"], at["tile"], at["tile"]],
+            (qi, ki, wt, p, pairs), [at["row"]] * 3,
+            [jax.ShapeDtypeStruct((1, super_rows), f32)] * 3,
+            [pltpu.VMEM((1, rows), f32)] * 4)
+        kl.append(jnp.sum(kl_row))
+        if not with_grads:
+            continue
+        rows = _IDX_GRAD_ROWS
+        at = _idx_specs(hi, di, rows, r0)
+        dwt_rows, dqit_rows, dki_rows = _idx_call(
+            _idx_grad_kernel, "index_kl_grad",
+            (super_rows // rows, extent // b), r0 // b,
+            [at["qit"], at["ki"], at["wt"], at["tile"], at["tile"],
+             at["row"], at["row"], at["kit"]],
+            (qit, ki, wt, p, pairs, lse_i, sum_p, kit),
+            [at["dwt"], at["dqit"],
+             pl.BlockSpec((extent, di), lambda i, j: (0, 0))],
+            [jax.ShapeDtypeStruct((hi, super_rows), f32),
+             jax.ShapeDtypeStruct((hi, di, super_rows), qi.dtype),
+             jax.ShapeDtypeStruct((extent, di), f32)],
+            [pltpu.VMEM((hi, b, rows), f32),
+             pltpu.VMEM((hi, di, rows), qi.dtype),
+             pltpu.VMEM((hi, di, rows), f32)])
+        dwt.append(dwt_rows)
+        dqit.append(dqit_rows)
+        dki = dki.at[:extent].add(dki_rows)
+    if not with_grads:
+        return sum(kl), None
+    return sum(kl), (jnp.swapaxes(jnp.concatenate(dqit, axis=2), 1, 2),
+                     dki.astype(ki.dtype), jnp.concatenate(dwt, axis=1).T)
+
+
+def index_kl_tpu(qi, ki, w, qg, k, lse, sel, scale, super_rows, with_grads):
+    """One sequence's indexer loss on the core: (sum over queries of KL(p ||
+    softmax over the set of I), and with ``with_grads`` its gradients for
+    ``qi`` [HI, S, DI], ``ki`` [S, DI] and ``w`` [S, HI]); ``qg`` [Hkv, G, S,
+    D], ``k`` [Hkv, S, D], ``lse`` [Hkv, G, S] and ``sel`` [S, S / 8] as
+    ``selected_probability_mean_tpu`` takes them, which forms ``p`` a super
+    block of ``super_rows`` queries at a time (``sparse_attention.
+    _index_kl_one`` is the ``jnp`` spelling)."""
+    return _index_kl_jit(qi, ki, w, qg, k, lse, sel, float(scale),
+                         int(super_rows), bool(with_grads))
 
 
 # ---------------------------------------------------------------------------

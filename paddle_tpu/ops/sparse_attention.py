@@ -32,10 +32,12 @@ Everything here is row blocks of queries against the keys up to the block's
 end: no [heads, S, S] array and no float [S, S] array exists; the selection
 that waits from a layer's forward for its backward is one bit a pair (32
 MiB at 16384 tokens; a byte a pair was 1 GiB over four layers).  The ``jnp`` spellings serve the CPU, partitioned
-programs and the index scores on the chip; the selected attention on the
-chip is ``pallas_kernels.selected_attention_tpu`` (``attention_path``'s
-fifth answer) and the heads' mean probabilities of the loss
-``pallas_kernels.selected_probability_mean_tpu``.
+programs and the selection's index scores on the chip; the selected
+attention on the chip is ``pallas_kernels.selected_attention_tpu``
+(``attention_path``'s fifth answer) and the whole loss
+``pallas_kernels.index_kl_tpu`` (the heads' mean probabilities by
+``selected_probability_mean_tpu``, then two passes that keep a tile's
+[heads, rows, keys] index scores on the core).
 """
 from __future__ import annotations
 
@@ -262,19 +264,19 @@ def _index_kl_one(qi, ki, w, q, k, lse, sel, scale, with_grads, on_chip):
     ``with_grads`` the gradients of that sum with respect to ``qi``, ``ki``
     and ``w``).  ``qi`` [HI, S, DI], ``ki`` [S, DI], ``w`` [S, HI], ``q``
     [Hq, S, D], ``k`` [Hkv, S, D], ``lse`` [Hq, S], ``sel`` [S, S / 8].
-    The probabilities are formed a super block of queries at a time (by the
-    Pallas kernel where ``on_chip``), the index scores and what follows them
-    a block at a time."""
+    ``on_chip``: all of it by ``pallas_kernels.index_kl_tpu``.  Else the
+    probabilities are formed a super block of queries at a time, the index
+    scores and what follows them a block at a time."""
     hq, seq, d = q.shape
     hkv = k.shape[0]
-    _, rows = _block_rows(seq)
+    sup, rows = _block_rows(seq)
     qg = q.reshape(hkv, hq // hkv, seq, d)
     lse_g = lse.reshape(hkv, hq // hkv, seq)
-    neg = jnp.finfo(jnp.float32).min
     if on_chip:
-        from .pallas_kernels import selected_probability_mean_tpu as mean_p
-    else:
-        mean_p = probability_mean
+        from .pallas_kernels import index_kl_tpu
+        return index_kl_tpu(qi, ki, w, qg, k, lse_g, sel, scale, sup,
+                            with_grads)
+    neg = jnp.finfo(jnp.float32).min
 
     def block(dki, extent, row0, r0, p_super):
         keep = unpack_selection(_rows_of(sel, row0, rows, 0))[:, :extent]
@@ -301,8 +303,8 @@ def _index_kl_one(qi, ki, w, q, k, lse, sel, scale, with_grads, on_chip):
 
     dki, out = _scan_row_blocks(
         block, jnp.zeros(ki.shape, jnp.float32), seq,
-        lambda r0, sup, extent: mean_p(qg, k, lse_g, sel, scale, r0, sup,
-                                       extent))
+        lambda r0, sup, extent: probability_mean(qg, k, lse_g, sel, scale,
+                                                 r0, sup, extent))
     if not with_grads:
         return jnp.sum(out[0]), None
     kl, dqi, dw = out
@@ -326,8 +328,8 @@ def index_kl_loss(qi, ki, w, q, k, lse, sel, scale, on_chip=False):
     attention's probabilities ``exp(q . k * scale - lse)`` (``q``, ``k`` and
     the log-sum-exp ``lse`` [B, Hq, S] as the attention op has them), which
     is a constant: the gradient reaches ``qi``, ``ki`` and ``w`` only, and is
-    computed with the loss.  ``on_chip``: the probabilities by the Pallas
-    kernel."""
+    computed with the loss.  ``on_chip``: the whole loss by the Pallas
+    kernels."""
     return _index_kl(qi, ki, w, q, k, lse, sel, scale, on_chip, False)[0]
 
 
@@ -375,16 +377,19 @@ def _sparse_attention_index_loss(ins, attrs, ctx):
     output); Selection [B, S, S / 8] -> Loss [1] float32 = ``weight`` x
     ``index_kl_loss``.  IndexKL [1] (the unweighted loss) stays on the
     device for the host's gauge.  On a chip, outside a partitioned program
-    and over shapes the kernel covers, the probabilities come from
-    ``pallas_kernels.selected_probability_mean_tpu``
-    (``sparse_attention.loss_lowering.<pallas|xla>`` counts which)."""
+    and over shapes the kernels cover
+    (``pallas_kernels.index_loss_supported``), the whole loss and its
+    gradients come from ``pallas_kernels.index_kl_tpu``: no array with a
+    head axis and a key axis reaches HBM
+    (``sparse_attention.loss_lowering.<kernel|xla>`` counts which)."""
     q, k, sel = ins["Q"][0], ins["K"][0], ins["Selection"][0]
     on_chip = False
     if ctx.pallas_ok():
-        from .pallas_kernels import selected_attention_supported
-        on_chip = selected_attention_supported(q, k, k, sel) \
-            and _block_rows(q.shape[2])[0] % 512 == 0
-    _count("loss_lowering", "pallas" if on_chip else "xla")
+        from .pallas_kernels import index_loss_supported
+        on_chip = index_loss_supported(
+            ins["QI"][0], ins["KI"][0], ins["W"][0], q, k, sel,
+            _block_rows(q.shape[2])[0])
+    _count("loss_lowering", "kernel" if on_chip else "xla")
     loss = index_kl_loss(ins["QI"][0], ins["KI"][0], ins["W"][0], q, k,
                          ins["LSE"][0], sel, float(attrs["scale"]), on_chip)
     return {"Loss": [(float(attrs.get("weight", 1.0)) * loss).reshape(1)],

@@ -116,14 +116,8 @@ def _run(built, fetch, cfg, mix, seed=11):
     return [np.asarray(g) for g in got]
 
 
-@pytest.mark.parametrize("part", ["lm_loss", "index_loss"])
-def test_each_loss_reaches_its_own_parameters_and_gives_the_rest_exact_zero(
-        part):
-    """``L_LM`` gives the indexer's parameters exactly zero and ``L_I``
-    gives every other parameter exactly zero: the gradients of each part
-    alone, through ``append_backward`` over the same Program."""
+def _each_loss_reaches_its_own(part, cfg, mix, model):
     from paddle_tpu.fluid.backward import append_backward
-    cfg, mix, model, _ = _small()
     # the build's own backward is of the objective: take the part's on the
     # build's forward alone
     built = _build_without_backward(model, cfg, mix)
@@ -148,6 +142,48 @@ def test_each_loss_reaches_its_own_parameters_and_gives_the_rest_exact_zero(
             assert value is not None and np.abs(value).max() > 0, name
         else:
             assert value is None or not value.any(), name
+
+
+@pytest.mark.parametrize("part", ["lm_loss", "index_loss"])
+def test_each_loss_reaches_its_own_parameters_and_gives_the_rest_exact_zero(
+        part):
+    """``L_LM`` gives the indexer's parameters exactly zero and ``L_I``
+    gives every other parameter exactly zero: the gradients of each part
+    alone, through ``append_backward`` over the same Program."""
+    cfg, mix, model, _ = _small()
+    _each_loss_reaches_its_own(part, cfg, mix, model)
+
+
+# the narrowest shapes the selected-attention and indexer-loss kernels take:
+# 2 : 1 heads of 128, 8 index heads of 64, one 512 x 512 tile
+KERNEL_SHAPES = {
+    "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 128,
+    "num_hidden_layers": 1,
+    "rope_scaling": {"mrope_section": [16, 24, 24], "rope_type": "default",
+                     "type": "default"},
+    "sa_config": {**SMALL["sa_config"], "indexer_head_dim": 64,
+                  "indexer_num_heads": 8, "topk": 16}}
+
+
+@pytest.mark.parametrize("part", ["lm_loss", "index_loss"])
+def test_both_exact_zeros_hold_where_the_kernels_run(part, monkeypatch):
+    """The same two zeros with the lowerings answered as on a TPU and the
+    kernels in the interpreter: the selected attention and the indexer's
+    loss (``sparse_attention.loss_lowering.kernel``) on the core."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.registry import LoweringContext
+    monkeypatch.setattr(LoweringContext, "pallas_ok",
+                        lambda self: not self.partitioned)
+    cfg, mix, model, _ = _small(**KERNEL_SHAPES)
+    mix["seq_len"] = 512
+    names = ("sparse_attention.loss_lowering.kernel",
+             "sparse_attention.lowering.selected_kernel")
+    before = [trace.metrics().counter(n).value for n in names]
+    with pltpu.force_tpu_interpret_mode():
+        _each_loss_reaches_its_own(part, cfg, mix, model)
+    grew = [trace.metrics().counter(n).value - b
+            for n, b in zip(names, before)]
+    assert all(g > 0 for g in grew), dict(zip(names, grew))
 
 
 def _build_without_backward(model, cfg, mix):

@@ -142,6 +142,23 @@ class TestRepoKernels:
                                                    0.1, k), KEY)
 
 
+    def test_indexer_loss_passes(self):
+        """The indexer's loss at ``keye_vl2_train_seq16384``'s widths (32 :
+        4 heads of 128, 16 index heads of 64, bfloat16), two super blocks
+        of 2048 queries: the heads' mean probabilities, the row statistics
+        with the KL, and the gradients of QI, KI and W with dKI resident:
+        three kernels a super block."""
+        seq, sds, bf16 = 4096, jax.ShapeDtypeStruct, jnp.bfloat16
+        args = (sds((16, seq, 64), bf16), sds((seq, 64), bf16),
+                sds((seq, 16), jnp.float32), sds((4, 8, seq, 128), bf16),
+                sds((4, seq, 128), bf16), sds((4, 8, seq), jnp.float32),
+                sds((seq, seq // 8), jnp.uint8))
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        compiled = compile_for_tpu(
+            lambda *a: pk.index_kl_tpu(*a, 128 ** -0.5, 2048, True), *args)
+        assert mosaic_call_count(compiled) == 6
+
     @pytest.mark.parametrize("op, tokens, z_dtype", [
         ("mix", 4096, None), ("merge", 4096, "float32"),
         ("mix", 4096 + 40, None), ("merge", 4096 + 40, "bfloat16")])

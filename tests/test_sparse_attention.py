@@ -2,7 +2,8 @@
 the index scores, the exact top-k with ties and with rows shorter than
 ``topk``, the selected attention against a gather-based spelling (forward
 and gradients, the ``jnp`` path and the Pallas kernel in the interpreter),
-the indexer's loss and its own gradients, the Program ops and their layers,
+the indexer's loss and its own gradients (the ``jnp`` path and the Pallas
+kernels in the interpreter), the Program ops and their layers,
 ``attention_path``'s fifth answer and ``rotary_embedding`` under three
 unequal rows of positions."""
 import jax
@@ -219,7 +220,7 @@ def _plain_index_kl(qi, ki, w, q, k, sel, scale):
     scores = jnp.stack([sa.index_scores(qi[i], ki[i], w[i])
                         for i in range(b)])
     log_q = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
-    safe = jnp.where(keep, p, 1.0)
+    safe = jnp.where(keep & (p > 0), p, 1.0)          # 0 log 0 = 0
     return jnp.sum(jnp.where(keep, p * (jnp.log(safe)
                                         - jnp.where(keep, log_q, 0.0)),
                              0.0)) / (b * seq)
@@ -436,3 +437,112 @@ def test_the_loss_behind_the_kernel_differentiates_in_one_function():
         got = both(pk.selected_attention_tpu)
     for a, b in zip(got, both(sa.selected_attention)):
         np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3)
+
+
+def _loss_operands(seq, hi=16, di=64, sharp=1.0, topk=64, seed=3):
+    """Two sequences at the kernels' widths: 4 : 2 heads of 128, ``hi``
+    index heads of ``di``, a selection of ``topk`` keys and the attention's
+    own log-sum-exp; ``sharp`` scales the queries (at 25 most probabilities
+    of a set underflow to exactly 0)."""
+    r = np.random.RandomState(seed)
+    q = jnp.asarray((sharp * r.randn(2, 4, seq, 128)).astype("float32"))
+    k = jnp.asarray(r.randn(2, 2, seq, 128).astype("float32"))
+    qi = jnp.asarray(r.randn(2, hi, seq, di).astype("float32"))
+    ki = jnp.asarray(r.randn(2, seq, di).astype("float32"))
+    w = jnp.asarray(r.randn(2, seq, hi).astype("float32"))
+    sel = jnp.stack([sa.select_topk(qi[i], ki[i], w[i], topk)
+                     for i in range(2)])
+    scale = 128 ** -0.5
+    return qi, ki, w, q, k, _lse(q, k, sel, scale), sel, scale
+
+
+@pytest.mark.parametrize("case", ["one_super_block", "two_super_blocks",
+                                  "zero_probabilities_in_the_set"])
+def test_loss_kernels_in_interpret_mode_equal_the_jnp_path_and_autodiff(
+        case, monkeypatch):
+    """The two passes of ``pallas_kernels.index_kl_tpu`` (row statistics
+    and the loss; the gradients of QI, KI, W) against ``_index_kl_one``'s
+    ``jnp`` path and against jax's autodiff of the plain spelling: 1024
+    tokens (two tiles a side), 16 x 64 index heads, 64 keys kept, two
+    sequences; rows before the 64th have fewer keys than ``topk``; with a
+    second super block dKI sums over calls; with sharp attention the set
+    holds pairs whose probability is exactly 0 (the ``held`` branch)."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    seq = 1024
+    if case == "two_super_blocks":
+        monkeypatch.setattr(sa, "_SUPER_ROWS", 512)
+    sharp = 25.0 if case == "zero_probabilities_in_the_set" else 1.0
+    qi, ki, w, q, k, lse, sel, scale = _loss_operands(seq, sharp=sharp)
+    assert sa._block_rows(seq)[0] == (512 if case == "two_super_blocks"
+                                      else 1024)
+    assert pk.index_loss_supported(qi, ki, w, q, k, sel,
+                                   sa._block_rows(seq)[0])
+    keep = np.asarray(sa.unpack_selection(sel))
+    assert keep[0, 0].sum() == 1 and keep[0, 40].sum() == 41 \
+        and keep[0, -1].sum() == 64
+    p = np.asarray(sa.probability_mean(
+        q[0].reshape(2, 2, seq, 128), k[0], lse[0].reshape(2, 2, seq),
+        sel[0], scale, 0, seq, seq))
+    assert ((p == 0) & keep[0]).any() == (sharp > 1)
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda *a: 3.0 * fn(*a), (0, 1, 2, 3, 4, 5))(qi, ki, w, q, k,
+                                                          lse)
+    with pltpu.force_tpu_interpret_mode():
+        got, g_got = both(lambda *a: sa.index_kl_loss(*a, sel, scale, True))
+        alone = sa.index_kl_loss(qi, ki, w, q, k, lse, sel, scale, True)
+    want, g_want = both(lambda *a: sa.index_kl_loss(*a, sel, scale, False))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_allclose(3.0 * alone, want, rtol=1e-5)
+    plain, g_plain = jax.value_and_grad(
+        lambda *a: 3.0 * _plain_index_kl(*a, q, k, sel, scale),
+        (0, 1, 2))(qi, ki, w)
+    np.testing.assert_allclose(got, plain, rtol=1e-5)
+    for name, a, b, c in zip(("dQI", "dKI", "dW"), g_got, g_want, g_plain):
+        top = float(jnp.abs(c).max())
+        assert top > 0, name
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5 * top,
+                                   err_msg=name)
+        np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-5 * top,
+                                   err_msg=name)
+    # p is a constant: Q, K and LSE get exactly nothing
+    for g in g_got[3:]:
+        assert not np.asarray(g).any()
+
+
+class _Chip(LoweringContext):
+    """A context that answers as on a TPU (the kernels then run in the
+    interpreter)."""
+
+    def pallas_ok(self):
+        return not self.partitioned
+
+
+@pytest.mark.parametrize("case,path", [
+    ("chip", "kernel"), ("cpu", "xla"), ("partitioned", "xla"),
+    ("index_heads_of_48", "xla"), ("not_whole_tiles", "xla")])
+def test_the_loss_op_counts_which_lowering_it_took(case, path):
+    """``sparse_attention.loss_lowering.kernel`` once per lowering where the
+    context allows a kernel and ``index_loss_supported`` covers the shapes,
+    ``.xla`` elsewhere; both give the plain spelling's loss."""
+    from paddle_tpu.fluid import trace
+    seq = 768 if case == "not_whole_tiles" else 512
+    di = 48 if case == "index_heads_of_48" else 64
+    qi, ki, w, q, k, lse, sel, scale = _loss_operands(seq, di=di, topk=32)
+    ctx = (LoweringContext if case == "cpu" else _Chip)(
+        jax.random.PRNGKey(0))
+    ctx.partitioned = case == "partitioned"
+    names = [f"sparse_attention.loss_lowering.{n}"
+             for n in ("kernel", "xla", "pallas")]
+    before = [trace.metrics().counter(n).value for n in names]
+    with pltpu.force_tpu_interpret_mode():
+        out = get_op("sparse_attention_index_loss").fn(
+            {"QI": [qi], "KI": [ki], "W": [w], "Q": [q], "K": [k],
+             "LSE": [lse], "Selection": [sel]}, {"scale": scale}, ctx)
+    after = [trace.metrics().counter(n).value for n in names]
+    assert [a - b for a, b in zip(after, before)] \
+        == [path == "kernel", path == "xla", 0]
+    np.testing.assert_allclose(
+        out["Loss"][0][0], _plain_index_kl(qi, ki, w, q, k, sel, scale),
+        rtol=1e-5)
