@@ -60,6 +60,74 @@ _g_step_live = trace.metrics().gauge("executor.steps_in_progress")
 _g_compiling = trace.metrics().gauge("executor.compiles_in_progress")
 _c_steps_done = trace.metrics().counter("executor.steps_completed")
 
+# every executable XLA builds or loads, in this process, leaves a flight
+# record and an observation (registered once, here)
+_flight.watch_xla_compiles()
+
+#: where one ``Executor.run`` / ``run_scan`` spends the host's time, in
+#: order (docs/observability.md "Where a step's host time goes").  A compile
+#: miss adds ``prepare``: ``_prepare`` and the bookkeeping after the first
+#: call, which belong to the compile record and to none of the eight.
+PHASES = ("resolve", "gather", "stage", "persist", "place", "call",
+          "scatter", "fetch")
+
+
+def _no_mark(name):
+    """``mark`` of a run with recorder and tracing both off."""
+
+
+class _StepClock:
+    """The stamps of one ``run``: ``mark(name)`` closes the phase ``name``
+    at now, one clock reading and one append.  A phase whose work did not
+    happen (``persist`` without donation, ``place`` without a plan) is not
+    marked and reads 0; a name marked twice adds up.  ``finish`` turns the
+    stamps into the step's flight record and, with tracing on, into spans:
+    every number of one step comes from the same readings."""
+
+    __slots__ = ("t0", "marks")
+
+    def __init__(self):
+        self.t0 = trace.now()
+        self.marks = []
+
+    def mark(self, name):
+        self.marks.append((name, trace.now()))
+
+    def finish(self, tr_on, step, n_fetch, fp, compile_miss, bucket=None,
+               batch_valid=None, scan=None):
+        """After the call went through (``call`` is marked): the record
+        and the spans of this step, whatever the tail of ``run`` did."""
+        phases = dict.fromkeys(PHASES, 0.0)
+        prev, step_t0, step_t1 = self.t0, None, None
+        for name, t in self.marks:
+            phases[name] = phases.get(name, 0.0) + (t - prev) / 1e3
+            if step_t0 is None and name in ("place", "call"):
+                step_t0 = prev
+            if name == "call":
+                step_t1 = t
+            if tr_on:
+                trace.complete("executor::run/" + name, prev, cat="step",
+                               end_ns=t)
+            prev = t
+        if tr_on:
+            trace.complete("executor::run", self.t0, cat="step", end_ns=prev,
+                           args={"step": step})
+            # the jitted call (with a plan's placing before it): what
+            # goodput and the host table have always read under this name
+            args = {"step": step, "n_fetch": n_fetch}
+            if scan:
+                args["steps_fused"] = scan
+            trace.complete("executor::step", step_t0, cat="step",
+                           end_ns=step_t1, args=args)
+        if _flight.enabled():
+            # one wide event per step, tracing on or off (the flight
+            # recorder is the always-on forensic ring)
+            _flight.record_step(
+                step=step, dur_us=(step_t1 - step_t0) / 1e3, bucket=bucket,
+                batch_valid=batch_valid, compile_miss=compile_miss, fp=fp,
+                n_fetch=n_fetch, scan=scan, t0_ns=self.t0,
+                run_us=(prev - self.t0) / 1e3, phases_us=phases)
+
 
 def check_feed_width(name, v):
     """Without x64, jax canonicalizes int64/uint64 feeds to 32 bits — for
@@ -407,6 +475,10 @@ class Executor:
             scope: Optional[Scope] = None,
             return_numpy: bool = True,
             use_program_cache: bool = True):
+        # recorder and tracing both off: no clock is made, no stamp taken
+        tr_on = trace.enabled()
+        clock = _StepClock() if tr_on or _flight.enabled() else None
+        mark = clock.mark if clock is not None else _no_mark
         program = program or default_main_program()
         fetch_names = [_fetch_name(f) for f in _as_list(fetch_list)]
         # CompiledProgram facade (compiler.py) unwraps to its program +
@@ -510,9 +582,9 @@ class Executor:
         # first-order perf signal on this stack: a miss is a whole-block
         # XLA recompile).  Counters are always on (one int bump per run);
         # timeline events only when the plane is enabled.
-        tr_on = trace.enabled()
         pending_compile = None
         compiled = self._cache.get(key)
+        mark("resolve")
         if compiled is None:
             trace.metrics().counter("executor.compile_cache_miss").inc()
             if tr_on:
@@ -555,11 +627,13 @@ class Executor:
                                      bucket=bucket, plan=plan)
             # the XLA compile itself happens lazily on the FIRST jitted
             # call — the executor::compile span, the compile_seconds
-            # observation, and the persistent record all land after the
-            # step call below so they cover the real compile
-            pending_compile = (_t0, pcache, pkey, pwarm)
+            # observation, the compile record and the persistent record
+            # all land after the step call below so they cover the real
+            # compile
+            pending_compile = (_t0, trace.now(), pcache, pkey, pwarm)
             if use_program_cache:
                 self._cache_store(key, compiled)
+            mark("prepare")
         else:
             self._cache.move_to_end(key)
             trace.metrics().counter("executor.compile_cache_hit").inc()
@@ -571,123 +645,153 @@ class Executor:
                if n in compiled.written_names}
         ro = {n: scope.find_var(n) for n in compiled.param_names
               if n not in compiled.written_names}
+        mark("gather")
         feeds = {k: jnp.asarray(v) for k, v in feed.items()}
         if bucket is not None:
             feeds["__batch_valid__"] = jnp.asarray(n_valid, jnp.int32)
         seed = program.random_seed if program.random_seed is not None else 0
         step_key = jax.random.fold_in(jax.random.PRNGKey(seed), self._step)
         self._step += 1
+        mark("stage")
 
         if compiled.donates:
             self._persist_alias_live()
-        _t0 = trace.now()               # always: the flight recorder and
-        _g_step_live.add(1)             # the watchdog time every step
-        if pending_compile is not None:
+            mark("persist")
+        fetches, new_vals = self._call(compiled, (mut, ro, feeds, step_key),
+                                       pending_compile is not None, mark)
+        _c_steps_done.inc()
+        try:
+            if pending_compile is not None:
+                # trace + XLA compile both happened inside this first call
+                _t0c, t_prepared, pcache, pkey, pwarm = pending_compile
+                compile_s = self._note_compiled(key, compiled, _t0c,
+                                                t_prepared, tr_on)
+                # device truth AFTER the compile span closes: the AOT
+                # analysis pays a second (only partially cached) compile,
+                # which must not pollute executor.compile_seconds (it lands
+                # in xla.analysis_seconds instead).  Uncached runs
+                # (use_program_cache=False) miss on EVERY call — capturing
+                # there would put the analysis on the step path and grow
+                # _footprints without an eviction to retire it.
+                dinfo = self._capture_device_stats(
+                    key, compiled, (mut, ro, feeds, step_key),
+                    bucket=bucket,
+                    n_devices=plan.n_devices if plan is not None else 1) \
+                    if use_program_cache else None
+                if pcache is not None and not pwarm:
+                    meta = {
+                        "fingerprint": key[0], "feed_sig": list(feed_sig),
+                        "fetch": list(fetch_names), "bucket": bucket,
+                        "compile_seconds": round(compile_s, 4),
+                        "n_ops": compiled.n_ops}
+                    if dinfo is not None:
+                        meta["device"] = {
+                            "flops": dinfo.get("flops"),
+                            "peak_bytes": dinfo.get("peak_bytes"),
+                            "argument_bytes": dinfo.get("argument_bytes")}
+                    pcache.record(pkey, meta)
+                mark("prepare")
+            deferred_err = (compiled.err_cell.pop("err", None)
+                            if compiled.err_cell else None)
+            if bucket is not None and bucket != n_valid:
+                fetches = self._slice_true_batch(
+                    program, compiled.fetch_names, fetches, bucket, n_valid)
+            for n, v in new_vals.items():
+                scope.set_var(n, v)
+            mark("scatter")
+
+            if return_numpy:
+                if deferred_err is not None:
+                    deferred_err.throw()
+                # ONE D2H transfer for the whole fetch tree (was: np.asarray
+                # per fetch — N serial device syncs per step)
+                host = jax.device_get(list(fetches))
+                if core.get_flag("check_nan_inf"):
+                    for n, v in zip(compiled.fetch_names, host):
+                        va = np.asarray(v)
+                        if np.issubdtype(va.dtype, np.floating) \
+                                and not np.all(np.isfinite(va)):
+                            raise FloatingPointError(
+                                f"NaN/Inf in fetched var '{n}'")
+                host = [np.asarray(f) for f in host]
+                mark("fetch")
+                return host
+            # lazy fetches: live device arrays behind FetchHandle — no sync
+            # at all until someone materialises.  NaN scans and deferred
+            # checkify errors fire at materialisation; aliases_state marks
+            # fetches that share a buffer with scope state (the
+            # donation-safety signal the async runner consumes before the
+            # next dispatch donates).
+            from .async_pipeline import FetchHandle, _once
+            check = bool(core.get_flag("check_nan_inf"))
+            mask = compiled.fetch_alias_mask(len(fetches))
+            pre = _once(deferred_err.throw) if deferred_err is not None \
+                else None
+            handles = [FetchHandle(f, name=n, aliases_state=alias,
+                                   check_nan=check, pre_check=pre)
+                       for n, f, alias
+                       in zip(compiled.fetch_names, fetches, mask)]
+            import weakref
+            self._alias_live.extend(weakref.ref(h) for h in handles
+                                    if h.aliases_state)
+            if len(self._alias_live) > 4096:
+                # never-donating processes (CPU) only ever append: compact
+                # to the handles still alive and unpersisted
+                self._alias_live = [r for r in self._alias_live
+                                    if (h := r()) is not None
+                                    and not h.is_materialized()]
+            mark("fetch")
+            return handles
+        finally:
+            if clock is not None:
+                clock.finish(tr_on, self._step - 1, len(fetch_names),
+                             key[0][:12], pending_compile is not None,
+                             bucket=bucket, batch_valid=n_valid)
+
+    def _call(self, compiled, args, compiling, mark):
+        """The jitted call of one step (``run`` and ``run_scan`` alike),
+        under the watchdog's gauges; under a sharding plan the placing of
+        the arguments first, stamped apart from the call."""
+        _g_step_live.add(1)
+        if compiling:
             _g_compiling.add(1)
         try:
-            fetches, new_vals = compiled.fn(mut, ro, feeds, step_key)
+            place = getattr(compiled.fn, "place", None)
+            if place is None:
+                out = compiled.fn(*args)
+            else:
+                args = place(*args)
+                mark("place")
+                out = compiled.jitted(*args)
         except Exception as e:          # noqa: BLE001 — OOM forensics only
             if device_stats.is_oom(e):
                 device_stats.attach_oom_report(e, self.top_footprints())
             raise
         finally:
             _g_step_live.add(-1)
-            if pending_compile is not None:
+            if compiling:
                 _g_compiling.add(-1)
-        if tr_on:
-            # device-program launch span (per-step time; the per-op "op"
-            # spans above are per-compile host cost)
-            trace.complete("executor::step", _t0, cat="step",
-                           args={"step": self._step - 1,
-                                 "n_fetch": len(fetch_names)})
-        _c_steps_done.inc()
-        if _flight.enabled():
-            # one wide event per step, tracing on or off (the flight
-            # recorder is the always-on forensic ring)
-            _flight.record_step(
-                step=self._step - 1, dur_us=(trace.now() - _t0) / 1e3,
-                bucket=bucket, batch_valid=n_valid,
-                compile_miss=pending_compile is not None,
-                fp=key[0][:12], n_fetch=len(fetch_names))
-        if pending_compile is not None:
-            # trace + XLA compile both happened inside this first call
-            _t0c, pcache, pkey, pwarm = pending_compile
-            compile_s = (trace.now() - _t0c) / 1e9
-            trace.metrics().histogram("executor.compile_seconds").observe(
-                compile_s)
-            if tr_on:
-                trace.complete("executor::compile", _t0c, cat="compile",
-                               args={"fingerprint": key[0][:12],
-                                     "n_ops": compiled.n_ops})
-            # device truth AFTER the compile span closes: the AOT
-            # analysis pays a second (only partially cached) compile,
-            # which must not pollute executor.compile_seconds (it lands
-            # in xla.analysis_seconds instead).  Uncached runs
-            # (use_program_cache=False) miss on EVERY call — capturing
-            # there would put the analysis on the step path and grow
-            # _footprints without an eviction to retire it.
-            dinfo = self._capture_device_stats(
-                key, compiled, (mut, ro, feeds, step_key),
-                bucket=bucket,
-                n_devices=plan.n_devices if plan is not None else 1) \
-                if use_program_cache else None
-            if pcache is not None and not pwarm:
-                meta = {
-                    "fingerprint": key[0], "feed_sig": list(feed_sig),
-                    "fetch": list(fetch_names), "bucket": bucket,
-                    "compile_seconds": round(compile_s, 4),
-                    "n_ops": compiled.n_ops}
-                if dinfo is not None:
-                    meta["device"] = {
-                        "flops": dinfo.get("flops"),
-                        "peak_bytes": dinfo.get("peak_bytes"),
-                        "argument_bytes": dinfo.get("argument_bytes")}
-                pcache.record(pkey, meta)
-        deferred_err = (compiled.err_cell.pop("err", None)
-                        if compiled.err_cell else None)
-        if bucket is not None and bucket != n_valid:
-            fetches = self._slice_true_batch(program, compiled.fetch_names,
-                                             fetches, bucket, n_valid)
-        for n, v in new_vals.items():
-            scope.set_var(n, v)
+        mark("call")
+        return out
 
-        if return_numpy:
-            if deferred_err is not None:
-                deferred_err.throw()
-            # ONE D2H transfer for the whole fetch tree (was: np.asarray
-            # per fetch — N serial device syncs per step)
-            host = jax.device_get(list(fetches))
-            if core.get_flag("check_nan_inf"):
-                for n, v in zip(compiled.fetch_names, host):
-                    va = np.asarray(v)
-                    if np.issubdtype(va.dtype, np.floating) \
-                            and not np.all(np.isfinite(va)):
-                        raise FloatingPointError(
-                            f"NaN/Inf in fetched var '{n}'")
-            return [np.asarray(f) for f in host]
-        # lazy fetches: live device arrays behind FetchHandle — no sync at
-        # all until someone materialises.  NaN scans and deferred checkify
-        # errors fire at materialisation; aliases_state marks fetches that
-        # share a buffer with scope state (the donation-safety signal the
-        # async runner consumes before the next dispatch donates).
-        from .async_pipeline import FetchHandle, _once
-        check = bool(core.get_flag("check_nan_inf"))
-        mask = compiled.fetch_alias_mask(len(fetches))
-        pre = _once(deferred_err.throw) if deferred_err is not None else None
-        handles = [FetchHandle(f, name=n, aliases_state=alias,
-                               check_nan=check, pre_check=pre)
-                   for n, f, alias
-                   in zip(compiled.fetch_names, fetches, mask)]
-        import weakref
-        self._alias_live.extend(weakref.ref(h) for h in handles
-                                if h.aliases_state)
-        if len(self._alias_live) > 4096:
-            # never-donating processes (CPU) only ever append: compact to
-            # the handles still alive and unpersisted
-            self._alias_live = [r for r in self._alias_live
-                                if (h := r()) is not None
-                                and not h.is_materialized()]
-        return handles
+    def _note_compiled(self, key, compiled, _t0c, t_prepared, tr_on,
+                       scan=None):
+        """After the first call of a compile miss that began at ``_t0c``
+        and came out of ``_prepare`` at ``t_prepared``: the
+        ``executor.compile_seconds`` observation, the flight recorder's
+        ``compile`` record and the ``executor::compile`` span, all of one
+        reading.  Returns the seconds."""
+        total_ns = trace.now() - _t0c
+        trace.metrics().histogram("executor.compile_seconds").observe(
+            total_ns / 1e9)
+        fields = _flight.record_compile(
+            key[0][:12], compiled.n_ops, _t0c, total_ns / 1e3,
+            (t_prepared - _t0c) / 1e3, scan=scan)
+        if tr_on:
+            fields["fingerprint"] = fields.pop("fp")
+            trace.complete("executor::compile", _t0c, cat="compile",
+                           end_ns=_t0c + total_ns, args=fields)
+        return total_ns / 1e9
 
     # -- checkpoint plane ---------------------------------------------------
     @property
@@ -827,6 +931,10 @@ class Executor:
         feeds_in = list(feed_list or [])
         if not feeds_in:
             return []
+        # the step clock of run(), stamp for stamp
+        tr_on = trace.enabled()
+        clock = _StepClock() if tr_on or _flight.enabled() else None
+        mark = clock.mark if clock is not None else _no_mark
         fetch_names = [_fetch_name(f) for f in _as_list(fetch_list)]
         mesh = getattr(program, "_mesh", None)
         plan = getattr(program, "_sharding_plan", None)
@@ -912,9 +1020,9 @@ class Executor:
                bool(program._hints.get("inference_no_prune")),
                bool(program._hints.get("donate_buffers")),
                bucket, None, ("scan", k_steps))
-        tr_on = trace.enabled()
         pending_compile = None
         compiled = self._cache.get(key)
+        mark("resolve")
         if compiled is None:
             trace.metrics().counter("executor.compile_cache_miss").inc()
             if tr_on:
@@ -950,9 +1058,10 @@ class Executor:
                                       base.written_names, fetch_names,
                                       n_ops=base.n_ops, donates=donate,
                                       jitted=jfn)
-            pending_compile = _t0
+            pending_compile = (_t0, trace.now())
             if use_program_cache:
                 self._cache_store(key, compiled)
+            mark("prepare")
         else:
             self._cache.move_to_end(key)
             trace.metrics().counter("executor.compile_cache_hit").inc()
@@ -965,6 +1074,7 @@ class Executor:
                if n in compiled.written_names}
         ro = {n: scope.find_var(n) for n in compiled.param_names
               if n not in compiled.written_names}
+        mark("gather")
         stacked = {k: jnp.stack([jnp.asarray(f[k]) for f in feeds])
                    for k in feeds[0]}
         if bucket is not None:
@@ -974,69 +1084,50 @@ class Executor:
         keys = jnp.stack([jax.random.fold_in(base_key, self._step + i)
                           for i in range(k_steps)])
         self._step += k_steps
+        mark("stage")
 
         if compiled.donates:
             self._persist_alias_live()
-        _t0 = trace.now()
-        _g_step_live.add(1)
-        if pending_compile is not None:
-            _g_compiling.add(1)
-        try:
-            st_fetches, carry_end, st_extras = compiled.fn(mut, ro, stacked,
-                                                           keys)
-        except Exception as e:          # noqa: BLE001 — OOM forensics only
-            if device_stats.is_oom(e):
-                device_stats.attach_oom_report(e, self.top_footprints())
-            raise
-        finally:
-            _g_step_live.add(-1)
-            if pending_compile is not None:
-                _g_compiling.add(-1)
-        if tr_on:
-            trace.complete("executor::step", _t0, cat="step",
-                           args={"step": self._step - k_steps,
-                                 "steps_fused": k_steps,
-                                 "n_fetch": len(fetch_names)})
+            mark("persist")
+        st_fetches, carry_end, st_extras = self._call(
+            compiled, (mut, ro, stacked, keys), pending_compile is not None,
+            mark)
         _c_steps_done.inc(k_steps)
-        if _flight.enabled():
-            _flight.record_step(
-                step=self._step - k_steps,
-                dur_us=(trace.now() - _t0) / 1e3, bucket=bucket,
-                compile_miss=pending_compile is not None,
-                fp=key[0][:12], n_fetch=len(fetch_names), scan=k_steps)
-        if pending_compile is not None:
-            compile_s = (trace.now() - pending_compile) / 1e9
-            trace.metrics().histogram("executor.compile_seconds").observe(
-                compile_s)
-            if tr_on:
-                trace.complete("executor::compile", pending_compile,
-                               cat="compile",
-                               args={"fingerprint": key[0][:12],
-                                     "scan": k_steps,
-                                     "n_ops": compiled.n_ops})
-            if use_program_cache:   # uncached scans miss every call
-                self._capture_device_stats(key, compiled,
-                                           (mut, ro, stacked, keys),
-                                           bucket=bucket, scan=k_steps)
-        for n, v in carry_end.items():
-            scope.set_var(n, v)
-        for n, v in st_extras.items():
-            scope.set_var(n, v[-1])
+        try:
+            if pending_compile is not None:
+                self._note_compiled(key, compiled, *pending_compile, tr_on,
+                                    scan=k_steps)
+                if use_program_cache:   # uncached scans miss every call
+                    self._capture_device_stats(key, compiled,
+                                               (mut, ro, stacked, keys),
+                                               bucket=bucket, scan=k_steps)
+                mark("prepare")
+            for n, v in carry_end.items():
+                scope.set_var(n, v)
+            for n, v in st_extras.items():
+                scope.set_var(n, v[-1])
 
-        out = []
-        for i in range(k_steps):
-            row = [f[i] for f in st_fetches]
-            if bucket is not None and bucket != n_valids[i]:
-                row = self._slice_true_batch(program, fetch_names, row,
-                                             bucket, n_valids[i])
-            out.append(row)
-        if return_handles:
-            return [[FetchHandle(f, name=n)
-                     for n, f in zip(fetch_names, row)] for row in out]
-        if return_numpy:
-            host = jax.device_get(out)    # ONE transfer for all K steps
-            return [[np.asarray(f) for f in row] for row in host]
-        return out
+            out = []
+            for i in range(k_steps):
+                row = [f[i] for f in st_fetches]
+                if bucket is not None and bucket != n_valids[i]:
+                    row = self._slice_true_batch(program, fetch_names, row,
+                                                 bucket, n_valids[i])
+                out.append(row)
+            mark("scatter")
+            if return_handles:
+                out = [[FetchHandle(f, name=n)
+                        for n, f in zip(fetch_names, row)] for row in out]
+            elif return_numpy:
+                host = jax.device_get(out)    # ONE transfer for all K steps
+                out = [[np.asarray(f) for f in row] for row in host]
+            mark("fetch")
+            return out
+        finally:
+            if clock is not None:
+                clock.finish(tr_on, self._step - k_steps, len(fetch_names),
+                             key[0][:12], pending_compile is not None,
+                             bucket=bucket, scan=k_steps)
 
     @staticmethod
     def _normalize_feed(feed):

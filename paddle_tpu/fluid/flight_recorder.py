@@ -14,10 +14,23 @@ A wide event is one flat dict carrying everything an incident
 responder asks first:
 
 * step records — ``{"kind": "step", "seq", "ts_us", "step", "dur_us",
-  "bucket", "batch_valid", "compile_miss", "fp", "n_fetch", "scan",
-  "inflight", "goodput_ratio", "rss_bytes", "hbm_peak_bytes",
-  "trace_id"}`` (trace_id present when the step ran under a serving
-  batch's context);
+  "t0_us", "run_us", "phases_us", "bucket", "batch_valid",
+  "compile_miss", "fp", "n_fetch", "scan", "inflight", "goodput_ratio",
+  "rss_bytes", "hbm_peak_bytes", "trace_id"}`` (trace_id present when
+  the step ran under a serving batch's context).  ``t0_us`` is the start
+  of ``Executor.run`` on the trace epoch (``ts_us`` stays the time of
+  recording), ``run_us`` the whole of ``run`` and ``phases_us`` its
+  parts by name (``executor.PHASES``; they add up to ``run_us``);
+  ``dur_us`` is the jitted call, with a plan's placing before it;
+* compile records — ``{"kind": "compile", "fp", "n_ops", "t0_us",
+  "total_us", "prepare_us", "backend_us", "cache_hit", "xla_compiles",
+  "scan"}``, one per Executor compile miss: ``total_us`` is what
+  ``executor.compile_seconds`` observes, ``prepare_us`` Program -> step
+  function, ``backend_us`` the ``xla_compile`` records inside the miss,
+  and the rest the Python side (jaxpr trace, MLIR lowering);
+* ``{"kind": "xla_compile", "t0_us", "backend_us", "cache_hit",
+  "retrieval_us"}``, one per executable XLA built or the persistent
+  cache loaded, anywhere in the process (:func:`watch_xla_compiles`);
 * request records — ``{"kind": "request", "seq", "ts_us", "trace_id",
   "batch_id", "rows", "batch_rows", "bucket", "queue_us", "device_us",
   "latency_us", "outcome"}`` (outcome ``ok`` / ``timeout`` /
@@ -40,6 +53,7 @@ signal and embeds ``snapshot()`` into diagnostic bundles.
 """
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
@@ -49,7 +63,8 @@ from . import trace
 
 __all__ = [
     "FlightRecorder", "recorder", "enabled", "record", "record_step",
-    "record_request", "configure", "reset", "rss_bytes",
+    "record_request", "record_compile", "watch_xla_compiles",
+    "configure", "reset", "rss_bytes",
 ]
 
 class FlightRecorder:
@@ -202,9 +217,12 @@ def rss_bytes(max_age_s: float = 1.0) -> int:
 
 def record_step(step: int, dur_us: float, bucket=None, batch_valid=None,
                 compile_miss: bool = False, fp: Optional[str] = None,
-                n_fetch: int = 0, scan: Optional[int] = None) -> None:
+                n_fetch: int = 0, scan: Optional[int] = None,
+                t0_ns: Optional[int] = None, run_us: Optional[float] = None,
+                phases_us: Optional[Dict[str, float]] = None) -> None:
     """One wide event per completed executor step.  Callers guard with
-    :func:`enabled` so a disabled recorder costs one boolean."""
+    :func:`enabled` so a disabled recorder costs one boolean.  ``t0_ns``
+    is the ``trace.now()`` stamp at the start of ``Executor.run``."""
     rec: Dict[str, Any] = {
         "kind": "step", "step": int(step), "dur_us": round(dur_us, 1),
         "compile_miss": bool(compile_miss), "n_fetch": int(n_fetch),
@@ -221,10 +239,107 @@ def record_step(step: int, dur_us: float, bucket=None, batch_valid=None,
         rec["fp"] = fp
     if scan:
         rec["scan"] = int(scan)
+    if phases_us is not None:
+        rec["t0_us"] = trace._ts_us(t0_ns)
+        rec["run_us"] = run_us
+        rec["phases_us"] = phases_us
     tid = trace.current_trace_id()
     if tid is not None:
         rec["trace_id"] = tid
     _recorder.record(rec)
+
+
+# ---------------------------------------------------------------------------
+# where a compile's time went: XLA's share against the Python side
+# ---------------------------------------------------------------------------
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_h_backend = _m.histogram("xla.backend_compile_seconds")
+_c_cache_hits = _m.counter("xla.persistent_cache_hits")
+
+
+class _CompileLocal(threading.local):
+    def __init__(self):
+        self.hit = False                # a cache hit since the last record
+        self.retrieval_us = None
+        # (t0_us, backend_us, cache_hit) of this thread's last compiles:
+        # an Executor compile miss finds its own among them
+        self.recent = collections.deque(maxlen=256)
+
+
+_compiling = _CompileLocal()
+_watching = []                          # the two listeners, once registered
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT:
+        _compiling.hit = True
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event == _CACHE_RETRIEVAL:
+        _compiling.retrieval_us = round(secs * 1e6, 1)
+    elif event == _BACKEND_COMPILE:
+        # jax times compile_or_get_cached as one block and reports as it
+        # ends (pxla._cached_compilation), the hit and its retrieval time
+        # from inside that block: they belong to this executable
+        tl = _compiling
+        hit, retrieval_us = tl.hit, tl.retrieval_us
+        tl.hit, tl.retrieval_us = False, None
+        backend_us = round(secs * 1e6, 1)
+        _h_backend.observe(secs)
+        if hit:
+            _c_cache_hits.inc()
+        t0_us = trace.elapsed_us() - backend_us
+        tl.recent.append((t0_us, backend_us, hit))
+        if _recorder.enabled:
+            rec = {"kind": "xla_compile", "t0_us": t0_us,
+                   "backend_us": backend_us, "cache_hit": hit}
+            if retrieval_us is not None:
+                rec["retrieval_us"] = retrieval_us
+            _recorder.record(rec)
+
+
+def watch_xla_compiles() -> None:
+    """Register the two ``jax.monitoring`` listeners behind the
+    ``xla_compile`` records and the ``xla.*`` instruments, once per
+    process however often this is called (the Executor's module calls it
+    as it is imported).  Process-wide on purpose: a reference's plain
+    ``jax.jit`` and a loader's staging programs start a job late too."""
+    if _watching:
+        return
+    import jax.monitoring
+    _watching.extend((_on_event, _on_duration))
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def record_compile(fp: str, n_ops: Optional[int], t0_ns: int,
+                   total_us: float, prepare_us: float,
+                   scan: Optional[int] = None) -> Dict[str, Any]:
+    """One wide event per Executor compile miss (not progress), written
+    by the thread the miss ran on as it ends: the ``xla_compile`` events
+    of this thread since ``t0_ns`` are the miss's own.  Returns the fields
+    a span's ``args`` repeat."""
+    t0_us = trace._ts_us(t0_ns)
+    xla = [(us, hit) for t, us, hit in _compiling.recent if t >= t0_us]
+    fields: Dict[str, Any] = {
+        "fp": fp, "n_ops": n_ops, "total_us": round(total_us, 1),
+        "prepare_us": round(prepare_us, 1),
+        "backend_us": round(sum(us for us, _ in xla), 1),
+        # of the executables inside, the step's own is the largest; the
+        # small eager programs beside it are never written to the cache
+        "cache_hit": bool(xla) and max(xla)[1],
+        "xla_compiles": len(xla),
+    }
+    if scan:
+        fields["scan"] = int(scan)
+    if _recorder.enabled:
+        _recorder.record(dict(fields, kind="compile", t0_us=t0_us))
+    return fields
 
 
 def record_request(trace_id: str, rows: int, outcome: str = "ok",

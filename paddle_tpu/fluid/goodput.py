@@ -17,8 +17,11 @@ emit (``executor::compile``, ``executor::step``, ``executor::host_wait``,
 =================  =========================================================
 bucket             meaning
 =================  =========================================================
-device_compute     the device is doing training work: ``executor::step``
-                   dispatch plus host time *blocked on device results*
+device_compute     the device is doing training work, as far as the host
+                   can tell: ``executor::step`` (the HOST's jitted call of
+                   a step, which returns while the device still runs the
+                   step before: a proxy, not the device's time) plus host
+                   time *blocked on device results*
                    (``executor::host_wait`` — backpressure means the device
                    is the bottleneck, which is the productive state)
 host_input_wait    host blocked waiting for the input pipeline

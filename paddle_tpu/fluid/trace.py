@@ -34,8 +34,14 @@ changes.
 Note on per-op span semantics: under whole-block jit the op loop runs at
 TRACE time, so ``cat="op"`` spans measure host dispatch/lowering cost per
 op (the operator.cc RunImpl host-side analog) and appear once per compile,
-not per step.  Per-step device time is the ``executor::step`` span; dygraph
-mode (``cat="dygraph_op"``) times real eager execution per call.
+not per step.  The ``executor::step`` span is the HOST's jitted call of a
+step, not the device's time: dispatch is asynchronous, the call returns
+while the device still runs the step before, so the span reads the host's
+6-61 ms of dispatch whatever the device does (on a compile miss it also
+holds the trace and the compile).  Where the rest of a step's host time
+goes is ``executor::run`` and its ``executor::run/<phase>`` children; device
+time by op is ``device_stats.device_time_by_op``.  Dygraph mode
+(``cat="dygraph_op"``) times real eager execution per call.
 """
 from __future__ import annotations
 
@@ -52,6 +58,7 @@ __all__ = [
     "counter_event", "add_event", "span", "get_events", "event_count",
     "tail_events", "reset",
     "reset_all", "set_path", "get_path", "set_max_events", "elapsed_us",
+    "epoch_unix_ns",
     "new_id", "new_trace_id", "trace_context", "current_trace_id",
     "current_span_id", "set_context", "restore_context",
     "propagation_fields",
@@ -298,6 +305,14 @@ def elapsed_us() -> float:
     since the trace epoch ≈ process start) — what goodput attribution
     uses as its default window end."""
     return _ts_us(now())
+
+
+def epoch_unix_ns() -> int:
+    """The wall clock (ns since 1970) at this timeline's zero: a ``ts`` or
+    a flight record's ``t0_us`` is at ``epoch_unix_ns() + us * 1000`` on
+    the wall clock, which is where a profiler session's events are too
+    (wall-clock ns less the session's ``profile_start_time``)."""
+    return _state.epoch_wall_ns
 
 
 def _append(ev: Dict[str, Any]) -> None:

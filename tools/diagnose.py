@@ -141,6 +141,25 @@ def _wide_event_section(doc, last=8):
             f"goodput {last_step.get('goodput_ratio', 0):.0%}, "
             f"rss {_fmt_bytes(last_step.get('rss_bytes'))}, "
             f"{misses} compile misses across the ring")
+        phases = last_step.get("phases_us")
+        if phases:
+            # where that step's host time went (Executor.run's own stamps)
+            top = max(phases, key=phases.get)
+            lines.append(
+                f"    its run  : {last_step.get('run_us', 0) / 1e3:.1f}ms "
+                f"on the host, most of it in '{top}' "
+                f"({phases[top] / 1e3:.1f}ms)")
+    for r in wide:
+        if r.get("kind") == "compile":
+            # one line per Executor compile miss: the Python side (trace,
+            # lowering) against XLA's (compiled, or loaded from the cache)
+            lines.append(
+                f"    compile  : {r.get('fp')} at "
+                f"{r.get('t0_us', 0) / 1e6:.2f}s, python "
+                f"{(r.get('total_us', 0) - r.get('backend_us', 0)) / 1e6:.2f}"
+                f"s, xla {r.get('backend_us', 0) / 1e6:.2f}s "
+                f"(persistent cache "
+                f"{'hit' if r.get('cache_hit') else 'miss'})")
     bad = [r for r in reqs if r.get("outcome") not in (None, "ok")]
     if bad:
         by = {}
