@@ -804,6 +804,66 @@ def roll_sparse_attention(tiny, tpu, key):
                             for k_, e in loss_errs.items()}}
 
 
+def roll_expert_permutation(tiny, tpu, key):
+    """``moe_dispatch`` and ``moe_combine`` through their lowerings, forward
+    and every gradient, against the same lowerings where the context allows
+    no kernel (the held rows regrouped alike, each token's added one ``k``
+    at a time): a chip that holds a quarter of the experts at the sparse
+    training cell's shape, then one that holds them all."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+    from paddle_tpu.parallel import moe
+
+    class NoKernel(LoweringContext):
+        def pallas_ok(self):
+            return False
+
+    tokens, top_k, width, experts = (256, 4, 128, 8) if tiny \
+        else (8192, 8, 2304, 64)
+
+    def fwd_bwd(ctx_type, plan, g):
+        plan_ins = {"Order": [plan.order], "Pos": [plan.pos],
+                    "GroupSizes": [plan.group_sizes]}
+
+        def f(x, w):
+            ctx = ctx_type(base_key=key)
+            rows = get_op("moe_dispatch").fn({"X": [x], **plan_ins}, {},
+                                             ctx)["Out"][0]
+            return get_op("moe_combine").fn(
+                {"X": [rows * 2], "TopKWeight": [w], **plan_ins}, {},
+                ctx)["Out"][0]
+
+        def run(x, w):
+            out, vjp = jax.vjp(f, x, w)
+            return (out,) + vjp(g)
+        return jax.jit(run)
+
+    names = ("out", "dx", "dw")
+    cases = []
+    for held in (experts // 4, experts):
+        ks = jax.random.split(jax.random.fold_in(key, held), 4)
+        chosen = jnp.argsort(jax.random.uniform(ks[0], (tokens, experts)),
+                             axis=1)[:, :top_k].astype(jnp.int32)
+        plan = moe.dispatch_plan(chosen, 0, held)
+        x = jax.random.normal(ks[1], (tokens, width), jnp.bfloat16)
+        w = jax.nn.softmax(jax.random.normal(ks[2], (tokens, top_k)), -1)
+        g = jax.random.normal(ks[3], (tokens, width), jnp.bfloat16)
+        got, n_calls = run_lowered(fwd_bwd(LoweringContext, plan, g), x, w,
+                                   expect_mosaic=tpu)
+        want = fwd_bwd(NoKernel, plan, g)(x, w)
+        # float32 sums in another order, rounded once to bfloat16
+        errs = {name: rel_err(a, r) for name, a, r in zip(names, got, want)}
+        assert all(e < 2e-2 for e in errs.values()), errs
+        if tpu:
+            assert n_calls == 2, n_calls    # combine forward, dispatch grad
+        cases.append({"held_rows": int(jnp.sum(plan.group_sizes)),
+                      "rows": int(plan.order.shape[0]), "mosaic": n_calls,
+                      "rel_err": {k_: float(f"{v:.2e}")
+                                  for k_, v in errs.items()}})
+    return {"cases": cases}
+
+
 # which of pallas_kernels.__all__ each roll-call entry drives
 ROLL_CALL = [
     ("flash", roll_flash, ["flash_attention_tpu"]),
@@ -818,6 +878,7 @@ ROLL_CALL = [
     ("sparse_attention", roll_sparse_attention,
      ["selected_attention_tpu", "selected_probability_mean_tpu",
       "index_kl_tpu"]),
+    ("expert_permutation", roll_expert_permutation, ["held_rows_sum_tpu"]),
 ]
 
 

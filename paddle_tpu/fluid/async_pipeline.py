@@ -371,9 +371,12 @@ class AsyncStepRunner:
         ["device_counters"]``: persistable variable -> metric name, e.g. the
         expert layer's tokens per held expert) become gauges of
         ``trace.metrics()``: ``<metric>`` for a scalar, ``<metric>.<i>`` for
-        element ``i`` of a vector.  Read here, after the window has emptied
-        and the device is idle, so no step carries a fetch or a host
-        callback for them."""
+        element ``i`` of a vector.  An entry ``(metric, total, steps, rows)``
+        is a share of two of them: ``sum(total) / (steps x rows)``, ``rows``
+        a gauge the lowering set from its shapes, e.g. the part of the
+        expert layer's buffers that held a row.  Read here,
+        after the window has emptied and the device is idle, so no step
+        carries a fetch or a host callback for them."""
         prog = getattr(self._program, "_program", self._program)
         counters = (getattr(prog, "_hints", None) or {}).get(
             "device_counters")
@@ -382,6 +385,16 @@ class AsyncStepRunner:
         scope = self._scope or core.global_scope()
         metrics = trace.metrics()
         for var, metric in counters.items():
+            if isinstance(metric, tuple):
+                metric, total, steps, rows = metric
+                total, steps = scope.find_var(total), scope.find_var(steps)
+                rows = trace.gauge_value(rows)
+                if total is not None and steps is not None and rows:
+                    calls = float(np.asarray(steps).sum())
+                    metrics.gauge(metric).set(
+                        float(np.asarray(total).sum()) / (calls * rows)
+                        if calls else 0.0)
+                continue
             value = scope.find_var(var)
             if value is None:
                 continue

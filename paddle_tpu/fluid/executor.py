@@ -385,6 +385,7 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
             # batch-major, so a parameter whose dim 0 aliases the bucket
             # size is never masked
             ctx.cur_op_batch_major = _batch_major_hint(block, op)
+        ctx.cur_op = op
         # named_scope: the op in the executable's HLO metadata
         # (platform/profiler.h:127 RecordEvent placement, operator.cc:1077);
         # the host span below keeps the plain op type

@@ -620,7 +620,9 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
     of op and the ``swiglu`` gate between the grouped matmuls.  The routing's counts accumulate
     on the device in ``<name>.tokens_per_expert`` [num_held] and
     ``<name>.steps`` [1], published as gauges of ``trace.metrics()`` under
-    ``moe.<name>.…`` when an ``AsyncStepRunner`` drains.
+    ``moe.<name>.…`` when an ``AsyncStepRunner`` drains, and with them
+    ``moe.<name>.rows_visited_share``: the share of the buffers' rows that
+    held an assignment, which is all the permutation's gathers visit.
 
     ``scoring`` ``"softmax"``: top-k of the logits, softmax over the chosen.
     ``"sigmoid"``: top-k of ``sigmoid(logits) + correction bias`` (a
@@ -648,8 +650,16 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
                                name=name + ".tokens_per_expert")
     steps = create_global_var([1], 0, "int32", persistable=True,
                               name=name + ".steps")
-    default_main_program()._hints.setdefault("device_counters", {}).update(
-        {counts.name: "moe." + counts.name, steps.name: "moe." + steps.name})
+    counters = default_main_program()._hints.setdefault("device_counters",
+                                                        {})
+    counters.update({counts.name: "moe." + counts.name,
+                     steps.name: "moe." + steps.name})
+    # how far the permutation engages: of the rows its buffers hold, the
+    # share the gathers visit, sum(tokens_per_expert) / (steps x rows); the
+    # rows are the trace's (``moe_route`` sets the gauge as it is lowered)
+    counters[name + ".rows_visited_share"] = (
+        "moe." + name + ".rows_visited_share", counts.name, steps.name,
+        "moe." + steps.name + ".buffer_rows")
 
     def var(dtype, stop_gradient=False):
         return helper.create_variable_for_type_inference(

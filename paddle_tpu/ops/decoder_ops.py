@@ -199,13 +199,22 @@ def _moe_route(ins, attrs, ctx):
     if ins.get("Counts"):
         out["CountsOut"] = [ins["Counts"][0] + plan.group_sizes]
         out["StepsOut"] = [ins["Steps"][0] + 1]
+        if ctx.cur_op is not None and ctx.cur_op.type == "moe_route":
+            # (not a grad op tracing this forward again: its slots differ)
+            # the rows of the buffers this trace sized, beside the counts:
+            # their quotient is the share the permutation visits
+            from ..fluid import trace
+            trace.metrics().gauge(
+                f"moe.{ctx.cur_op.input('Steps')[0]}.buffer_rows").set(
+                    float(plan.order.shape[0]))
     return out
 
 
 @register_op("moe_dispatch", nondiff_inputs=_PLAN_SLOTS)
 def _moe_dispatch(ins, attrs, ctx):
     """X [T, D] -> Out [T * top_k, D]: the tokens in the plan's order."""
-    return {"Out": [moe.dispatch(ins["X"][0], _plan(ins))]}
+    return {"Out": [moe.dispatch(ins["X"][0], _plan(ins),
+                                 ctx.pallas_ok())]}
 
 
 @register_op("moe_grouped_matmul", nondiff_inputs=("GroupSizes",))
@@ -224,7 +233,7 @@ def _moe_combine(ins, attrs, ctx):
     TopKWeight [T, top_k] -> Out [T, D]: each token's weighted sum over its
     held assignments."""
     return {"Out": [moe.combine(ins["X"][0], ins["TopKWeight"][0],
-                                _plan(ins))]}
+                                _plan(ins), ctx.pallas_ok())]}
 
 
 # ---------------------------------------------------------------------------

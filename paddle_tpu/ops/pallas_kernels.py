@@ -26,11 +26,15 @@
   the chooser, whose [heads, rows, keys] index scores and their gradient
   XLA wrote to HBM a 128-query block at a time: two passes that keep a
   tile's scores on the core.
+* the expert layer's segment sum — each token's weighted sum of its held
+  experts' rows, which XLA gathered as [T, top_k, D], a row at a time,
+  padding included: the rows regrouped by tile of tokens are read once and
+  added on the matmul unit (parallel/moe.py, docs/moe.md).
 * paged decode attention, fused embedding gather+pool, bucketed optimizer
   updates — see each section.
 
 The callers (ops/attention.py, ops/nn_ops.py, ops/ctr_ops.py,
-ops/optimizer_ops.py, ops/decoder_ops.py) take these on the ``tpu`` backend
+ops/optimizer_ops.py, ops/decoder_ops.py, parallel/moe.py) take these on the ``tpu`` backend
 only; every kernel here is compiled by Mosaic and checked against its XLA
 reference by ``chip_smoke.py``'s kernel roll-call.
 """
@@ -52,7 +56,8 @@ __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
            "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
            "hyper_connection_merge_tpu", "selected_attention_tpu",
-           "selected_probability_mean_tpu", "index_kl_tpu"]
+           "selected_probability_mean_tpu", "index_kl_tpu",
+           "held_rows_sum_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
 # VMEM default is 16 MiB: one block of every operand together stays under
@@ -1979,6 +1984,115 @@ def hyper_connection_merge_tpu(x, z, post, c, tile=None):
     d], ``Out[i] = post_i z + sum_j c[i, j] x[j]``: 2.25 passes forward,
     3.5 backward (one kernel each)."""
     return _hc_merge_jit(x, z, post, c, tile or _HC_TILE)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's segment sum (parallel/moe.py ``_sum_by_token``).  The
+# held experts' rows lie grouped by tile of tokens, a tile's rows contiguous;
+# each token wants the weighted sum of its own (at most top_k) rows.  XLA
+# gathered [T, top_k, D] for it, a row at a time, padding included.  Here a
+# grid step holds one block of rows and one tile of tokens, builds the
+# [tokens, rows] matrix that has a row's weight where the row is the token's
+# (top_k compares of the tokens' row numbers against the block's) and lets
+# the matmul unit add: the rows are read once, at stream speed, and only the
+# blocks that hold rows are visited (a work list like megablox's: one entry
+# per block of a tile, a tile without rows gets one entry to write its zeros).
+# A float32 weight enters as three bfloat16 terms whose sum it is exactly;
+# products and sums are float32.
+# ---------------------------------------------------------------------------
+
+_SEGSUM_ROWS = 256            # rows of one grid step
+
+
+def held_rows_sum_supported(rows, num_tokens, token_tile) -> bool:
+    """bfloat16 rows in whole blocks, tokens in whole tiles, a lane-aligned
+    width."""
+    return (rows.dtype == jnp.bfloat16 and rows.shape[0] % _SEGSUM_ROWS == 0
+            and num_tokens % token_tile == 0 and token_tile % 8 == 0
+            and rows.shape[1] % 128 == 0)
+
+
+def _held_rows_sum_kernel(offsets_ref, tiles_ref, blocks_ref, at_ref, w_ref,
+                          rows_ref, o_ref, acc_ref, *, unit_weights):
+    i = pl.program_id(0)
+    tile = tiles_ref[i]
+    before = tiles_ref[jnp.maximum(i - 1, 0)]
+    after = tiles_ref[jnp.minimum(i + 1, pl.num_programs(0) - 1)]
+
+    @pl.when((i == 0) | (before != tile))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(offsets_ref[tile + 1] > offsets_ref[tile])
+    def _():
+        tokens, block = at_ref.shape[0], rows_ref.shape[0]
+        row = blocks_ref[i] * block + jax.lax.broadcasted_iota(
+            jnp.int32, (tokens, block), 1)
+        at = at_ref[...]
+        w = w_ref[...]
+        picks = jnp.zeros((tokens, block), jnp.float32)
+        for k in range(at.shape[1]):
+            picks = jnp.where(at[:, k:k + 1] == row,
+                              1.0 if unit_weights else w[:, k:k + 1], picks)
+        rows = rows_ref[...]
+        for _ in range(1 if unit_weights else 3):
+            term = picks.astype(rows.dtype)
+            acc_ref[...] += jnp.dot(term, rows,
+                                    preferred_element_type=jnp.float32)
+            picks = picks - term.astype(jnp.float32)
+
+    @pl.when((i == pl.num_programs(0) - 1) | (after != tile))
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def held_rows_sum_tpu(rows, at, weights, sizes, token_tile):
+    """out[t] = sum_k weights[t, k] * rows[at[t, k]] over the ``k`` with
+    ``at[t, k] >= 0``: ``rows`` [R, D] bfloat16, finite everywhere, the rows
+    of the tokens of tile ``g`` (``token_tile`` tokens) contiguous and the
+    tiles in order, ``sizes[g]`` rows each; ``at`` [T, top_k] int32;
+    ``weights`` [T, top_k] float32 or None (1).  [T, D] in ``rows``'s dtype,
+    accumulated in float32."""
+    total, width = rows.shape
+    num_tokens, top_k = at.shape
+    groups = num_tokens // token_tile
+    unit = weights is None
+    if unit:
+        weights = jnp.ones((num_tokens, 1), jnp.float32)
+    # the work list: a tile's steps are the blocks its rows lie in (its one
+    # step, where it has no row, writes its zeros); step s is the tile that
+    # begins last at or before s, and that tile's first block plus the rest
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    lo = (ends - sizes) // _SEGSUM_ROWS
+    count = jnp.where(sizes > 0, (ends - 1) // _SEGSUM_ROWS - lo, 0) + 1
+    first = jnp.cumsum(count) - count
+    step = jnp.arange(total // _SEGSUM_ROWS + groups, dtype=jnp.int32)
+    tiles = jnp.sum(step[:, None] >= first[None, :], axis=1,
+                    dtype=jnp.int32) - 1
+    blocks = jnp.minimum(lo[tiles] + step - first[tiles],
+                         total // _SEGSUM_ROWS - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    steps = jnp.sum(count)
+    return pl.pallas_call(
+        functools.partial(_held_rows_sum_kernel, unit_weights=unit),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((token_tile, top_k),
+                             lambda i, o, t, b: (t[i], 0)),
+                pl.BlockSpec((token_tile, weights.shape[1]),
+                             lambda i, o, t, b: (t[i], 0)),
+                pl.BlockSpec((_SEGSUM_ROWS, width),
+                             lambda i, o, t, b: (b[i], 0))],
+            out_specs=pl.BlockSpec((token_tile, width),
+                                   lambda i, o, t, b: (t[i], 0)),
+            scratch_shapes=[pltpu.VMEM((token_tile, width), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((num_tokens, width), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(offsets, tiles, blocks, at, weights.astype(jnp.float32), rows)
 
 
 # ---------------------------------------------------------------------------

@@ -205,6 +205,10 @@ class LoweringContext:
         # the batch, even if dim 0 aliases the bucket size), True when the
         # IR marks it batch-major (-1 leading dim), None when unknown
         self.cur_op_batch_major = None
+        # the Program op being lowered (run_block_ops sets it; None under
+        # shape inference, whose stand-in batch is no run's): for a lowering
+        # that publishes a trace-time reading under its own variables' names
+        self.cur_op = None
         # set by the executor when the whole block compiles as ONE
         # GSPMD-partitioned program over a multi-device mesh
         # (parallel/sharding.py wrap_with_plan, parallel/api.py

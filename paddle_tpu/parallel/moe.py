@@ -17,8 +17,13 @@ No capacity, no dropping: the assignments are sorted by expert into one
 ``[T * top_k, D]`` buffer (its worst case: every assignment held), the held
 experts multiply their own contiguous groups of rows (``grouped_matmul``:
 the megablox kernel on a TPU, ``lax.ragged_dot`` elsewhere; both skip the
-rows past the last group), and rows past the last group are zero.  Every
-shape is static; only ``group_sizes`` carries the data-dependent counts.
+rows past the last group), and rows past the last group are zero, which
+since PR 35 also means never read: what reads the buffer (``combine``, its
+gradient, ``dispatch``'s gradient) visits its first ``sum(group_sizes)``
+rows in blocks of 512 under a trip count read from the plan, so a chip that
+holds a quarter of the experts moves a quarter of the buffer, and no
+operator gathers a row per assignment (``[T, top_k, D]``).  Every shape is
+static; only ``group_sizes`` carries the data-dependent counts.
 
 The pieces are separate functions because they are separate Program ops
 (``ops/decoder_ops.py``: ``moe_route``, ``moe_dispatch``, ``moe_grouped_matmul``,
@@ -120,68 +125,234 @@ def dispatch_plan(experts, first_expert: int, num_held: int,
     return Plan(order, pos, group_sizes)
 
 
-def _rows_valid(plan: Plan):
-    """[R, 1]: is row ``r`` inside a held expert's group."""
-    return (jnp.arange(plan.order.shape[0]) < jnp.sum(plan.group_sizes))[:, None]
+def _num_held(plan: Plan):
+    """``n``: the rows of the buffer that hold an assignment of a held
+    expert, ``sum(group_sizes)``."""
+    return jnp.sum(plan.group_sizes)
 
 
-def _held(plan: Plan):
-    """[T, top_k]: does assignment ``(t, k)`` go to a held expert."""
-    return plan.pos < jnp.sum(plan.group_sizes)
+def _zero_from(n, rows, start=0):
+    """``rows`` (the buffer's rows from ``start`` on) with those from row
+    ``n`` of the buffer on set to zero."""
+    keep = start + jnp.arange(rows.shape[0]) < n
+    return jnp.where(keep.reshape((-1,) + (1,) * (rows.ndim - 1)), rows, 0)
 
 
-@jax.custom_vjp
-def dispatch(x, plan: Plan):
+def _visit_held_blocks(n, like, block_fn):
+    """Buffers shaped ``like`` (``[R, ...]`` each) whose rows ``[0, n)`` are
+    ``block_fn``'s and whose other rows are zero, visiting the held rows
+    alone: ``block_fn(start, size)`` gives every buffer's rows ``[start,
+    start + size)``; blocks of ``_GMM_ROWS`` rows, ``ceil(n / _GMM_ROWS)``
+    trips.  The shapes are static, the trip count is the data's (one
+    ``while``, the buffers updated in place); the rows past the last visited
+    block cost the one fill."""
+    rows = like[0].shape[0]
+    size = min(_GMM_ROWS, rows)
+
+    def first(i):
+        # a last block that would overhang starts early and rewrites rows
+        # an earlier trip wrote, with the same values
+        return jnp.clip(i * size, 0, rows - size)
+
+    def body(i, bufs):
+        return tuple(
+            lax.dynamic_update_slice_in_dim(buf, block.astype(buf.dtype),
+                                            first(i), 0)
+            for buf, block in zip(bufs, block_fn(first(i), size)))
+    trips = (n + size - 1) // size
+    bufs = lax.fori_loop(0, trips, body,
+                         tuple(jnp.zeros(b.shape, b.dtype) for b in like))
+    # the last visited block alone can hold rows from n on: one more pass
+    # over it, not a select in every trip
+    last = first(trips - 1)
+    return tuple(
+        lax.dynamic_update_slice_in_dim(buf, _zero_from(
+            n, lax.dynamic_slice_in_dim(buf, last, size, 0), last), last, 0)
+        for buf in bufs)
+
+
+# tokens of one tile of the segment sum: the held rows of a tile's tokens
+# are gathered side by side, and one grid step of the kernel adds a block of
+# them into the tile's [128, D] result
+_TOKEN_TILE = 128
+
+
+def permutation_lowering(use_kernel: bool, rows, num_tokens: int) -> str:
+    """``pallas`` or ``xla``, for the sum of each token's held rows: the
+    kernel wants the TPU backend and the shapes ``held_rows_sum_supported``
+    states."""
+    from ..ops import pallas_kernels
+    if use_kernel and pallas_kernels.held_rows_sum_supported(
+            rows, num_tokens, _TOKEN_TILE):
+        return "pallas"
+    return "xla"
+
+
+def _by_token_tile(plan: Plan, token_tile: int):
+    """The held rows regrouped by tile of ``token_tile`` tokens, from the
+    plan alone and without a sort: inside a held expert's group the rows are
+    in assignment order (the plan's sort is stable), so the rows of (tile,
+    expert) are one run of the buffer, and a tile's rows are its experts'
+    runs one after the other.  A run keeps its order, so a row moves by its
+    run's ``shift``, and everything is counts of ``[tiles, held experts]``
+    and their running sums.  Returns ``sizes`` [tiles] (held rows a tile),
+    ``at`` [T, top_k] (where assignment ``(t, k)``'s row goes, -1: not held)
+    and ``index_block`` (the buffer rows that come to lie at ``[start, start
+    + size)``)."""
+    num_tokens, top_k = plan.pos.shape
+    num_held = plan.group_sizes.shape[0]
+    tiles = num_tokens // token_tile
+    ends = jnp.cumsum(plan.group_sizes)
+    starts = ends - plan.group_sizes
+    hot = ((plan.pos[..., None] >= starts) & (plan.pos[..., None] < ends)
+           ).reshape(tiles, token_tile * top_k, num_held)
+    runs = jnp.sum(hot, axis=1, dtype=jnp.int32)            # [tiles, held]
+    flat = runs.reshape(-1)
+    run_to = (jnp.cumsum(flat) - flat).reshape(tiles, num_held)
+    shift = starts[None, :] + jnp.cumsum(runs, axis=0) - runs - run_to
+    at = plan.pos - jnp.sum(jnp.where(hot, shift[:, None, :], 0),
+                            axis=-1).reshape(num_tokens, top_k)
+    at = jnp.where(plan.pos < ends[-1], at, -1)
+    # a position's run: its tile (the last that begins at or before it; an
+    # empty one begins where the next does), then the last of the tile's runs
+    # that begins at or before it; the tile's row of the two tables comes
+    # through a one-hot matmul, exact on integers at the highest precision
+    steps = jnp.concatenate([shift[:, :1], jnp.diff(shift, axis=1)], axis=1)
+    table = jnp.concatenate([run_to, steps], axis=1).astype(jnp.float32)
+
+    def index_block(start, size):
+        to = start + jnp.arange(size, dtype=jnp.int32)
+        tile = jnp.sum(to[:, None] >= run_to[None, :, 0], axis=1) - 1
+        mine = jnp.dot(jax.nn.one_hot(tile, tiles, dtype=jnp.float32), table,
+                       precision=lax.Precision.HIGHEST).astype(jnp.int32)
+        return to + jnp.sum(jnp.where(to[:, None] >= mine[:, :num_held],
+                                      mine[:, num_held:], 0), axis=1)
+    return jnp.sum(runs, axis=1), at, index_block
+
+
+def _sum_by_token(rows, weights, plan: Plan, use_kernel: bool):
+    """out[t] = sum over the held assignments (t, k) of weights[t, k] *
+    rows[pos[t, k]] ([T, D] in ``rows``'s dtype, summed in float32;
+    ``weights`` None: 1): the transpose of the dispatch gather, without a
+    row per assignment.  The held rows are regrouped by tile of tokens, then
+    each token's are added: on a TPU by ``held_rows_sum_tpu``, which reads a
+    tile's rows once and lets the matmul unit add them (float32 products
+    and sums; a float32 weight enters as three bfloat16 terms whose sum it
+    is exactly), elsewhere one ``k`` at a time.
+
+    The regrouping gather reads ``rows`` from HBM, about 37 ns a row in one
+    piece and 45 to 50 in blocks: up to two thirds of the buffer held, the
+    held rows alone are visited (``_visit_held_blocks``: the fill and ``n``
+    rows); a fuller buffer is permuted whole, in one piece (its rows from
+    ``n`` on then hold the last held row, which nothing reads: the kernel
+    picks a row only through ``at``)."""
+    lowering = permutation_lowering(use_kernel, rows, plan.pos.shape[0])
+    from ..fluid import trace
+    trace.metrics().counter(f"moe.permutation_lowering.{lowering}").inc()
+    return _sum_by_token_jit(rows, weights, plan, lowering)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _sum_by_token_jit(rows, weights, plan: Plan, lowering: str):
+    """One trace per shapes, shared by a program's expert layers."""
+    num_tokens, top_k = plan.pos.shape
+    total, n = rows.shape[0], _num_held(plan)
+    tile = _TOKEN_TILE if num_tokens % _TOKEN_TILE == 0 else num_tokens
+    sizes, at, index_block = _by_token_tile(plan, tile)
+
+    def gather(start, size):
+        # a position from n on reads the last held row: what lies past it
+        # in ``rows`` need not be finite
+        return (jnp.take(rows, jnp.minimum(index_block(start, size), n - 1),
+                         axis=0, mode="clip"),)
+    by_tile, = lax.cond(3 * n > 2 * total, lambda: gather(0, total),
+                        lambda: _visit_held_blocks(n, (rows,), gather))
+    if lowering == "pallas":
+        from ..ops import pallas_kernels
+        return pallas_kernels.held_rows_sum_tpu(by_tile, at, weights, sizes,
+                                                tile)
+    out = jnp.zeros((num_tokens, rows.shape[1]), jnp.float32)
+    for k in range(top_k):
+        term = jnp.take(by_tile, at[:, k], axis=0,
+                        mode="clip").astype(jnp.float32)
+        if weights is not None:
+            term = term * weights[:, k, None].astype(jnp.float32)
+        out = out + jnp.where(at[:, k, None] >= 0, term, 0)
+    return out.astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def dispatch(x, plan: Plan, use_kernel: bool = False):
     """Rows of ``x`` [T, D] in the plan's order: [R, D], zero past the last
-    group (the assignments of experts not held)."""
+    group (the assignments of experts not held).  One gather from ``x`` with
+    a zero row appended, which the rows past the last group read: no select
+    pass over the buffer follows it (that pass was half of the 1.4 ms this
+    took at [65536, 2304]; ``x`` is small enough to wait on the core, so the
+    gather runs at the speed the buffer is written, whatever the share
+    held).  ``use_kernel`` is the gradient's."""
     top_k = plan.pos.shape[1]
-    return jnp.where(_rows_valid(plan),
-                     jnp.take(x, plan.order // top_k, axis=0), 0)
+    at = jnp.where(jnp.arange(plan.order.shape[0]) < _num_held(plan),
+                   plan.order // top_k, x.shape[0])
+    return jnp.take(jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:],
+                                                  x.dtype)]),
+                    at, axis=0, mode="clip")
 
 
-def _dispatch_fwd(x, plan):
-    return dispatch(x, plan), plan
+def _dispatch_fwd(x, plan, use_kernel):
+    return dispatch(x, plan, use_kernel), plan
 
 
-def _dispatch_bwd(plan, g):
+def _dispatch_bwd(use_kernel, plan, g):
     # the transpose of a gather is a scatter-add; the plan knows the rows of
-    # each token, so it is a gather and a sum over top_k instead
-    rows = jnp.take(g, plan.pos, axis=0, mode="clip")          # [T, K, D]
-    gx = jnp.sum(jnp.where(_held(plan)[..., None], rows, 0)
-                 .astype(jnp.float32), axis=1)
-    return gx.astype(g.dtype), None
+    # each token, so it is a regrouping gather and a sum of each token's
+    # rows instead
+    return _sum_by_token(g, None, plan, use_kernel), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def combine(y, weights, plan: Plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine(y, weights, plan: Plan, use_kernel: bool = False):
     """out[t] = sum over the held assignments (t, k) of weights[t, k] *
     y[pos[t, k]]: [T, D] in ``y``'s dtype, accumulated in float32."""
-    rows = jnp.take(y, plan.pos, axis=0, mode="clip")          # [T, K, D]
-    out = jnp.sum(jnp.where(_held(plan)[..., None], rows.astype(jnp.float32)
-                            * weights[..., None].astype(jnp.float32), 0),
-                  axis=1)
-    return out.astype(y.dtype)
+    return _sum_by_token(y, weights, plan, use_kernel)
 
 
-def _combine_fwd(y, weights, plan):
-    return combine(y, weights, plan), (y, weights, plan)
+def _combine_fwd(y, weights, plan, use_kernel):
+    return combine(y, weights, plan, use_kernel), (y, weights, plan)
 
 
-def _combine_bwd(res, g):
-    y, weights, plan = res
-    top_k = plan.pos.shape[1]
-    rows = jnp.take(y, plan.pos, axis=0, mode="clip")          # [T, K, D]
-    gw = jnp.sum(rows.astype(jnp.float32)
-                 * g[:, None, :].astype(jnp.float32), axis=-1)
-    gw = jnp.where(_held(plan), gw, 0).astype(weights.dtype)
-    w_row = jnp.take(weights.reshape(-1), plan.order)          # [R]
-    g_row = jnp.take(g, plan.order // top_k, axis=0)           # [R, D]
-    gy = jnp.where(_rows_valid(plan), g_row.astype(jnp.float32)
-                   * w_row[:, None].astype(jnp.float32), 0)
-    return gy.astype(y.dtype), gw, None
+def _combine_bwd(use_kernel, res, g):
+    return _combine_bwd_jit(*res, g) + (None,)
+
+
+@jax.jit
+def _combine_bwd_jit(y, weights, plan: Plan, g):
+    """One trace per shapes, shared by a program's expert layers."""
+    top_k, n = plan.pos.shape[1], _num_held(plan)
+    flat_w = weights.reshape(-1).astype(jnp.float32)
+
+    def block(start, size):
+        # row r's token gradient, once: times the row's weight it is y's
+        # gradient, against y's row it is the weight's
+        at = lax.dynamic_slice_in_dim(plan.order, start, size)
+        g_row = jnp.take(g, at // top_k, axis=0,
+                         mode="clip").astype(jnp.float32)
+        y_row = lax.dynamic_slice_in_dim(y, start, size, 0)
+        return (g_row * jnp.take(flat_w, at, mode="clip")[:, None],
+                jnp.sum(y_row.astype(jnp.float32) * g_row, axis=-1))
+    like = (y, jax.ShapeDtypeStruct(plan.order.shape, jnp.float32))
+
+    # as in ``_sum_by_token``: the held rows alone up to two thirds of the
+    # buffer, a fuller one whole
+    gy, dot = lax.cond(
+        3 * n > 2 * y.shape[0],
+        lambda: tuple(_zero_from(n, b).astype(buf.dtype) for b, buf in zip(
+            block(0, y.shape[0]), like)),
+        lambda: _visit_held_blocks(n, like, block))
+    gw = jnp.where(plan.pos < n, jnp.take(dot, plan.pos, mode="clip"), 0)
+    return gy, gw.astype(weights.dtype)
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -209,11 +380,7 @@ def grouped_matmul(x, w, group_sizes, use_kernel: bool = False):
         return lax.ragged_dot(x, w, group_sizes,
                               preferred_element_type=jnp.float32
                               ).astype(x.dtype)
-    # the kernel never visits the tiles past the last group: what it leaves
-    # there (and in their gradient) is whatever the buffer held
-    valid = (jnp.arange(x.shape[0]) < jnp.sum(group_sizes))[:, None]
-    return jnp.where(valid, _megablox(jnp.where(valid, x, 0), w,
-                                      group_sizes), 0)
+    return _megablox(x, w, group_sizes)
 
 
 def _megablox_kernels():
@@ -228,11 +395,17 @@ def _megablox_kernels():
 def _megablox(x, w, group_sizes):
     """jax's megablox grouped matmul with a tile plan per call: its own
     ``ops.gmm`` hands one tiling to the forward and both backward kernels,
-    and 2304 and 896 share no tile wider than 128."""
+    and 2304 and 896 share no tile wider than 128.  The kernels never visit
+    the tiles past the last group, so what they leave there is whatever the
+    buffer held: zeroed here, in the result and in the gradient of ``x``.
+    Their operands need no such pass: a row that is not its tile's group's
+    is masked where it is loaded (``tgmm``) or where its result is stored
+    (``gmm``), so what ``x`` or a cotangent holds past the last group is
+    never used."""
     backend = _megablox_kernels()
-    return backend.gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-                       tiling=(_GMM_ROWS, _tile(w.shape[1]),
-                               _tile(w.shape[2])))
+    return _zero_from(jnp.sum(group_sizes), backend.gmm(
+        x, w, group_sizes, preferred_element_type=x.dtype,
+        tiling=(_GMM_ROWS, _tile(w.shape[1]), _tile(w.shape[2]))))
 
 
 def _megablox_fwd(x, w, group_sizes):
@@ -250,7 +423,7 @@ def _megablox_bwd(res, g):
                       preferred_element_type=w.dtype,
                       tiling=(_GMM_ROWS, _tile(k), _tile(n)),
                       num_actual_groups=w.shape[0])
-    return gx, gw, None
+    return _zero_from(jnp.sum(group_sizes), gx), gw, None
 
 
 _megablox.defvjp(_megablox_fwd, _megablox_bwd)
@@ -285,9 +458,9 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     num_held = w_gate.shape[0]
     if axis_name is None:
         plan = dispatch_plan(experts, first_expert, num_held)
-        ys = held_ffn(dispatch(x, plan), plan.group_sizes, w_gate, w_up,
-                      w_down, use_kernel)
-        return combine(ys, weights, plan)
+        ys = held_ffn(dispatch(x, plan, use_kernel), plan.group_sizes,
+                      w_gate, w_up, w_down, use_kernel)
+        return combine(ys, weights, plan, use_kernel)
 
     n = lax.axis_size(axis_name)
     t, d = x.shape
@@ -300,7 +473,8 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     filled = slot < by_chip.group_sizes[:, None]               # [n, cap]
     row = jnp.where(filled, start[:, None] + slot, 0)
     sent = jnp.where(filled[..., None],
-                     jnp.take(dispatch(x, by_chip), row, axis=0), 0)
+                     jnp.take(dispatch(x, by_chip, use_kernel), row, axis=0),
+                     0)
     sent_expert = jnp.where(
         filled, jnp.take(experts.reshape(-1), by_chip.order)[row] % num_held,
         num_held)
@@ -308,9 +482,9 @@ def expert_layer(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     got_expert = lax.all_to_all(sent_expert, axis_name, 0, 0, tiled=True)
     # each received row is one assignment to a held expert (or an empty slot)
     here = dispatch_plan(got_expert.reshape(-1, 1), 0, num_held)
-    ys = held_ffn(dispatch(got.reshape(-1, d), here), here.group_sizes,
-                  w_gate, w_up, w_down, use_kernel)
-    back = combine(ys, jnp.ones((n * cap, 1), jnp.float32), here)
+    ys = held_ffn(dispatch(got.reshape(-1, d), here, use_kernel),
+                  here.group_sizes, w_gate, w_up, w_down, use_kernel)
+    back = combine(ys, jnp.ones((n * cap, 1), jnp.float32), here, use_kernel)
     back = lax.all_to_all(back.reshape(n, cap, d), axis_name, 0, 0,
                           tiled=True)
     # slab (chip, slot) is row start[chip] + slot of the by-chip order

@@ -942,6 +942,35 @@ class TestCausalDecoderStepKeepsTheScoresOnTheCore:
         assert (moe._tile(2304), moe._tile(896)) == (768, 896)
 
 
+    @pytest.mark.parametrize("tokens, top_k, width, rows", [
+        (8192, 8, 2304, 65536),       # mellum2_train_seq8192
+        (16384, 8, 2048, 32768),      # keye_vl2_train_seq16384 (max_rows)
+        (4096, 4, 3584, 16384)])      # xing4_train_seq4096
+    def test_the_permutation_compiles_at_the_cells_shapes(self, tokens,
+                                                           top_k, width,
+                                                           rows):
+        """``moe_combine`` forward with its gradient and ``moe_dispatch``'s
+        gradient for a described v5e: the segment-sum kernel (scoped VMEM
+        included) once each way, and no array with a row per assignment."""
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        from paddle_tpu.parallel import moe
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        plan = moe.Plan(i32(rows), i32(tokens, top_k), i32(16))
+
+        def step(x, y, w, plan, g):
+            out, vjp = jax.vjp(
+                lambda x, y, w: moe.combine(
+                    y + moe.dispatch(x, plan, True), w, plan, True), x, y, w)
+            return (out,) + vjp(g)
+        compiled = compile_for_tpu(
+            step, _sds(tokens, width), _sds(rows, width),
+            jax.ShapeDtypeStruct((tokens, top_k), jnp.float32), plan,
+            _sds(tokens, width))
+        assert mosaic_call_count(compiled) == 2
+        assert f"[{tokens},{top_k},{width}]" not in compiled.as_text()
+
+
 # ---------------------------------------------------------------------------
 # fuse_sparse_embedding
 # ---------------------------------------------------------------------------

@@ -188,6 +188,8 @@ def test_routing_counts_leave_the_device_when_the_runner_drains():
         np.testing.assert_array_equal(counts, held)
         # 3 steps x 64 tokens x top-4 of 16 experts, a quarter of them held
         assert 0 < sum(counts) <= 3 * 64 * 4
+        # the share of the buffers' 64 x 4 rows the permutation visits
+        assert gauge("rows_visited_share") == sum(counts) / (3 * 64 * 4)
         runner.submit(feeds[0])
         runner.drain()
         assert gauge("steps") == 4

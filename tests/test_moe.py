@@ -282,3 +282,171 @@ def test_megablox_lowering_in_interpret_mode():
                                    atol=1e-4)
     assert not np.asarray(got[0])[513:].any()
     assert not np.asarray(got[1])[513:].any()
+
+
+# ---------------------------------------------------------------------------
+# the permutation visits only the rows a chip holds.  The plain reference is
+# the four formulas the module had until PR 35: ``take`` through ``order`` /
+# ``pos`` over every row of the buffer and every (t, k), masked afterwards.
+# ---------------------------------------------------------------------------
+
+def _ref_masks(plan):
+    n = jnp.sum(plan.group_sizes)
+    return (jnp.arange(plan.order.shape[0]) < n)[:, None], plan.pos < n
+
+
+def _ref_dispatch(x, plan):
+    valid, _ = _ref_masks(plan)
+    return jnp.where(valid, jnp.take(x, plan.order // plan.pos.shape[1],
+                                     axis=0), 0)
+
+
+def _ref_dispatch_bwd(plan, g):
+    _, held = _ref_masks(plan)
+    rows = jnp.take(g, plan.pos, axis=0, mode="clip")           # [T, K, D]
+    return jnp.sum(jnp.where(held[..., None], rows, 0).astype(jnp.float32),
+                   axis=1).astype(g.dtype)
+
+
+def _ref_combine(y, weights, plan):
+    _, held = _ref_masks(plan)
+    rows = jnp.take(y, plan.pos, axis=0, mode="clip")           # [T, K, D]
+    return jnp.sum(jnp.where(held[..., None], rows.astype(jnp.float32)
+                             * weights[..., None].astype(jnp.float32), 0),
+                   axis=1).astype(y.dtype)
+
+
+def _ref_combine_bwd(y, weights, plan, g):
+    valid, held = _ref_masks(plan)
+    top_k = plan.pos.shape[1]
+    rows = jnp.take(y, plan.pos, axis=0, mode="clip")           # [T, K, D]
+    gw = jnp.sum(rows.astype(jnp.float32)
+                 * g[:, None, :].astype(jnp.float32), axis=-1)
+    gw = jnp.where(held, gw, 0).astype(weights.dtype)
+    w_row = jnp.take(weights.reshape(-1), plan.order)
+    g_row = jnp.take(g, plan.order // top_k, axis=0)
+    gy = jnp.where(valid, g_row.astype(jnp.float32)
+                   * w_row[:, None].astype(jnp.float32), 0)
+    return gy.astype(y.dtype), gw
+
+
+def _held_plan(case, seed=0):
+    """(tokens, top_k, width, plan) of a routing whose held share ``n / R``
+    is the case's.  512 rows are one block of the gathers."""
+    tokens, top_k, width, experts = 512, 4, 128, 16
+    max_rows = None
+    rng = np.random.RandomState(seed)
+    chosen = np.stack([rng.permutation(experts)[:top_k]
+                       for _ in range(tokens)]).astype(np.int32)
+    first, held = 0, {"none": 0, "eighth": 2, "quarter": 4, "all": 16,
+                      "ragged": 5, "bounded": 2}.get(case, 0)
+    if case == "none":
+        first, held = 20, 2                    # a range nobody is routed to
+    elif case == "one_row":
+        chosen[:] = rng.randint(1, experts, chosen.shape)
+        chosen[37, 2] = 0
+        held = 1
+    elif case == "bounded":                    # keye's: twice the even share
+        max_rows = 2 * tokens * top_k * held // experts
+    plan = moe.dispatch_plan(jnp.asarray(chosen), first, held, max_rows)
+    return tokens, top_k, width, plan
+
+
+@pytest.mark.parametrize("case", ["none", "one_row", "eighth", "quarter",
+                                  "all", "ragged", "bounded"])
+def test_the_permutation_equals_the_plain_formulas_and_reads_held_rows_only(
+        case):
+    tokens, top_k, width, plan = _held_plan(case)
+    total, n = plan.order.shape[0], int(jnp.sum(plan.group_sizes))
+    want_n = {"none": 0, "one_row": 1, "all": tokens * top_k}.get(case)
+    assert want_n is None or n == want_n
+    assert case != "ragged" or n % 512
+    assert case != "bounded" or (total == tokens * top_k // 4 and n < total)
+    k = jax.random.split(jax.random.PRNGKey(7), 5)
+    x = jax.random.normal(k[0], (tokens, width))
+    y = jax.random.normal(k[1], (total, width))
+    weights = jax.nn.softmax(jax.random.normal(k[2], (tokens, top_k)), -1)
+    g_rows = jax.random.normal(k[3], (total, width))
+    g_tokens = jax.random.normal(k[4], (tokens, width))
+
+    rows, vjp = jax.vjp(lambda x: moe.dispatch(x, plan), x)
+    np.testing.assert_array_equal(np.asarray(rows),
+                                  np.asarray(_ref_dispatch(x, plan)))
+    assert not np.asarray(rows)[n:].any()
+    np.testing.assert_allclose(
+        np.asarray(vjp(g_rows)[0]), np.asarray(_ref_dispatch_bwd(plan, g_rows)),
+        rtol=1e-6, atol=1e-6)
+
+    out, vjp = jax.vjp(lambda y, w: moe.combine(y, w, plan), y, weights)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_ref_combine(y, weights, plan)),
+        rtol=1e-6, atol=1e-6)
+    gy, gw = vjp(g_tokens)
+    want_gy, want_gw = _ref_combine_bwd(y, weights, plan, g_tokens)
+    np.testing.assert_allclose(np.asarray(gy), np.asarray(want_gy),
+                               rtol=1e-6, atol=1e-6)
+    assert not np.asarray(gy)[n:].any()
+    np.testing.assert_allclose(np.asarray(gw), np.asarray(want_gw),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["none", "one_row", "quarter", "all",
+                                  "ragged", "bounded"])
+def test_segment_sum_kernel_in_interpret_mode(case):
+    """The kernel path of ``combine`` forward and ``dispatch`` backward
+    (``held_rows_sum_tpu``: the matmul unit adds a tile's rows) against the
+    plain formulas, bfloat16 rows as the kernel takes them."""
+    from jax.experimental.pallas import tpu as pltpu
+    tokens, top_k, width, plan = _held_plan(case, seed=1)
+    total = plan.order.shape[0]
+    k = jax.random.split(jax.random.PRNGKey(11), 3)
+    valid, _ = _ref_masks(plan)
+    y = jnp.where(valid, jax.random.normal(k[0], (total, width)),
+                  0).astype(jnp.bfloat16)
+    weights = jax.nn.softmax(jax.random.normal(k[1], (tokens, top_k)), -1)
+    assert moe.permutation_lowering(True, y, tokens) == "pallas"
+    assert moe.permutation_lowering(False, y, tokens) == "xla"
+    assert moe.permutation_lowering(True, y.astype(jnp.float32),
+                                    tokens) == "xla"
+    with pltpu.force_tpu_interpret_mode():
+        out = jax.jit(lambda y, w: moe.combine(y, w, plan, True))(y, weights)
+        gx = jax.jit(lambda g: moe._dispatch_bwd(True, plan, g)[0])(y)
+    # float32 sums before the one rounding to bfloat16: at most one unit of
+    # bfloat16 apart where the order of the adds moved a float32 sum across
+    # a rounding boundary
+    for got, want in ((out, _ref_combine(y, weights, plan)),
+                      (gx, _ref_dispatch_bwd(plan, y))):
+        assert got.dtype == jnp.bfloat16
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=1e-6)
+        assert (got == want).mean() > 0.99
+
+
+def test_no_array_of_every_assignments_row_exists_at_the_cells_shape():
+    """``mellum2_train_seq8192``'s shape (8192 tokens, top-8, 2304 wide, a
+    buffer of 65,536 rows): the four operators, as jit lowers them, hold no
+    [T, top_k, D] array in any dtype: nothing gathers a row per assignment,
+    held or not (float32, that array was 604 MB a layer)."""
+    tokens, top_k, width, held = 8192, 8, 2304, 16
+    total = tokens * top_k
+    s = jax.ShapeDtypeStruct
+    plan = moe.Plan(s((total,), jnp.int32), s((tokens, top_k), jnp.int32),
+                    s((held,), jnp.int32))
+    x = s((tokens, width), jnp.bfloat16)
+    y = s((total, width), jnp.bfloat16)
+    w = s((tokens, top_k), jnp.float32)
+    lowered = {
+        "dispatch": jax.jit(lambda x, p: moe.dispatch(x, p)).lower(x, plan),
+        "dispatch_grad": jax.jit(
+            lambda p, g: moe._dispatch_bwd(False, p, g)).lower(plan, y),
+        "combine": jax.jit(
+            lambda y, w, p: moe.combine(y, w, p)).lower(y, w, plan),
+        "combine_grad": jax.jit(lambda y, w, p, g: moe._combine_bwd(
+            False, (y, w, p), g)).lower(y, w, plan, x),
+    }
+    for name, low in lowered.items():
+        text = low.as_text()
+        assert f"{tokens}x{top_k}x{width}x" not in text, name
+        # the three that read the buffer visit its held rows under a trip
+        # count of the data's; ``dispatch`` writes the buffer in one gather
+        assert ("stablehlo.while" in text) == (name != "dispatch"), name
