@@ -864,6 +864,105 @@ def roll_expert_permutation(tiny, tpu, key):
     return {"cases": cases}
 
 
+def roll_selective_scan(tiny, tpu, key):
+    """``selective_scan`` through its lowering at the training cell's shape
+    (4096 tokens, 5120 channels of 16 states) and with a ragged last chunk,
+    forward and all six gradients, against the ``jnp`` path the same
+    lowering takes where the context allows no kernel."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    class NoKernel(LoweringContext):
+        def pallas_ok(self):
+            return False
+
+    def fwd_bwd(ctx_type, w):
+        def f(*args):
+            return get_op("selective_scan").fn(
+                {slot: [a] for slot, a in zip("X Dt A B C D".split(), args)},
+                {}, ctx_type(base_key=key))["Y"][0]
+
+        def g(*args):
+            out, vjp = jax.vjp(f, *args)
+            return (out,) + vjp(w)
+        return jax.jit(g)
+
+    names = ("y", "dx", "ddt", "da", "db", "dc", "dd")
+    cases = []
+    for seq, di, n in [(256 + 40, 128, 8)] if tiny \
+            else [(4096, 5120, 16), (512 + 40, 256, 16)]:
+        ks = jax.random.split(jax.random.fold_in(key, seq), 6)
+        x = jax.random.normal(ks[0], (1, seq, di), jnp.float32)
+        # steps log-uniform in [1e-3, 1e-1], Mamba's start
+        dt = jnp.exp(jax.random.uniform(ks[1], (1, seq, di), jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        a = -jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32), (di, 1))
+        b = jax.random.normal(ks[2], (1, seq, n), jnp.float32)
+        c = jax.random.normal(ks[3], (1, seq, n), jnp.float32)
+        d = jnp.ones((di,), jnp.float32)
+        w = jax.random.normal(ks[4], (1, seq, di), jnp.float32)
+        args = (x, dt, a, b, c, d)
+        got, n_calls = run_lowered(fwd_bwd(LoweringContext, w), *args,
+                                   expect_mosaic=tpu)
+        want = fwd_bwd(NoKernel, w)(*args)
+        errs = {name: rel_l2(g_, r) for name, g_, r in zip(names, got, want)}
+        # float32 on both sides, the sums in another order
+        assert all(e < 1e-4 for e in errs.values()), errs
+        if tpu:
+            assert n_calls == 2, n_calls        # forward, backward
+        cases.append({"shape": [seq, di, n], "mosaic": n_calls,
+                      "rel_l2": {k_: float(f"{v:.2e}")
+                                 for k_, v in errs.items()}})
+    return {"cases": cases}
+
+
+def roll_differential_heads(tiny, tpu, key):
+    """``fused_multihead_attention`` over a 64-wide score head with a
+    128-wide value head, grouped 40 : 20 and causal, with a 512 window and
+    without: the splash kernel against the banded XLA spelling the same
+    lowering takes where the context allows no kernel, forward and
+    gradients."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    class NoKernel(LoweringContext):
+        def kernel_site(self, x):
+            return None
+
+    hq, hkv, seq = (4, 2, 1024) if tiny else (40, 20, 4096)
+    ks = jax.random.split(key, 4)
+    q = jax.random.normal(ks[0], (1, hq, seq, 64), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, hkv, seq, 64), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, hkv, seq, 128), jnp.bfloat16)
+    w = jax.random.normal(ks[3], (1, hq, seq, 128), jnp.float32)
+    cases = []
+    for window in (512, 0):
+        def fwd_bwd(ctx_type):
+            def f(q, k, v):
+                return get_op("fused_multihead_attention").fn(
+                    {"Q": [q], "K": [k], "V": [v]},
+                    {"causal": True, "window": window, "scale": 0.125},
+                    ctx_type(base_key=key))["Out"][0]
+
+            def g(q, k, v):
+                out, vjp = jax.vjp(f, q, k, v)
+                return (out,) + vjp(w.astype(out.dtype))
+            return jax.jit(g)
+        got, n_calls = run_lowered(fwd_bwd(LoweringContext), q, k, v,
+                                   expect_mosaic=tpu)
+        want = fwd_bwd(NoKernel)(q, k, v)
+        errs = [rel_l2(a, r) for a, r in zip(got, want)]
+        assert got[0].shape == (1, hq, seq, 128)
+        assert max(errs) < 2e-2, errs       # bf16 operands on both sides
+        if tpu:
+            assert n_calls == 3, n_calls    # forward, dq, dk/dv
+        cases.append({"window": window, "mosaic": n_calls,
+                      "rel_l2_out_dq_dk_dv": [round(e, 4) for e in errs]})
+    return {"shape": [hq, hkv, seq, 64, 128], "cases": cases}
+
+
 # which of pallas_kernels.__all__ each roll-call entry drives
 ROLL_CALL = [
     ("flash", roll_flash, ["flash_attention_tpu"]),
@@ -879,6 +978,9 @@ ROLL_CALL = [
      ["selected_attention_tpu", "selected_probability_mean_tpu",
       "index_kl_tpu"]),
     ("expert_permutation", roll_expert_permutation, ["held_rows_sum_tpu"]),
+    ("selective_scan", roll_selective_scan, ["selective_scan_tpu"]),
+    # jax's splash kernel under this repo's rule for a head of two widths
+    ("differential_heads", roll_differential_heads, []),
 ]
 
 

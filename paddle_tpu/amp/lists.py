@@ -38,6 +38,10 @@ WHITE_OPS = {
     # `mul` runs it, bf16 operands and float32 logits; the log-sum-exp and
     # the loss are float32 inside the lowering
     "linear_cross_entropy",
+    # the depthwise causal convolution before a selective scan (ops/
+    # selective_scan.py): bf16 in and out as the projection around it, its
+    # four products summed in float32 inside the lowering
+    "causal_conv1d",
 }
 # input slots of white ops that keep float32 all the same: small operands
 # whose precision decides the result
@@ -61,6 +65,11 @@ BLACK_OPS = {
     # coefficients and the 20 normalisations behind them stay float32 (a
     # branch's bf16 output is cast as it is merged in)
     "hyper_connection_mix", "hyper_connection_merge",
+    # a state-space layer's recurrence: 4096 steps of exp(dt A) compound in
+    # the state, and dt (a softplus, black already) sits in an exponent;
+    # state, decay and sums are float32 inside the lowering whatever
+    # arrives, and black keeps bf16 from being the operands' precision
+    "selective_scan",
 }
 # matmul/conv-family ops deliberately kept fp32: recurrent cells whose
 # hidden-state chains drift in bf16, int8-quantized kernels, gather-heavy

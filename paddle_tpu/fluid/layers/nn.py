@@ -19,8 +19,8 @@ _this = sys.modules[__name__]
 
 
 def _single_out(op_type, x, attrs=None, dtype=None, out_slot="Out",
-                in_slot="X", stop_gradient=False):
-    helper = LayerHelper(op_type)
+                in_slot="X", stop_gradient=False, name=None):
+    helper = LayerHelper(op_type, name=name)
     out = helper.create_variable_for_type_inference(
         dtype=dtype or getattr(x, "dtype", "float32"),
         stop_gradient=stop_gradient)
@@ -43,7 +43,7 @@ for _name in _UNARY:
     def _mk(op_type):
         def f(x, name=None, **attrs):
             attrs.pop("inplace", None)
-            return _single_out(op_type, x, attrs)
+            return _single_out(op_type, x, attrs, name=name)
         f.__name__ = op_type
         return f
     setattr(_this, _name, _mk(_name))
@@ -487,7 +487,8 @@ def rotary_embedding(input, inv_freq, scale=1.0, name=None, positions=None,
                           attrs)
 
 
-def linear_cross_entropy(input, label, size, param_attr=None, name=None):
+def linear_cross_entropy(input, label, size, param_attr=None, name=None,
+                         tied_to=None):
     """A decoder's head and its loss as one op: ``-log softmax(input W)
     [label]`` of every token, ``input`` [..., H], ``label`` [..., 1] ->
     [..., 1] float32, with ``W`` [H, ``size``] this layer's parameter.
@@ -495,13 +496,70 @@ def linear_cross_entropy(input, label, size, param_attr=None, name=None):
     tokens: the [tokens, size] logits, their softmax and their gradient never
     exist whole (at 16384 tokens and 18992 classes they are 2.3 GiB of the
     step's fullest moment), at the price of the head's matmul once more in
-    backward."""
+    backward.
+
+    ``tied_to`` (an embedding's [``size``, H] parameter; no ``param_attr``
+    then): the head has no matrix of its own and reads the embedding's rows,
+    ``logits = input E^T``; ``append_backward`` adds the head's gradient to
+    the lookup's."""
     helper = LayerHelper("linear_cross_entropy", name=name)
-    w = helper.create_parameter(param_attr, [int(input.shape[-1]), size],
-                                "float32")
+    if tied_to is not None:
+        if param_attr is not None or list(tied_to.shape) != [
+                size, int(input.shape[-1])]:
+            raise ValueError(
+                f"linear_cross_entropy: tied_to is the embedding's [size, H] "
+                f"parameter and takes param_attr's place; got "
+                f"{list(tied_to.shape)} for size {size}, H "
+                f"{int(input.shape[-1])}")
+    w = tied_to if tied_to is not None else helper.create_parameter(
+        param_attr, [int(input.shape[-1]), size], "float32")
     return _append_single(helper, "linear_cross_entropy",
                           {"X": [input], "W": [w], "Label": [label]},
-                          "float32", out_slot="Loss")
+                          "float32",
+                          {"transpose_w": True} if tied_to is not None
+                          else None, out_slot="Loss")
+
+
+def selective_scan(x, dt, A, B, C, D, gauges=None, name=None):
+    """The selective scan of a state-space layer (ops/selective_scan.py,
+    docs/state_space.md): ``x``, ``dt`` [B, S, Di] (the convolved input and
+    the step size after its softplus), ``A`` [Di, N] (negative), ``B``,
+    ``C`` [B, S, N], ``D`` [Di] -> ``y`` [B, S, Di] with ``s_t = exp(dt_t
+    (x) A) * s_{t-1} + (dt_t * x_t) (x) B_t`` from no state and ``y_t = s_t
+    C_t + D * x_t``; the state [Di, N] is float32 and exists a chunk at a
+    time.  With ``gauges`` (a name such as ``layer_0``) the largest |state|
+    at a chunk's end and the mean step size stay on the device in
+    ``<gauges>.state_abs_max`` and ``.dt_mean`` and are published as
+    ``ssm.<gauges>.…`` when an ``AsyncStepRunner`` drains."""
+    helper = LayerHelper("selective_scan", name=name)
+    y = helper.create_variable_for_type_inference(dtype=x.dtype)
+    outputs = {"Y": [y]}
+    if gauges:
+        outputs.update(_device_gauges(
+            helper, gauges, {"StateAbsMax": "state_abs_max",
+                             "DtMean": "dt_mean"}, family="ssm"))
+    helper.append_op("selective_scan",
+                     inputs={"X": [x], "Dt": [dt], "A": [A], "B": [B],
+                             "C": [C], "D": [D]}, outputs=outputs)
+    return y
+
+
+def causal_conv1d(input, kernel_size, param_attr=None, bias_attr=None,
+                  name=None):
+    """Depthwise causal convolution over time of ``input`` [B, S, C]: token
+    t sees itself and the ``kernel_size`` - 1 tokens before it (zeros before
+    the first), each channel under its own ``kernel_size`` weights (the
+    parameter is [``kernel_size``, C], the tap on the current token last)
+    and bias."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    channels = int(input.shape[-1])
+    inputs = {"X": [input],
+              "W": [helper.create_parameter(
+                  param_attr, [int(kernel_size), channels], "float32")]}
+    if bias_attr is not False:
+        inputs["Bias"] = [helper.create_parameter(
+            bias_attr, [channels], "float32", is_bias=True)]
+    return _append_single(helper, "causal_conv1d", inputs, input.dtype)
 
 
 def sparse_attention_index(qi, ki, w, topk, gauges=None, name=None):
@@ -555,10 +613,11 @@ def sparse_attention_index_loss(qi, ki, w, q, k, lse, selection, scale,
     return loss
 
 
-def _device_gauges(helper, prefix, slots):
+def _device_gauges(helper, prefix, slots, family="dsa"):
     """{slot: [a persistable float32 [1] variable ``<prefix>.<suffix>``]},
-    each entered among the program's device counters as ``dsa.<prefix>.
-    <suffix>`` (``AsyncStepRunner`` publishes them when it drains)."""
+    each entered among the program's device counters as ``<family>.
+    <prefix>.<suffix>`` (``AsyncStepRunner`` publishes them when it
+    drains)."""
     from ..framework import default_main_program
     counters = default_main_program()._hints.setdefault("device_counters",
                                                         {})
@@ -567,7 +626,7 @@ def _device_gauges(helper, prefix, slots):
         var = helper.block().create_var(
             name=f"{prefix}.{suffix}", shape=[1], dtype="float32",
             persistable=True, stop_gradient=True)
-        counters[var.name] = "dsa." + var.name
+        counters[var.name] = f"{family}.{var.name}"
         out[slot] = [var]
     return out
 
@@ -708,6 +767,13 @@ def expert_layer(input, num_experts, top_k, expert_size, first_expert=0,
                               shared_up_attr, shared_down_attr,
                               num_flatten_dims=1, name=name + ".shared")
     return out
+
+
+def swiglu(x, y, name=None):
+    """``silu(x) * y`` as one op: the gate of a gated FFN, of a state-space
+    mixer (``y * silu(z)``) and of a gated memory unit; in ``x``'s dtype."""
+    return _append_single(LayerHelper("swiglu", name=name), "swiglu",
+                          {"X": [x], "Y": [y]}, x.dtype)
 
 
 def gated_ffn(input, size, gate_attr=None, up_attr=None, down_attr=None,

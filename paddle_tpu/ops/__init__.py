@@ -27,6 +27,7 @@ from . import fused_extra_ops # noqa: F401  nn tail + fused compositions
 from . import catalog_tail_ops # noqa: F401  fc/py_func/rnn/detection tail
 from . import decoder_ops     # noqa: F401  rms_norm/rotary/sparse experts
 from . import sparse_attention  # noqa: F401  indexer / selection / its loss
+from . import selective_scan  # noqa: F401  state-space scan, causal conv
 
 # stamp per-op exclusion reasons onto non-differentiable registrations
 # (test_op_grads_auto.py enforces full coverage of the audit)

@@ -151,8 +151,11 @@ def _linear_cross_entropy(ins, attrs, ctx):
     """X [..., H], W [H, V], Label [..., 1] (or [...]) -> Loss [..., 1]
     float32: a decoder's head and its loss as one op, so that the [tokens,
     V] logits, their softmax and their gradient never exist whole
-    (``linear_cross_entropy``)."""
+    (``linear_cross_entropy``).  ``transpose_w``: W is an embedding's [V, H]
+    rows (a tied head), read as they lie."""
     x, w = ins["X"][0], ins["W"][0]
+    if attrs.get("transpose_w", False):
+        w = w.T
     labels = ins["Label"][0].reshape(x.shape[:-1]).astype(jnp.int32)
     loss = linear_cross_entropy(x.reshape(-1, x.shape[-1]), w,
                                 labels.reshape(-1))

@@ -51,13 +51,15 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.flash_attention import (
     flash_attention as _jax_flash_attention)
 
+from .selective_scan import CHUNK as _SSM_CHUNK
+
 __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "fused_dropout_add_tpu", "fused_act_dropout_tpu",
            "fused_embedding_pool_tpu", "embedding_pool_grad_tpu",
            "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
            "hyper_connection_merge_tpu", "selected_attention_tpu",
            "selected_probability_mean_tpu", "index_kl_tpu",
-           "held_rows_sum_tpu"]
+           "held_rows_sum_tpu", "selective_scan_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
 # VMEM default is 16 MiB: one block of every operand together stays under
@@ -118,15 +120,17 @@ def splash_attention_supported(q, k, v, mask) -> bool:
     (latent attention scores over 192 = 128 + 64 rotary numbers and carries
     values of 128).  The value width is whole 128-lane groups, because the
     output and its accumulator are tiled by it; the score width is whole
-    half groups of 64 from 128 up, because it is only ever contracted over
-    (Mosaic compiles 192 as it stands, no operand is padded with zeros by
-    the caller).  For one width this is the old rule, a multiple of 128."""
+    half groups of 64, because it is only ever contracted over (Mosaic
+    compiles 64 and 192 as they stand, no operand is padded with zeros by
+    the caller: differential attention scores over 64 and carries the pair
+    of 64-wide values side by side, 128).  For one width this is the old
+    rule, a multiple of 128."""
     seq = q.shape[2]
     score, value = q.shape[3], v.shape[3]
     return (mask is None and q.ndim == 4 and k.shape[:3] == v.shape[:3]
             and k.shape[2] == seq and k.shape[3] == score
             and value % _LANES == 0 and score % (_LANES // 2) == 0
-            and score >= _LANES and q.shape[1] % k.shape[1] == 0
+            and score > 0 and q.shape[1] % k.shape[1] == 0
             and seq % min(_SPLASH_BLOCK, seq) == 0 and seq % _LANES == 0)
 
 
@@ -2205,3 +2209,266 @@ def embedding_pool_grad_tpu(g, ids, wgt, vocab):
             out_specs=pl.BlockSpec((vocab, d), lambda i, *_: (0, 0))),
         out_shape=jax.ShapeDtypeStruct((vocab, d), g.dtype),
     )(ids_f, wgt_f, g)
+
+
+# ---------------------------------------------------------------------------
+# the selective scan of a state-space layer (ops/selective_scan.py):
+# s_t = exp(dt_t (x) A) * s_{t-1} + (dt_t * x_t) (x) B_t, y_t = s_t C_t + D x_t.
+# No matmul covers it (A differs per channel and state): S sequential steps
+# of elementwise work.  The state of a block of 512 channels, [N, 512]
+# float32 with the channels on the lanes (8 vector registers at N = 16),
+# stays in registers over a chunk of 256 tokens and in VMEM from one grid
+# step to the next; the grid is (batch, channel blocks, chunks), the chunks
+# last and in order.  x, dt are read and y written once, a token's row at a
+# time; B and C come as [S / 8, N, 8] tiles (eight tokens' vectors down the
+# sublanes), so a token's is a static lane of a tile, broadcast over the
+# channels.  The state after every chunk is written out ([S / 256, N, Di]
+# float32, 5 MB a layer): all that backward needs besides the operands.
+# Backward visits the chunks last to first: it computes a chunk's states
+# again from the state before it into VMEM ([257, N, 512] float32, 8.4 MB),
+# then sweeps the chunk in reverse with the state's gradient in registers,
+# giving the gradients of x and dt a row a token, of B and C a lane a token
+# (per channel block, added up outside), and of A and D summed on the core
+# over the sequence (per batch row, added up outside).
+# ---------------------------------------------------------------------------
+
+_SSM_GROUP = 8           # tokens unrolled in the loop body: a sublane tile
+
+
+def _ssm_channel_block(di):
+    return next((b for b in (512, 256, 128) if di % b == 0), 0)
+
+
+def selective_scan_supported(x, a) -> bool:
+    """Does the scan kernel cover X [B, S, Di], A [Di, N]: channels in whole
+    128-lane groups, states in whole sublane tiles and few enough that a
+    block's state stays in registers, at least one chunk of tokens (a ragged
+    last chunk is padded with steps of size 0)."""
+    return (x.ndim == 3 and a.ndim == 2 and a.shape[0] == x.shape[2]
+            and _ssm_channel_block(x.shape[2]) > 0
+            and a.shape[1] % 8 == 0 and a.shape[1] <= 32
+            and x.shape[1] >= _SSM_CHUNK)
+
+
+def _ssm_params(name):
+    return dict(
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name=name)
+
+
+def _ssm_step(s, xt, dtt, a, bcol):
+    return jnp.exp(dtt * a) * s + (dtt * xt) * bcol
+
+
+def _ssm_fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref,
+                    end_ref, s_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    a, skip = a_ref[...], d_ref[...]
+
+    def group(g, s):
+        r0 = pl.multiple_of(g * _SSM_GROUP, _SSM_GROUP)
+        xg = x_ref[pl.ds(r0, _SSM_GROUP), :]
+        dtg = dt_ref[pl.ds(r0, _SSM_GROUP), :]
+        bg, cg = b_ref[g], c_ref[g]
+        for j in range(_SSM_GROUP):
+            xt, dtt = xg[j:j + 1], dtg[j:j + 1]
+            s = _ssm_step(s, xt, dtt, a, bg[:, j:j + 1])
+            y_ref[pl.ds(r0 + j, 1), :] = jnp.sum(
+                s * cg[:, j:j + 1], axis=0, keepdims=True) + skip * xt
+        return s
+
+    s = jax.lax.fori_loop(0, x_ref.shape[0] // _SSM_GROUP, group, s_ref[...])
+    s_ref[...] = s
+    end_ref[...] = s
+
+
+def _ssm_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, dy_ref,
+                    start_ref, dx_ref, ddt_ref, da_ref, db_ref, dc_ref,
+                    dd_ref, hist_ref, g_ref):
+    k = pl.program_id(2)              # 0 is the LAST chunk of the sequence
+    groups = x_ref.shape[0] // _SSM_GROUP
+
+    @pl.when(k == 0)
+    def _():
+        g_ref[...] = jnp.zeros_like(g_ref)
+        da_ref[...] = jnp.zeros_like(da_ref)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    a, skip = a_ref[...], d_ref[...]
+    # the first chunk starts from no state (its block of ``starts`` is a
+    # stand-in)
+    s0 = jnp.where(k == pl.num_programs(2) - 1, 0.0, start_ref[...])
+    hist_ref[0] = s0
+
+    def again(g, s):
+        r0 = pl.multiple_of(g * _SSM_GROUP, _SSM_GROUP)
+        xg = x_ref[pl.ds(r0, _SSM_GROUP), :]
+        dtg = dt_ref[pl.ds(r0, _SSM_GROUP), :]
+        bg = b_ref[g]
+        for j in range(_SSM_GROUP):
+            s = _ssm_step(s, xg[j:j + 1], dtg[j:j + 1], a, bg[:, j:j + 1])
+            hist_ref[r0 + j + 1] = s
+        return s
+
+    jax.lax.fori_loop(0, groups, again, s0)
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape[1:], 1)
+
+    def back(i, carry):
+        gs, da = carry                # d loss / d s_t from later tokens; dA
+        g = groups - 1 - i
+        r0 = pl.multiple_of(g * _SSM_GROUP, _SSM_GROUP)
+        xg = x_ref[pl.ds(r0, _SSM_GROUP), :]
+        dtg = dt_ref[pl.ds(r0, _SSM_GROUP), :]
+        dyg = dy_ref[pl.ds(r0, _SSM_GROUP), :]
+        bg, cg = b_ref[g], c_ref[g]
+        dbg = jnp.zeros(lane.shape, jnp.float32)
+        dcg = jnp.zeros(lane.shape, jnp.float32)
+        for j in reversed(range(_SSM_GROUP)):
+            xt, dtt, dyt = xg[j:j + 1], dtg[j:j + 1], dyg[j:j + 1]
+            decay = jnp.exp(dtt * a)
+            gs = gs + dyt * cg[:, j:j + 1]
+            into = jnp.sum(gs * bg[:, j:j + 1], axis=0, keepdims=True)
+            w = gs * hist_ref[r0 + j] * decay          # d loss / d (dt A)
+            dx_ref[pl.ds(r0 + j, 1), :] = dyt * skip + dtt * into
+            ddt_ref[pl.ds(r0 + j, 1), :] = jnp.sum(
+                w * a, axis=0, keepdims=True) + xt * into
+            da = da + w * dtt
+            dbg = jnp.where(lane == j, jnp.sum(gs * (dtt * xt), axis=1,
+                                               keepdims=True), dbg)
+            dcg = jnp.where(lane == j, jnp.sum(hist_ref[r0 + j + 1] * dyt,
+                                               axis=1, keepdims=True), dcg)
+            gs = decay * gs
+        db_ref[g] = dbg
+        dc_ref[g] = dcg
+        return gs, da
+
+    gs, da = jax.lax.fori_loop(0, groups, back, (g_ref[...], da_ref[...]))
+    g_ref[...] = gs
+    da_ref[...] = da
+    dd_ref[...] += jnp.sum(dy_ref[...] * x_ref[...], axis=0, keepdims=True)
+
+
+def _ssm_operands(x, dt, a, b, c, d):
+    """The operands as the kernels read them, float32, the sequence padded
+    to whole chunks with steps of size 0: x, dt [B, S, Di]; A^T [N, Di]; B,
+    C as [B, S / 8, N, 8]; D [1, Di]."""
+    pad = -x.shape[1] % _SSM_CHUNK
+    f32 = jnp.float32
+
+    def rows(v):
+        return jnp.pad(v.astype(f32), ((0, 0), (0, pad), (0, 0)))
+
+    def tiles(v):
+        v = rows(v)
+        return v.reshape(v.shape[0], -1, _SSM_GROUP, v.shape[2]) \
+            .transpose(0, 1, 3, 2)
+    return (rows(x), rows(dt), a.astype(f32).T, tiles(b), tiles(c),
+            d.astype(f32).reshape(1, -1))
+
+
+def _ssm_specs(di, n, chunks, reverse):
+    """BlockSpecs of (a [S, Di] operand, A^T or a [N, Di] sum, a [S / 8, N,
+    8] operand, D) on the grid (batch, channel block, chunk); ``reverse``
+    visits the chunks last to first."""
+    blk = _ssm_channel_block(di)
+
+    def at(k):
+        return chunks - 1 - k if reverse else k
+    return (pl.BlockSpec((None, _SSM_CHUNK, blk),
+                         lambda i, j, k: (i, at(k), j)),
+            pl.BlockSpec((n, blk), lambda i, j, k: (0, j)),
+            pl.BlockSpec((None, _SSM_CHUNK // _SSM_GROUP, n, _SSM_GROUP),
+                         lambda i, j, k: (i, at(k), 0, 0)),
+            pl.BlockSpec((1, blk), lambda i, j, k: (0, j)))
+
+
+def _ssm_forward(ops):
+    x, dt, at, bt, ct, d = ops
+    bsz, seq, di = x.shape
+    n, chunks, blk = at.shape[0], seq // _SSM_CHUNK, _ssm_channel_block(di)
+    row, state, tile, skip = _ssm_specs(di, n, chunks, False)
+    return pl.pallas_call(
+        _ssm_fwd_kernel,
+        grid=(bsz, di // blk, chunks),
+        in_specs=[row, row, state, tile, tile, skip],
+        out_specs=[row, pl.BlockSpec((None, None, n, blk),
+                                     lambda i, j, k: (i, k, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((bsz, seq, di), jnp.float32),
+                   jax.ShapeDtypeStruct((bsz, chunks, n, di), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, blk), jnp.float32)],
+        **_ssm_params("selective_scan_fwd"))(x, dt, at, bt, ct, d)
+
+
+@jax.custom_vjp
+def selective_scan_tpu(x, dt, a, b, c, d):
+    """(y [B, S, Di] float32, the state after each chunk [B, S / 256, N, Di]
+    float32) of ``ops.selective_scan``'s recurrence; no gradient passes
+    through the states."""
+    return _selective_scan_fwd(x, dt, a, b, c, d)[0]
+
+
+def _selective_scan_fwd(x, dt, a, b, c, d):
+    ops = _ssm_operands(x, dt, a, b, c, d)
+    y, ends = _ssm_forward(ops)
+    return (y[:, :x.shape[1]], ends), (x, dt, a, b, c, d, ends)
+
+
+def _selective_scan_bwd(res, cotangents):
+    x, dt, a, b, c, d, ends = res
+    dy = cotangents[0]
+    xs, dts, at, bt, ct, skip = _ssm_operands(x, dt, a, b, c, d)
+    dy = jnp.pad(dy.astype(jnp.float32),
+                 ((0, 0), (0, xs.shape[1] - dy.shape[1]), (0, 0)))
+    bsz, seq, di = xs.shape
+    n, chunks, blk = at.shape[0], seq // _SSM_CHUNK, _ssm_channel_block(di)
+    row, state, tile, skip_spec = _ssm_specs(di, n, chunks, True)
+    f32 = jnp.float32
+    dx, ddt, da, db, dc, dd = pl.pallas_call(
+        _ssm_bwd_kernel,
+        grid=(bsz, di // blk, chunks),
+        in_specs=[row, row, state, tile, tile, skip_spec, row,
+                  # the state BEFORE chunk c is the one after chunk c - 1
+                  pl.BlockSpec((None, None, n, blk),
+                               lambda i, j, k: (
+                                   i, jnp.maximum(chunks - 2 - k, 0), 0, j))],
+        out_specs=[row, row,
+                   pl.BlockSpec((None, n, blk), lambda i, j, k: (i, 0, j)),
+                   pl.BlockSpec(
+                       (None, None, _SSM_CHUNK // _SSM_GROUP, n, _SSM_GROUP),
+                       lambda i, j, k: (i, j, chunks - 1 - k, 0, 0)),
+                   pl.BlockSpec(
+                       (None, None, _SSM_CHUNK // _SSM_GROUP, n, _SSM_GROUP),
+                       lambda i, j, k: (i, j, chunks - 1 - k, 0, 0)),
+                   pl.BlockSpec((None, 1, blk), lambda i, j, k: (i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((bsz, seq, di), f32),
+                   jax.ShapeDtypeStruct((bsz, seq, di), f32),
+                   jax.ShapeDtypeStruct((bsz, n, di), f32),
+                   jax.ShapeDtypeStruct(
+                       (bsz, di // blk, seq // _SSM_GROUP, n, _SSM_GROUP),
+                       f32),
+                   jax.ShapeDtypeStruct(
+                       (bsz, di // blk, seq // _SSM_GROUP, n, _SSM_GROUP),
+                       f32),
+                   jax.ShapeDtypeStruct((bsz, 1, di), f32)],
+        scratch_shapes=[pltpu.VMEM((_SSM_CHUNK + 1, n, blk), f32),
+                        pltpu.VMEM((n, blk), f32)],
+        **_ssm_params("selective_scan_bwd"))(xs, dts, at, bt, ct, skip, dy,
+                                             ends)
+    true = x.shape[1]
+
+    def vectors(g):          # [B, blocks, S / 8, N, 8] -> [B, S, N]
+        g = jnp.sum(g, axis=1).transpose(0, 1, 3, 2)
+        return g.reshape(bsz, seq, n)[:, :true]
+    return (dx[:, :true].astype(x.dtype), ddt[:, :true].astype(dt.dtype),
+            jnp.sum(da, axis=0).T.astype(a.dtype),
+            vectors(db).astype(b.dtype), vectors(dc).astype(c.dtype),
+            jnp.sum(dd, axis=(0, 1)).astype(d.dtype))
+
+
+selective_scan_tpu.defvjp(_selective_scan_fwd, _selective_scan_bwd)

@@ -253,7 +253,8 @@ def test_splash_kernel_numerics_in_interpret_mode():
     (512, 192, 128, True, "xla"),       # ungrouped, under the streaming floor
     (4096, 192, 64, True, "xla"),       # values not whole lane groups
     (4096, 160, 128, True, "xla"),      # scores not whole half groups
-    (4096, 64, 128, True, "xla"),       # scores under one group
+    (4096, 64, 128, True, "splash_kernel"),     # differential: 64, 2 x 64
+    (4096, 32, 128, True, "xla"),       # scores under half a group
     (4096, 192, 128, False, "xla"),     # no kernel of two widths but splash
     (512, 192, 128, False, "xla"),
 ])
@@ -307,6 +308,53 @@ def test_splash_kernel_two_widths_in_interpret_mode():
     for a, r in zip(grads, ref_vjp(got)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    rtol=5e-3, atol=5e-3)
+
+
+@pytest.mark.parametrize("window", [128, 0])
+def test_splash_kernel_takes_a_differential_head_in_interpret_mode(window):
+    """Scores over 64, values of 128 (a differential pair's two value heads
+    side by side), 4 : 2 grouped heads, with a window and without: the
+    splash kernel in the interpreter against the dense spelling, forward
+    and gradients."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_kernels as pk
+    q = _rand(1, 4, 256, 64, scale=0.5)
+    k, v = _rand(1, 2, 256, 64, seed=1, scale=0.5), \
+        _rand(1, 2, 256, 128, seed=2)
+    assert pk.splash_attention_supported(q, k, v, None)
+    f = lambda q, k, v: pk.splash_attention_tpu(q, k, v, 0.125, window)
+    with pltpu.force_tpu_interpret_mode():
+        got, vjp = jax.vjp(f, q, k, v)
+        grads = vjp(got)
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: _attention_ref(q, k, v, 0.125, window), q, k, v)
+    assert got.shape == (1, 4, 256, 128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+    for a, r in zip(grads, ref_vjp(got)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=5e-3, atol=5e-3)
+
+
+@pytest.mark.parametrize("window", [8, 0])
+def test_attention_op_differential_head_on_the_xla_path(window):
+    """The op over a 16-wide score head and a 32-wide value head, grouped
+    and causal: the banded spelling the CPU takes, forward and gradients."""
+    q = _rand(2, 4, 24, 16, scale=0.5)
+    k, v = _rand(2, 2, 24, 16, seed=1, scale=0.5), _rand(2, 2, 24, 32, seed=2)
+    attrs = {"causal": True, "scale": 0.25, "window": window}
+    ins = {"Q": q, "K": k, "V": v}
+    out = _op("fused_multihead_attention", ins, attrs)
+    assert out.shape == (2, 4, 24, 32)
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: _attention_ref(q, k, v, 0.25, window), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    cot = _rand(*out.shape, seed=7)
+    for got, ref in zip(_op_grads("fused_multihead_attention", ins, attrs,
+                                  cot, ["Q", "K", "V"]), ref_vjp(cot)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_latent_attention_equals_an_uncompressed_multi_head_spelling():

@@ -688,6 +688,24 @@ def build_specs():
                                       np.int64)},
             grad_slots=["X", "W"], out_slot="Loss"),
     })
+    # a state-space layer's scan and the causal convolution before it (ops/
+    # selective_scan.py), from a stream of their own
+    r36 = np.random.RandomState(36)
+
+    def _sym36(*shape):
+        return r36.uniform(-1.0, 1.0, shape).astype("float32")
+    S.update({
+        "selective_scan": dict(
+            inputs={"X": _sym36(2, 6, 4),
+                    "Dt": 0.3 + 0.2 * np.abs(_sym36(2, 6, 4)),
+                    "A": -0.5 - np.abs(_sym36(4, 3)), "B": _sym36(2, 6, 3),
+                    "C": _sym36(2, 6, 3), "D": _sym36(4)},
+            grad_slots=["X", "Dt", "A", "B", "C", "D"], out_slot="Y"),
+        "causal_conv1d": dict(
+            inputs={"X": _sym36(2, 6, 4), "W": _sym36(3, 4),
+                    "Bias": _sym36(4)},
+            grad_slots=["X", "W", "Bias"]),
+    })
     return S
 
 

@@ -159,6 +159,42 @@ class TestRepoKernels:
             lambda *a: pk.index_kl_tpu(*a, 128 ** -0.5, 2048, True), *args)
         assert mosaic_call_count(compiled) == 6
 
+    @pytest.mark.parametrize("seq", [4096, 4096 + 40])
+    def test_selective_scan_fwd_bwd(self, seq):
+        """The scan's two kernels at ``phi4_flash_train_seq4096``'s shape
+        (5120 channels of 16 states), and with a ragged last chunk."""
+        f32 = jnp.float32
+        sds = jax.ShapeDtypeStruct
+        args = (sds((1, seq, 5120), f32), sds((1, seq, 5120), f32),
+                sds((5120, 16), f32), sds((1, seq, 16), f32),
+                sds((1, seq, 16), f32), sds((5120,), f32))
+        assert pk.selective_scan_supported(args[0], args[2])
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        compiled = compile_for_tpu(
+            lambda *a: jax.value_and_grad(
+                lambda *b: jnp.sum(pk.selective_scan_tpu(*b)[0]),
+                argnums=tuple(range(6)))(*a), *args)
+        assert mosaic_call_count(compiled) == 2
+
+    @pytest.mark.parametrize("window", [512, 0])
+    def test_splash_takes_a_64_wide_score_head(self, window):
+        """Differential attention's head: scores over 64, values of 128,
+        40 : 20 heads, 4096 tokens; forward, dq and dk/dv."""
+        bf16 = jnp.bfloat16
+        sds = jax.ShapeDtypeStruct
+        args = (sds((1, 40, 4096, 64), bf16), sds((1, 20, 4096, 64), bf16),
+                sds((1, 20, 4096, 128), bf16))
+        assert pk.splash_attention_supported(*args, None)
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        compiled = compile_for_tpu(
+            lambda *a: jax.value_and_grad(
+                lambda *b: jnp.sum(pk.splash_attention_tpu(
+                    *b, scale=0.125, window=window).astype(jnp.float32)),
+                argnums=(0, 1, 2))(*a), *args)
+        assert mosaic_call_count(compiled) == 3
+
     @pytest.mark.parametrize("op, tokens, z_dtype", [
         ("mix", 4096, None), ("merge", 4096, "float32"),
         ("mix", 4096 + 40, None), ("merge", 4096 + 40, "bfloat16")])
