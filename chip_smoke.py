@@ -963,6 +963,52 @@ def roll_differential_heads(tiny, tpu, key):
     return {"shape": [hq, hkv, seq, 64, 128], "cases": cases}
 
 
+def roll_embedding_grad(tiny, tpu, key):
+    """``lookup_table_v2``'s gradient through its lowering (the kernel on a
+    chip) against the same lowering where the context allows no kernel
+    (XLA's scatter), at the hybrid decoder's timed shape and BERT's two
+    tables.  Within 1e-6 of the largest element, or, where thousands of
+    duplicates add in another float32 order (BERT's two token types: 8192 a
+    row), within 1e-6 of the float64 sum or no further from it than XLA."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    class NoKernel(LoweringContext):
+        def pallas_ok(self):
+            return False
+
+    def grad(ctx_type):
+        def run(w, ids, g):
+            fn = get_op("lookup_table_v2").fn
+            return jax.vjp(lambda w: fn({"W": [w], "Ids": [ids]}, {},
+                                        ctx_type())["Out"][0], w)[1](g)
+        return jax.jit(run)
+
+    shapes = [(256, 300, 128), (256, 2, 128)] if tiny else \
+        [(4096, 25008, 2560), (16384, 30522, 768), (16384, 2, 768)]
+    cases = []
+    for i, (n, vocab, width) in enumerate(shapes):
+        ks = jax.random.split(jax.random.fold_in(key, i), 3)
+        w = jax.random.normal(ks[0], (vocab, width), jnp.float32)
+        ids = jax.random.randint(ks[1], (1, n), 0, vocab, jnp.int32)
+        g = jax.random.normal(ks[2], (1, n, width), jnp.float32)
+        (got,), n_calls = run_lowered(grad(LoweringContext), w, ids, g,
+                                      expect_mosaic=tpu)
+        want, = grad(NoKernel)(w, ids, g)
+        exact = np.zeros((vocab, width))
+        np.add.at(exact, np.asarray(ids).reshape(-1),
+                  np.asarray(g, np.float64).reshape(-1, width))
+        case = {"tokens": n, "table": [vocab, width], "mosaic": n_calls,
+                "rel_err": float(f"{rel_err(got, want):.2e}"),
+                "from_float64": [float(f"{rel_err(a, exact):.2e}")
+                                 for a in (got, want)]}
+        assert case["rel_err"] < 1e-6 or case["from_float64"][0] \
+            <= max(case["from_float64"][1], 1e-6), case
+        cases.append(case)
+    return {"cases": cases}
+
+
 # which of pallas_kernels.__all__ each roll-call entry drives
 ROLL_CALL = [
     ("flash", roll_flash, ["flash_attention_tpu"]),
@@ -979,6 +1025,7 @@ ROLL_CALL = [
       "index_kl_tpu"]),
     ("expert_permutation", roll_expert_permutation, ["held_rows_sum_tpu"]),
     ("selective_scan", roll_selective_scan, ["selective_scan_tpu"]),
+    ("embedding_grad", roll_embedding_grad, ["embedding_grad_tpu"]),
     # jax's splash kernel under this repo's rule for a head of two widths
     ("differential_heads", roll_differential_heads, []),
 ]

@@ -7,6 +7,8 @@ the nondiff slot so the generic vjp grad never differentiates them.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -485,28 +487,68 @@ def _shard_index(ins, attrs, ctx):
     return {"Out": [jnp.where(mask, x % size, ignore)]}
 
 
+def embedding_grad_lowering(ctx, w) -> str:
+    """Where the gradient of a lookup into the dense table ``w`` is added up:
+    ``kernel`` (``pallas_kernels.embedding_grad_tpu``: each block of table
+    rows written once, the tokens' rows added on the matmul unit) where a
+    kernel runs directly (``ctx.pallas_ok``: a one-chip program on the TPU)
+    and the kernel takes ``w``; ``xla`` (``jax.vjp`` of ``jnp.take``: XLA
+    sorts the ids and scatter-adds a row at a time) elsewhere: off the chip
+    and in a partitioned program.  Alone, the kernel was the faster at every
+    table measured, two rows to 30522 (``docs/performance.md`` "The
+    embedding's gradient")."""
+    from . import pallas_kernels as pk
+    return ("kernel" if ctx.pallas_ok() and pk.embedding_grad_supported(w)
+            else "xla")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _take(w, ids, vocab, lowering):
+    return jnp.take(w, ids, axis=0)
+
+
+def _take_fwd(w, ids, vocab, lowering):
+    return jnp.take(w, ids, axis=0), ids
+
+
+def _take_bwd(vocab, lowering, ids, g):
+    from ..fluid import trace
+    from . import pallas_kernels as pk
+    trace.metrics().counter(f"embedding.grad_lowering.{lowering}").inc()
+    if lowering == "kernel":
+        return pk.embedding_grad_tpu(ids, g.reshape(-1, g.shape[-1]),
+                                     vocab), None
+    w = jnp.zeros((vocab,) + g.shape[ids.ndim:], g.dtype)
+    return jax.vjp(lambda w: jnp.take(w, ids, axis=0), w)[1](g)[0], None
+
+
+_take.defvjp(_take_fwd, _take_bwd)
+
+
+def _embedding(w, ids, attrs, ctx):
+    """``ids``' rows of ``w``, zero where the id is ``padding_idx``; the
+    gradient is added where ``embedding_grad_lowering`` says, counted under
+    ``embedding.grad_lowering.<kernel|xla>`` each time one is formed."""
+    out = _take(w, ids, w.shape[0], embedding_grad_lowering(ctx, w))
+    padding_idx = attrs.get("padding_idx", -1)
+    if padding_idx is not None and padding_idx >= 0:
+        out = jnp.where((ids == padding_idx)[..., None], 0.0, out)
+    return out
+
+
 @register_op("lookup_table_v2", nondiff_inputs=("Ids",))
 def _lookup_table_v2(ins, attrs, ctx):
     """Embedding (operators/lookup_table_v2_op).  SelectedRows sparse grad
-    becomes a dense vjp-scatter; XLA turns one-hot matmul / take into an
-    efficient dynamic-gather on TPU."""
+    becomes a dense gradient (``_embedding``)."""
     w, ids = _x(ins, "W"), _x(ins, "Ids").astype(jnp.int32)
-    padding_idx = attrs.get("padding_idx", -1)
-    out = jnp.take(w, ids, axis=0)
-    if padding_idx is not None and padding_idx >= 0:
-        out = jnp.where((ids == padding_idx)[..., None], 0.0, out)
-    return {"Out": [out]}
+    return {"Out": [_embedding(w, ids, attrs, ctx)]}
 
 
 @register_op("lookup_table", nondiff_inputs=("Ids",))
 def _lookup_table(ins, attrs, ctx):
     w, ids = _x(ins, "W"), _x(ins, "Ids").astype(jnp.int32)
     ids = ids.reshape(ids.shape[:-1]) if ids.shape[-1] == 1 else ids
-    out = jnp.take(w, ids, axis=0)
-    padding_idx = attrs.get("padding_idx", -1)
-    if padding_idx is not None and padding_idx >= 0:
-        out = jnp.where((ids == padding_idx)[..., None], 0.0, out)
-    return {"Out": [out]}
+    return {"Out": [_embedding(w, ids, attrs, ctx)]}
 
 
 @register_op("space_to_depth")

@@ -30,11 +30,16 @@
   experts' rows, which XLA gathered as [T, top_k, D], a row at a time,
   padding included: the rows regrouped by tile of tokens are read once and
   added on the matmul unit (parallel/moe.py, docs/moe.md).
+* the embedding's gradient — XLA scatter-adds the incoming rows into the
+  dense table's gradient a row at a time; here each block of table rows is
+  written once, its tokens' rows (contiguous once the ids are sorted) added
+  on the matmul unit (ops/manipulation.py, docs/performance.md).
 * paged decode attention, fused embedding gather+pool, bucketed optimizer
   updates — see each section.
 
 The callers (ops/attention.py, ops/nn_ops.py, ops/ctr_ops.py,
-ops/optimizer_ops.py, ops/decoder_ops.py, parallel/moe.py) take these on the ``tpu`` backend
+ops/optimizer_ops.py, ops/decoder_ops.py, ops/manipulation.py,
+parallel/moe.py) take these on the ``tpu`` backend
 only; every kernel here is compiled by Mosaic and checked against its XLA
 reference by ``chip_smoke.py``'s kernel roll-call.
 """
@@ -59,7 +64,7 @@ __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
            "hyper_connection_merge_tpu", "selected_attention_tpu",
            "selected_probability_mean_tpu", "index_kl_tpu",
-           "held_rows_sum_tpu", "selective_scan_tpu"]
+           "held_rows_sum_tpu", "selective_scan_tpu", "embedding_grad_tpu"]
 
 # A pallas_call double-buffers every block it pipelines, and v5e's scoped
 # VMEM default is 16 MiB: one block of every operand together stays under
@@ -2016,6 +2021,29 @@ def held_rows_sum_supported(rows, num_tokens, token_tile) -> bool:
             and rows.shape[1] % 128 == 0)
 
 
+def _work_list(sizes, block, num_blocks):
+    """The steps of a grid over tiles whose rows lie contiguous, tile after
+    tile, in a buffer of ``num_blocks`` blocks of ``block`` rows, ``sizes[g]``
+    rows for tile ``g``: a tile's steps are the blocks its rows lie in (its
+    one step, where it has no row, writes its zeros); step ``s`` is
+    the tile that begins last at or before ``s``, and that tile's first block
+    plus the rest.  ``(offsets, tiles, blocks, steps)``: ``offsets[g]`` tile
+    ``g``'s first row (``offsets[-1]`` the rows in all), ``tiles[s]`` /
+    ``blocks[s]`` step ``s``'s tile and block, ``steps`` how many steps there
+    are; the arrays hold the most there can be, ``num_blocks + len(sizes)``."""
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    lo = (ends - sizes) // block
+    count = jnp.where(sizes > 0, (ends - 1) // block - lo, 0) + 1
+    first = jnp.cumsum(count) - count
+    step = jnp.arange(num_blocks + sizes.shape[0], dtype=jnp.int32)
+    tiles = jnp.sum(step[:, None] >= first[None, :], axis=1,
+                    dtype=jnp.int32) - 1
+    blocks = jnp.minimum(lo[tiles] + step - first[tiles], num_blocks - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, tiles, blocks, jnp.sum(count)
+
+
 def _held_rows_sum_kernel(offsets_ref, tiles_ref, blocks_ref, at_ref, w_ref,
                           rows_ref, o_ref, acc_ref, *, unit_weights):
     i = pl.program_id(0)
@@ -2060,25 +2088,11 @@ def held_rows_sum_tpu(rows, at, weights, sizes, token_tile):
     accumulated in float32."""
     total, width = rows.shape
     num_tokens, top_k = at.shape
-    groups = num_tokens // token_tile
     unit = weights is None
     if unit:
         weights = jnp.ones((num_tokens, 1), jnp.float32)
-    # the work list: a tile's steps are the blocks its rows lie in (its one
-    # step, where it has no row, writes its zeros); step s is the tile that
-    # begins last at or before s, and that tile's first block plus the rest
-    sizes = sizes.astype(jnp.int32)
-    ends = jnp.cumsum(sizes)
-    lo = (ends - sizes) // _SEGSUM_ROWS
-    count = jnp.where(sizes > 0, (ends - 1) // _SEGSUM_ROWS - lo, 0) + 1
-    first = jnp.cumsum(count) - count
-    step = jnp.arange(total // _SEGSUM_ROWS + groups, dtype=jnp.int32)
-    tiles = jnp.sum(step[:, None] >= first[None, :], axis=1,
-                    dtype=jnp.int32) - 1
-    blocks = jnp.minimum(lo[tiles] + step - first[tiles],
-                         total // _SEGSUM_ROWS - 1)
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
-    steps = jnp.sum(count)
+    offsets, tiles, blocks, steps = _work_list(sizes, _SEGSUM_ROWS,
+                                               total // _SEGSUM_ROWS)
     return pl.pallas_call(
         functools.partial(_held_rows_sum_kernel, unit_weights=unit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -2097,6 +2111,105 @@ def held_rows_sum_tpu(rows, at, weights, sizes, token_tile):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(offsets, tiles, blocks, at, weights.astype(jnp.float32), rows)
+
+
+# ---------------------------------------------------------------------------
+# the gradient of an embedding lookup: the rows of a table's dense [V, H]
+# float32 gradient, each the sum of the incoming rows whose id it is.  XLA
+# sorts the ids and scatter-adds the rows into the table a row at a time
+# (10.0 ms for 4096 rows into [25008, 2560] on a v5e, where writing the
+# table once takes 0.31).  Here the ids are sorted the same way, a block of
+# table rows is a tile, its rows' tokens lie contiguous in sorted order, and
+# the ``_work_list`` grid visits each tile's blocks of tokens: the matmul
+# unit adds them through a one-hot of (id == row), each float32 row split
+# on the core into three bfloat16 terms whose sum it is exactly, and each
+# block of the table is written once (0.67 ms at that shape).
+# ---------------------------------------------------------------------------
+
+_EMB_GRAD_TOKENS = 128       # sorted ids a grid step reads: its picks' lanes
+_EMB_GRAD_ROWS = 128          # table rows a grid step writes
+
+
+def embedding_grad_supported(table) -> bool:
+    """A float32 [V, H] table of whole lanes."""
+    return (table.ndim == 2 and table.dtype == jnp.float32
+            and table.shape[0] > 0 and table.shape[1] % 128 == 0)
+
+
+def _embedding_grad_kernel(offsets_ref, tiles_ref, blocks_ref, ids_ref,
+                           rows_ref, o_ref):
+    i = pl.program_id(0)
+    tile = tiles_ref[i]
+
+    @pl.when((i == 0) | (tiles_ref[jnp.maximum(i - 1, 0)] != tile))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(offsets_ref[tile + 1] > offsets_ref[tile])
+    def _():
+        table_rows, tokens = o_ref.shape[0], ids_ref.shape[1]
+        row = tile * table_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (table_rows, tokens), 0)
+        picks = jnp.where(ids_ref[...] == row, 1.0, 0.0).astype(jnp.bfloat16)
+        rows = rows_ref[...]            # as three bfloat16 terms, exactly
+        hi = rows.astype(jnp.bfloat16)
+        rest = rows - hi.astype(jnp.float32)
+        mid = rest.astype(jnp.bfloat16)
+        lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        o_ref[...] += jnp.dot(jnp.concatenate([picks] * 3, axis=1),
+                              jnp.concatenate([hi, mid, lo], axis=0),
+                              preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def embedding_grad_tpu(ids, grads, vocab):
+    """``dW[v] = sum of grads[n] over the n with ids[n] == v`` for ``dW``
+    [vocab, H] float32: the gradient of ``jnp.take(W, ids, 0)`` as
+    ``jax.vjp`` gives it, ids read as ``jnp.take`` reads them (a negative
+    id counts from the end once; what lies outside ``[0, vocab)`` then adds
+    nothing).  ``ids`` [N] int; ``grads`` [N, H] float32.  A row without
+    duplicates is its own bits; duplicates add in
+    float32 in an order of their own (XLA's order is unspecified too).  A
+    non-finite row makes its whole tile non-finite (0 x inf), where XLA's
+    scatter confines it to its row; either way the step sees it."""
+    n, width = grads.shape
+    tokens = _EMB_GRAD_TOKENS
+    table_rows = min(_EMB_GRAD_ROWS, vocab)
+    tiles_n = pl.cdiv(vocab, table_rows)
+    padded = pl.cdiv(n, tokens) * tokens
+    # jnp.take's reading of an id; every id it drops, and the padding, reads
+    # as a row past the last tile's and sorts after the table's
+    ids = ids.reshape(-1).astype(jnp.int32)
+    ids = jnp.where(ids < 0, ids + vocab, ids)
+    past = tiles_n * table_rows
+    ids = jnp.where((ids >= 0) & (ids < vocab), ids, past)
+    ids = jnp.pad(ids, (0, padded - n), constant_values=past)
+    ids, order = jax.lax.sort(
+        (ids, jnp.arange(padded, dtype=jnp.int32)), num_keys=1)
+    # the rows in sorted order; the kernel splits them into their bfloat16
+    # terms (XLA would fold away the round trips that takes)
+    rows = jnp.take(grads.astype(jnp.float32), order, axis=0, mode="fill",
+                    fill_value=0)
+    num_blocks = padded // tokens
+    bounds = jnp.minimum(jnp.arange(tiles_n + 1, dtype=jnp.int32)
+                         * table_rows, vocab)
+    starts = jnp.searchsorted(ids, bounds, method="compare_all")
+    offsets, tiles, blocks, steps = _work_list(jnp.diff(starts), tokens,
+                                               num_blocks)
+    return pl.pallas_call(
+        _embedding_grad_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((1, tokens), lambda i, o, t, b: (0, b[i])),
+                pl.BlockSpec((tokens, width), lambda i, o, t, b: (b[i], 0))],
+            out_specs=pl.BlockSpec((table_rows, width),
+                                   lambda i, o, t, b: (t[i], 0))),
+        out_shape=jax.ShapeDtypeStruct((vocab, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 << 20),
+    )(offsets, tiles, blocks, ids.reshape(1, padded), rows)
 
 
 # ---------------------------------------------------------------------------

@@ -310,9 +310,10 @@ def test_tiny_bert_step_holds_one_forward_attention_per_layer(monkeypatch):
     assert [g.attrs["fwd_type"] for g in custom] \
         == ["softmax_with_cross_entropy"]
     assert _moved(before) == {"kept": len(grads) - 1, "retraced": 1}
-    # per layer one forward kernel (out, lse) and one backward (dq, dk, dv)
+    # per layer one forward kernel (out, lse) and one backward (dq, dk, dv);
+    # one more for the word embedding's gradient (embedding_grad_tpu)
     calls = _pallas_calls(jaxpr.jaxpr, [])
-    assert sorted(len(c.outvars) for c in calls) == [2] * layers \
+    assert sorted(len(c.outvars) for c in calls) == [1] + [2] * layers \
         + [3] * layers
 
 

@@ -177,6 +177,19 @@ class TestRepoKernels:
                 argnums=tuple(range(6)))(*a), *args)
         assert mosaic_call_count(compiled) == 2
 
+    @pytest.mark.parametrize("n, vocab, width", [
+        (4096, 25008, 2560), (16384, 30522, 768), (16384, 2, 768)])
+    def test_embedding_grad(self, n, vocab, width):
+        """The embedding's gradient at ``phi4_flash_train_seq4096``'s table
+        (a ragged last tile) and at BERT's word and token-type tables."""
+        sds = jax.ShapeDtypeStruct
+        args = (sds((n,), jnp.int32), sds((n, width), jnp.float32))
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        compiled = compile_for_tpu(
+            lambda *a: pk.embedding_grad_tpu(*a, vocab), *args)
+        assert mosaic_call_count(compiled) == 1
+
     @pytest.mark.parametrize("window", [512, 0])
     def test_splash_takes_a_64_wide_score_head(self, window):
         """Differential attention's head: scores over 64, values of 128,
