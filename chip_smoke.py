@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import json
 import math
 import os
@@ -864,6 +865,42 @@ def roll_expert_permutation(tiny, tpu, key):
     return {"cases": cases}
 
 
+def roll_moe_plan(tiny, tpu, key):
+    """``dispatch_plan`` at the four expert cells' (tokens, top_k, experts,
+    held, rows), as their shapes pick it (counted at nemotron's), against a
+    stable ``argsort`` on the host and its inverse: equal in every entry of
+    Order, Pos and GroupSizes."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import moe
+
+    shapes = [(300, 22, 512, 8, 256), (256, 8, 64, 16, None)] if tiny else [
+        (4096, 22, 512, 8, 3072),       # nemotron3_super_train_seq4096
+        (8192, 8, 64, 16, None),        # mellum2_train_seq8192
+        (4096, 4, 64, 8, None),         # xing4_train_seq4096
+        (16384, 8, 128, 16, 32768),     # keye_vl2_train_seq16384
+    ]
+    cases = []
+    for i, (tokens, top_k, experts, held, rows) in enumerate(shapes):
+        logits = jax.random.normal(jax.random.fold_in(key, i),
+                                   (tokens, experts))
+        chosen = jax.lax.top_k(logits, top_k)[1].astype(jnp.int32)
+        plan = jax.jit(functools.partial(moe.dispatch_plan, first_expert=0,
+                                         num_held=held, max_rows=rows))(chosen)
+        local = np.asarray(chosen).reshape(-1)
+        key_ = np.where(local < held, local, held)
+        order = np.argsort(key_, kind="stable").astype(np.int32)
+        want = (order[:rows], np.argsort(order).astype(np.int32).reshape(
+            tokens, top_k), np.bincount(key_, minlength=held + 1)[:held])
+        for name, got, w in zip(("order", "pos", "group_sizes"), plan, want):
+            assert np.array_equal(np.asarray(got), w), (tokens, name)
+        r = tokens * top_k
+        cases.append({"shape": [tokens, top_k, experts, held, rows],
+                      "lowering": moe.plan_lowering(held + 1, rows or r, r),
+                      "held_rows": int(np.sum(want[2]))})
+    return {"cases": cases}
+
+
 def roll_selective_scan(tiny, tpu, key):
     """``selective_scan`` through its lowering at the training cell's shape
     (4096 tokens, 5120 channels of 16 states) and with a ragged last chunk,
@@ -1077,6 +1114,8 @@ ROLL_CALL = [
      ["selected_attention_tpu", "selected_probability_mean_tpu",
       "index_kl_tpu"]),
     ("expert_permutation", roll_expert_permutation, ["held_rows_sum_tpu"]),
+    # the expert plan is XLA: counted or sorted, checked against argsort
+    ("moe_plan", roll_moe_plan, []),
     ("selective_scan", roll_selective_scan, ["selective_scan_tpu"]),
     ("embedding_grad", roll_embedding_grad, ["embedding_grad_tpu"]),
     ("ssd_scan", roll_ssd_scan, ["ssd_scan_tpu"]),

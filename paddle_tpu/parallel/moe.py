@@ -104,25 +104,109 @@ def route(x, router_w, top_k: int, scoring: str = "softmax", bias=None,
     return weights, experts.astype(jnp.int32)
 
 
+# assignments of one tile of the plan's count: a tile's running count of
+# each group is one 0/1 matmul against a [128, 128] triangle, whose float32
+# sums (at most 127) are exact
+_PLAN_TILE = 128
+# groups (the held experts and "not held") up to which the plan is counted:
+# the tiles' one-hot and running counts, 6 bytes an assignment and group,
+# stay under 200 bytes an assignment
+_PLAN_MAX_GROUPS = 32
+
+
+def plan_lowering(num_groups: int, num_rows: int, num_keys: int) -> str:
+    """How ``dispatch_plan`` forms the plan of ``num_keys`` assignments in
+    ``num_groups`` groups, Order cut to ``num_rows``: ``counting`` (Pos
+    counted, Order searched in the counts: no sort) or ``sort`` (the two
+    argsorts).  On a v5e the count costs ~0.4 ns a key, the search ~4 ns a
+    row of Order and ~1 ps a row and run (tile, group), the argsorts ~1 ns
+    a key each (docs/moe.md "The plan"): the plan is counted where the
+    search costs at most 1 ns a key, and within ``_PLAN_MAX_GROUPS``."""
+    runs = num_groups * -(-num_keys // _PLAN_TILE)
+    if num_groups <= _PLAN_MAX_GROUPS \
+            and num_rows * (4000 + runs) <= 1000 * num_keys:
+        return "counting"
+    return "sort"
+
+
 def dispatch_plan(experts, first_expert: int, num_held: int,
                   max_rows: Optional[int] = None) -> Plan:
-    """Sort the assignments ``experts`` [T, top_k] by held expert (stable, so
-    a group keeps token order); those of experts outside ``[first_expert,
-    first_expert + num_held)`` go last.  ``max_rows`` keeps only that many
-    rows of the buffer (a chip that holds an eighth of the experts fills an
-    eighth of the worst case): the held assignments must fit, which is the
-    caller's to check against ``group_sizes`` (``moe_route`` fails the step
-    where they do not)."""
+    """Order the assignments ``experts`` [T, top_k] by held expert (stable,
+    so a group keeps token order); those of experts outside
+    ``[first_expert, first_expert + num_held)`` go last.  ``max_rows`` keeps
+    only that many rows of the buffer (a chip that holds an eighth of the
+    experts fills an eighth of the worst case): the held assignments must
+    fit, which is the caller's to check against ``group_sizes``
+    (``moe_route`` fails the step where they do not).
+
+    The key takes ``num_held + 1`` values, so the plan can be a counting
+    sort: ``pos`` counted (``_count_plan``) and ``order``, its inverse,
+    searched for its own rows alone, where ``plan_lowering`` finds that
+    cheaper than the two argsorts; either way the result equals
+    ``argsort(key, stable=True)`` and its inverse bit for bit."""
     local = experts - first_expert
     key = jnp.where((local >= 0) & (local < num_held), local,
-                    num_held).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    pos = jnp.argsort(order).astype(jnp.int32).reshape(experts.shape)
-    group_sizes = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :],
-                          axis=0, dtype=jnp.int32)
-    if max_rows is not None:
-        order = order[:max_rows]
-    return Plan(order, pos, group_sizes)
+                    num_held).reshape(-1).astype(jnp.int32)
+    rows = key.shape[0] if max_rows is None else min(max_rows, key.shape[0])
+    lowering = plan_lowering(num_held + 1, rows, key.shape[0])
+    from ..fluid import trace
+    trace.metrics().counter(f"moe.plan_lowering.{lowering}").inc()
+    if lowering == "counting":
+        by_tile, ends, totals = _count_plan(key, num_held + 1)
+        order = _order_by_search(by_tile, ends, rows)
+        pos = by_tile.reshape(-1)[:key.shape[0]]
+        group_sizes = totals[:num_held]
+    else:
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        pos = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :],
+                              axis=0, dtype=jnp.int32)
+    return Plan(order[:rows], pos.reshape(experts.shape), group_sizes)
+
+
+def _count_plan(key, groups: int):
+    """Every assignment's row, ``starts[key]`` + the number of earlier
+    assignments with its key (``starts``: the groups' exclusive running
+    sum), counted per tile of ``_PLAN_TILE`` assignments: the tile's one-hot
+    [groups, tile], its exclusive running count (a matmul), and per group
+    the tiles before it.  Returns the rows as [tiles, tile] (-1 on the
+    padding past R), ``ends`` [tiles, groups] (the row after the last of
+    tile t's assignments of group g: where the run (t, g) of the buffer
+    ends) and the groups' sizes."""
+    tiles = -(-key.shape[0] // _PLAN_TILE)
+    # the padding takes a key past the last group, and so no group's count
+    k = jnp.pad(key, (0, tiles * _PLAN_TILE - key.shape[0]),
+                constant_values=groups).reshape(tiles, _PLAN_TILE)
+    hot = k[:, None, :] == jnp.arange(groups, dtype=k.dtype)[None, :, None]
+    lane = jnp.arange(_PLAN_TILE)
+    within = jnp.einsum("tgj,ji->tgi", hot.astype(jnp.bfloat16),
+                        (lane[:, None] < lane[None, :]).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32).astype(jnp.int32)
+    counts = jnp.sum(hot, axis=2, dtype=jnp.int32)             # [tiles, G]
+    sizes = jnp.sum(counts, axis=0)
+    # the tiles before, as the tile's running count: a count is at most
+    # 128, exact in bfloat16, its sums exact in float32
+    tile = jnp.arange(tiles)
+    before = jnp.dot((tile[:, None] > tile[None, :]).astype(jnp.bfloat16),
+                     counts.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    before = before + (jnp.cumsum(sizes) - sizes)[None, :]
+    rows = jnp.sum(jnp.where(hot, within + before[:, :, None], 0), axis=1)
+    return jnp.where(k < groups, rows, -1), before + counts, sizes
+
+
+def _order_by_search(by_tile, ends, rows: int):
+    """``order[:rows]`` from the counts, without a sort: the runs, in
+    (group, tile) order, end at rising rows, so row p lies in the run
+    (tile t, group g) counted by how many end at or before p, and is the one
+    assignment of tile t whose row is p."""
+    tiles, tile = by_tile.shape
+    p = jnp.arange(rows, dtype=jnp.int32)
+    run = jnp.searchsorted(ends.T.reshape(-1), p, side="right",
+                           method="compare_all").astype(jnp.int32)
+    t = run % tiles
+    at = jnp.argmax(jnp.take(by_tile, t, axis=0) == p[:, None], axis=1)
+    return t * tile + at.astype(jnp.int32)
 
 
 def _num_held(plan: Plan):

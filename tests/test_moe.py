@@ -149,6 +149,91 @@ def test_no_drop_at_overflow():
     assert (np.abs(np.asarray(out)).sum(-1) > 0).all()
 
 
+def _argsort_plan(experts, first, held, max_rows):
+    """The oracle: the plan as a stable argsort of the key and its inverse,
+    on the host."""
+    local = np.asarray(experts).reshape(-1) - first
+    key = np.where((local >= 0) & (local < held), local, held)
+    order = np.argsort(key, kind="stable").astype(np.int32)
+    pos = np.argsort(order).astype(np.int32).reshape(np.shape(experts))
+    return (order[:max_rows], pos,
+            np.bincount(key, minlength=held + 1)[:held].astype(np.int32))
+
+
+def _plan_case(case):
+    """(experts [T, top_k], first_expert, num_held, max_rows) of a case."""
+    rng = np.random.RandomState(3)
+
+    def routed(tokens, top_k, experts):
+        return np.stack([rng.permutation(experts)[:top_k]
+                         for _ in range(tokens)]).astype(np.int32)
+    if case == "none_held":                # a range nobody is routed to
+        return routed(64, 4, 16), 20, 2, None
+    if case == "one_row":
+        chosen = rng.randint(1, 16, (64, 4)).astype(np.int32)
+        chosen[37, 2] = 0
+        return chosen, 0, 1, None
+    if case == "all_held":
+        return routed(64, 4, 16), 0, 16, None
+    if case == "ragged":
+        return routed(96, 3, 16), 0, 5, None
+    if case == "first_expert_inside":
+        return routed(64, 4, 16), 6, 4, None
+    if case == "bounded":                  # twice the even share
+        return routed(512, 4, 16), 0, 2, 2 * 512 * 4 * 2 // 16
+    if case == "keys_not_whole_tiles":     # R = 150
+        return routed(50, 3, 16), 2, 5, 100
+    if case == "top22_of_512":             # nemotron's routing, 64 tokens
+        return routed(64, 22, 512), 0, 8, 64
+    if case == "ep_by_chip":               # expert_layer's plan by chip
+        return routed(64, 4, 16) // 4, 0, 4, None
+    if case == "ep_received":              # ... and the received rows'
+        got = rng.randint(0, 5, (4 * 64, 1)).astype(np.int32)
+        return got, 0, 4, None
+    raise ValueError(case)
+
+
+_PLAN_CASES = ["none_held", "one_row", "all_held", "ragged",
+               "first_expert_inside", "bounded", "keys_not_whole_tiles",
+               "top22_of_512", "ep_by_chip", "ep_received"]
+
+
+@pytest.mark.parametrize("lowering", [None, "counting", "sort"])
+@pytest.mark.parametrize("case", _PLAN_CASES)
+def test_the_counted_plan_equals_two_argsorts(case, lowering, monkeypatch):
+    """``dispatch_plan`` against the argsort oracle, bit for bit, in Order
+    (rows past ``n`` and the ``max_rows`` cut included), Pos and
+    GroupSizes: as the shapes choose (None) and under each construction."""
+    experts, first, held, max_rows = _plan_case(case)
+    if lowering is not None:
+        monkeypatch.setattr(moe, "plan_lowering", lambda *a: lowering)
+    plan = jax.jit(lambda e: moe.dispatch_plan(e, first, held, max_rows))(
+        jnp.asarray(experts))
+    want = _argsort_plan(experts, first, held, max_rows)
+    for name, got, w in zip(("order", "pos", "group_sizes"), plan, want):
+        assert got.dtype == jnp.int32, name
+        np.testing.assert_array_equal(np.asarray(got), w, err_msg=name)
+
+
+def test_the_cells_plans_are_counted_without_a_sort():
+    """What each expert cell's shape picks, the counter, and no sort in
+    the plan at nemotron3_super_train_seq4096's shape (4096 tokens, top-22,
+    8 held, 3072 rows)."""
+    from paddle_tpu.fluid import trace
+    assert moe.plan_lowering(9, 3072, 4096 * 22) == "counting"
+    # mellum2, xing4 and keye_vl2: Order's rows are too many for the search
+    for groups, rows, keys in ((17, 65536, 65536), (9, 16384, 16384),
+                               (17, 32768, 131072)):
+        assert moe.plan_lowering(groups, rows, keys) == "sort"
+    assert moe.plan_lowering(65, 64, 4096) == "sort"
+    counter = trace.metrics().counter("moe.plan_lowering.counting")
+    before = counter.value
+    jaxpr = jax.make_jaxpr(lambda e: moe.dispatch_plan(e, 0, 8, 3072))(
+        jax.ShapeDtypeStruct((4096, 22), jnp.int32))
+    assert counter.value == before + 1
+    assert "sort" not in {e.primitive.name for e in jaxpr.jaxpr.eqns}
+
+
 @pytest.mark.parametrize("case", ["all_to_one_held", "none_held"])
 def test_dropless_under_skew_on_a_share(case):
     """A chip that holds experts 2..3 of 8: every token routed to held
