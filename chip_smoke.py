@@ -728,7 +728,9 @@ def roll_sparse_attention(tiny, tpu, key):
     gradients of QI, KI and W (the loss's own kernels: the heads' mean
     probabilities and the two passes over the index scores), against the
     ``jnp`` path the same lowerings take where the context allows no kernel;
-    and the selection's count."""
+    the selection's count; and the selection kernel's bits against the
+    ``jnp`` path's on whole-number operands, whose every sum is exact in
+    either order (ties on nearly every row: the kernel's tie path)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.registry import LoweringContext, get_op
@@ -751,14 +753,21 @@ def roll_sparse_attention(tiny, tpu, key):
                                          jnp.bfloat16)],
                 "KI": [jax.random.normal(ks[5], (1, seq, di), jnp.bfloat16)],
                 "W": [jax.random.normal(ks[6], (1, seq, hi), jnp.float32)]}
-    chosen, _ = run_lowered(
-        jax.jit(lambda ins: get_op("sparse_attention_index").fn(
-            ins, {"topk": topk}, LoweringContext(base_key=key))),
-        index_in, expect_mosaic=False if tpu else None)
+    def select(ctx_type):
+        return jax.jit(lambda ins: get_op("sparse_attention_index").fn(
+            ins, {"topk": topk}, ctx_type(base_key=key)))
+    chosen, index_calls = run_lowered(select(LoweringContext), index_in,
+                                      expect_mosaic=tpu)
     sel = chosen["Selection"][0]
     mean = float(chosen["SelectedKeysMean"][0][0])
     want_mean = (topk * (topk + 1) / 2 + (seq - topk) * topk) / seq
     assert abs(mean - want_mean) < 0.01, (mean, want_mean)
+    whole = {name: [jax.random.randint(kk, x[0].shape, -2, 3).astype(
+        x[0].dtype)] for kk, (name, x) in zip(jax.random.split(ks[7], 3),
+                                               index_in.items())}
+    same = bool((select(LoweringContext)(whole)["Selection"][0]
+                 == select(NoKernel)(whole)["Selection"][0]).all())
+    assert same, "the selection kernel's bits differ from the jnp path's"
 
     def fwd_bwd(ctx_type):
         """The attention and the indexer's loss behind it: the loss reads
@@ -797,8 +806,9 @@ def roll_sparse_attention(tiny, tpu, key):
         # forward, dq, dk/dv, and a super block of the loss: the heads' mean
         # probabilities, the row statistics with the KL, the gradients
         assert n_calls == 3 + 3 * (seq // 2048), n_calls
-    return {"shape": [hq, hkv, seq, d], "topk": topk, "mosaic": n_calls,
-            "selected_keys_mean": mean,
+    return {"shape": [hq, hkv, seq, d], "topk": topk,
+            "mosaic": n_calls + index_calls, "selected_keys_mean": mean,
+            "selection_equal_on_whole_numbers": same,
             "tile_occupancy": float(chosen["TileOccupancy"][0][0]),
             "rel_err": {k_: float(f"{e:.2e}") for k_, e in errs.items()},
             "loss_rel_l2": {k_: float(f"{e:.2e}")
@@ -1112,7 +1122,7 @@ ROLL_CALL = [
      ["hyper_connection_mix_tpu", "hyper_connection_merge_tpu"]),
     ("sparse_attention", roll_sparse_attention,
      ["selected_attention_tpu", "selected_probability_mean_tpu",
-      "index_kl_tpu"]),
+      "index_kl_tpu", "select_topk_tpu"]),
     ("expert_permutation", roll_expert_permutation, ["held_rows_sum_tpu"]),
     # the expert plan is XLA: counted or sorted, checked against argsort
     ("moe_plan", roll_moe_plan, []),

@@ -22,10 +22,12 @@
   the core forward and stay in XLA, on [24, T] arrays, backward.
 * attention over a key set the device chose a moment ago — a flash kernel
   whose mask is data: one [S, S] int8 selection, each tile of it read once
-  for all the query heads of a key/value group; and the loss that trains
-  the chooser, whose [heads, rows, keys] index scores and their gradient
-  XLA wrote to HBM a 128-query block at a time: two passes that keep a
-  tile's scores on the core.
+  for all the query heads of a key/value group; the chooser itself, whose
+  exact top-k XLA searched in 32 passes over scores it wrote to HBM: a
+  block of queries' scores, search and packed bits in one kernel; and the
+  loss that trains the chooser, whose [heads, rows, keys] index scores and
+  their gradient XLA wrote to HBM a 128-query block at a time: two passes
+  that keep a tile's scores on the core.
 * the expert layer's segment sum — each token's weighted sum of its held
   experts' rows, which XLA gathered as [T, top_k, D], a row at a time,
   padding included: the rows regrouped by tile of tokens are read once and
@@ -64,6 +66,7 @@ __all__ = ["flash_attention_tpu", "fused_attention_tpu", "fused_dropout_tpu",
            "paged_flash_attention_tpu", "hyper_connection_mix_tpu",
            "hyper_connection_merge_tpu", "selected_attention_tpu",
            "selected_probability_mean_tpu", "index_kl_tpu",
+           "select_topk_tpu",
            "held_rows_sum_tpu", "selective_scan_tpu", "embedding_grad_tpu",
            "ssd_scan_tpu"]
 
@@ -779,6 +782,204 @@ def index_kl_tpu(qi, ki, w, qg, k, lse, sel, scale, super_rows, with_grads):
     _index_kl_one`` is the ``jnp`` spelling)."""
     return _index_kl_jit(qi, ki, w, qg, k, lse, sel, float(scale),
                          int(super_rows), bool(with_grads))
+
+
+# ---------------------------------------------------------------------------
+# the indexer's selection (ops/sparse_attention.py, ``select_topk``): each
+# query's ``min(t + 1, topk)`` keys with the largest index scores, exactly.
+# XLA's row blocks write a 128-query block's [heads, 128, extent] scores and
+# their ordered int32 image to HBM and read it 32 times in the threshold
+# search (15.2 ms a layer at 16384 tokens against 6.8 here, v5e).  Here a
+# grid step holds a block of queries: it forms their scores a key tile and a
+# head after the other (``_idx_scores``, as the loss's passes do) up to the
+# tile that holds the block's diagonal, keeps their ordered image in VMEM,
+# runs the 32 counting passes of ``kth_largest`` over it, and writes the
+# packed bits: only the selection reaches HBM.
+#
+# Layout, as the loss's passes have it: the KEYS on the rows and the queries
+# on the lanes.  A per-query quantity (its head weights, ``k``, the
+# candidate, a count) is a row over the lanes; a pass compares the keys
+# against the candidates' row and adds the hits into per-sublane partial
+# sums [8, rows] with plain vector adds, reduced across the sublanes once a
+# pass.  Eight keys that share a byte of the selection are the eight
+# sublanes of one vreg: the bytes of a chunk of keys are one 0/1 matmul
+# with the bits' weights (``_idx_byte_weights``), exact in float32, turned
+# to the output's [queries, bytes] once at the end.
+#
+# Ties exactly as ``select_rows``: where more keys equal a row's threshold
+# than it still needs (a block whose counts of ``keys >= threshold`` pass
+# ``k`` anywhere), the equal keys are taken in key order: a running count of
+# them, within a chunk by an inclusive triangular 0/1 matmul (exact: at most
+# a chunk's keys), across chunks by a carried row.
+# ---------------------------------------------------------------------------
+
+# queries (lanes) of one grid step and keys of one step of a counting pass.
+# A step's ordered keys are [S, rows] int32 in VMEM scratch: 8 MiB at 16384
+# keys; at 256 rows the 18 MiB of scratch pass the 16 MiB that a call inside
+# a loop is given, and the call fails at run time.  Steps of 256 keys take
+# 7.50 ms a layer at 16384 keys against 6.71 at 512 (v5e)
+_IDX_SELECT_ROWS = 128
+_IDX_SELECT_CHUNK = 512
+# The VMEM a call inside a loop (``lax.scan``, ``lax.map``) is given,
+# whatever ``vmem_limit_bytes`` says; the host compile does not check it.
+# 1 MiB of it is left to the kernel body's own values (a head's score tile,
+# the triangular matrix of the tie path).
+_IDX_SELECT_VMEM_BYTES = 15 << 20
+
+
+def _idx_select_vmem_bytes(hi, seq, di, itemsize):
+    """What a grid step of ``select_topk_tpu`` holds in VMEM: the ordered
+    keys and the bytes (scratch), and two buffers of each block (``ki``
+    [S, DI], ``qi`` [HI, rows, DI], ``w`` [HI, rows] float32, the output
+    [rows, S / 8] uint8).  14.0 MiB at 16384 keys, 16 heads of 64."""
+    rows = _IDX_SELECT_ROWS
+    scratch = seq * rows * 4 + seq // 8 * rows * 4
+    blocks = (seq * di * itemsize + hi * rows * di * itemsize
+              + hi * rows * 4 + rows * seq // 8)
+    return scratch + 2 * blocks
+
+
+def index_select_supported(qi, ki, w) -> bool:
+    """Does ``select_topk_tpu`` cover these operands: index heads ``qi``
+    [B, HI, S, DI] and ``ki`` [B, S, DI] of one dtype with DI whole half
+    lane groups, ``w`` [B, S, HI] with HI whole sublane groups, and S whole
+    512-key tiles (what ``index_loss_supported`` asks of the same
+    operands), short enough that a grid step fits the VMEM of a call inside
+    a loop (S up to 17408 at 16 heads of 64)."""
+    if qi.ndim != 4:
+        return False
+    b, hi, seq, di = qi.shape
+    return (seq % _SEL_BLOCK == 0 and qi.dtype == ki.dtype
+            and ki.shape == (b, seq, di) and w.shape == (b, seq, hi)
+            and di % (_LANES // 2) == 0 and hi % 8 == 0
+            and _idx_select_vmem_bytes(hi, seq, di, qi.dtype.itemsize)
+            <= _IDX_SELECT_VMEM_BYTES)
+
+
+def _idx_byte_weights(chunk):
+    """[chunk / 8, chunk] bfloat16: ``2 ** (s % 8)`` where key ``s`` of a
+    chunk lies in byte ``s // 8``, else 0, so that (this) @ bits [chunk,
+    rows] is the bytes of ``pack_selection``."""
+    b = jax.lax.broadcasted_iota(jnp.int32, (chunk // 8, chunk), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (chunk // 8, chunk), 1)
+    return jnp.where(s // 8 == b, jax.lax.shift_left(1, s % 8),
+                     0).astype(jnp.bfloat16)
+
+
+def _idx_select_kernel(qi_ref, ki_ref, wt_ref, sel_ref, keys_ref, bytes_ref,
+                       *, topk):
+    from .sparse_attention import ordered_keys
+    hi, rows, _ = qi_ref.shape
+    b, c = _SEL_BLOCK, _IDX_SELECT_CHUNK
+    f32, i32, int_min = jnp.float32, jnp.int32, jnp.int32(-2 ** 31)
+    r0 = pl.program_id(1) * rows
+    tiles = (r0 + rows - 1) // b + 1        # up to the diagonal's key tile
+    chunks = tiles * (b // c)
+    t = r0 + jax.lax.broadcasted_iota(i32, (1, rows), 1)
+    k = jnp.minimum(t + 1, topk)
+
+    def tile(j, carry):
+        ki = ki_ref[pl.ds(pl.multiple_of(j * b, b), b), :]
+        s = _idx_scores(lambda h: jax.lax.dot_general(
+            ki, qi_ref[h], _NT, preferred_element_type=f32), hi, wt_ref)
+        key = j * b + jax.lax.broadcasted_iota(i32, (b, rows), 0)
+        keys_ref[pl.ds(pl.multiple_of(j * (b // 8), b // 8), b // 8)] = \
+            jnp.where(key <= t, ordered_keys(s), int_min).reshape(
+                b // 8, 8, rows)
+        return carry
+    jax.lax.fori_loop(0, tiles, tile, 0)
+
+    def chunk_keys(i):
+        return keys_ref[pl.ds(pl.multiple_of(i * (c // 8), c // 8), c // 8)]
+
+    def count(hit):
+        """[1, rows]: the keys where ``hit(keys [c / 8, 8, rows])``."""
+        def body(i, acc):
+            return acc + jnp.sum(hit(chunk_keys(i)).astype(i32), axis=0)
+        acc = jax.lax.fori_loop(0, chunks, body, jnp.zeros((8, rows), i32))
+        return jnp.sum(acc, axis=0, keepdims=True)
+
+    def rows8(x):
+        return jnp.broadcast_to(x, (8, rows))
+
+    def bit(p, found):          # kth_largest's pass p
+        cand = found | jax.lax.shift_left(1, 31 - p)
+        at_least = rows8(cand ^ int_min)
+        reach = count(lambda keys: keys >= at_least)
+        return jnp.where(reach >= k, cand, found)
+    tau = rows8(jax.lax.fori_loop(0, 32, bit, jnp.zeros((1, rows), i32))
+                ^ int_min)
+    weights = _idx_byte_weights(c)
+    bytes_ref[...] = jnp.zeros_like(bytes_ref)
+
+    def put(i, chosen):
+        bytes_ref[pl.ds(pl.multiple_of(i * (c // 8), c // 8), c // 8)] = \
+            jnp.dot(weights, chosen.reshape(c, rows).astype(jnp.bfloat16),
+                    preferred_element_type=f32).astype(i32)
+
+    def take_reaching(i, acc):
+        chosen = chunk_keys(i) >= tau
+        put(i, chosen)
+        return acc + jnp.sum(chosen.astype(i32), axis=0)
+    reach = jnp.sum(jax.lax.fori_loop(0, chunks, take_reaching,
+                                      jnp.zeros((8, rows), i32)),
+                    axis=0, keepdims=True)
+
+    @pl.when(jnp.max(reach - k) > 0)
+    def _():                    # more keys equal the threshold than needed
+        need = k - count(lambda keys: keys > tau)
+        lower = (jax.lax.broadcasted_iota(i32, (c, c), 0)
+                 >= jax.lax.broadcasted_iota(i32, (c, c), 1)
+                 ).astype(jnp.bfloat16)
+
+        def take_in_order(i, before):
+            keys = chunk_keys(i).reshape(c, rows)
+            key = i * c + jax.lax.broadcasted_iota(i32, (c, rows), 0)
+            equal = (keys == tau[:1]) & (key <= t)
+            ahead = before + jnp.dot(
+                lower, equal.astype(jnp.bfloat16),
+                preferred_element_type=f32).astype(i32)
+            put(i, (keys > tau[:1]) | (equal & (ahead <= need)))
+            return before + jnp.sum(equal.astype(i32), axis=0,
+                                    keepdims=True)
+        jax.lax.fori_loop(0, chunks, take_in_order,
+                          jnp.zeros((1, rows), i32))
+
+    sel_ref[...] = bytes_ref[...].T.astype(sel_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _select_topk_jit(qi, ki, w, topk):
+    """One trace per (shapes, topk), shared by a program's layers."""
+    b, hi, seq, di = qi.shape
+    rows = _IDX_SELECT_ROWS
+    return pl.pallas_call(
+        functools.partial(_idx_select_kernel, topk=topk),
+        grid=(b, seq // rows),
+        in_specs=[
+            pl.BlockSpec((None, hi, rows, di), lambda n, i: (n, 0, i, 0)),
+            pl.BlockSpec((None, seq, di), lambda n, i: (n, 0, 0)),
+            pl.BlockSpec((None, hi, rows), lambda n, i: (n, 0, i))],
+        out_specs=pl.BlockSpec((None, rows, seq // 8),
+                               lambda n, i: (n, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, seq, seq // 8), jnp.uint8),
+        scratch_shapes=[pltpu.VMEM((seq // 8, 8, rows), jnp.int32),
+                        pltpu.VMEM((seq // 8, rows), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 << 20),
+        name="index_select")(qi, ki, jnp.swapaxes(w.astype(jnp.float32),
+                                                   1, 2))
+
+
+def select_topk_tpu(qi, ki, w, topk):
+    """The indexer's selection on the core: ``qi`` [B, HI, S, DI], ``ki``
+    [B, S, DI], ``w`` [B, S, HI] -> [B, S, S / 8] uint8, the bits
+    ``sparse_attention.select_topk`` sets on these scores (they may differ
+    from its scores in the last bits through the order of the sums only).
+    A grid step is a block of 128 queries: key tiles above its diagonal are
+    not computed, and the keys it visits stop at that tile."""
+    return _select_topk_jit(qi, ki, w, int(topk))
 
 
 # ---------------------------------------------------------------------------

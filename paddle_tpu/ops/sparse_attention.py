@@ -32,12 +32,13 @@ Everything here is row blocks of queries against the keys up to the block's
 end: no [heads, S, S] array and no float [S, S] array exists; the selection
 that waits from a layer's forward for its backward is one bit a pair (32
 MiB at 16384 tokens; a byte a pair was 1 GiB over four layers).  The ``jnp`` spellings serve the CPU, partitioned
-programs and the selection's index scores on the chip; the selected
-attention on the chip is ``pallas_kernels.selected_attention_tpu``
-(``attention_path``'s fifth answer) and the whole loss
-``pallas_kernels.index_kl_tpu`` (the heads' mean probabilities by
-``selected_probability_mean_tpu``, then two passes that keep a tile's
-[heads, rows, keys] index scores on the core).
+programs and shapes the kernels do not cover.  On the chip the selection is
+``pallas_kernels.select_topk_tpu`` (a block of queries' scores, threshold
+search and packing on the core), the selected attention
+``pallas_kernels.selected_attention_tpu`` (``attention_path``'s fifth
+answer) and the whole loss ``pallas_kernels.index_kl_tpu`` (the heads' mean
+probabilities by ``selected_probability_mean_tpu``, then two passes that
+keep a tile's [heads, rows, keys] index scores on the core).
 """
 from __future__ import annotations
 
@@ -355,14 +356,23 @@ def _sparse_attention_index(ins, attrs, ctx):
     """QI [B, HI, S, DI], KI [B, S, DI], W [B, S, HI] -> Selection [B, S,
     S / 8] uint8 (the module's docstring).  SelectedKeysMean [1] and TileOccupancy
     [1] stay on the device and are read by the host when a runner drains.
-    ``sparse_attention.topk_lowering.bisect_xla`` counts the lowerings: the
-    threshold search has one spelling (``kth_largest``; docs/
-    sparse_attention.md has what a kernel of its own would and would not
-    buy)."""
+    On a chip, outside a partitioned program and over shapes the kernel
+    covers (``pallas_kernels.index_select_supported``), the scores, the
+    threshold search and the packing of a block of queries stay on the core
+    (``pallas_kernels.select_topk_tpu``); elsewhere ``select_topk``'s row
+    blocks.  ``sparse_attention.topk_lowering.<kernel|bisect_xla>`` counts
+    which."""
     qi, ki, w = ins["QI"][0], ins["KI"][0], ins["W"][0]
-    _count("topk_lowering", "bisect_xla")
-    sel = _per_sequence(
-        lambda *one: select_topk(*one, int(attrs["topk"])), qi, ki, w)
+    topk = int(attrs["topk"])
+    on_chip = False
+    if ctx.pallas_ok():
+        from .pallas_kernels import index_select_supported, select_topk_tpu
+        on_chip = index_select_supported(qi, ki, w)
+    _count("topk_lowering", "kernel" if on_chip else "bisect_xla")
+    if on_chip:
+        sel = select_topk_tpu(qi, ki, w, topk)
+    else:
+        sel = _per_sequence(lambda *one: select_topk(*one, topk), qi, ki, w)
     mean, tiles = selection_gauges(sel)
     return {"Selection": [sel], "SelectedKeysMean": [mean.reshape(1)],
             "TileOccupancy": [tiles.reshape(1)]}

@@ -159,6 +159,24 @@ class TestRepoKernels:
             lambda *a: pk.index_kl_tpu(*a, 128 ** -0.5, 2048, True), *args)
         assert mosaic_call_count(compiled) == 6
 
+    @pytest.mark.parametrize("seq", [16384, 17408])
+    def test_indexer_selection(self, seq):
+        """The indexer's selection at ``keye_vl2_train_seq16384``'s widths
+        (16 index heads of 64, bfloat16, 16384 tokens that keep 2048 keys)
+        and at the longest sequence ``index_select_supported`` covers:
+        scores, search and packing of a block of queries in one kernel,
+        whose VMEM scratch and blocks (14.0 and 14.9 MiB) fit the 16 MiB a
+        call inside a loop is given."""
+        sds, bf16 = jax.ShapeDtypeStruct, jnp.bfloat16
+        args = (sds((1, 16, seq, 64), bf16), sds((1, seq, 64), bf16),
+                sds((1, seq, 16), jnp.float32))
+        assert pk.index_select_supported(*args)
+        from paddle_tpu.ops.pallas_preflight import (compile_for_tpu,
+                                                     mosaic_call_count)
+        compiled = compile_for_tpu(
+            lambda *a: pk.select_topk_tpu(*a, 2048), *args)
+        assert mosaic_call_count(compiled) == 1
+
     @pytest.mark.parametrize("seq", [4096, 4096 + 40])
     def test_selective_scan_fwd_bwd(self, seq):
         """The scan's two kernels at ``phi4_flash_train_seq4096``'s shape

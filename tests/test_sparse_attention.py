@@ -546,3 +546,139 @@ def test_the_loss_op_counts_which_lowering_it_took(case, path):
     np.testing.assert_allclose(
         out["Loss"][0][0], _plain_index_kl(qi, ki, w, q, k, sel, scale),
         rtol=1e-5)
+
+
+def _whole_number_indexer(seq, case, hi=8, di=64, batch=2, seed=5):
+    """Index operands whose every score is exact in float32 whatever the
+    order of its sums, so the kernel and ``select_topk`` must set the same
+    bits: QI, KI bfloat16 whole numbers, W float32 whole numbers.
+
+    ``small``: entries in -2..2 (W -3..3), many equal scores a row.
+    ``distinct``: a key's two first numbers spell a permutation of the
+    keys, every head of a query scales it by 1 or 2, and its signed head
+    weights by their sum: each row's scores are distinct multiples of that
+    permutation (never 0), negative where the sum is.  ``planted``: as
+    ``distinct``, and every 37th query's QI is 0, so all its scores are
+    equal: every head's term -0.0 where its weights are all negative (every
+    74th), else 0.0."""
+    r = np.random.RandomState(seed)
+    if case == "small":
+        qi = r.randint(-2, 3, (batch, hi, seq, di))
+        ki = r.randint(-2, 3, (batch, seq, di))
+        w = r.randint(-3, 4, (batch, seq, hi))
+    else:
+        perm = np.stack([r.permutation(seq) for _ in range(batch)])
+        ki = np.zeros((batch, seq, di))
+        ki[..., 0], ki[..., 1] = perm // 32, perm % 32
+        c = r.randint(1, 3, (batch, hi, seq))
+        qi = np.zeros((batch, hi, seq, di))
+        qi[..., 0], qi[..., 1] = 32 * c, c
+        w = r.randint(-4, 5, (batch, seq, hi))
+        flat = np.einsum("bth,bht->bt", w, c) == 0      # no zero multiple
+        w[..., 0] += flat * np.where(w[..., 0] == -1, 2, 1)
+        if case == "planted":
+            qi[:, :, ::37] = 0
+            w[:, ::74] = -1 - np.abs(w[:, ::74])         # -0.0 terms
+    return (jnp.asarray(qi, jnp.bfloat16), jnp.asarray(ki, jnp.bfloat16),
+            jnp.asarray(w, jnp.float32))
+
+
+@pytest.mark.parametrize("case,topk,super_rows", [
+    ("small", 64, 2048),
+    ("small", 200, 2048),          # rows before the 200th keep all theirs
+    ("small", 64, 512),            # select_topk in two super blocks
+    ("distinct", 64, 2048),
+    ("distinct", 700, 512),
+    ("planted", 96, 512)])
+def test_selection_kernel_in_interpret_mode_sets_select_topks_bits(
+        case, topk, super_rows, monkeypatch):
+    """``pallas_kernels.select_topk_tpu`` (scores, the 32 counting passes,
+    the ties in key order, the packing) against ``select_topk`` bit for bit
+    and against a stable sort of the scores: 1024 tokens (two key tiles,
+    eight blocks of queries), two sequences."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(sa, "_SUPER_ROWS", super_rows)
+    seq = 1024
+    qi, ki, w = _whole_number_indexer(seq, case)
+    assert pk.index_select_supported(qi, ki, w)
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(pk.select_topk_tpu(qi, ki, w, topk))
+    assert got.dtype == np.uint8 and got.shape == (2, seq, seq // 8)
+    for i in range(2):
+        np.testing.assert_array_equal(
+            got[i], np.asarray(sa.select_topk(qi[i], ki[i], w[i], topk)))
+        scores = np.asarray(sa.index_scores(qi[i], ki[i], w[i]))
+        want = _oracle_selection(scores, topk)
+        np.testing.assert_array_equal(
+            np.asarray(sa.unpack_selection(jnp.asarray(got[i]))), want)
+        # rows where more keys equal the threshold than are still needed
+        tied = [t for t in range(topk, seq)
+                if (scores[t, :t + 1] >= np.sort(scores[t, :t + 1])[-topk]
+                    ).sum() > topk]
+        if case == "small":
+            assert len(tied) > seq // 4
+        else:
+            assert tied == [t for t in range(topk, seq)
+                            if case == "planted" and t % 37 == 0]
+        assert (scores < 0).any(axis=1).sum() > seq // 4
+        if case == "planted":
+            # relu(0) times a negative weight is -0.0 a head: the kernel's
+            # sum, which starts from the first head's term, is -0.0 there,
+            # the jnp sum, which starts from +0.0, is 0.0
+            assert (scores[::37] == 0).all()
+            assert (np.asarray(w)[i, ::74] < 0).all()
+            # every key ties: the first k in key order
+            np.testing.assert_array_equal(
+                want[::37], np.tril(np.ones((seq, seq), np.int8))[::37]
+                * (np.arange(seq) < topk)[None, :])
+
+
+@pytest.mark.parametrize("case,path", [
+    ("chip", "kernel"), ("cpu", "bisect_xla"), ("partitioned", "bisect_xla"),
+    ("index_heads_of_48", "bisect_xla"), ("not_whole_tiles", "bisect_xla"),
+    ("longer_than_vmem", "bisect_xla")])
+def test_the_index_op_counts_which_lowering_it_took(case, path):
+    """``sparse_attention.topk_lowering.kernel`` once per lowering where the
+    context allows a kernel and ``index_select_supported`` covers the
+    shapes (DI whole half lane groups, S whole 512-key tiles, a grid step
+    within the VMEM of a call inside a loop), ``.bisect_xla`` elsewhere;
+    both set ``select_topk``'s bits."""
+    from paddle_tpu.fluid import trace
+    from paddle_tpu.ops import pallas_kernels as pk
+    names = [f"sparse_attention.topk_lowering.{n}"
+             for n in ("kernel", "bisect_xla")]
+    if case == "longer_than_vmem":
+        # whole 512-key tiles, but 27.5 MiB of VMEM a grid step: traced
+        # only, the XLA row blocks at 32768 keys are too slow for a test
+        seq = 32768
+        ops = {"QI": [jax.ShapeDtypeStruct((1, 16, seq, 64), jnp.bfloat16)],
+               "KI": [jax.ShapeDtypeStruct((1, seq, 64), jnp.bfloat16)],
+               "W": [jax.ShapeDtypeStruct((1, seq, 16), jnp.float32)]}
+        assert not pk.index_select_supported(
+            *(ops[n][0] for n in ("QI", "KI", "W")))
+        before = [trace.metrics().counter(n).value for n in names]
+        out = jax.eval_shape(
+            lambda o: get_op("sparse_attention_index").fn(
+                o, {"topk": 2048}, _Chip(jax.random.PRNGKey(0))), ops)
+        after = [trace.metrics().counter(n).value for n in names]
+        assert [a - b for a, b in zip(after, before)] == [0, 1]
+        assert out["Selection"][0].shape == (1, seq, seq // 8)
+        return
+    seq = 768 if case == "not_whole_tiles" else 512
+    qi, ki, w = _whole_number_indexer(
+        seq, "small", di=48 if case == "index_heads_of_48" else 64)
+    ctx = (LoweringContext if case == "cpu" else _Chip)(
+        jax.random.PRNGKey(0))
+    ctx.partitioned = case == "partitioned"
+    before = [trace.metrics().counter(n).value for n in names]
+    with pltpu.force_tpu_interpret_mode():
+        out = get_op("sparse_attention_index").fn(
+            {"QI": [qi], "KI": [ki], "W": [w]}, {"topk": 32}, ctx)
+    after = [trace.metrics().counter(n).value for n in names]
+    assert [a - b for a, b in zip(after, before)] \
+        == [path == "kernel", path == "bisect_xla"]
+    for i in range(2):
+        np.testing.assert_array_equal(
+            out["Selection"][0][i], sa.select_topk(qi[i], ki[i], w[i], 32))
+    assert float(out["SelectedKeysMean"][0][0]) == pytest.approx(
+        (32 * 33 / 2 + (seq - 32) * 32) / seq)
