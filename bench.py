@@ -267,14 +267,6 @@ def _compile_stats():
     return out
 
 
-def _autotune_block():
-    """The `autotune` block every leg carries (docs/performance.md
-    "Auto-tuning"): chosen config, probe cost, tuned-vs-untuned delta —
-    {"enabled": False, ...} when the tuner never ran in this process."""
-    from paddle_tpu.fluid import autotune as _at
-    return _at.bench_block()
-
-
 def dtype_mix():
     """Share of the value plane per dtype from the AMP plane's
     amp.dtype_hist.* gauges (populated by the amp_bf16 pass on static
@@ -316,7 +308,6 @@ def report(metric, unit, rate, flops_rate, device, config=None,
                       f"Chinchilla matmul-only count and XLA cost_analysis "
                       f"disagree on this program", file=sys.stderr)
     out.update(extras or {})
-    out["autotune"] = _autotune_block()
     mix = dtype_mix()
     if mix:
         out["dtype_mix"] = mix
@@ -467,10 +458,6 @@ def main_ctr(quick):
     bs = fluid.BuildStrategy()
     bs.fuse_elewise_add_act_ops = True
     bs.constant_folding = True
-    # FLAGS_auto_tune=1 closes the loop here: the first tuned step sweeps
-    # dispatch knobs in probe windows and commits the winner (persisted —
-    # the next bench round starts tuned at zero probe cost)
-    bs.auto_tune = bool(fluid.core.get_flag("auto_tune"))
     train_prog = fluid.CompiledProgram(main, build_strategy=bs)
 
     rng = np.random.RandomState(0)
@@ -551,7 +538,6 @@ def main_ctr(quick):
         # bf16 leg rides bf16_value/amp_speedup
         "amp_dtype": "float32",
     }
-    out["autotune"] = _autotune_block()
     mix = dtype_mix()
     if mix:
         out["dtype_mix"] = mix
@@ -657,7 +643,6 @@ def main_sharding(quick):
         "single_chip_examples_per_sec": round(steps * batch / dt1, 1),
         "loss_parity_rel_err": round(parity, 8),
     }
-    out["autotune"] = _autotune_block()
     out.update(_compile_stats())
     print(json.dumps(out))
 
@@ -669,18 +654,14 @@ def main_serve(quick):
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), "tools"))
     import serve_bench
-    from paddle_tpu.fluid import core as _core
 
     device = device_info(quick)
     qps = 200.0 if quick else 2000.0
     n = 300 if quick else 4000
     report = serve_bench.serve_bench(qps=qps, n_requests=n,
                                      sizes=(1, 2, 4, 8),
-                                     max_batch=32, hidden=64,
-                                     auto_tune=bool(
-                                         _core.get_flag("auto_tune")))
+                                     max_batch=32, hidden=64)
     out = dict(report, **device)
-    out["autotune"] = _autotune_block()
     out.update(_compile_stats())
     print(json.dumps(out))
 
@@ -776,7 +757,6 @@ def main_ps(quick):
             "prefetch_hit_rate": round(hit_rate, 3),
             "prefetch_patched": m.counter("ps.prefetch_patched").value,
         }
-        out["autotune"] = _autotune_block()
         print(json.dumps(out))
     finally:
         tbl.close()

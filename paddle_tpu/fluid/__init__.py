@@ -32,7 +32,6 @@ from .checkpoint import CheckpointManager
 from . import nets
 from .compiler import CompiledProgram, BuildStrategy, ExecutionStrategy
 from . import passes
-from . import autotune
 from . import dygraph
 from ..contrib import memory_usage_calc as _muc  # noqa: F401 (cycle guard)
 from .. import contrib                            # fluid.contrib alias

@@ -268,28 +268,6 @@ def persistent_cache() -> Optional[PersistentCache]:
     return _instance
 
 
-_config_instance: Optional[PersistentCache] = None
-
-
-def config_store() -> Optional[PersistentCache]:
-    """The tuned-config store (fluid/autotune.py): the same atomic
-    JSON-per-key index, rooted at ``FLAGS_auto_tune_dir`` when set, else
-    riding the shared ``FLAGS_persistent_cache_dir`` cache — winning
-    configs live beside the executables they were measured for.  None
-    when neither flag is set (tuning still works, it just re-probes
-    after a restart).  Re-reads the flags each call so ``set_flags``
-    can repoint it mid-run."""
-    global _config_instance
-    from . import core
-    root = core.get_flag("auto_tune_dir")
-    if not root:
-        return persistent_cache()
-    root = os.path.abspath(str(root))
-    if _config_instance is None or _config_instance.root != root:
-        _config_instance = PersistentCache(root)
-    return _config_instance
-
-
 # ---------------------------------------------------------------------------
 # recompile-storm detection
 # ---------------------------------------------------------------------------

@@ -79,12 +79,6 @@ class BuildStrategy:
         # optional {"axis": size, ...} mesh override; default is a
         # 1-axis mesh over all local devices (dp/fsdp -> "dp", tp -> "tp")
         self.sharding_mesh = None
-        # profile-guided self-tuning (fluid/autotune.py,
-        # docs/performance.md "Auto-tuning"): True opts this program
-        # into the executor-side search — bucket edges and dispatch
-        # fusion/inflight depth tune once per fingerprint on the first
-        # run, and persisted winners apply with zero probe cost on restart
-        self.auto_tune = False
         self.enable_sequential_execution = False
         self.remove_unnecessary_lock = True
         self.sync_batch_norm = False        # -> sync_batch_norm op psum
@@ -119,10 +113,6 @@ class CompiledProgram:
         self._ir_passes_applied = False
         # forwarded so Executor.run can treat us like a Program
         self._hints = self._program._hints
-        if getattr(self._build_strategy, "auto_tune", False):
-            # the hint rides the Program (shared dict) so the executor
-            # sees it after the CompiledProgram facade unwraps
-            self._program._hints["auto_tune"] = True
         if exec_strategy is not None:
             self._apply_exec_strategy(exec_strategy)
         trace.metrics().counter("compiler.compiled_programs").inc()
@@ -171,11 +161,6 @@ class CompiledProgram:
             return
         self._ir_passes_applied = True
         from . import passes
-        hint_fg = self._program._hints.get("fuse_grad_size_in_num")
-        if hint_fg is not None:
-            # auto-tuner override: the hint travels with the program so a
-            # persisted winning config re-applies without a BuildStrategy
-            self._build_strategy.fuse_grad_size_in_num = int(hint_fg)
         plist = passes.passes_for_build_strategy(self._build_strategy)
         gv = self._build_strategy.debug_graphviz_path or None
         if not plist and not gv:

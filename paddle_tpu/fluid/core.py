@@ -289,25 +289,6 @@ _FLAGS: Dict[str, object] = {
         "FLAGS_ps_wal_fsync", "0") not in ("0", "", "false", "False"),
     "ps_shard_vnodes": int(_os.environ.get(
         "FLAGS_ps_shard_vnodes", "64") or 64),
-    # profile-guided self-tuning runtime (fluid/autotune.py,
-    # docs/performance.md "Auto-tuning"): auto_tune arms BOTH surfaces
-    # (executor programs tune once per fingerprint on first run; serving
-    # engines get a flag-started online tuner, reconciled by
-    # autotune.apply_flags on mid-run flips); auto_tune_probe_steps is
-    # the probe-window length in real steps; auto_tune_dir re-roots the
-    # persisted-config store away from FLAGS_persistent_cache_dir;
-    # auto_tune_hbm_budget_mb pins the OOM-rejection budget (0 = ask the
-    # backend for bytes_limit); auto_tune_max_candidates bounds the
-    # proposal stream per search.
-    "auto_tune": _os.environ.get(
-        "FLAGS_auto_tune", "0") not in ("0", "", "false", "False"),
-    "auto_tune_probe_steps": int(_os.environ.get(
-        "FLAGS_auto_tune_probe_steps", "8") or 8),
-    "auto_tune_dir": _os.environ.get("FLAGS_auto_tune_dir") or None,
-    "auto_tune_hbm_budget_mb": float(_os.environ.get(
-        "FLAGS_auto_tune_hbm_budget_mb", "0") or 0),
-    "auto_tune_max_candidates": int(_os.environ.get(
-        "FLAGS_auto_tune_max_candidates", "16") or 16),
 }
 
 
@@ -377,12 +358,6 @@ def set_flags(flags: Dict[str, object]):
             # install/replace/uninstall the fault-injection schedule
             from ..distributed import faultline
             faultline.apply_flags()
-        elif k in ("auto_tune", "auto_tune_probe_steps", "auto_tune_dir"):
-            # reconcile the self-tuning runtime with the new flag values
-            # (start flag-started serving tuners / stop ONLY flag-started
-            # ones — the metrics-export reconciliation contract)
-            from . import autotune
-            autotune.apply_flags()
 
 
 def get_flags(names):

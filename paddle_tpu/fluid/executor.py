@@ -519,20 +519,6 @@ class Executor:
         scope = scope or global_scope()
         feed = self._normalize_feed(feed)
 
-        # profile-guided self-tuning (fluid/autotune.py): a program that
-        # opted in (BuildStrategy.auto_tune hint or FLAGS_auto_tune)
-        # tunes ONCE per fingerprint before its first real step — a
-        # persisted winner applies with zero probe cost; the search
-        # itself re-enters run()/run_async() under the _in_autotune
-        # guard.  Placed BEFORE bucketing so a tuned bucket_edges hint
-        # shapes this very run.
-        if (feed and (program._hints.get("auto_tune")
-                      or core.get_flag("auto_tune"))
-                and not getattr(self, "_in_autotune", False)):
-            from . import autotune
-            autotune.maybe_tune_executor(self, program, feed,
-                                         fetch_names, scope)
-
         # shape bucketing (fluid/compile_cache.py): pad the leading batch
         # dim up to a bucket edge BEFORE computing feed_sig, so a ragged
         # epoch compiles <= len(edges) executables instead of one per
@@ -1217,79 +1203,6 @@ class Executor:
             import weakref
             self._fp_finalizer = weakref.finalize(
                 self, _unpublish_footprints, self._footprints)
-        return info
-
-    def analyze(self, program: Optional[Program] = None,
-                feed: Optional[Dict[str, Any]] = None,
-                fetch_list: Optional[Sequence] = None,
-                scope: Optional[Scope] = None) -> Optional[Dict[str, Any]]:
-        """AOT cost/memory analysis of (program, feed) WITHOUT executing
-        a step: lower + compile at ShapeDtypeStruct examples and return
-        the ``device_stats.capture`` record (flops, bytes_accessed,
-        per_device_peak_bytes, ...), or None when the backend refuses.
-
-        This is the autotuner's free pricing path — a candidate config
-        is judged OOM from ``memory_analysis`` here before any probe
-        window runs it — but it is also a public "would this fit?"
-        question for tooling.  No step executes, no scope state moves,
-        nothing lands in the run cache or the footprint gauges."""
-        program = program or default_main_program()
-        fetch_names = [_fetch_name(f) for f in _as_list(fetch_list)]
-        mesh = getattr(program, "_mesh", None)
-        plan = getattr(program, "_sharding_plan", None)
-        if hasattr(program, "_program"):   # CompiledProgram
-            if hasattr(program, "_ensure_sharding_plan"):
-                plan = program._ensure_sharding_plan() or plan
-            if hasattr(program, "_apply_ir_passes"):
-                program._apply_ir_passes(fetch_names)
-            mesh = getattr(program, "_mesh", None) or mesh
-            program = program._program
-            plan = getattr(program, "_sharding_plan", None) or plan
-        if plan is not None:
-            mesh = None
-        scope = scope or global_scope()
-        feed = self._normalize_feed(feed)
-        # mirror run()'s bucketing so the analysed shapes are the shapes
-        # a real step would compile
-        bucket = n_valid = None
-        want_bucketing = program._hints.get("shape_bucketing")
-        if want_bucketing is None:
-            want_bucketing = core.get_flag("shape_bucketing")
-        if (want_bucketing and feed and mesh is None
-                and (plan is None or plan.data_axis is None)
-                and not program._hints.get("pipeline_microbatches")
-                and not program._hints.get("recompute_checkpoints")):
-            dims = {np.shape(v)[0] for v in feed.values() if np.ndim(v) >= 1}
-            if len(dims) == 1:
-                n_valid = int(next(iter(dims)))
-                edges = compile_cache.normalize_edges(
-                    program._hints.get("bucket_edges")
-                    or core.get_flag("shape_bucket_edges"))
-                bucket = compile_cache.bucket_for(n_valid, edges)
-                if bucket != n_valid:
-                    feed = {k: compile_cache.pad_dim0(v, bucket)
-                            for k, v in feed.items()}
-        compiled = self._prepare(program, feed, fetch_names, scope, mesh,
-                                 bucket=bucket, plan=plan)
-        if compiled.jitted is None:
-            return None
-        mut = {n: scope.find_var(n) for n in compiled.param_names
-               if n in compiled.written_names}
-        ro = {n: scope.find_var(n) for n in compiled.param_names
-              if n not in compiled.written_names}
-        feeds = {k: jnp.asarray(v) for k, v in feed.items()}
-        if bucket is not None:
-            feeds["__batch_valid__"] = jnp.asarray(n_valid, jnp.int32)
-        seed = program.random_seed if program.random_seed is not None else 0
-        info = device_stats.capture(
-            compiled.jitted,
-            device_stats.sds_tree(
-                (mut, ro, feeds, jax.random.PRNGKey(seed)),
-                shardings=getattr(compiled.fn, "in_shardings", True)),
-            n_devices=plan.n_devices if plan is not None else 1)
-        if info is not None:
-            info["bucket"] = bucket
-            info["n_ops"] = compiled.n_ops
         return info
 
     def top_footprints(self, n: int = 5):

@@ -189,18 +189,6 @@ def stats_payload() -> Dict[str, Any]:
             }
     if engines:
         out["engines"] = engines
-    # self-tuning decisions (fluid/autotune.py): the fleet monitor and
-    # diagnose tooling see what the tuner did from the same cheap poll.
-    # Only present once the tuner has actually acted — the payload stays
-    # small for untuned processes.
-    try:
-        from . import autotune
-        at = autotune.state()
-        if (at.get("enabled") or at.get("accepts") or at.get("rejects")
-                or at.get("reverts") or at.get("warm_starts")):
-            out["autotune"] = at
-    except Exception:                   # noqa: BLE001 — a scrape never
-        pass                            # crashes on a half-imported tuner
     if m.get("decode.requests") is not None:
         out["decode"] = {
             "requests": _counter("decode.requests"),
@@ -220,8 +208,9 @@ def stats_payload() -> Dict[str, Any]:
         }
     # per-device HBM truth (fluid/device_stats.py): the worst resident
     # executable's per-shard peak + the widest mesh it compiled for —
-    # how the router and autotuner see that a sharded replica fits a
-    # batch one chip could not hold (FLAGS_device_cost_analysis)
+    # how a /stats reader (tools/serve_bench.py) sees that a sharded
+    # replica fits a batch one chip could not hold
+    # (FLAGS_device_cost_analysis)
     _suffix = ".per_device_peak_bytes"
     peak = 0.0
     mesh_devices = 1

@@ -136,22 +136,6 @@ def _faultline_state() -> Optional[Dict[str, Any]]:
         return None
 
 
-def _autotune_state() -> Optional[Dict[str, Any]]:
-    """The self-tuning runtime's decisions (fluid/autotune.py) — an
-    incident bundle must say which knob values the tuner committed (and
-    when it last reverted one), or a responder chases a perf regression
-    the tuner caused.  None when the tuner module never loaded."""
-    mod = sys.modules.get("paddle_tpu.fluid.autotune")
-    if mod is None:
-        return None
-    try:
-        st = mod.state()
-        st["decisions"] = mod.decisions(10)
-        return st
-    except Exception:               # noqa: BLE001 — forensics degrade
-        return None
-
-
 def _program_fingerprints(wide_events) -> List[str]:
     return sorted({r["fp"] for r in wide_events
                    if r.get("kind") == "step" and r.get("fp")})
@@ -210,7 +194,6 @@ def build_bundle_doc(reason: str, exc: Optional[BaseException] = None,
         "device_footprints": _device_footprints(),
         "program_fingerprints": _program_fingerprints(wide),
         "faultline": _faultline_state(),
-        "autotune": _autotune_state(),
     }
     if exc is not None:
         doc["exception"] = {

@@ -357,9 +357,7 @@ class ServingEngine:
                  auto_start: bool = True,
                  mesh=None,
                  sharding=None,
-                 name: Optional[str] = None,
-                 auto_tune: bool = False,
-                 slo_ms: Optional[float] = None):
+                 name: Optional[str] = None):
         # per-engine instrument namespace (serving.<name>.* beside the
         # process aggregate; None = the plain serving.* family)
         self.name = name
@@ -458,15 +456,6 @@ class ServingEngine:
         self._batcher_t: Optional[threading.Thread] = None
         self._collector_t: Optional[threading.Thread] = None
         self.warmup_report: Optional[Dict[str, Any]] = None
-        # online self-tuning (fluid/autotune.py): auto_tune=True attaches
-        # a programmatic tuner (never stopped by flag flips); otherwise
-        # FLAGS_auto_tune attaches a flag-started one that
-        # autotune.apply_flags() reconciles.  A persisted winner for this
-        # program applies max_batch/max_wait_us here, before the first
-        # batch forms — the zero-probe warm start.
-        from ..fluid import autotune as _autotune
-        self._autotuner = _autotune.attach_engine(
-            self, programmatic=bool(auto_tune), slo_ms=slo_ms)
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ServingEngine":
@@ -481,8 +470,6 @@ class ServingEngine:
                 daemon=True)
             self._batcher_t.start()
             self._collector_t.start()
-        if self._autotuner is not None:
-            self._autotuner.start()
         return self
 
     def pause(self) -> None:
@@ -506,8 +493,6 @@ class ServingEngine:
         Implies :meth:`resume` — a close must drain, never deadlock on a
         paused batcher."""
         self._resume.set()
-        if self._autotuner is not None:
-            self._autotuner.stop()
         with self._lock:
             if self._closed:
                 return
@@ -686,8 +671,7 @@ class ServingEngine:
         stopping = False
         while True:
             # read the formation deadline EVERY round, not once at thread
-            # start: the autotuner retunes max_wait_us on a live engine
-            # and a stale local would make the knob silently inert
+            # start: max_wait_us may be set on a live engine
             max_wait_s = self.max_wait_us / 1e6
             timeout = 0.05
             if pending:
@@ -917,10 +901,6 @@ class ServingEngine:
             st = self._ins.hist_stats(h)
             out[h] = {k: st[k] for k in
                       ("count", "avg", "p50", "p95", "p99") if k in st}
-        if self._autotuner is not None:
-            out["autotune"] = dict(self._autotuner.state(),
-                                   max_batch=self.max_batch,
-                                   max_wait_us=self.max_wait_us)
         plan = getattr(getattr(self._backend, "program", None),
                        "_sharding_plan", None)
         if plan is not None:

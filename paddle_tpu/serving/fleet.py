@@ -79,16 +79,11 @@ def demo_mlp_spec(hidden: int = 32, features: int = 16, classes: int = 10,
                   max_batch: int = 16, max_wait_us: int = 2000,
                   queue_depth: int = 256, seed: int = 0,
                   warmup: bool = True, watchdog_stall_s: float = 0.0,
-                  auto_tune: bool = False,
                   mesh: Optional[Dict[str, int]] = None,
                   sharding: Optional[str] = None,
                   emulate_devices: Optional[int] = None) -> Dict[str, Any]:
     """The built-in demo replica spec (a small frozen mlp) — what
     serve_bench --fleet and the ci_smoke fleet gate serve.
-    ``auto_tune=True`` arms the per-replica online tuner
-    (fluid/autotune.py): each replica hill-climbs max_batch/max_wait
-    against its own window p99, and the decisions surface in the
-    replica's /stats payload the fleet monitor scrapes.
 
     ``mesh`` (axis→size, e.g. ``{"tp": 8}``) makes the replica itself a
     pjit mesh: its subprocess builds the engine over a TP-sharded
@@ -101,8 +96,7 @@ def demo_mlp_spec(hidden: int = 32, features: int = 16, classes: int = 10,
             "classes": classes, "max_batch": max_batch,
             "max_wait_us": max_wait_us, "queue_depth": queue_depth,
             "seed": seed, "warmup": warmup,
-            "watchdog_stall_s": watchdog_stall_s,
-            "auto_tune": bool(auto_tune)}
+            "watchdog_stall_s": watchdog_stall_s}
     if mesh:
         spec["mesh"] = {str(k): int(v) for k, v in dict(mesh).items()}
         spec["sharding"] = sharding or "tp"
@@ -144,13 +138,8 @@ def build_engine_from_spec(spec: Dict[str, Any]) -> ServingEngine:
     warm-start path)."""
     kind = spec.get("kind", "demo_mlp")
     kwargs = {k: spec[k] for k in ("max_batch", "max_wait_us",
-                                   "queue_depth", "default_deadline_ms",
-                                   "auto_tune")
+                                   "queue_depth", "default_deadline_ms")
               if spec.get(k) is not None}
-    if kwargs.get("auto_tune") and spec.get("watchdog_p99_ms"):
-        # the tuner's revert guard judges against the same p99 the
-        # replica's SLO watchdog enforces
-        kwargs["slo_ms"] = float(spec["watchdog_p99_ms"])
     shard_kw: Dict[str, Any] = {}
     if spec.get("mesh"):
         # the replica IS a pjit mesh: build it here (inside the child,
@@ -1458,31 +1447,6 @@ class FleetMetricsAggregator:
                         pass
             if st.get("p99_ms") is not None:
                 p99s.append(float(st["p99_ms"]))
-            at = st.get("autotune")
-            if isinstance(at, dict):
-                # tuner-decision rollup: how many commits/reverts the
-                # fleet's replicas made, without reaching into them
-                ar = rollup.setdefault(
-                    "autotune", {"accepts": 0, "rejects": 0,
-                                 "reverts": 0})
-                for k in ("accepts", "rejects", "reverts"):
-                    try:
-                        ar[k] += int(at.get(k) or 0)
-                    except (TypeError, ValueError):
-                        pass
-                # per-topology attribution: decisions carry the
-                # replica's mesh shape, so an 8-chip TP replica's
-                # accepts roll up separately from a 1-chip one's
-                for d in at.get("last_decisions") or []:
-                    if not isinstance(d, dict):
-                        continue
-                    mesh = str(d.get("mesh") or "unsharded")
-                    bym = ar.setdefault("by_mesh", {})
-                    row = bym.setdefault(
-                        mesh, {"accept": 0, "reject": 0, "revert": 0})
-                    act = d.get("action")
-                    if act in row:
-                        row[act] += 1
         rollup["p99_ms_max"] = max(p99s) if p99s else None
         if decode_seen:
             decode["spec_accept_rate"] = (

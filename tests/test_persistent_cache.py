@@ -85,6 +85,30 @@ class TestPersistentCache:
         assert all(m and "fingerprint" in m and "compile_seconds" in m
                    for m in metas)
 
+    @pytest.mark.parametrize("garbage", ["{not json", "\x00garbage\x00",
+                                         ""],
+                             ids=["truncated_json", "nul_bytes", "empty"])
+    def test_corrupt_entry_is_skipped_not_raised(self, cache_dir, garbage):
+        """An unreadable index record reads as absent: ``get`` returns
+        None, ``entries()`` skips it, and an Executor under the same
+        directory still compiles, runs and records its own entry."""
+        pc = cc.persistent_cache()
+        pc.record("corrupt", {"fingerprint": "x"})
+        with open(pc.path_for("corrupt"), "w") as f:
+            f.write(garbage)
+        assert pc.get("corrupt") is None
+        assert "corrupt" not in [e["key"] for e in pc.entries()]
+        main, startup, loss = _build()
+        exe = fluid.Executor()
+        exe.run(startup)
+        out, = exe.run(main, feed={"x": np.ones((4, 8), "float32")},
+                       fetch_list=[loss])
+        assert np.isfinite(out).all()
+        recorded = pc.entries()
+        assert recorded and "corrupt" not in [e["key"] for e in recorded]
+        assert all("fingerprint" in e and "compile_seconds" in e
+                   for e in recorded)
+
     def test_second_process_zero_cold_misses(self, cache_dir):
         """Acceptance: a second process reusing FLAGS_persistent_cache_dir
         reports ZERO program-level cold misses for an identical

@@ -1366,187 +1366,6 @@ def main():
           f"{len(restP)} restart, {refP.size()} rows, {_ps_dt:.1f}s",
           flush=True)
 
-    step("autotune: tuned >= untuned paired epochs, OOM priced out "
-         "pre-execution, serving tuner never commits a breach, "
-         "seeded + warm-restart replay")
-    import shutil as _atsh
-    import tempfile as _attmp
-    from paddle_tpu.fluid import autotune as at
-    from paddle_tpu.fluid import trace as trAT
-    from paddle_tpu.fluid.core import Scope as _ATScope, \
-        scope_guard as _at_scope_guard
-    from paddle_tpu.fluid.executor import _fingerprint as _at_fp
-
-    _at_dir = _attmp.mkdtemp(prefix="smoke-autotune-")
-    _at_saved = {k: fluid.core.get_flag(k) for k in
-                 ("auto_tune", "auto_tune_dir", "auto_tune_probe_steps",
-                  "auto_tune_hbm_budget_mb")}
-    fluid.core._FLAGS.update({"auto_tune": False,
-                              "auto_tune_dir": _at_dir,
-                              "auto_tune_probe_steps": 4,
-                              "auto_tune_hbm_budget_mb": 0})
-    at.reset_for_tests()
-
-    def _at_counts():
-        return {k: trAT.counter_value(f"autotune.{k}") for k in
-                ("probes", "accepts", "rejects", "warm_starts",
-                 "errors")}
-
-    try:
-        # gate 1: the search commits a config that is never slower than
-        # the untuned baseline.  Same measurement discipline as the
-        # forensics gate: PAIRED baseline/tuned probe windows interleave
-        # over one warmed program, best pair is the verdict.
-        reset_unique_name()
-        mpA, spA, loA = build_demo()
-        mpA.random_seed = 11
-        mpA._hints["auto_tune"] = True
-        exA = fluid.Executor()
-        with _at_scope_guard(_ATScope()):
-            exA.run(spA)
-            c0 = _at_counts()
-            exA.run(mpA, feed=demo_feed, fetch_list=[loA])  # tunes here
-            c1 = _at_counts()
-            assert c1["accepts"] - c0["accepts"] == 1, (c0, c1)
-            assert c1["probes"] - c0["probes"] > 0
-            assert c1["errors"] - c0["errors"] == 0
-            dA = [d for d in at.decisions()
-                  if d.get("surface") == "train"
-                  and d.get("action") == "accept"][-1]
-            tuned_cfg, base_cfg = dA["config"], dA["baseline"]
-            spaceA = at.training_space(mpA, demo_feed)
-            fluid.core._FLAGS["auto_tune_probe_steps"] = 20
-            exA._in_autotune = True      # measurement, not re-tuning
-            ratios = []
-            try:
-                for _ in range(4):
-                    pair = []
-                    for cfg in (base_cfg, tuned_cfg):
-                        s = at._probe_training(
-                            exA, mpA, demo_feed, [loA.name],
-                            fluid.core._global_scope, spaceA, cfg)
-                        assert s is not None, cfg
-                        pair.append(s)
-                    ratios.append(pair[1] / pair[0])
-            finally:
-                exA._in_autotune = False
-                spaceA.apply(tuned_cfg, program=mpA)
-                fluid.core._FLAGS["auto_tune_probe_steps"] = 4
-            best_ratio = min(ratios)
-            assert best_ratio <= 1.05, \
-                (f"tuned config slower than untuned in every pair "
-                 f"(best tuned/untuned {best_ratio:.3f}; "
-                 f"tuned={tuned_cfg} base={base_cfg})")
-
-        # gate 2: a budget below the program's own peak prices every
-        # candidate out from memory_analysis alone — rejected without
-        # executing a single probe step
-        reset_unique_name()
-        mpB, spB = fluid.Program(), fluid.Program()
-        mpB.random_seed = 11
-        with fluid.program_guard(mpB, spB):
-            xb = fluid.data("xb", [-1, 16])
-            hb = fluid.layers.fc(xb, 8, act="tanh")
-            lob = fluid.layers.mean(fluid.layers.fc(hb, 4))
-        mpB._hints["auto_tune"] = True
-        fluid.core._FLAGS["auto_tune_hbm_budget_mb"] = 1e-6
-        exB = fluid.Executor()
-        with _at_scope_guard(_ATScope()):
-            exB.run(spB)
-            c0 = _at_counts()
-            exB.run(mpB, feed={"xb": rng.randn(8, 16).astype("float32")},
-                    fetch_list=[lob])
-            c1 = _at_counts()
-        fluid.core._FLAGS["auto_tune_hbm_budget_mb"] = 0
-        assert c1["probes"] - c0["probes"] == 0, \
-            "OOM-predicted candidates executed probe steps"
-        assert c1["rejects"] - c0["rejects"] > 0
-        oomB = [d for d in at.decisions()
-                if d.get("reason") == "oom_predicted"]
-        assert oomB and all(not d["executed"] for d in oomB)
-        assert all(d["peak_bytes"] > d["budget_bytes"] for d in oomB)
-
-        # gate 3: the serving tuner under live load converges without
-        # ever committing a config whose probe window breached the SLO
-        from paddle_tpu import serving as _at_serving
-        reset_unique_name()
-        engT = _at_serving.build_engine_from_spec(
-            _at_serving.demo_mlp_spec(max_batch=8, max_wait_us=1000,
-                                      auto_tune=True))
-        try:
-            engT.start()
-            tunerT = engT._autotuner
-            assert tunerT is not None
-            tunerT._slo_ms = 5_000.0
-            tunerT._window()             # drain earlier gates' records
-
-            def _at_load(n):
-                fs = [engT.submit({"x": rng.rand(2, 16)
-                                   .astype("float32")})
-                      for _ in range(n)]
-                for f in fs:
-                    f.result(timeout=30)
-
-            for _ in range(4):           # propose/judge rounds
-                _at_load(16)
-                tunerT.tick()
-            servD = [d for d in at.decisions()
-                     if d.get("surface") == "serving"]
-            assert servD, "serving tuner never judged a window"
-            for d in servD:
-                if d.get("action") == "accept" and d.get("window"):
-                    assert d["window"]["p99_ms"] <= d["slo_ms"], \
-                        f"committed a breaching config: {d}"
-            assert engT.max_batch >= 1 and engT.max_wait_us >= 200
-            assert tunerT.committed == {
-                "max_batch": engT.max_batch,
-                "max_wait_us": engT.max_wait_us} or tunerT._pending, \
-                "engine drifted from the tuner's committed config"
-        finally:
-            engT.close()
-
-        # gate 4: seeded determinism — same seed, same proposal order,
-        # for both surfaces (the decision log replays)
-        seqs = [at.training_space(mpA, demo_feed).candidates(seed=5)
-                for _ in range(2)]
-        assert seqs[0] == seqs[1]
-        t1 = at.ServingAutoTuner(engT, seed=9, persist=False)
-        t2 = at.ServingAutoTuner(engT, seed=9, persist=False)
-        assert [t1._neighbours() for _ in range(3)] \
-            == [t2._neighbours() for _ in range(3)]
-
-        # gate 5: warm restart — a fresh "process" (cleared memo, same
-        # regenerated program names) starts tuned with ZERO probes
-        at.reset_for_tests()
-        reset_unique_name()
-        mpW, spW, loW = build_demo()
-        mpW.random_seed = 11
-        assert _at_fp(mpW) == _at_fp(mpA), "restart fingerprint drifted"
-        mpW._hints["auto_tune"] = True
-        exW = fluid.Executor()
-        with _at_scope_guard(_ATScope()):
-            exW.run(spW)
-            c0 = _at_counts()
-            exW.run(mpW, feed=demo_feed, fetch_list=[loW])
-            c1 = _at_counts()
-        assert c1["probes"] - c0["probes"] == 0, \
-            "warm restart re-probed a persisted config"
-        assert c1["warm_starts"] - c0["warm_starts"] == 1
-        dW = at.decisions()[-1]
-        assert dW["source"] == "persisted" and dW["config"] == tuned_cfg
-        atb = at.bench_block()
-        assert atb["enabled"] and atb["chosen"] == tuned_cfg, atb
-    finally:
-        fluid.core._FLAGS.update(_at_saved)
-        at.reset_for_tests()
-        _atsh.rmtree(_at_dir, ignore_errors=True)
-    print(f"[smoke]   autotune: train commit {tuned_cfg} "
-          f"(best tuned/untuned {best_ratio:.3f}), "
-          f"{c1['rejects'] - 0:.0f} total rejects incl. "
-          f"{len(oomB)} OOM-priced (0 probe steps), serving "
-          f"{len(servD)} judged windows 0 breach commits, warm "
-          f"restart 0 probes OK", flush=True)
-
     step("bench.py --quick prints one JSON row naming the cpu, no mfu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--quick"],

@@ -61,7 +61,7 @@ if _ROOT not in sys.path:
 
 
 def build_demo_engine(hidden=64, features=16, classes=10, max_batch=32,
-                      max_wait_us=2000, queue_depth=256, auto_tune=False):
+                      max_wait_us=2000, queue_depth=256):
     """A small frozen mlp + ServingEngine — the ci_smoke serving demo."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu import serving
@@ -77,8 +77,7 @@ def build_demo_engine(hidden=64, features=16, classes=10, max_batch=32,
     frozen = serving.freeze_program(main_p, ["x"], [logits])
     eng = serving.ServingEngine(frozen, executor=exe, max_batch=max_batch,
                                 max_wait_us=max_wait_us,
-                                queue_depth=queue_depth,
-                                auto_tune=auto_tune)
+                                queue_depth=queue_depth)
     return eng, frozen, exe, logits.name, features
 
 
@@ -170,7 +169,7 @@ def slowest_requests(futures, top=5):
 def serve_bench(qps=200.0, n_requests=400, sizes=(1, 2, 4, 8),
                 max_batch=32, max_wait_us=2000, queue_depth=256,
                 hidden=64, deadline_ms=None, metrics_port=None,
-                warmup=True, auto_tune=False):
+                warmup=True):
     """Build the demo engine, warm it, run the open-loop load, and
     return the report dict."""
     from paddle_tpu.fluid import trace, metrics_export
@@ -183,7 +182,7 @@ def serve_bench(qps=200.0, n_requests=400, sizes=(1, 2, 4, 8),
     try:
         eng, frozen, exe, fetch_name, features = build_demo_engine(
             hidden=hidden, max_batch=max_batch, max_wait_us=max_wait_us,
-            queue_depth=queue_depth, auto_tune=auto_tune)
+            queue_depth=queue_depth)
         rng = np.random.RandomState(1)
         pool = rng.randn(max(sizes) * 4, features).astype("float32")
 
