@@ -659,7 +659,10 @@ def roll_paged(tiny, tpu, key):
 def roll_hyper_connection(tiny, tpu, key):
     """``hyper_connection_mix`` and ``hyper_connection_merge`` through their
     lowerings, forward and every gradient, against the ``jnp`` spelling the
-    same lowerings take where the context allows no kernel."""
+    same lowerings take where the context allows no kernel; and with the
+    mix asked to accumulate (attr ``__accumulate__``), a partial dX given
+    as the cotangent of its ``X@ACC`` output, which the mix's second
+    backward kernel adds in, in the partial's own buffer."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.registry import LoweringContext, get_op
@@ -673,19 +676,22 @@ def roll_hyper_connection(tiny, tpu, key):
     attrs = {"n": n, "epsilon": 1e-6, "sinkhorn_iters": 20, "hc_eps": 1e-6,
              "clamp_min": -30.0, "clamp_max": 30.0}
 
-    def fwd_bwd(ctx_type, w):
+    def fwd_bwd(ctx_type, w, accumulate):
         def f(x, phi, alpha, b, z):
             ctx = ctx_type(base_key=key)
             mixed = get_op("hyper_connection_mix").fn(
                 {"X": [x], "Phi": [phi], "Alpha": [alpha], "B": [b]},
-                attrs, ctx)
+                dict(attrs, __accumulate__=("X",)) if accumulate else attrs,
+                ctx)
             out = get_op("hyper_connection_merge").fn(
                 {"X": [x], "Z": [z], "Post": mixed["Post"],
                  "C": mixed["C"]}, {}, ctx)["Out"][0]
             # Y reaches the result in float32: through a bfloat16 branch a
             # last-bit difference in Y would read as a bfloat16 step in Out
-            return (out + jnp.tile(jnp.tanh(mixed["Y"][0]), n),
-                    mixed["RowSumError"][0])
+            out = out + jnp.tile(jnp.tanh(mixed["Y"][0]), n)
+            if accumulate:      # w is then (out's cotangent, the partial)
+                out = (out, mixed["X@ACC"][0])
+            return out, mixed["RowSumError"][0]
 
         def g(*args):
             out, vjp, err = jax.vjp(f, *args, has_aux=True)
@@ -697,8 +703,11 @@ def roll_hyper_connection(tiny, tpu, key):
     limits = dict.fromkeys(names, 1e-4)
     limits.update(dphi=2e-3, dalpha=2e-3, db=2e-3, dz=2e-2)
     cases = []
-    # the training cell's streams, then a ragged last tile
-    for tokens, d in [(256, 128)] if tiny else [(4096, 3584), (128 + 40, 256)]:
+    # the training cell's streams, then a ragged last tile; each plain and
+    # accumulating
+    shapes = [(256, 128)] if tiny else [(4096, 3584), (128 + 40, 256)]
+    for (tokens, d), accumulate in [(s, a) for a in (False, True)
+                                    for s in shapes]:
         ks = jax.random.split(jax.random.fold_in(key, tokens), 5)
         x = jax.random.normal(ks[0], (1, tokens, n * d), jnp.float32)
         phi = 0.02 * jax.random.normal(ks[1], (n * d, k), jnp.float32)
@@ -706,15 +715,22 @@ def roll_hyper_connection(tiny, tpu, key):
         b = 0.5 * jax.random.normal(ks[2], (k,), jnp.float32)
         z = jax.random.normal(ks[3], (1, tokens, d), jnp.bfloat16)
         w = jax.random.normal(ks[4], (1, tokens, n * d), jnp.float32)
+        if accumulate:
+            w = (w, jax.random.normal(jax.random.fold_in(ks[4], 1), w.shape,
+                                      jnp.float32))
         args = (x, phi, alpha, b, z)
-        got, n_calls = run_lowered(fwd_bwd(LoweringContext, w), *args,
-                                   expect_mosaic=tpu)
-        want = fwd_bwd(NoKernel, w)(*args)
+        got, n_calls = run_lowered(fwd_bwd(LoweringContext, w, accumulate),
+                                   *args, expect_mosaic=tpu)
+        want = fwd_bwd(NoKernel, w, accumulate)(*args)
+        if accumulate:      # the streams came back as they went in
+            assert all(bool(jnp.array_equal(o[0][1], x)) for o in (got, want))
+            got, want = ((o[0][0],) + tuple(o[1:]) for o in (got, want))
         errs = {name: rel_err(a, r) for name, a, r in zip(names, got, want)}
         assert all(errs[name] < limits[name] for name in names), errs
         if tpu:
             assert n_calls == 5, n_calls    # mix 1 + 2, merge 1 + 1
-        cases.append({"streams": [tokens, n, d], "mosaic": n_calls,
+        cases.append({"streams": [tokens, n, d], "accumulate": accumulate,
+                      "mosaic": n_calls,
                       "rel_err": {k_: float(f"{v:.2e}")
                                   for k_, v in errs.items()}})
     return {"cases": cases}

@@ -22,11 +22,19 @@ from .rpc import PsClient
 
 class AsyncCommunicator:
     """Fire-and-forget push: grads enqueue, a background thread drains
-    (communicator.h:268).  Pulls always hit the server directly — async
-    PS-SGD reads whatever the server has now."""
+    (communicator.h:268).  Pulls hit the server directly, with bounded
+    staleness: at most ``FLAGS_ps_staleness`` pushes may be outstanding
+    before a pull fences, so what a pull reads does not depend on how fast
+    the drain thread ran (0, the default, = every push lands first; k > 0
+    lets k pushes overlap the next pull, as the reference's unbounded
+    queue does)."""
 
     def __init__(self, client: PsClient, queue_size=64):
+        from ...fluid import core
         self.client = client
+        self.staleness = int(core.get_flag("ps_staleness", 0))
+        self._cv = threading.Condition()
+        self._pushed = self._applied = 0
         self._q: "queue.Queue[Optional[Tuple]]" = queue.Queue(queue_size)
         self._thread = threading.Thread(target=self._drain, daemon=True)
         self._err: Optional[BaseException] = None
@@ -48,6 +56,9 @@ class AsyncCommunicator:
             except BaseException as e:       # noqa: BLE001 — surfaced on next call
                 self._err = e
             finally:
+                with self._cv:
+                    self._applied += 1
+                    self._cv.notify_all()
                 self._q.task_done()
 
     def _check(self):
@@ -55,20 +66,33 @@ class AsyncCommunicator:
             err, self._err = self._err, None
             raise RuntimeError(f"async communicator send failed: {err}")
 
+    def _enqueue(self, item):
+        with self._cv:
+            self._pushed += 1
+        self._q.put(item)
+
+    def _fence(self):
+        """Wait until at most ``staleness`` pushes are outstanding."""
+        with self._cv:
+            while (self._pushed - self._applied > self.staleness
+                   and self._err is None and self._thread.is_alive()):
+                self._cv.wait(0.1)
+        self._check()
+
     def push_sparse(self, name, ids, grads):
         self._check()
-        self._q.put(("sparse", name, np.asarray(ids), np.asarray(grads)))
+        self._enqueue(("sparse", name, np.asarray(ids), np.asarray(grads)))
 
     def push_dense(self, name, grad):
         self._check()
-        self._q.put(("dense", name, np.asarray(grad), None))
+        self._enqueue(("dense", name, np.asarray(grad), None))
 
     def pull_sparse(self, name, ids):
-        self._check()
+        self._fence()
         return self.client.pull_sparse(name, ids)
 
     def pull_dense(self, name):
-        self._check()
+        self._fence()
         return self.client.pull_dense(name)
 
     def flush(self):

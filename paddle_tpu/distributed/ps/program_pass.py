@@ -19,7 +19,9 @@ Choreography per batch (run_program_with_ps):
          the server trajectory equals a single process applying every
          trainer's grads — the oracle the tests check against)
   async  pull -> step -> enqueue pushes on the AsyncCommunicator; no
-         barriers (hogwild over the table, reference communicator.h:268)
+         barriers (hogwild over the table, reference communicator.h:268);
+         a pull waits while more than FLAGS_ps_staleness of this
+         trainer's pushes are unapplied (0 by default)
 
 GEO mode keeps the explicit communicator API (its delta-exchange semantics
 need trainer-local optimizer state, not a server push per batch).

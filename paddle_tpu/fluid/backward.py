@@ -16,6 +16,12 @@ between the two, a `call_op` override) traces the forward lowering again
 over `I_<slot>` and leaves the copy to XLA's CSE, which merges its own ops
 and does not merge two Mosaic kernel calls.  Ops with special grad semantics
 register `custom_grad` (registry.py) and stay on that second route.
+
+Fan-in is a `sum` of a variable's partial gradients, except into an op that
+declares the input slot in `accumulate_grads` (registry.py): the partials
+its later readers' grads gave are handed to its grad op (`G_<slot>@ACC`),
+whose backward adds them into the gradient it computes, so that a Pallas
+backward adds them on the core instead of in a pass of its own.
 """
 from __future__ import annotations
 
@@ -24,7 +30,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set
 import jax
 import jax.numpy as jnp
 
-from ..ops.registry import register_op, get_op, has_op
+from ..ops.registry import accumulate_slot, register_op, get_op, has_op
 from . import trace
 from .framework import Program, Block, Variable, Parameter
 
@@ -43,7 +49,8 @@ def _is_float(x) -> bool:
 # ---------------------------------------------------------------------------
 # the generic grad op
 # ---------------------------------------------------------------------------
-def lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx, grad_slots):
+def lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx, grad_slots,
+                    accumulate=()):
     """Call the forward op's lowering once, under ``jax.vjp`` over its
     differentiable inputs: ``(outs, (primal_outs, vjp_fn))``.  ``outs`` is
     everything the lowering returned (what ``run_block_ops`` writes to
@@ -53,7 +60,13 @@ def lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx, grad_slots):
 
     Differentiable: the float args of ``grad_slots`` outside
     ``nondiff_inputs`` (per arg, by runtime dtype); every other input is
-    closed over, non-float and ``nondiff_outputs`` outputs ride as aux."""
+    closed over, non-float and ``nondiff_outputs`` outputs ride as aux.
+    ``accumulate``: the grad op's ``accumulate`` attr, the slots whose
+    partial the lowering is asked (its attr ``__accumulate__``) to take as
+    the cotangent of its ``accumulate_slot`` output
+    (``OpDef.accumulate_grads``)."""
+    if accumulate:
+        fwd_attrs = dict(fwd_attrs, __accumulate__=tuple(accumulate))
     diff_tree, closed = {}, {}
     for s, args in fwd_ins.items():
         if s in fwd_def.nondiff_inputs or s not in grad_slots:
@@ -78,7 +91,10 @@ def lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx, grad_slots):
 
 @register_op("generic_grad", differentiable=False)
 def _generic_grad(ins, attrs, ctx):
-    """ins:  I_<slot> forward inputs, G_<slot> output cotangents.
+    """ins:  I_<slot> forward inputs, G_<slot> output cotangents
+          (``G_<slot>@ACC``: a partial gradient the backward adds into
+          GI_<slot>, for the slots of attr ``accumulate``,
+          ``OpDef.accumulate_grads``).
     outs: GI_<slot> input cotangents (only for slots listed in grad_slots).
 
     Applies the ``jax.vjp`` that ``run_block_ops`` kept when it lowered the
@@ -108,7 +124,7 @@ def _generic_grad(ins, attrs, ctx):
             return {"GI_" + s: v for s, v in in_grads.items()
                     if s in grad_slots}
         _, kept = lower_under_vjp(fwd_def, fwd_ins, fwd_attrs, ctx,
-                                  grad_slots)
+                                  grad_slots, attrs.get("accumulate", ()))
 
     primal_outs, vjp_fn = kept
     cotangents = {}
@@ -289,22 +305,29 @@ def append_backward(loss: Variable, parameter_list=None,
         if not has_any_outgrad:
             continue
 
+        into = _accumulating(op, opdef, grad_slots, grads)
+        for slot in into:
+            g_ins["G_" + accumulate_slot(slot)] = [
+                _sum_partials(block, n, grads) for n in op.input(slot)]
         g_outs: Dict[str, List[str]] = {}
         for slot in grad_slots:
             outs = []
             for n in op.input(slot):
-                gname = _grad_name(n)
-                if n in grads or gname in {x for v in grads.values() for x in v}:
-                    gname = gname + "@RENAME_" + str(len(grads.get(n, [])))
+                gname = _partial_name(block, n, grads)
                 outs.append(gname)
-                grads.setdefault(n, []).append(gname)
+                if slot in into:        # the gradient so far, with this op's
+                    grads[n] = [gname]
+                else:
+                    grads.setdefault(n, []).append(gname)
             g_outs["GI_" + slot] = outs
 
-        block.append_op(
-            "generic_grad", inputs=g_ins, outputs=g_outs,
-            attrs={"fwd_type": op.type, "fwd_attrs": dict(op.attrs),
+        g_attrs = {"fwd_type": op.type, "fwd_attrs": dict(op.attrs),
                    "in_slots": list(op.inputs.keys()),
-                   "grad_slots": grad_slots, "op_role": 1})
+                   "grad_slots": grad_slots, "op_role": 1}
+        if into:
+            g_attrs["accumulate"] = into
+        block.append_op("generic_grad", inputs=g_ins, outputs=g_outs,
+                        attrs=g_attrs)
         for slot_outs in g_outs.values():
             for n in slot_outs:
                 block.var(n).stop_gradient = True
@@ -325,6 +348,33 @@ def append_backward(loss: Variable, parameter_list=None,
     return result
 
 
+def _accumulating(op, opdef, grad_slots, grads) -> List[str]:
+    """The slots of ``op``'s grad that take the partial gradients found so
+    far as an operand to add into (``OpDef.accumulate_grads``) instead of
+    leaving them to a ``sum``: a slot whose every variable has partials
+    already (from the grads of its later readers) and is no other input
+    of ``op``."""
+    names, written = op.input_arg_names, op.output_arg_names
+    return [s for s in opdef.accumulate_grads if s in grad_slots
+            and all(n in grads and names.count(n) == 1
+                    and n not in written for n in op.input(s))]
+
+
+def _partial_name(block: Block, name: str, grads: Dict[str, List[str]]) -> str:
+    """A new partial gradient's name: ``name@GRAD`` for the first,
+    ``name@GRAD@RENAME_<k>`` with ``k`` the partials held, raised past a
+    name in use (a partial an accumulating grad op took, or a sum's)."""
+    gname = _grad_name(name)
+    taken = {x for v in grads.values() for x in v}
+    if name not in grads and gname not in taken:
+        return gname
+    k = len(grads.get(name, []))
+    while f"{gname}@RENAME_{k}" in taken \
+            or block.has_var(f"{gname}@RENAME_{k}"):
+        k += 1
+    return f"{gname}@RENAME_{k}"
+
+
 def _sum_partials(block: Block, name: str, grads: Dict[str, List[str]]) -> str:
     """Collapse accumulated partial grads into one var (fluid's inserted
     `sum` op for fan-in, backward.py _addup_repetitive_outputs_)."""
@@ -333,8 +383,10 @@ def _sum_partials(block: Block, name: str, grads: Dict[str, List[str]]) -> str:
         final = parts[0]
     else:
         final = _grad_name(name)
-        if final in parts:
+        if final in parts or block.has_var(final):
             tmp = final + "@SUM"
+            while block.has_var(tmp):
+                tmp += "@SUM"
             block.append_op("sum", inputs={"X": parts},
                             outputs={"Out": [tmp]}, attrs={"op_role": 1})
             final = tmp

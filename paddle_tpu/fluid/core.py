@@ -271,10 +271,13 @@ _FLAGS: Dict[str, object] = {
         "FLAGS_fleet_breaker_failures", "5") or 5),
     "fleet_breaker_cooldown_s": float(_os.environ.get(
         "FLAGS_fleet_breaker_cooldown_s", "3.0") or 3.0),
-    # sharded parameter server (distributed/ps/sharded.py,
+    # parameter server (distributed/ps/sharded.py and the trainer-side
+    # AsyncCommunicator, distributed/ps/communicator.py;
     # docs/parameter_server.md).  ps_staleness bounds how many async
-    # pushes may be outstanding before a pull fences (0 = fully
-    # synchronous = bit-parity with the single-table baseline);
+    # pushes may be outstanding before a pull fences, in the sharded PS
+    # and in the async communicator alike (0 = fully synchronous =
+    # bit-parity with the single-table baseline); the rest are the
+    # sharded PS's:
     # ps_hot_rows caps each shard's hot RAM tier (0 = untired);
     # ps_snapshot_every takes an incremental snapshot after every N
     # logged mutations (0 = manual snapshots only); ps_wal_fsync forces

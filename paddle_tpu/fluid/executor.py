@@ -401,7 +401,8 @@ def run_block_ops(block: Block, env: Dict[str, Any], ctx: LoweringContext,
             elif grad_op is not None:
                 read = [(n, env[n]) for n in op.input_arg_names if n in env]
                 outs, vjp = backward.lower_under_vjp(
-                    opdef, ins, op_attrs, ctx, grad_op.attrs["grad_slots"])
+                    opdef, ins, op_attrs, ctx, grad_op.attrs["grad_slots"],
+                    grad_op.attrs.get("accumulate", ()))
                 kept_vjps[id(grad_op)] = (vjp, read)
             else:
                 if "SkipUpdate" in ins:   # GradientMerge k-step gate

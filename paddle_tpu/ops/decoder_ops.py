@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..parallel import moe
-from .registry import register_op
+from .registry import accumulate_slot, register_op
 
 
 @register_op("rms_norm")
@@ -298,7 +298,8 @@ def _mixer_kernels(ctx, x, n):
     return pk if use else None
 
 
-@register_op("hyper_connection_mix", nondiff_outputs=("RowSumError",))
+@register_op("hyper_connection_mix", nondiff_outputs=("RowSumError",),
+             accumulate_grads=("X",))
 def _hyper_connection_mix(ins, attrs, ctx):
     """X [..., n * d] float32 streams, Phi [n * d, n * n + 2 n], Alpha [3],
     B [n * n + 2 n] -> Y [..., d] = sum_i pre_i X[i], the branch's input;
@@ -306,7 +307,14 @@ def _hyper_connection_mix(ins, attrs, ctx):
     epsilon)`` over the token's whole row, the matmul at the highest
     precision (its 24 outputs decide how every stream moves).  RowSumError
     [1] is the largest ``|row sum of C - 1|`` over the call's tokens, kept
-    on the device and read by the host when a runner drains."""
+    on the device and read by the host when a runner drains.
+
+    With ``X`` in the attr ``__accumulate__`` (set by
+    ``backward.lower_under_vjp`` for a grad op that accumulates, see
+    ``OpDef.accumulate_grads``) it also returns ``X@ACC``, X
+    again, whose cotangent (the stream's gradient from its other readers)
+    the kernels' backward adds into dX inside its second kernel, in dX's
+    buffer; the ``jnp`` spelling leaves that add to XLA's fusions."""
     x = ins["X"][0]
     n = int(attrs["n"])
     lead = x.shape[:-1]
@@ -315,10 +323,15 @@ def _hyper_connection_mix(ins, attrs, ctx):
     iters, hc_eps = int(attrs["sinkhorn_iters"]), float(attrs["hc_eps"])
     clamp = (float(attrs["clamp_min"]), float(attrs["clamp_max"]))
     pk = _mixer_kernels(ctx, x, n)
+    passes_x = "X" in attrs.get("__accumulate__", ())
+    again = x
     if pk:
-        y, post, c, row_error = pk.hyper_connection_mix_tpu(
+        got = pk.hyper_connection_mix_tpu(
             x.reshape(-1, x.shape[-1]), phi, alpha, b, n, epsilon, iters,
-            hc_eps, clamp)
+            hc_eps, clamp, accumulate=passes_x)
+        y, post, c, row_error = got[:4]
+        if passes_x:
+            again = got[4].reshape(x.shape)
     else:
         rows = x.astype(jnp.float32).reshape(-1, x.shape[-1])
         inv = jax.lax.rsqrt(jnp.mean(jnp.square(rows), axis=-1,
@@ -332,10 +345,13 @@ def _hyper_connection_mix(ins, attrs, ctx):
             hc_eps, clamp)
         y = sum(pre[:, i:i + 1] * xi
                 for i, xi in enumerate(_streams(rows, n)))
-    return {"Y": [y.reshape(lead + (y.shape[-1],))],
-            "Post": [post.reshape(lead + (n,))],
-            "C": [c.reshape(lead + (n * n,))],
-            "RowSumError": [row_error.reshape(1)]}
+    out = {"Y": [y.reshape(lead + (y.shape[-1],))],
+           "Post": [post.reshape(lead + (n,))],
+           "C": [c.reshape(lead + (n * n,))],
+           "RowSumError": [row_error.reshape(1)]}
+    if passes_x:
+        out[accumulate_slot("X")] = [again]
+    return out
 
 
 @register_op("hyper_connection_merge")

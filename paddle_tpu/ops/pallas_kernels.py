@@ -1918,12 +1918,16 @@ def _hc_mix_dpre_kernel(x_ref, dy_ref, dpre_ref, cols_ref, *, n):
     dpre_ref[...] = jnp.transpose(cols_ref[...])[:dpre_ref.shape[0]]
 
 
-def _hc_mix_dx_kernel(x_ref, dy_ref, g_ref, phit_ref, dx_ref, dphit_ref,
-                      cols_ref, *, n, tokens):
+def _hc_mix_dx_kernel(x_ref, dy_ref, g_ref, phit_ref, *refs, n, tokens,
+                      accumulate):
     """``g_ref`` [kp, tile]: rows [0, k) the projections' gradient times the
     rsqrt, rows [k, k + n) pre, row k + n the rsqrt's own term; ``phit_ref``
     has zeros from row k on, so the extra rows add nothing to dX (their rows
-    of dPhi^T are dropped by the caller)."""
+    of dPhi^T are dropped by the caller).  With ``accumulate`` the first of
+    ``refs`` is a partial dX (the caller aliases its buffer to dX's) that
+    is added in."""
+    acc_ref = refs[0] if accumulate else None
+    dx_ref, dphit_ref, cols_ref = refs[1:] if accumulate else refs
     tile, d = dy_ref.shape
     k = _hc_rows(n)[0]
     g, x = g_ref[...], x_ref[...]
@@ -1950,8 +1954,10 @@ def _hc_mix_dx_kernel(x_ref, dy_ref, g_ref, phit_ref, dx_ref, dphit_ref,
             dy = dy_ref[rows, s:s + w]
             for i in range(n):
                 at = slice(i * d + s, i * d + s + w)
-                dx_ref[rows, at] += (col[:, k + i:k + i + 1] * dy
-                                     + rs * x_ref[rows, at])
+                term = col[:, k + i:k + i + 1] * dy + rs * x_ref[rows, at]
+                if accumulate:
+                    term = term + acc_ref[rows, at]
+                dx_ref[rows, at] += term
 
     _for_row_chunks(tile, finish)
 
@@ -2006,12 +2012,13 @@ def _hc_merge_bwd_kernel(do_ref, x_ref, z_ref, coef_ref, dx_ref, dz_ref,
 
 
 def _hc_call(kernel, tokens, tile, in_specs, out_specs, out_shape, scratch,
-             operands, smem_first=False):
+             operands, smem_first=False, aliases=None):
     """One grid step per tile of tokens, in order (the accumulators of the
     mix need it; v5e has one core).  Specs are ``("row", width)`` for a
     [T, width] array blocked by token rows, ``("lane", rows)`` for a
     [rows, T] array blocked by token lanes, ``("whole", shape)`` for an
-    array every step sees whole."""
+    array every step sees whole.  ``aliases``: {operand: output} whose
+    buffers are one."""
     def spec(kind, arg):
         if kind == "row":
             return pl.BlockSpec((tile, arg), lambda i: (i, 0))
@@ -2026,6 +2033,7 @@ def _hc_call(kernel, tokens, tile, in_specs, out_specs, out_shape, scratch,
         out_specs=[spec(*s) for s in out_specs], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((tile, _LANES), jnp.float32)
                         for _ in range(scratch)],
+        input_output_aliases=aliases or {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_HC_VMEM_LIMIT_BYTES),
@@ -2059,19 +2067,19 @@ def _hc_mix_forward(x, phi, alpha, b, n, statics, tile):
     return (y, coef[:n].T, coef[n:n + n * n].T, err[0, :1]), m
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _hc_mix(x, phi, alpha, b, n, statics, tile):
-    return _hc_mix_forward(x, phi, alpha, b, n, statics, tile)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _hc_mix(x, phi, alpha, b, n, statics, tile, passes_x):
+    return _hc_mix_fwd(x, phi, alpha, b, n, statics, tile, passes_x)[0]
 
 
-def _hc_mix_fwd(x, phi, alpha, b, n, statics, tile):
+def _hc_mix_fwd(x, phi, alpha, b, n, statics, tile, passes_x):
     out, m = _hc_mix_forward(x, phi, alpha, b, n, statics, tile)
-    return out, (x, phi, alpha, b, m)
+    return (out + (x,) if passes_x else out), (x, phi, alpha, b, m)
 
 
-def _hc_mix_bwd(n, statics, tile, res, cts):
+def _hc_mix_bwd(n, statics, tile, passes_x, res, cts):
     x, phi, alpha, b, m = res
-    dy, dpost, dc, _ = cts
+    dy, dpost, dc = cts[:3]
     tokens, width = x.shape
     k, kp, _ = _hc_rows(n)
     f32 = jnp.float32
@@ -2097,15 +2105,22 @@ def _hc_mix_bwd(n, statics, tile, res, cts):
     rs = -jnp.sum(dmt * mt, axis=0, keepdims=True) * inv * inv / width
     packed = jnp.concatenate(
         [g, pre, rs, jnp.zeros((kp - k - n - 1, tokens), f32)], axis=0)
+    specs = [("row", width), ("row", width // n), ("lane", kp),
+             ("whole", (kp, width))]
+    operands = [x, dy, packed, _hc_phit(phi, n)]
+    if passes_x:
+        # the partial dX of the streams' other readers: added on the core,
+        # dX written into its buffer
+        specs.append(("row", width))
+        operands.append(cts[4].astype(f32))
     dx, dphit = _hc_call(
-        functools.partial(_hc_mix_dx_kernel, n=n, tokens=tokens),
-        tokens, tile,
-        [("row", width), ("row", width // n), ("lane", kp),
-         ("whole", (kp, width))],
+        functools.partial(_hc_mix_dx_kernel, n=n, tokens=tokens,
+                          accumulate=passes_x),
+        tokens, tile, specs,
         [("row", width), ("whole", (kp, width))],
         [jax.ShapeDtypeStruct((tokens, width), f32),
          jax.ShapeDtypeStruct((kp, width), f32)],
-        1, [x, dy, packed, _hc_phit(phi, n)])
+        1, operands, aliases={4: 0} if passes_x else None)
     return (dx, dphit[:k].T.astype(phi.dtype), dalpha.astype(alpha.dtype),
             dbt.reshape(k).astype(b.dtype))
 
@@ -2113,22 +2128,26 @@ def _hc_mix_bwd(n, statics, tile, res, cts):
 _hc_mix.defvjp(_hc_mix_fwd, _hc_mix_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
-def _hc_mix_jit(x, phi, alpha, b, n, statics, tile):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _hc_mix_jit(x, phi, alpha, b, n, statics, tile, passes_x):
     """One trace per (shapes, statics), shared by a program's mixers."""
-    return _hc_mix(x, phi, alpha, b, n, statics, tile)
+    return _hc_mix(x, phi, alpha, b, n, statics, tile, passes_x)
 
 
 def hyper_connection_mix_tpu(x, phi, alpha, b, n, epsilon, iters, hc_eps,
-                             clamp, tile=None):
+                             clamp, tile=None, accumulate=False):
     """The mixer before a branch (``hyper_connection_mix``'s contract):
     ``x`` [T, n * d] float32 streams -> (Y [T, d], Post [T, n], C [T, n *
     n], RowSumError [1]).  Forward is one kernel (1.25 passes over the
     streams); backward is two with the coefficients' gradient between them
-    in XLA (3.5 passes)."""
+    in XLA (3.5 passes).  ``accumulate``: also return ``x`` itself, whose
+    cotangent the second backward kernel adds into dX (one more pass, read
+    into dX's own buffer), so that a partial gradient of the streams from
+    elsewhere costs no add of its own."""
     statics = (float(epsilon), int(iters), float(hc_eps),
                (float(clamp[0]), float(clamp[1])))
-    return _hc_mix_jit(x, phi, alpha, b, int(n), statics, tile or _HC_TILE)
+    return _hc_mix_jit(x, phi, alpha, b, int(n), statics, tile or _HC_TILE,
+                       bool(accumulate))
 
 
 def _hc_merge_coef(post, c, n):

@@ -52,6 +52,14 @@ class OpDef:
     nondiff_reason: Optional[str] = None
     stateful_rng: bool = False           # needs a PRNG key (dropout, *_random)
     custom_grad: Optional[Callable] = None  # (ins, outs, out_grads, attrs, ctx) -> in_grads
+    # input slots whose gradient the op's backward can add a given partial
+    # into (fluid/backward.py hands a variable's other partial gradients to
+    # the grad op instead of summing them after it).  Where the attr
+    # ``__accumulate__`` names such a slot, the lowering also returns
+    # ``accumulate_slot(slot)``: that input again, so that the cotangent
+    # of this output, the partial, is added into the slot's gradient by
+    # whatever backward the lowering's spelling has
+    accumulate_grads: Sequence[str] = ()
     # optional shape/dtype inference for IR bookkeeping (advisory; XLA retraces)
     infer: Optional[Callable] = None
     # True for user plugin ops (load_op_library) — outside the framework's
@@ -84,6 +92,13 @@ def get_op(type: str) -> OpDef:
 
 def has_op(type: str) -> bool:
     return type in _OP_REGISTRY
+
+
+def accumulate_slot(slot: str) -> str:
+    """The output a lowering adds for an input slot it accumulates into
+    (``OpDef.accumulate_grads``); its grad op reads the partial as
+    ``G_<this>``."""
+    return slot + "@ACC"
 
 
 def all_ops() -> List[str]:

@@ -392,3 +392,123 @@ def test_kept_vjp_charges_forward_and_backward_to_their_ops():
                                                    "mean_grad"}
     assert not any("pd:f:" in n for n in bwd)
     assert not any("pd:b:" in n for n in fwd)
+
+
+# ---------------------------------------------------------------------------
+# (e) a grad op that adds the partials of its input's other readers
+#     (``OpDef.accumulate_grads``): the mixer of a multi-stream residual
+# ---------------------------------------------------------------------------
+
+def _streams_program(third, fold):
+    """Streams [2, 6, 4 x 8] out of an fc, read by a mixer, its merge and,
+    with ``third`` ("before" or "after" the mixer), a third op.  ``fold``:
+    the mixer's grad takes the streams' other partials as it declares;
+    else the registry is told it declares nothing, and a ``sum`` adds
+    them, as before the declaration."""
+    reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with pytest.MonkeyPatch.context() as mp:
+        if not fold:
+            mp.setattr(registry.get_op("hyper_connection_mix"),
+                       "accumulate_grads", ())
+        with fluid.program_guard(main, startup):
+            e = _data("e", [2, 6, 8])
+            stream = L.fc(e, 32, num_flatten_dims=2, act="tanh")
+            extra = [L.sin(stream)] if third == "before" else []
+            y, post, c = L.hyper_connection_mix(stream, 4, alpha_init=1.0)
+            out = L.hyper_connection_merge(stream, L.tanh(y), post, c)
+            if third == "after":
+                extra.append(L.sin(stream))
+            loss = L.mean(L.elementwise_mul(out, out))
+            for t in extra:
+                loss = loss + L.mean(t)
+            backward.append_backward(loss)
+    return main, startup, loss, stream.name
+
+
+def _streams_gradients(third, fold, pair=True):
+    main, startup, loss, stream = _streams_program(third, fold)
+    block = main.global_block()
+    fetch = [loss.name, "e@GRAD"] + sorted(
+        p.name + "@GRAD" for p in main.all_parameters())
+    feed = {"e": np.random.RandomState(2).randn(2, 6, 8).astype("float32")}
+    before = _counts()
+    with pytest.MonkeyPatch.context() as mp:
+        if not pair:
+            mp.setattr(backward, "pair_grads", lambda ops: {})
+        exe = fluid.Executor()
+        with scope_guard(Scope()):
+            exe.run(startup)
+            vals = exe.run(main, feed=feed, fetch_list=fetch)
+        exe.close()
+    return [np.asarray(v) for v in vals], _moved(before), block, stream
+
+
+def _partials(block, stream):
+    """{grad op type: its partial of ``stream``}, and the ``sum`` ops that
+    add such partials."""
+    def of_stream(n):
+        return n.startswith(stream + "@GRAD")
+    made = {op.attrs["fwd_type"]: n for op in block.ops
+            if op.type == "generic_grad"
+            for n in op.output_arg_names if of_stream(n)}
+    return made, [op for op in block.ops if op.type == "sum"
+                  and any(of_stream(n) for n in op.input("X"))]
+
+
+@pytest.mark.parametrize("third", [None, "before", "after"])
+def test_mixer_grad_adds_the_other_readers_partials(third):
+    """The streams' gradient is the same with and without the fold, and
+    every reader's partial reaches it: the merge's (and a later reader's)
+    ride into the mixer's grad, an earlier reader's is summed after it."""
+    got, moved, block, stream = _streams_gradients(third, fold=True)
+    want, moved_want, plain_block, _ = _streams_gradients(third, fold=False)
+    assert moved == moved_want and moved["retraced"] == 0
+    assert len(got) == len(want) >= 6
+    for a, b in zip(got, want):
+        # float32 adds of the same partials in another order
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    assert all(np.abs(v).sum() > 0 for v in got)
+
+    made, sums = _partials(block, stream)
+    plain_made, plain_sums = _partials(plain_block, stream)
+    readers = {"hyper_connection_mix", "hyper_connection_merge"} \
+        | ({"sin"} if third else set())
+    assert set(made) == set(plain_made) == readers
+    mix = next(op for op in block.ops if op.type == "generic_grad"
+               and op.attrs["fwd_type"] == "hyper_connection_mix")
+    taken, = mix.inputs["G_X@ACC"]
+    assert mix.attrs["accumulate"] == ["X"]
+    assert not any("accumulate" in op.attrs for op in plain_block.ops)
+    # without the fold: one sum of every partial; with it, the mixer's grad
+    # takes what came before it and a sum is left only for a reader that
+    # comes before the mixer in the forward (its grad comes after)
+    assert [sorted(op.input("X")) for op in plain_sums] \
+        == [sorted(plain_made.values())]
+    if third == "after":
+        assert [sorted(op.input("X")) for op in sums] == [
+            sorted([made["hyper_connection_merge"], made["sin"]])]
+        assert taken == sums[0].output("Out")[0]
+    else:
+        assert taken == made["hyper_connection_merge"]
+        assert [sorted(op.input("X")) for op in sums] == (
+            [sorted([made["hyper_connection_mix"], made["sin"]])]
+            if third == "before" else [])
+    # every partial has a name of its own
+    names = [n for op in block.ops for n in op.output_arg_names]
+    assert len(names) == len(set(names))
+
+
+def test_mixer_grad_adds_the_partial_when_it_traces_the_forward_again():
+    """The fallback route (no pairing: the grad lowers the forward again)
+    asks the lowering for the same pass-through and gives the kept
+    route's gradients bit for bit."""
+    kept, moved, block, _ = _streams_gradients("after", fold=True)
+    again, moved_again, _, _ = _streams_gradients("after", fold=True,
+                                                  pair=False)
+    n_grads = sum(op.type == "generic_grad" for op in block.ops)
+    assert moved == {"kept": n_grads, "retraced": 0}
+    assert moved_again == {"kept": 0, "retraced": n_grads}
+    for a, b in zip(kept, again):
+        assert np.array_equal(a, b)

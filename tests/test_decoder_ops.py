@@ -1,6 +1,7 @@
 """The ops of a causal decoder block (ops/decoder_ops.py, and the attention
 op's ``causal`` / ``window`` / grouped heads) against plain jnp spellings,
 forward and gradient, through the op registry and ``generic_grad``."""
+import contextlib
 import math
 
 import jax
@@ -645,6 +646,92 @@ def test_mixer_kernels_equal_the_jnp_spelling(mixer_kernels_and_spelling,
     tol = 1e-2 if name == "dZ" else 2e-5
     np.testing.assert_allclose(got, want, rtol=tol,
                                atol=tol * np.abs(want).max())
+
+
+def test_mixer_backward_adds_the_partial_it_is_given_in_interpret_mode():
+    """``accumulate=True``: the mix also returns its streams, and the
+    cotangent given for them (a partial dX from the streams' other
+    readers) comes out added into dX by the second backward kernel, in the
+    partial's own buffer; the other gradients are the plain backward's.
+    Whole tiles: the interpreter cannot alias an operand of a ragged grid
+    (it pads the operand and hands the padded buffer back as dX); the
+    ragged case is compiled for the chip in test_pallas_preflight.py."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import pallas_kernels as pk
+    n, d = 4, 256
+    ins = _mixer_inputs(t=96, d=d, seed=13)
+    args = (ins["X"], ins["Phi"] * 0.2, ins["Alpha"], ins["B"])
+    partial = _rand(96, n * d, seed=14)
+
+    def mix(accumulate):
+        return lambda *a: pk.hyper_connection_mix_tpu(
+            *a, n, 1e-6, 20, 1e-6, (-30.0, 30.0), tile=32,
+            accumulate=accumulate)
+    with jax.default_matmul_precision("highest"), \
+            pltpu.force_tpu_interpret_mode():
+        plain, plain_vjp = jax.vjp(mix(False), *args)
+        cot = tuple(_rand(*o.shape, seed=30 + i) for i, o in enumerate(plain))
+        want = plain_vjp(cot)
+        again, vjp = jax.vjp(mix(True), *args)
+        got = vjp(cot + (partial,))
+    assert len(again) == 5 and np.array_equal(again[4], args[0])
+    for a, b in zip(again, plain):
+        assert np.array_equal(a, b)
+    # the partial joins the kernel's own float32 sum of dX's terms, in
+    # another order than dX + partial: a rounding or two of the larger
+    scale = float(jnp.max(jnp.abs(want[0]) + jnp.abs(partial)))
+    np.testing.assert_allclose(np.asarray(got[0]),
+                               np.asarray(want[0] + partial),
+                               rtol=0, atol=4 * 2.0 ** -23 * scale)
+    assert float(jnp.max(jnp.abs(partial))) > 1.0
+    for a, b in zip(got[1:], want[1:]):
+        assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("on_chip", [False, True])
+def test_mixer_lowering_returns_its_streams_to_accumulate_into(on_chip):
+    """The op's contract (``OpDef.accumulate_grads``): with ``X`` in the
+    attr ``__accumulate__`` the lowering also returns ``X@ACC``, X again,
+    and the gradient of X is its own plus X@ACC's cotangent, on the
+    ``jnp`` spelling and through the kernels (under the interpreter)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.registry import accumulate_slot
+
+    class OnTheChip(LoweringContext):
+        def pallas_ok(self):
+            return True
+
+    assert get_op("hyper_connection_mix").accumulate_grads == ("X",)
+    ins = _mixer_inputs(t=256, d=128, seed=6)
+    x3 = ins["X"].reshape(2, 128, 512)
+    partial = _rand(2, 128, 512, seed=7)
+    ctx = OnTheChip() if on_chip else LoweringContext()
+
+    def run(x, accumulate):
+        attrs = dict(_MIX_ATTRS, __accumulate__=("X",)) if accumulate \
+            else _MIX_ATTRS
+        return get_op("hyper_connection_mix").fn(
+            {"X": [x], "Phi": [ins["Phi"]], "Alpha": [ins["Alpha"]],
+             "B": [ins["B"]]}, attrs, ctx)
+
+    def y_of(x, accumulate):
+        out = run(x, accumulate)
+        extra = out[accumulate_slot("X")][0] if accumulate else None
+        return out["Y"][0], extra
+    with jax.default_matmul_precision("highest"), \
+            (pltpu.force_tpu_interpret_mode() if on_chip
+             else contextlib.nullcontext()):
+        assert accumulate_slot("X") not in run(x3, False)
+        (y, _), vjp = jax.vjp(lambda x: y_of(x, False), x3)
+        dy = _rand(*y.shape, seed=8)
+        want, = vjp((dy, None))
+        (y2, again), vjp2 = jax.vjp(lambda x: y_of(x, True), x3)
+        got, = vjp2((dy, partial))
+    assert again.shape == x3.shape and np.array_equal(again, x3)
+    assert np.array_equal(y2, y)
+    scale = float(jnp.max(jnp.abs(want) + jnp.abs(partial)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want + partial),
+                               rtol=0, atol=4 * 2.0 ** -23 * scale)
 
 
 def test_mixer_lowerings_take_the_kernels_where_the_context_allows():

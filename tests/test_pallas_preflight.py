@@ -249,21 +249,27 @@ class TestRepoKernels:
 
     @pytest.mark.parametrize("op, tokens, z_dtype", [
         ("mix", 4096, None), ("merge", 4096, "float32"),
-        ("mix", 4096 + 40, None), ("merge", 4096 + 40, "bfloat16")])
+        ("mix", 4096 + 40, None), ("merge", 4096 + 40, "bfloat16"),
+        ("mix_accumulate", 4096, None), ("mix_accumulate", 4096 + 40, None)])
     def test_hyper_connection_mixers_fwd_bwd(self, op, tokens, z_dtype):
         """Both mixer ops, forward and backward (five kernels), at
         ``xing4_train_seq4096``'s shape: 4096 tokens of four float32 streams
         of 3584, ``Phi`` [14336, 24], the branch's output widened by XLA
         before the merge sees it; and with a ragged last tile, the branch's
-        output in bfloat16."""
+        output in bfloat16.  ``mix_accumulate``: the mix's backward adds a
+        partial dX in its second kernel (one more stream-wide block, its
+        buffer dX's), also over a ragged last tile, which the interpreter
+        cannot alias."""
         f32 = jnp.float32
         n, d = 4, 3584
         x = jax.ShapeDtypeStruct((tokens, n * d), f32)
         assert pk.hyper_connection_supported(x, n)
-        if op == "mix":
+        if op.startswith("mix"):
             def f(x, phi, alpha, b):
-                return pk.hyper_connection_mix_tpu(
-                    x, phi, alpha, b, n, 1e-6, 20, 1e-6, (-30.0, 30.0))[:3]
+                outs = pk.hyper_connection_mix_tpu(
+                    x, phi, alpha, b, n, 1e-6, 20, 1e-6, (-30.0, 30.0),
+                    accumulate=op == "mix_accumulate")
+                return outs[:3] + outs[4:]
             args = (x, jax.ShapeDtypeStruct((n * d, n * n + 2 * n), f32),
                     jax.ShapeDtypeStruct((3,), f32),
                     jax.ShapeDtypeStruct((n * n + 2 * n,), f32))

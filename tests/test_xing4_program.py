@@ -3,6 +3,7 @@ xing4_29b_a4b_train) through Program -> passes -> Executor, at a small size
 on the CPU: against its float32 reference with and without the MTP module,
 under AMP, its counts, the shares of the eight chips added up, the
 device-side gauges, and its four readers on a hand-made trace."""
+import fnmatch
 import os
 
 import jax
@@ -14,6 +15,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import trace
 from paddle_tpu.fluid.core import Scope, scope_guard
 from paddle_tpu.fluid.framework import reset_unique_name
+from paddle_tpu.ops.registry import get_op
 from paddle_tpu.parallel import moe
 
 from benchmark.harness import compare, program_ops
@@ -129,6 +131,77 @@ def test_the_comparisons_program_is_the_timed_steps_twin(mtp):
     assert step[:len(check)] == check
     rest = {t for t, *_ in step[len(check):]}
     assert "adam" in rest and "generic_grad" not in rest
+
+
+def _mixer_fold(mtp, fold):
+    """The comparison's program (forward and backward) of the small
+    configuration, the mixers' grads taking the streams' other partial
+    gradients as their op declares (``fold``) or, with the declaration
+    taken away, leaving them to a ``sum``: the loss and every parameter's
+    gradient of one run, the vjp counters it moved, and the program."""
+    cfg, mix, model, _ = _small(mtp)
+    kind = REG.module("traffic_kinds", mix["kind"] + ".py")
+    batch = kind.generate(mix, cfg, 11, 2, n_batches=1)[0]
+    with pytest.MonkeyPatch.context() as mp:
+        if not fold:
+            mp.setattr(get_op("hyper_connection_mix"), "accumulate_grads", ())
+        reset_unique_name()
+        built = model.build(cfg, mix, train=False)
+    built["startup"].random_seed = 11
+    names = sorted(built["grads"])
+    counters = [trace.metrics().counter("backward.vjp_" + k)
+                for k in ("kept", "retraced")]
+    exe = fluid.Executor()
+    with scope_guard(Scope()):
+        exe.run(built["startup"])
+        scope = fluid.global_scope()
+        for pattern, value in MIXING.items():
+            for name in fnmatch.filter(names, pattern):
+                scope.set_var(name, jnp.full_like(scope.find_var(name),
+                                                  value))
+        before = [c.value for c in counters]
+        vals = exe.run(built["main"], feed=batch,
+                       fetch_list=[built["loss"].name]
+                       + [built["grads"][n] for n in names])
+        moved = [c.value - b for c, b in zip(counters, before)]
+    exe.close()
+    return (dict(zip(["loss"] + names, map(np.asarray, vals))), moved,
+            built["main"].global_block())
+
+
+def _sums_of_mixer_grads(block):
+    made = {n for op in block.ops if op.type == "generic_grad"
+            and op.attrs["fwd_type"] == "hyper_connection_mix"
+            for n in op.output("GI_X")}
+    return [op for op in block.ops
+            if op.type == "sum" and made & set(op.input("X"))]
+
+
+@pytest.mark.parametrize("mtp", [0, 1])
+def test_mixer_grads_take_the_streams_other_partials(mtp):
+    """Each stream feeds its mixer and its merge: the merge's partial
+    gradient rides into the mixer's grad, and no ``sum`` adds a mixer
+    grad's output; the gradients are the unfolded spelling's, and the
+    forward is still lowered once (the vjp counters are the same)."""
+    got, moved, block = _mixer_fold(mtp, fold=True)
+    want, moved_want, plain = _mixer_fold(mtp, fold=False)
+    mixers = sum(op.type == "hyper_connection_mix" for op in block.ops)
+    assert mixers == (8 if mtp else 6)
+    assert len(_sums_of_mixer_grads(plain)) == mixers
+    assert _sums_of_mixer_grads(block) == []
+    count = [sum(op.type == "sum" for op in b.ops) for b in (plain, block)]
+    assert count[0] - count[1] == mixers
+    assert all("G_X@ACC" in op.inputs and op.attrs["accumulate"] == ["X"]
+               for op in block.ops if op.type == "generic_grad"
+               and op.attrs["fwd_type"] == "hyper_connection_mix")
+    # the ops with a custom_grad re-trace, the rest keep, as before
+    assert moved == moved_want and moved[0] > 100
+    assert got.keys() == want.keys() and len(got) == (91 if mtp else 66)
+    for name, w in want.items():
+        # float32 adds of the same partials in another order, carried
+        # through the layers below
+        err = np.linalg.norm(got[name] - w) / np.linalg.norm(w)
+        assert err < 1e-5, (name, err)
 
 
 def test_amp_keeps_mixers_norms_router_and_loss_in_float32():
